@@ -5,7 +5,7 @@ rate) as the last single-chip perf lever.  This harness produces the
 evidence for the measured lever table in docs/benchmarks.md in one
 command:
 
-1. calibrates the chip's matmul roofline (the ``bench.py`` 8192^3 bf16
+1. calibrates the chip's matmul roofline (an 8192^3 bf16 matmul
    probe — the honest denominator: the rate a perfect MXU-bound kernel
    could sustain),
 2. sweeps every VMEM-feasible (block_q, block_k) pair at the flagship
@@ -37,6 +37,32 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 BWD_VARIANTS = ("pallas", "pallas_onepass", "chunked")
 
 
+def probe_peak_flops(jax, jnp):
+    """Calibrated roofline: sustained rate of a large bf16 matmul
+    chain, by differential (2N - N) windows that each end in a fetch of
+    one scalar of the last product."""
+    import numpy as np
+    n = 1024 if jax.devices()[0].platform == "cpu" else 8192
+    a = jnp.ones((n, n), jnp.bfloat16)
+    b = (jnp.eye(n, dtype=jnp.float32) * 1.0001).astype(jnp.bfloat16)
+    f = jax.jit(lambda a, b: a @ b)
+    fetch = jax.jit(lambda v: v[0, 0].astype(jnp.float32))
+    float(np.asarray(fetch(f(a, b))))
+
+    def run(k):
+        t0 = time.perf_counter()
+        c = a
+        for _ in range(k):
+            c = f(c, b)
+        float(np.asarray(fetch(c)))
+        return time.perf_counter() - t0
+
+    run(5)
+    t1, t2 = run(10), run(20)
+    dt = max((t2 - t1) / 10, 1e-9)
+    return 2 * n ** 3 / dt
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=2048)
@@ -60,7 +86,6 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import probe_peak_flops
     from horovod_tpu.ops import pallas_kernels as pk
 
     causal = bool(args.causal)

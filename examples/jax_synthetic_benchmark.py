@@ -46,8 +46,9 @@ def main():
     opt = optax.sgd(0.01, momentum=0.9)
     step, opt_init = hvd.make_data_parallel_step(
         loss_fn, opt, compression=hvd.Compression.bf16)
-    opt_state = opt_init(params)
     params = hvd.broadcast_parameters(params, root_rank=0)
+    # Placed like the step returns it, or the second step recompiles.
+    opt_state = hvd.broadcast_optimizer_state(opt_init(params))
 
     world = hvd.size()
     global_bs = args.batch_size * world

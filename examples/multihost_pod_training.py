@@ -12,8 +12,9 @@ collectives over the global device mesh.  Shows both API levels:
   device-resident end to end (metric averaging, debugging, custom
   loops).
 
-Run on a real pod with one process per host, or locally on the CPU
-test world:
+Run on a real pod with one process per host, on one TPU host with one
+process per chip (the launcher binds each local slot to its own chip),
+or locally on the CPU test world:
 
     JAX_PLATFORMS=cpu python -m horovod_tpu.runner -np 2 --multihost \
       python examples/multihost_pod_training.py
@@ -70,8 +71,7 @@ def main(steps: int = 20, batch_per_rank: int = 32, lr: float = 0.05):
                 print("step %d: mean loss %.4f"
                       % (i, float(np.asarray(avg)[0])), flush=True)
 
-    if rank == 0:
-        print("DONE", flush=True)
+    print("DONE rank=%d size=%d" % (rank, world), flush=True)
     hvd.shutdown()
 
 

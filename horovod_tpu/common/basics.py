@@ -110,6 +110,14 @@ def init(devices: Optional[Sequence] = None,
         if config.timeline:
             timeline.initialize(config.timeline, config.timeline_mark_cycles)
 
+        import sys
+        if mode != "tcp" or "jax" in sys.modules:
+            # Before this process compiles anything.  A tcp worker
+            # that never imports jax (host payloads only) is not made
+            # to pay for the import here.
+            from .device import place_compile_cache
+            place_compile_cache()
+
         # Collective-plan plane (persistent autotuned plans): fresh
         # state per init — an elastic re-init re-loads/adopts against
         # the (possibly resized) world's fingerprint.

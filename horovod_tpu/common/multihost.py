@@ -100,56 +100,13 @@ def init_jax_distributed(config, rank: int, size: int):
     # keep the runtime's exit propagation — a member death should kill
     # the world there, loudly and everywhere; elastic worlds get
     # survival plus the explicit teardown barrier below.
-    recoverable = False
     if _is_elastic_world():
-        try:
-            jax.config.update("jax_enable_recoverability", True)
-            recoverable = True
-        except Exception:  # noqa: BLE001 - older jax without the option
-            pass
+        jax.config.update("jax_enable_recoverability", True)
     coordinator = resolve_coordinator(config, rank, size)
     LOG.info("multihost: joining jax.distributed at %s as %d/%d",
              coordinator, rank, size)
-    kwargs = {}
-    if _is_elastic_world() and not recoverable:
-        # Elastic world on a jax without recoverability: the
-        # coordination service's own failure detector would PUSH a
-        # fatal error into every surviving client the moment a member
-        # misses heartbeats (LOG(FATAL) in the runtime client's
-        # default callbacks — the survivor dies mid-recovery, killed
-        # by the payload plane's bookkeeping).  Failure detection is
-        # Horovod's job here (stall inspector, device-exec watchdog,
-        # elastic driver), so disarm the runtime's: heartbeat
-        # tolerance far beyond any job's rejoin window.  Worlds WITH
-        # recoverability keep defaults (the runtime then degrades
-        # gracefully by design), as do static worlds (member death
-        # should kill the world loudly — reference semantics).
-        kwargs = dict(service_max_missing_heartbeats=100000,
-                      client_max_missing_heartbeats=100000)
-    try:
-        jax.distributed.initialize(coordinator_address=coordinator,
-                                   num_processes=size, process_id=rank,
-                                   **kwargs)
-    except TypeError:
-        if not kwargs:
-            raise
-        # Public wrapper without the heartbeat knobs (e.g. jax 0.4.x):
-        # the private State.initialize has carried them for longer —
-        # same module the teardown barrier uses.  Last resort is the
-        # armed-detector default, loudly.
-        try:
-            from jax._src import distributed as _dist
-            _dist.global_state.initialize(
-                coordinator_address=coordinator, num_processes=size,
-                process_id=rank, **kwargs)
-        except (ImportError, AttributeError, TypeError):
-            LOG.warning(
-                "this jax cannot disarm the coordination service's "
-                "failure detector; if a member dies, runtime error "
-                "propagation may kill elastic survivors mid-recovery")
-            jax.distributed.initialize(coordinator_address=coordinator,
-                                       num_processes=size,
-                                       process_id=rank)
+    jax.distributed.initialize(coordinator_address=coordinator,
+                               num_processes=size, process_id=rank)
     init_jax_distributed._done = True
     # Verify the world actually formed.  A backend plugin (or any JAX
     # computation before hvd.init()) can pre-initialize the runtime, in
@@ -238,24 +195,18 @@ def _abandon_jax_distributed():
     last instant.  A leaked client/service pair per in-process world
     re-formation is the price of surviving a broken world on runtimes
     without recoverability."""
-    try:
-        import ctypes
+    import ctypes
 
-        from jax._src import distributed as _dist
-        gs = _dist.global_state
-        for obj in (getattr(gs, "client", None),
-                    getattr(gs, "service", None)):
-            if obj is not None:
-                ctypes.pythonapi.Py_IncRef(ctypes.py_object(obj))
-                _ABANDONED_RUNTIMES.append(obj)
-        gs.client = None
-        gs.service = None
-        gs.preemption_sync_manager = None
-        gs.coordinator_address = None
-    except Exception:  # noqa: BLE001 - version-dependent internals
-        LOG.warning("could not abandon the jax distributed state; "
-                    "elastic rejoin may fail to re-initialize",
-                    exc_info=True)
+    from jax._src import distributed as _dist
+    gs = _dist.global_state
+    for obj in (gs.client, gs.service):
+        if obj is not None:
+            ctypes.pythonapi.Py_IncRef(ctypes.py_object(obj))
+            _ABANDONED_RUNTIMES.append(obj)
+    gs.client = None
+    gs.service = None
+    gs.preemption_sync_manager = None
+    gs.coordinator_address = None
 
 
 def shutdown_jax_distributed():
@@ -279,14 +230,6 @@ def shutdown_jax_distributed():
         # lets the next init form the resized world; live jax.Arrays
         # from the old world become invalid, which is why elastic state
         # commits store host (numpy) copies.
-        try:
-            import jax.extend.backend as _jeb
-            _jeb.clear_backends()
-        except Exception:  # noqa: BLE001 - version-dependent API
-            try:
-                from jax._src import api as _api
-                _api.clear_backends()
-            except Exception:  # noqa: BLE001
-                LOG.warning("could not clear XLA backends; in-process "
-                            "elastic rejoin may fail to re-initialize")
+        import jax.extend.backend
+        jax.extend.backend.clear_backends()
         init_jax_distributed._done = False

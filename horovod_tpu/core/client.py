@@ -43,7 +43,11 @@ _build_lock = threading.Lock()
 
 
 def build_library(force: bool = False) -> str:
-    """Compile the core if the .so is missing or stale.
+    """Compile the core if the .so is missing or older than its
+    sources; ``force=True`` rebuilds it from ``src/`` whatever is on
+    disk (``make clean`` first: objects copied from another machine
+    carry mtimes that mean nothing here).  A machine without a
+    compiler fails here, loudly.
 
     ``HVD_TPU_CORE_LIB`` overrides the library outright (no build):
     the sanitizer test nodes compile ``make SANITIZE=thread`` side
@@ -67,8 +71,15 @@ def build_library(force: bool = False) -> str:
                 for f in os.listdir(src_dir))
             if not stale:
                 return _LIB_PATH
-        subprocess.run(["make", "-j", "-s"], cwd=_CORE_DIR, check=True,
-                       capture_output=True)
+        for target in (["clean"], []) if force else ([],):
+            proc = subprocess.run(
+                ["make", "-j", "-s", *target], cwd=_CORE_DIR,
+                capture_output=True, text=True)
+            if proc.returncode != 0:
+                raise RuntimeError(
+                    "building the native core failed (make %s, rc=%d):"
+                    "\n%s" % (" ".join(target), proc.returncode,
+                              proc.stderr[-2000:]))
         return _LIB_PATH
 
 
@@ -140,32 +151,24 @@ def load_library():
     lib.hvd_tcp_autotune_observe.argtypes = [ctypes.c_ulonglong,
                                              ctypes.c_double]
     lib.hvd_tcp_autotune_observe.restype = None
-    try:
-        # r14 symbols: a stale pre-plan-cache .so must degrade the warm
-        # start (TcpCore guards the call sites), never fail library
-        # load for every tcp/multihost init.
-        lib.hvd_tcp_autotune_warm_start.argtypes = [ctypes.c_ulonglong,
-                                                    ctypes.c_double,
-                                                    ctypes.c_int]
-        lib.hvd_tcp_autotune_warm_start.restype = None
-        lib.hvd_tcp_autotune_state.argtypes = [
-            ctypes.POINTER(ctypes.c_ulonglong),
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
-            ctypes.POINTER(ctypes.c_int)]
-        lib.hvd_tcp_autotune_state.restype = None
-    except AttributeError:
-        pass
-    try:
-        # r22 symbols: steady-state fast path (frozen schedules) — a
-        # stale .so keeps its normal idle cadence; the Python engine
-        # guards the call sites.
-        lib.hvd_tcp_set_fastpath.argtypes = [ctypes.c_int]
-        lib.hvd_tcp_set_fastpath.restype = None
-        lib.hvd_tcp_fastpath_idle_rounds.argtypes = []
-        lib.hvd_tcp_fastpath_idle_rounds.restype = ctypes.c_ulonglong
-    except AttributeError:
-        pass
+    # A library that lacks any symbol below does not match src/ and
+    # fails here, at load (ctypes names the symbol).
+    lib.hvd_tcp_autotune_warm_start.argtypes = [ctypes.c_ulonglong,
+                                                ctypes.c_double,
+                                                ctypes.c_int]
+    lib.hvd_tcp_autotune_warm_start.restype = None
+    lib.hvd_tcp_autotune_state.argtypes = [
+        ctypes.POINTER(ctypes.c_ulonglong),
+        ctypes.POINTER(ctypes.c_double),
+        ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
+        ctypes.POINTER(ctypes.c_int)]
+    lib.hvd_tcp_autotune_state.restype = None
+    lib.hvd_tcp_set_fastpath.argtypes = [ctypes.c_int]
+    lib.hvd_tcp_set_fastpath.restype = None
+    lib.hvd_tcp_fastpath_idle_rounds.argtypes = []
+    lib.hvd_tcp_fastpath_idle_rounds.restype = ctypes.c_ulonglong
+    lib.hvd_tcp_stopped.argtypes = []
+    lib.hvd_tcp_stopped.restype = ctypes.c_int
     lib.hvd_tcp_kernel_tune_record.argtypes = [ctypes.c_int,
                                                ctypes.c_double]
     lib.hvd_tcp_kernel_tune_record.restype = None
@@ -470,10 +473,7 @@ class TcpCore:
         """True once the background loop aborted (negotiation failure /
         peer disconnect): pending work was failed core-side and no
         further cycles will run."""
-        try:
-            return bool(self._lib.hvd_tcp_stopped())
-        except AttributeError:  # stale .so without the symbol
-            return False
+        return bool(self._lib.hvd_tcp_stopped())
 
     def external_done(self, handle: int, ok: bool = True,
                       error: str = ""):
@@ -488,44 +488,27 @@ class TcpCore:
     def set_fastpath(self, on: bool):
         """Stretch (on) / restore (off) the background loop's idle
         negotiation cadence while the engine's frozen schedule makes
-        rounds pointless.  No-op on a stale .so — the fast path still
-        works, the core just keeps polling at normal cycle time."""
-        try:
-            fn = self._lib.hvd_tcp_set_fastpath
-        except AttributeError:  # stale .so: degrade, don't fail
-            return
-        fn(1 if on else 0)
+        rounds pointless."""
+        self._lib.hvd_tcp_set_fastpath(1 if on else 0)
 
     def fastpath_idle_rounds(self) -> int:
         """Negotiation rounds the core skipped (stretched) while the
-        fast path was on, for levers.fastpath attribution; 0 on a
-        stale .so."""
-        try:
-            fn = self._lib.hvd_tcp_fastpath_idle_rounds
-        except AttributeError:  # stale .so: degrade, don't fail
-            return 0
-        return int(fn())
+        fast path was on, for levers.fastpath attribution."""
+        return int(self._lib.hvd_tcp_fastpath_idle_rounds())
 
     def autotune_warm_start(self, fusion_threshold: int,
                             cycle_time_ms: float, converged: bool):
         """Adopt a persisted plan's tuned operating point (plan-cache
         warm start): converged plans freeze the rank-0 tuner at the
         point; unconverged ones resume sampling there with a single
-        warm-up cycle left.  No-op on a stale .so."""
-        try:
-            fn = self._lib.hvd_tcp_autotune_warm_start
-        except AttributeError:  # stale .so: degrade, don't fail init
-            return
-        fn(int(fusion_threshold), float(cycle_time_ms),
-           1 if converged else 0)
+        warm-up cycle left."""
+        self._lib.hvd_tcp_autotune_warm_start(
+            int(fusion_threshold), float(cycle_time_ms),
+            1 if converged else 0)
 
-    def autotune_state(self) -> Optional[dict]:
-        """Native tuner snapshot for plan persistence, or None on a
-        stale .so without the symbol."""
-        try:
-            fn = self._lib.hvd_tcp_autotune_state
-        except AttributeError:  # stale .so: degrade, don't fail shutdown
-            return None
+    def autotune_state(self) -> dict:
+        """Native tuner snapshot for plan persistence."""
+        fn = self._lib.hvd_tcp_autotune_state
         fusion = ctypes.c_ulonglong()
         cycle = ctypes.c_double()
         converged = ctypes.c_int()

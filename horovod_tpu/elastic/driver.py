@@ -784,6 +784,14 @@ class ElasticDriver:
             "HOROVOD_SECRET_KEY": self._secret,
             "HOROVOD_ELASTIC_TIMEOUT": str(self.elastic_timeout),
         })
+        with self._lock:
+            shares_host = any(h == host and i != idx
+                              for h, i in self._target)
+        if shares_host:
+            # The slot index is the one per-host number that survives
+            # every epoch, so it names the worker's chip.
+            from ..runner.launch import own_chip_env
+            env.update(own_chip_env(idx))
         if self.tenant_id is not None:
             # Tenant identity travels with the worker: KV namespace,
             # spill subdirectory and @tenant= fault targeting all key

@@ -21,13 +21,12 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..common import basics
-from ..ops.xla_ops import AVERAGE
+from ..ops.xla_ops import AVERAGE, host_or_device
 from . import spmd
 from .compression import Compression
 from .optimizer import DistributedOptimizer
@@ -100,7 +99,7 @@ def shard_batch(batch):
 
         return jax.tree.map(put, batch)
     return jax.tree.map(
-        lambda x: jax.device_put(jnp.asarray(x), sharding), batch)
+        lambda x: jax.device_put(host_or_device(x), sharding), batch)
 
 
 def replicate(tree):
@@ -109,7 +108,7 @@ def replicate(tree):
     mesh = _world_mesh()
     sharding = NamedSharding(mesh, P())
     return jax.tree.map(
-        lambda x: jax.device_put(jnp.asarray(x), sharding), tree)
+        lambda x: jax.device_put(host_or_device(x), sharding), tree)
 
 
 def fetch(tree):

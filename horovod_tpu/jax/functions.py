@@ -12,12 +12,12 @@ import pickle
 from typing import Any, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..common import basics
 from ..common.process_sets import ProcessSet
 from ..ops import api as eager
+from ..ops.xla_ops import host_or_device
 
 
 def _replicate(tree):
@@ -26,7 +26,7 @@ def _replicate(tree):
     mc = eng.collectives_for(0)
     sharding = mc._replicated_sharding
     return jax.tree.map(
-        lambda x: jax.device_put(jnp.asarray(x), sharding), tree)
+        lambda x: jax.device_put(host_or_device(x), sharding), tree)
 
 
 def broadcast_parameters(params, root_rank: int = 0,

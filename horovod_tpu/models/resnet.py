@@ -44,10 +44,8 @@ def _pallas_bn_enabled() -> bool:
         return False
     if v == "force":
         return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    from ..common.device import on_tpu
+    return on_tpu()
 
 
 class NormAct(nn.Module):

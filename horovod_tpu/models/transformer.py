@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common import jax_compat  # noqa: F401 - installs shard_map/axis_size shims
 from ..parallel.moe import MoeConfig, moe_ffn
 from ..parallel.ring_attention import local_attention, ring_attention
 
@@ -266,15 +265,16 @@ def vocab_parallel_cross_entropy(logits_local, targets, tp_axis: str):
 
 
 def _use_flash_attention() -> bool:
-    """Pallas flash attention is the TPU default; interpret-mode is too
-    slow for training loops elsewhere (set HOROVOD_FLASH_ATTENTION=0/1
-    to force)."""
+    """Pallas flash attention on the TPU backend; plain XLA attention
+    on the CPU test world, where the interpreted kernel is too slow for
+    training loops (set HOROVOD_FLASH_ATTENTION=0/1 to force).  Any
+    other backend is an error (``common/device.py``)."""
     import os
     flag = os.environ.get("HOROVOD_FLASH_ATTENTION")
     if flag is not None:
         return flag not in ("0", "false", "False")
-    from ..ops.pallas_kernels import _on_tpu
-    return _on_tpu()
+    from ..common.device import on_tpu
+    return on_tpu()
 
 
 def _attention_block(x, lp, cfg: TransformerConfig, cos, sin, sp_size):

@@ -319,7 +319,7 @@ class CollectiveEngine:
 
     def enqueue_allreduce(self, name, stacked, red_op, prescale, postscale,
                           process_set_id) -> CollectiveHandle:
-        arr = jnp.asarray(stacked)
+        arr = xla_ops.host_or_device(stacked)
         return self._enqueue(name, _OP_ALLREDUCE, arr, red_op=red_op,
                              prescale=prescale, postscale=postscale,
                              process_set_id=process_set_id,
@@ -330,7 +330,8 @@ class CollectiveEngine:
                              process_set_id=process_set_id)
 
     def enqueue_broadcast(self, name, stacked, root_rank, process_set_id):
-        return self._enqueue(name, _OP_BROADCAST, jnp.asarray(stacked),
+        return self._enqueue(name, _OP_BROADCAST,
+                             xla_ops.host_or_device(stacked),
                              root_rank=root_rank,
                              process_set_id=process_set_id)
 
@@ -339,7 +340,8 @@ class CollectiveEngine:
                              process_set_id=process_set_id)
 
     def enqueue_reducescatter(self, name, stacked, red_op, process_set_id):
-        return self._enqueue(name, _OP_REDUCESCATTER, jnp.asarray(stacked),
+        return self._enqueue(name, _OP_REDUCESCATTER,
+                             xla_ops.host_or_device(stacked),
                              red_op=red_op, process_set_id=process_set_id)
 
     def enqueue_barrier(self, name, process_set_id):
@@ -607,7 +609,8 @@ class CollectiveEngine:
                 # never retroactive.
                 if not joined_idx:
                     return stacked
-                return stacked.at[jnp.asarray(joined_idx)].set(0)
+                return mc.shard_stacked(stacked).at[
+                    jnp.asarray(joined_idx)].set(0)
 
             # Average over live contributors: zero is not Average's
             # identity, so dividing by the full member count would bias

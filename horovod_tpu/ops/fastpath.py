@@ -362,10 +362,7 @@ def describe() -> Dict[str, Any]:
         "planes": planes,
     }
     if _CORE_ROUNDS is not None:
-        try:
-            out["core_idle_rounds_skipped"] = int(_CORE_ROUNDS())
-        except Exception:  # noqa: BLE001 - stale .so, degraded attribution
-            out["core_idle_rounds_skipped"] = None
+        out["core_idle_rounds_skipped"] = int(_CORE_ROUNDS())
     return out
 
 

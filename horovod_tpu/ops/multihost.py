@@ -654,9 +654,7 @@ class GlobalMeshCollectives:
         # The static replication/vma checker cannot see through the
         # axis_index masking / per-process static slicing these
         # programs use; the negotiation contract guarantees consistent
-        # collectives, so disable it.  jax.shard_map is always the
-        # vma-era API here: xla_ops (imported above) installs a
-        # translating shim on older jax.
+        # collectives, so disable it.
         mapped = jax.shard_map(
             fn, mesh=mesh if mesh is not None else self.mesh,
             in_specs=(in_spec if in_spec is not None
@@ -1678,9 +1676,7 @@ class MultihostEngine:
             plane_name="multihost", on_thaw=self._fp_flush,
             stage_lock=self._fp_lock)
         fastpath.register(self._fp)
-        rounds = getattr(core, "fastpath_idle_rounds", None)
-        if rounds is not None:
-            fastpath.set_core_rounds_provider(rounds)
+        fastpath.set_core_rounds_provider(core.fastpath_idle_rounds)
         self._m_fp_frozen = metrics.counter("fastpath_frozen_cycles_total")
         self._m_fp_bucket = metrics.histogram(
             "engine_overlap_bucket_seconds")
@@ -2007,16 +2003,8 @@ class MultihostEngine:
 
     def _fp_core_set(self, on: bool):
         """Tell the native core to stretch its idle negotiation cadence
-        while frozen (no requests will arrive); tolerate a stale .so
-        without the export — the fast path works without it, the core
-        just keeps polling at the normal cycle time."""
-        set_fp = getattr(self.core, "set_fastpath", None)
-        if set_fp is None:
-            return
-        try:
-            set_fp(bool(on))
-        except Exception:  # noqa: BLE001 - optional, stale .so
-            pass
+        while frozen (no requests will arrive)."""
+        self.core.set_fastpath(bool(on))
 
     def _fp_idle_check(self):
         """Partial-cycle safety valve (exec thread, every drain tick):

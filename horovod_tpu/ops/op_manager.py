@@ -233,11 +233,11 @@ class InProcessIciBackend(CollectiveBackend):
         return req.op_type in DEVICE_OPS
 
     def _stack(self, tensor, ps_size):
-        import jax.numpy as jnp
+        from .xla_ops import host_or_device, stack_rows
         if isinstance(tensor, (list, tuple)):
-            arr = jnp.stack([jnp.asarray(t) for t in tensor])
+            arr = stack_rows(tensor)
         else:
-            arr = jnp.asarray(tensor)
+            arr = host_or_device(tensor)
         if arr.shape[0] != ps_size:
             raise ValueError(
                 "expected rank-major stacked input with leading dim %d "
@@ -246,9 +246,8 @@ class InProcessIciBackend(CollectiveBackend):
         return arr
 
     def submit(self, req: OpRequest):
-        import jax.numpy as jnp
         from .engine import CollectiveHandle
-        from .xla_ops import ADASUM
+        from .xla_ops import ADASUM, host_or_device, stack_rows
         eng = self._get_engine()
         if req.op_type == "allreduce":
             if req.red_op == ADASUM:
@@ -279,11 +278,11 @@ class InProcessIciBackend(CollectiveBackend):
         if req.op_type == "allgather":
             def one_allgather(t, n):
                 if isinstance(t, (list, tuple)):
-                    per_rank = [jnp.asarray(x) for x in t]
+                    per_rank = [host_or_device(x) for x in t]
                     if len(per_rank) != req.ps_size:
                         raise ValueError("need one tensor per rank")
                 else:
-                    arr = jnp.asarray(t)
+                    arr = host_or_device(t)
                     per_rank = [arr[r] for r in range(req.ps_size)]
                 return eng.enqueue_allgather(n, per_rank,
                                              req.process_set_id)
@@ -304,12 +303,12 @@ class InProcessIciBackend(CollectiveBackend):
         if req.op_type == "alltoall":
             splits = req.splits
             if isinstance(t, (list, tuple)):
-                t = jnp.stack([jnp.asarray(x) for x in t]) \
-                    if splits is None else [jnp.asarray(x) for x in t]
+                t = stack_rows(t) if splits is None \
+                    else [host_or_device(x) for x in t]
             if splits is not None:
                 splits = np.asarray(splits)
                 if isinstance(t, list):
-                    t = jnp.stack(t) if len(
+                    t = stack_rows(t) if len(
                         {x.shape for x in t}) == 1 else t
             return eng.enqueue_alltoall(n, t, splits, req.process_set_id)
         raise HorovodInternalError("unsupported op %s" % req.op_type)

@@ -20,7 +20,7 @@ TPU-native equivalent of that vendor-kernel dependence, in the same
 spirit as ``pallas_kernels.py`` (SURVEY.md §7 phase 7).
 
 All kernels run compiled on TPU and through the Pallas interpreter
-off-TPU, so the CPU test world exercises the same code path; tests
+on the CPU test world (``common/device.py`` decides); tests
 compare y/dx/dgamma/dbeta/dres against an f32 XLA oracle
 (tests/test_pallas_bn.py).
 """
@@ -34,12 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+from ..common.device import on_tpu
 
 
 def _largest_divisor(m: int, cap: int) -> Optional[int]:
@@ -333,7 +328,7 @@ def _bn_act_apply(x2, gamma, beta, res2, mean, var, eps, relu, plan):
     """
     fold, c_blk = plan
     return _apply(x2, mean, var, gamma, beta, res2, c_blk, eps,
-                  relu, not _on_tpu())
+                  relu, not on_tpu())
 
 
 def _bn_act_apply_fwd(x2, gamma, beta, res2, mean, var, eps, relu,
@@ -346,7 +341,7 @@ def _bn_act_apply_fwd(x2, gamma, beta, res2, mean, var, eps, relu,
 def _bn_act_apply_bwd(eps, relu, plan, saved, dy):
     x2, gamma, beta, res2, mean, var = saved
     fold, c_blk = plan
-    interpret = not _on_tpu()
+    interpret = not on_tpu()
     mv, cv = x2.shape
     c = cv // fold
     # Raw per-view-column sums: exactly the cotangents of the TILED
@@ -388,7 +383,7 @@ def batch_norm_act(x, gamma, beta, residual=None, *, eps: float = 1e-5,
     mv, cv = m // fold, c * fold
     x2 = x.reshape(mv, cv)  # row-major: free view
     res2 = None if residual is None else residual.reshape(mv, cv)
-    interpret = not _on_tpu()
+    interpret = not on_tpu()
     # stop_gradient BEFORE the stats kernel: its x-dependence is folded
     # into the fused backward's dx formula, so the pallas_call itself
     # must never be traced for autodiff.

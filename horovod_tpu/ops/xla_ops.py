@@ -25,8 +25,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..common import jax_compat  # noqa: F401 - installs older-jax shims
-
 from .executable_cache import ExecutableCache
 
 AXIS = "hvd"
@@ -82,6 +80,26 @@ def product_allreduce(flat, axis_name: str, size: int):
     return full[:n]
 
 
+def host_or_device(x):
+    """``x`` as the engine carries it until placement: a ``jax.Array``
+    stays on its devices, anything else becomes host numpy in jax's
+    canonical dtype.  Host data is never committed to the default
+    device on the way: ``MeshCollectives.shard_stacked`` sends each
+    row straight to its own chip."""
+    if isinstance(x, jax.Array):
+        return x
+    x = np.asarray(x)
+    return x.astype(jax.dtypes.canonicalize_dtype(x.dtype), copy=False)
+
+
+def stack_rows(rows):
+    """Rank-major stack of per-rank pieces; built on the host unless a
+    piece already lives on a device."""
+    if any(isinstance(r, jax.Array) for r in rows):
+        return jnp.stack([jnp.asarray(r) for r in rows])
+    return host_or_device(np.stack([np.asarray(r) for r in rows]))
+
+
 def uneven_chunks(total_rows: int, n: int):
     """Reference ReducescatterOp chunk math: earlier members take the
     larger shards (cpu_ops.cc uses the same base/remainder split).
@@ -133,7 +151,7 @@ class MeshCollectives:
 
     def shard_stacked(self, x):
         """Place a rank-major stacked array so row r lives on device r."""
-        return jax.device_put(jnp.asarray(x), self._stacked_sharding)
+        return jax.device_put(host_or_device(x), self._stacked_sharding)
 
     def _key(self, op: str, dtype, shape, extra=()) -> tuple:
         return (self.name, op, str(dtype), tuple(shape)) + tuple(extra)
@@ -286,8 +304,7 @@ class MeshCollectives:
         """
         dims0 = {np.shape(t)[0] if np.ndim(t) else 1 for t in per_rank}
         if len(dims0) == 1:
-            stacked = jnp.stack([jnp.asarray(t) for t in per_rank])
-            stacked = self.shard_stacked(stacked)
+            stacked = self.shard_stacked(stack_rows(per_rank))
             key = self._key("allgather", stacked.dtype, stacked.shape)
             fn = self.cache.get_or_build(key, self._build_allgather)
             return fn(stacked)
@@ -337,7 +354,7 @@ class MeshCollectives:
         ``AlltoallOp`` with ``splits`` argument): single-controller
         reassembly; returns (stacked_out_list, recv_splits).
         """
-        stacked = jnp.asarray(stacked)
+        stacked = host_or_device(stacked)
         n = stacked.shape[1] if stacked.ndim > 1 else 0
         if splits is None:
             if stacked.shape[0] != self.size or n % self.size != 0:

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common import jax_compat  # noqa: F401 - installs lax.axis_size shim
-
 
 @dataclasses.dataclass(frozen=True)
 class MoeConfig:

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common import jax_compat  # noqa: F401 - installs lax.axis_size shim
-
 
 def pipeline_apply(stage_params, microbatches, stage_fn: Callable,
                    axis_name: str = "pp"):

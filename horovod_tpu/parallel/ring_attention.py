@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common import jax_compat  # noqa: F401 - installs lax.axis_size shim
-
 NEG_INF = -1e30
 
 
@@ -60,19 +58,14 @@ def _repeat_kv(kv, n_rep: int):
 
 def pvary_missing(v, axes):
     """Mark ``v`` varying over any of ``axes`` it is not already
-    varying over (vma tracking for check_vma=True shard_maps); identity
-    when tracking is off.  Loop carries must enter with the
-    varying-axes superset their outputs acquire."""
-    try:
-        have = jax.typeof(v).vma
-    except Exception:  # noqa: BLE001 - no vma tracking in this trace
-        return v
+    varying over (vma tracking for check_vma=True shard_maps).  Loop
+    carries must enter with the varying-axes superset their outputs
+    acquire."""
+    have = jax.typeof(v).vma
     missing = tuple(a for a in axes if a not in have)
     if not missing:
         return v
-    if hasattr(lax, "pcast"):
-        return lax.pcast(v, missing, to="varying")
-    return lax.pvary(v, missing)  # older jax spelling
+    return lax.pcast(v, missing, to="varying")
 
 
 def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
@@ -119,8 +112,8 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
         v_nxt = lax.ppermute(v_cur, axis_name, perm)
         return k_nxt, v_nxt, m, l, acc
 
-    vma = getattr(jax.typeof(q), "vma", ())
-    init = tuple(pvary_missing(c, tuple(vma)) for c in
+    vma = tuple(jax.typeof(q).vma)
+    init = tuple(pvary_missing(c, vma) for c in
                  (k, v, m0, l0, acc0))
     _, _, m, l, acc = lax.fori_loop(0, n, body, init)
     l_t = l.transpose(0, 2, 1)[..., None]

@@ -19,8 +19,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-from ..common import jax_compat  # noqa: F401 - installs lax.axis_size shim
-
 from .ring_attention import local_attention
 
 

@@ -764,7 +764,7 @@ def finalize(tcp_core=None, engine=None):
             note_tuned(pm.fusion_threshold, pm.cycle_time_ms, pm.frozen)
         if tcp_core is not None:
             st = tcp_core.autotune_state()
-            if st is not None and st["samples"] > 0:
+            if st["samples"] > 0:
                 note_tuned(st["fusion_threshold"], st["cycle_time_ms"],
                            bool(st["converged"]))
         persist()
