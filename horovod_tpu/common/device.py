@@ -37,11 +37,18 @@ def place_compile_cache() -> str:
     ``<checkout>/.jax_cache`` — a fixed path, because the path is part
     of what makes a later process find the entry.  Every process of a
     job (the engine's executor thread, the launcher's workers, the
-    smoke's legs) passes through ``hvd.init()`` and so lands here."""
+    smoke's legs) passes through ``hvd.init()`` and so lands here.
+
+    Wherever it lives, an entry is keyed with its operations' names and
+    source lines: JAX's default strips them from the key, and a step
+    would then be handed an executable compiled before it named its
+    parts (``common/scopes.py``), whose optimized HLO, which is where a
+    profile's operations find their scope, names nothing."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path
-    import jax
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -1,0 +1,45 @@
+"""The names the compiled step gives its own parts.
+
+A ``jax.named_scope`` is metadata written while a step is traced: every
+operation traced under it carries the scope in its ``op_name``
+(``jit(step)/jvp(hvd.model)/dot_general``), the optimized HLO of the
+loaded executable keeps it (a fusion carries its root's), and the
+profiler's trace viewer shows it.  Nothing runs on the hot path.  This is
+the whole vocabulary; ``docs/observability.md`` ("Scopes in the compiled
+step") says what falls under each and which benchmark metric reads it.
+
+Phases, opened by the step builders (``jax/data_parallel.py``,
+``models/bert.py``, ``models/transformer.py``):
+
+* ``MODEL``      round the loss inside ``jax.value_and_grad``: the forward
+                 pass is ``jvp(hvd.model)``, the backward pass
+                 ``transpose(jvp(hvd.model))``
+* ``OPTIMIZER``  round ``optimizer.update`` and ``optax.apply_updates``
+* ``EXCHANGE``   the in-program gradient all-reduce and what packs and
+                 unpacks it (``allreduce_gradients``); it sits inside
+                 ``OPTIMIZER`` and the inner scope wins
+
+Blocks inside the model, both passes: ``ATTENTION``, ``HEAD`` (logits and
+cross entropy).  Kernels, one ``pallas_call`` each: ``FLASH_FWD``,
+``FLASH_DQ``, ``FLASH_DKV``, ``FLASH_BWD_ONEPASS``; ``kernel_name`` gives
+the same words as the ``name=`` of the call (``hvd_flash_fwd``), which is
+what the trace viewer prints for a Mosaic kernel.
+"""
+
+from __future__ import annotations
+
+MODEL = "hvd.model"
+OPTIMIZER = "hvd.optimizer"
+EXCHANGE = "hvd.exchange"
+ATTENTION = "hvd.attention"
+HEAD = "hvd.head"
+FLASH_FWD = "hvd.flash_fwd"
+FLASH_DQ = "hvd.flash_dq"
+FLASH_DKV = "hvd.flash_dkv"
+FLASH_BWD_ONEPASS = "hvd.flash_bwd_onepass"
+
+
+def kernel_name(scope: str) -> str:
+    """A kernel scope as a ``pallas_call``'s ``name=``: Mosaic takes
+    letters, digits and ``_``."""
+    return scope.replace(".", "_")

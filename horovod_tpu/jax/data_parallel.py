@@ -25,7 +25,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..common import basics
+from ..common import basics, scopes
 from ..ops.xla_ops import AVERAGE, host_or_device
 from . import spmd
 from .compression import Compression
@@ -141,9 +141,13 @@ def make_data_parallel_step(loss_fn: Callable,
         backward_passes_per_step=backward_passes_per_step, axis_name=axis)
 
     def shard_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        updates, opt_state = dist_opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        # Traced under hvd.model: jvp(hvd.model) forward,
+        # transpose(jvp(hvd.model)) backward (common/scopes.py).
+        loss, grads = jax.value_and_grad(
+            jax.named_scope(scopes.MODEL)(loss_fn))(params, batch)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = dist_opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         # Replicated outputs: loss averaged across shards.
         loss = jax.lax.pmean(loss, axis)
         return params, opt_state, loss
@@ -172,9 +176,11 @@ def make_sharded_jit_step(loss_fn: Callable,
     sharded = NamedSharding(mesh, P(spmd.DEFAULT_AXIS))
 
     def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        loss, grads = jax.value_and_grad(
+            jax.named_scope(scopes.MODEL)(loss_fn))(params, batch)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     jitted = jax.jit(

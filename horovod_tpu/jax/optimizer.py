@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ..common import scopes
 from ..common.process_sets import ProcessSet
 from ..ops.xla_ops import ADASUM, AVERAGE, SUM
 from . import spmd
@@ -51,20 +52,25 @@ def allreduce_gradients(grads, op: str = AVERAGE,
     ``HOROVOD_HIERARCHICAL_ALLREDUCE``).
     ``axis_name=None`` (eager, multi-process tcp world): engine allreduce
     per leaf, fused by the background cycle.
+
+    The in-program forms are traced under ``hvd.exchange``
+    (``common/scopes.py``): the collective and whatever packs, scales and
+    unpacks round it.
     """
     from .compression import check_reduce_safe
     check_reduce_safe(compression, "allreduce_gradients")
-    if isinstance(axis_name, (tuple, list)):
-        if compression is not Compression.none:
-            raise ValueError(
-                "compression is not supported on the hierarchical "
-                "reduce path")
-        inner, outer = axis_name
-        return spmd.hierarchical_allreduce_pytree(
-            grads, op=op, inner_axis=inner, outer_axis=outer)
     if axis_name is not None:
-        return spmd.allreduce_pytree(grads, op=op, axis_name=axis_name,
-                                     compression=compression)
+        with jax.named_scope(scopes.EXCHANGE):
+            if isinstance(axis_name, (tuple, list)):
+                if compression is not Compression.none:
+                    raise ValueError(
+                        "compression is not supported on the hierarchical "
+                        "reduce path")
+                inner, outer = axis_name
+                return spmd.hierarchical_allreduce_pytree(
+                    grads, op=op, inner_axis=inner, outer_axis=outer)
+            return spmd.allreduce_pytree(grads, op=op, axis_name=axis_name,
+                                         compression=compression)
     from ..ops import api as eager
     leaves, treedef = jax.tree.flatten(grads)
     handles = []

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..common import scopes
 from .transformer import (_sharded_embed_lookup, _use_flash_attention,
                           opt_spec_tree, vocab_parallel_cross_entropy)
 
@@ -151,6 +152,7 @@ def layer_norm(x, g, b, eps):
             + b.astype(x.dtype))
 
 
+@jax.named_scope(scopes.ATTENTION)
 def _attention(h, lp, cfg: BertConfig, mask):
     """Bidirectional self-attention; per-shard code (tp slice of the
     heads).  ``mask`` is [B, S] with 1 = attend (transformers
@@ -247,9 +249,10 @@ def mlm_loss(params, batch, cfg: BertConfig):
     mesh invariance of the loss and gradients."""
     hidden = encode(params, batch["tokens"], cfg,
                     batch.get("token_type"), batch.get("mask"))
-    logits = mlm_logits_local(params, hidden, cfg)
-    nll = vocab_parallel_cross_entropy(logits, batch["targets"],
-                                       cfg.tp_axis)
+    with jax.named_scope(scopes.HEAD):
+        logits = mlm_logits_local(params, hidden, cfg)
+        nll = vocab_parallel_cross_entropy(logits, batch["targets"],
+                                           cfg.tp_axis)
     m = batch["mlm_mask"].astype(jnp.float32)
     num = lax.psum((nll * m).sum(), cfg.dp_axis)
     den = lax.psum(m.sum(), cfg.dp_axis)
@@ -260,9 +263,10 @@ def classification_loss(params, batch, cfg: BertConfig):
     """Per-shard [CLS] cross entropy (fine-tune objective)."""
     hidden = encode(params, batch["tokens"], cfg,
                     batch.get("token_type"), batch.get("mask"))
-    logits = cls_logits(params, hidden)
-    nll = -jax.nn.log_softmax(logits)[
-        jnp.arange(logits.shape[0]), batch["labels"]]
+    with jax.named_scope(scopes.HEAD):
+        logits = cls_logits(params, hidden)
+        nll = -jax.nn.log_softmax(logits)[
+            jnp.arange(logits.shape[0]), batch["labels"]]
     return lax.pmean(nll.mean(), cfg.dp_axis)
 
 
@@ -293,10 +297,13 @@ def make_finetune_step(cfg: BertConfig, mesh, optimizer,
         # pmean in the loss with exact collective transposes, so the
         # per-shard grads ARE the global-batch gradient — no manual
         # combine (verified by the sharded-vs-single gradient test).
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(p, batch, cfg))(params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        # So the dp reduction is part of the backward pass
+        # (transpose(jvp(hvd.model))) and nothing here is hvd.exchange.
+        loss, grads = jax.value_and_grad(jax.named_scope(scopes.MODEL)(
+            lambda p: loss_fn(p, batch, cfg)))(params)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     def build(params_host):

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..common import scopes
 from ..parallel.moe import MoeConfig, moe_ffn
 from ..parallel.ring_attention import local_attention, ring_attention
 
@@ -277,6 +278,7 @@ def _use_flash_attention() -> bool:
     return on_tpu()
 
 
+@jax.named_scope(scopes.ATTENTION)
 def _attention_block(x, lp, cfg: TransformerConfig, cos, sin, sp_size):
     b, s, _ = x.shape
     hd = cfg.head_dim
@@ -430,14 +432,15 @@ def forward(params, tokens, cfg: TransformerConfig):
     bf16_logits = (cfg.logits_dtype == "bf16"
                    or (cfg.logits_dtype == "auto"
                        and _use_flash_attention()))
-    if bf16_logits:
-        logits = jnp.matmul(
-            x.astype(cfg.act_dtype),
-            params["embed"].astype(cfg.act_dtype).T,
-            preferred_element_type=jnp.float32)
-    else:
-        logits = (x.astype(jnp.float32)
-                  @ params["embed"].astype(jnp.float32).T)
+    with jax.named_scope(scopes.HEAD):
+        if bf16_logits:
+            logits = jnp.matmul(
+                x.astype(cfg.act_dtype),
+                params["embed"].astype(cfg.act_dtype).T,
+                preferred_element_type=jnp.float32)
+        else:
+            logits = (x.astype(jnp.float32)
+                      @ params["embed"].astype(jnp.float32).T)
     return logits, aux / cfg.n_layers
 
 
@@ -445,7 +448,8 @@ def loss_fn(params, batch, cfg: TransformerConfig):
     """Per-shard mean nll (+ MoE aux); psum-averaged over dp and sp."""
     tokens, targets = batch["tokens"], batch["targets"]
     logits, aux = forward(params, tokens, cfg)
-    nll = vocab_parallel_cross_entropy(logits, targets, cfg.tp_axis)
+    with jax.named_scope(scopes.HEAD):
+        nll = vocab_parallel_cross_entropy(logits, targets, cfg.tp_axis)
     loss = nll.mean() + cfg.aux_loss_weight * aux
     return lax.pmean(loss, (cfg.dp_axis, cfg.sp_axis))
 
@@ -514,12 +518,13 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer,
         # grads over (dp, sp) on top of already-combined cotangents,
         # scaling the update by dp*sp: r4 correctness fix, verified by
         # the sharded-vs-single-device gradient test.)
-        return jax.value_and_grad(
-            lambda p: loss_fn(p, batch, cfg))(params)
+        return jax.value_and_grad(jax.named_scope(scopes.MODEL)(
+            lambda p: loss_fn(p, batch, cfg)))(params)
 
     def local_update(params, opt_state, grads):
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state
 
     def local_step(params, opt_state, batch):
         # Composed from the same two pieces the split path jits

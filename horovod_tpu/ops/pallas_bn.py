@@ -233,6 +233,7 @@ def _stats(x2, c_blk, interpret):
                         _vmem((1, c_blk), jnp.float32)],
         compiler_params=_params(interpret, reduce_m=True),
         interpret=interpret,
+        name="hvd_bn_stats",
     )(x2)
     return sums, sqs
 
@@ -253,6 +254,7 @@ def _apply(x2, mean, var, gamma, beta, res2, c_blk, eps, relu,
         out_shape=jax.ShapeDtypeStruct((m, c), x2.dtype),
         compiler_params=_params(interpret, reduce_m=False),
         interpret=interpret,
+        name="hvd_bn_apply",
     )(*args)
 
 
@@ -276,6 +278,7 @@ def _bwd_reductions(x2, dy2, mean, var, gamma, beta, res2, c_blk,
                         _vmem((1, c_blk), jnp.float32)],
         compiler_params=_params(interpret, reduce_m=True),
         interpret=interpret,
+        name="hvd_bn_bwd_reductions",
     )(*args)
     return db, dg
 
@@ -301,6 +304,7 @@ def _bwd_dx(x2, dy2, mean, var, gamma, beta, db, dg, res2, c_blk,
         out_shape=outs if residual else outs[0],
         compiler_params=_params(interpret, reduce_m=False),
         interpret=interpret,
+        name="hvd_bn_bwd_dx",
     )(*args)
     return res if residual else (res, None)
 

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from ..common import scopes
 from ..common.device import on_tpu
 
 LOG = logging.getLogger("horovod_tpu")
@@ -106,6 +107,7 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
+@jax.named_scope(scopes.FLASH_FWD)
 def _flash_attention_fwd_flat(q, k, v, *, causal: bool, block_q: int,
                               block_k: int, interpret: bool):
     """(BH, S, D) → ((BH, S, D) output, (BH, S, 1) lse), D lane-padded."""
@@ -141,6 +143,7 @@ def _flash_attention_fwd_flat(q, k, v, *, causal: bool, block_q: int,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=scopes.kernel_name(scopes.FLASH_FWD),
     )(q, k, v)
 
 
@@ -421,43 +424,47 @@ def _flash_attention_bwd_flat(q, k, v, g, lse, delta, *, causal: bool,
     qspec = pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, t, 0))
     rowspec = pl.BlockSpec((1, block_q, 1), lambda i, j, t: (i, j, 0))
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal),
-        grid=(bh, seq // block_q, seq // block_k),
-        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda i, j, t: (i, j, 0)),
-        out_shape=_sds((bh, seq, d), q.dtype, q),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, g, lse, delta)
+    with jax.named_scope(scopes.FLASH_DQ):
+        dq = pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
+                              block_k=block_k, causal=causal),
+            grid=(bh, seq // block_q, seq // block_k),
+            in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+            out_specs=pl.BlockSpec((1, block_q, d),
+                                   lambda i, j, t: (i, j, 0)),
+            out_shape=_sds((bh, seq, d), q.dtype, q),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name=scopes.kernel_name(scopes.FLASH_DQ),
+        )(q, k, v, g, lse, delta)
 
     # dkv grid: (bh, k block, q block) — inner axis streams q.
     qspec2 = pl.BlockSpec((1, block_q, d), lambda i, t, j: (i, j, 0))
     kspec2 = pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0))
     rowspec2 = pl.BlockSpec((1, block_q, 1), lambda i, t, j: (i, j, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal),
-        grid=(bh, seq // block_k, seq // block_q),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
-        ],
-        out_shape=[
-            _sds((bh, seq, d), k.dtype, k),
-            _sds((bh, seq, d), v.dtype, v),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, g, lse, delta)
+    with jax.named_scope(scopes.FLASH_DKV):
+        dk, dv = pl.pallas_call(
+            functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
+                              block_k=block_k, causal=causal),
+            grid=(bh, seq // block_k, seq // block_q),
+            in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
+                pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
+            ],
+            out_shape=[
+                _sds((bh, seq, d), k.dtype, k),
+                _sds((bh, seq, d), v.dtype, v),
+            ],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)],
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name=scopes.kernel_name(scopes.FLASH_DKV),
+        )(q, k, v, g, lse, delta)
     return dq, dk, dv
 
 
@@ -537,28 +544,30 @@ def _flash_attention_bwd_onepass_flat(q, k, v, g, lse, delta, *,
     qspec = pl.BlockSpec((1, block_q, d), lambda i, t, j: (i, j, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0))
     rowspec = pl.BlockSpec((1, block_q, 1), lambda i, t, j: (i, j, 0))
-    dqp, dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_onepass_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal),
-        grid=(bh, nk, seq // block_q),
-        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda i, t, j: (i, t, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
-        ],
-        out_shape=[
-            _sds((bh, nk, seq, d), jnp.float32, q),
-            _sds((bh, seq, d), k.dtype, k),
-            _sds((bh, seq, d), v.dtype, v),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, g, lse, delta)
+    with jax.named_scope(scopes.FLASH_BWD_ONEPASS):
+        dqp, dk, dv = pl.pallas_call(
+            functools.partial(_flash_bwd_onepass_kernel, block_q=block_q,
+                              block_k=block_k, causal=causal),
+            grid=(bh, nk, seq // block_q),
+            in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, d),
+                             lambda i, t, j: (i, t, j, 0)),
+                pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
+                pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
+            ],
+            out_shape=[
+                _sds((bh, nk, seq, d), jnp.float32, q),
+                _sds((bh, seq, d), k.dtype, k),
+                _sds((bh, seq, d), v.dtype, v),
+            ],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)],
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            interpret=interpret,
+            name=scopes.kernel_name(scopes.FLASH_BWD_ONEPASS),
+        )(q, k, v, g, lse, delta)
     return jnp.sum(dqp, axis=1), dk, dv
 
 

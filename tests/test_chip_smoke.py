@@ -64,6 +64,9 @@ def test_compile_cache_follows_the_environment_or_the_checkout(monkeypatch):
         want = os.path.join(REPO, ".jax_cache")
         assert device.place_compile_cache() == want
         assert jax.config.jax_compilation_cache_dir == want
+        # Either way an entry is keyed with its operations' names: a
+        # step is never handed an executable from before its scopes.
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
 
