@@ -15,9 +15,11 @@ Phases, opened by the step builders (``jax/data_parallel.py``,
                  pass is ``jvp(hvd.model)``, the backward pass
                  ``transpose(jvp(hvd.model))``
 * ``OPTIMIZER``  round ``optimizer.update`` and ``optax.apply_updates``
-* ``EXCHANGE``   the in-program gradient all-reduce and what packs and
-                 unpacks it (``allreduce_gradients``); it sits inside
-                 ``OPTIMIZER`` and the inner scope wins
+* ``EXCHANGE``   the in-program gradient all-reduces, one ``psum`` a
+                 leaf, and what scales or casts the gradients round them
+                 (``allreduce_gradients``; nothing is packed, but for the
+                 hierarchical form); it sits inside ``OPTIMIZER`` and the
+                 inner scope wins
 
 Blocks inside the model, both passes: ``ATTENTION``, ``HEAD`` (logits and
 cross entropy).  Kernels, one ``pallas_call`` each: ``FLASH_FWD``,

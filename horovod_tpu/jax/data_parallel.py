@@ -6,8 +6,12 @@ step, so this module provides the two TPU-native ways to run DP:
 
 * ``make_data_parallel_step`` — explicit SPMD via ``jax.shard_map`` over
   the 'hvd' mesh axis: per-device batch shard in, psum-averaged gradients
-  (through ``DistributedOptimizer``) in-program.  Collectives ride ICI and
-  overlap with backward compute under XLA's scheduler.
+  (through ``DistributedOptimizer``) in-program, one ``psum`` a gradient
+  and no packing; XLA combines them and the reduce rides ICI.  On the v5e
+  an all-reduce holds the op stream for as long as it runs, whether it
+  sits inside the backward pass or behind it (PERF.md, PR 26), so the
+  step is compiled with the compiler's own combining: one all-reduce of
+  every gradient, after the last.
 * ``make_sharded_jit_step`` — compiler-driven: params replicated, batch
   sharded; ``jax.jit`` with those shardings makes XLA insert the gradient
   all-reduce itself.  Zero framework code in the hot path — the ceiling

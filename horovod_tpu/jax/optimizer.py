@@ -10,8 +10,10 @@ fp16/bf16 wire compression, gradient predivision, local aggregation
 JAX re-design: the optimizer is an ``optax.GradientTransformation``; the
 distributed wrapper is *another* GradientTransformation that allreduces
 gradients first — composable, functional, jit-friendly.  Inside a
-mesh-sharded step the reduce is a fused ``lax.psum`` (XLA overlaps it with
-backward compute the way Horovod overlapped NCCL with autograd); in the
+mesh-sharded step the reduce is one ``lax.psum`` a gradient, packed into
+nothing: how many share one all-reduce, and where in the step it goes, is
+the compiler's (the benchmark's ``collective_ms_per_step`` is the time no
+compute hid: on the v5e all of it, see ``spmd.py``).  In the
 multi-process world it routes through the eager engine instead.
 """
 
@@ -45,17 +47,19 @@ def allreduce_gradients(grads, op: str = AVERAGE,
                         process_set: Optional[ProcessSet] = None):
     """Average a gradient pytree across the world.
 
-    ``axis_name`` set (inside shard_map/pjit): fused in-program psum; a
-    ``(inner, outer)`` PAIR of axis names selects the hierarchical
-    reduce over a hybrid mesh (reduce-scatter on ICI, cross-slice
-    allreduce of the shards on DCN, all-gather back — the reference's
+    ``axis_name`` set (inside shard_map/pjit): in-program psum, leaf by
+    leaf; a ``(inner, outer)`` PAIR of axis names selects the
+    hierarchical reduce over a hybrid mesh (all leaves packed into one
+    buffer: reduce-scatter on ICI, cross-slice allreduce of the shards
+    on DCN, all-gather back — the reference's
     ``HOROVOD_HIERARCHICAL_ALLREDUCE``).
     ``axis_name=None`` (eager, multi-process tcp world): engine allreduce
     per leaf, fused by the background cycle.
 
     The in-program forms are traced under ``hvd.exchange``
-    (``common/scopes.py``): the collective and whatever packs, scales and
-    unpacks round it.
+    (``common/scopes.py``): the collectives and what is done to the
+    gradients round them (codec casts, the ``Average`` division; the
+    hierarchical form's packing).
     """
     from .compression import check_reduce_safe
     check_reduce_safe(compression, "allreduce_gradients")

@@ -2,9 +2,18 @@
 
 These are the collectives you call *inside* a jitted, mesh-sharded train
 step (``jax.shard_map`` / pjit).  XLA lowers them to ICI/DCN collective HLO
-and fuses them with surrounding compute — the TPU equivalent of the
-reference's NCCL-on-stream hot path (``ops/nccl_operations.cc``), with the
-compiler doing the overlap that Horovod did with stream events.
+inside the compiled step: the TPU equivalent of the reference's
+NCCL-on-stream hot path (``ops/nccl_operations.cc``).
+
+The pytree forms (``grouped_allreduce``, ``allreduce_pytree``) reduce leaf
+by leaf and pack nothing: a collective can leave only when its operand
+exists, so each gradient's all-reduce depends on that gradient alone, and
+how many share one all-reduce, and where in the step it goes, is XLA's
+combiner's and scheduler's decision (the TPU's default merges all of a
+step's into one, without the copies a packed buffer costs).  The
+benchmark's ``exchange_pack_ms_per_step`` reads what is left round the
+collectives (the ``AVERAGE`` division, where it did not fuse into the
+update) and ``collective_ms_per_step`` the time no compute hid.
 
 The op surface mirrors the eager API (Sum/Average/Min/Max, prescale/
 postscale, compression) so a reference user can move a call inside jit
@@ -20,7 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.xla_ops import AVERAGE, MAX, MIN, PRODUCT, SUM
-from .compression import Compression
+from .compression import Compression, check_reduce_safe
 
 DEFAULT_AXIS = "hvd"
 
@@ -39,7 +48,6 @@ def allreduce(x, op: str = AVERAGE, axis_name: str = DEFAULT_AXIS,
               prescale_factor: float = 1.0, postscale_factor: float = 1.0,
               compression=Compression.none):
     """Cross-replica reduce inside an SPMD program."""
-    from .compression import check_reduce_safe
     check_reduce_safe(compression, "spmd.allreduce")
     if prescale_factor != 1.0:
         x = x * jnp.asarray(prescale_factor, dtype=x.dtype)
@@ -100,46 +108,40 @@ def hierarchical_allreduce(x, op: str = AVERAGE,
     return out[:x.size].reshape(x.shape).astype(x.dtype)
 
 
-def _fused_reduce(xs: Sequence, reduce_flat):
-    """Flatten-concat-reduce-split fusion shared by the grouped and
-    hierarchical paths: one large collective instead of one per tensor
-    (the explicit analog of the engine's fusion buffer)."""
-    flats = [jnp.ravel(x) for x in xs]
-    sizes = [f.shape[0] for f in flats]
-    fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-    red = reduce_flat(fused)
-    outs, off = [], 0
-    for x, n in zip(xs, sizes):
-        outs.append(red[off:off + n].reshape(x.shape).astype(x.dtype))
-        off += n
-    return outs
-
-
 def hierarchical_allreduce_pytree(tree, op: str = AVERAGE,
                                   inner_axis: str = "ici",
                                   outer_axis: str = "dcn"):
-    """Fused hierarchical reduce of a pytree: one concat, one
-    RS-inner/AR-outer/AG-inner round, one split."""
+    """Hierarchical reduce of a pytree as one payload: one concat, one
+    RS-inner/AR-outer/AG-inner round, one split.  The reduce-scatter
+    needs one flat buffer padded to the inner axis, so this form (unlike
+    ``grouped_allreduce``) packs, promotes mixed dtypes to their common
+    one on the wire, and cannot leave before the last leaf exists."""
     leaves, treedef = jax.tree.flatten(tree)
-    outs = _fused_reduce(
-        leaves, lambda fused: hierarchical_allreduce(
-            fused, op=op, inner_axis=inner_axis, outer_axis=outer_axis))
+    flats = [jnp.ravel(x) for x in leaves]
+    fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
+    red = hierarchical_allreduce(fused, op=op, inner_axis=inner_axis,
+                                 outer_axis=outer_axis)
+    outs, off = [], 0
+    for x in leaves:
+        outs.append(red[off:off + x.size].reshape(x.shape).astype(x.dtype))
+        off += x.size
     return jax.tree.unflatten(treedef, outs)
 
 
 def grouped_allreduce(xs: Sequence, op: str = AVERAGE,
                       axis_name: str = DEFAULT_AXIS,
                       compression=Compression.none):
-    """Reduce a list of tensors as one fused payload (one large
-    all-reduce — see _fused_reduce)."""
-    return _fused_reduce(
-        xs, lambda fused: allreduce(fused, op=op, axis_name=axis_name,
-                                    compression=compression))
+    """Reduce a list of tensors, each by its own collective in its own
+    dtype (see the module docstring: nothing is packed; combining
+    them is XLA's)."""
+    return [allreduce(x, op=op, axis_name=axis_name,
+                      compression=compression) for x in xs]
 
 
 def allreduce_pytree(tree, op: str = AVERAGE, axis_name: str = DEFAULT_AXIS,
                      compression=Compression.none):
-    """Fused reduce of every leaf of a pytree (gradients, metrics...)."""
+    """``grouped_allreduce`` of every leaf of a pytree (gradients,
+    metrics...)."""
     leaves, treedef = jax.tree.flatten(tree)
     return jax.tree.unflatten(
         treedef, grouped_allreduce(leaves, op=op, axis_name=axis_name,
