@@ -21,8 +21,13 @@ Phases, opened by the step builders (``jax/data_parallel.py``,
                  hierarchical form); it sits inside ``OPTIMIZER`` and the
                  inner scope wins
 
-Blocks inside the model, both passes: ``ATTENTION``, ``HEAD`` (logits and
-cross entropy).  Kernels, one ``pallas_call`` each: ``FLASH_FWD``,
+Blocks inside the model, both passes: ``ATTENTION`` (softmax attention
+with its projections), ``HEAD`` (logits and cross entropy),
+``LINEAR_ATTENTION`` (a delta-rule mixer whole; ``KDA_CORE`` inside it is
+the chunked recurrence alone), ``MOE`` (an expert layer whole; inside it
+``ROUTER`` is scores, top-k, the sort and the rows' gather and scatter,
+``EXPERTS`` the routed experts' matrix products alone, ``SHARED_EXPERT``
+the expert every token takes).  Kernels, one ``pallas_call`` each: ``FLASH_FWD``,
 ``FLASH_DQ``, ``FLASH_DKV``, ``FLASH_BWD_ONEPASS``; ``kernel_name`` gives
 the same words as the ``name=`` of the call (``hvd_flash_fwd``), which is
 what the trace viewer prints for a Mosaic kernel.
@@ -35,6 +40,12 @@ OPTIMIZER = "hvd.optimizer"
 EXCHANGE = "hvd.exchange"
 ATTENTION = "hvd.attention"
 HEAD = "hvd.head"
+LINEAR_ATTENTION = "hvd.linear_attention"
+KDA_CORE = "hvd.kda_core"
+MOE = "hvd.moe"
+ROUTER = "hvd.router"
+EXPERTS = "hvd.experts"
+SHARED_EXPERT = "hvd.shared_expert"
 FLASH_FWD = "hvd.flash_fwd"
 FLASH_DQ = "hvd.flash_dq"
 FLASH_DKV = "hvd.flash_dkv"

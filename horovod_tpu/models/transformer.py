@@ -20,6 +20,14 @@ a TPU mesh rather than wrapped around a torch model:
 Everything is a pure function over a params pytree with layer-stacked
 leaves ``[L, ...]`` consumed by ``lax.scan`` (single-layer trace, static
 shapes, bf16 activations on the MXU, optional ``jax.checkpoint`` remat).
+
+A model whose layers differ gives ``layer_pattern``: one period of
+(mixer, feed-forward) pairs out of ``MIXERS`` and ``FEED_FORWARDS``.  The
+scan then runs over periods, ``params["layers"]`` is a tuple with one
+stacked dict for each layer of the period, and each layer is recomputed on
+its own.  The block functions of the kinds that need more than a few lines
+live beside their mechanism (``models/linear_attention.py``,
+``parallel/moe.py``).
 """
 
 from __future__ import annotations
@@ -34,8 +42,22 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..common import scopes
-from ..parallel.moe import MoeConfig, moe_ffn
+from ..parallel.moe import (ExpertShare, MoeConfig, expert_share_ffn,
+                            init_expert_share_params, moe_ffn)
 from ..parallel.ring_attention import local_attention, ring_attention
+from .linear_attention import SAVED as kda_saved_names
+from .linear_attention import (KdaConfig, init_kda_params, kda_param_specs,
+                               linear_attention_block)
+
+# Kinds a layer is made of.  ``attention``: RoPE softmax attention (GQA);
+# ``gated_nope_attention``: the same with no positional encoding and an
+# element-wise sigmoid gate on the heads' output; ``linear_attention``: the
+# gated delta rule (``cfg.linear_attention``).  ``dense``: SwiGLU; ``moe``:
+# capacity-factor experts with an all-to-all over ``sp``;
+# ``expert_share``: this chip's share of a dropless expert layer beside a
+# shared expert (``cfg.experts``).
+MIXERS = ("attention", "gated_nope_attention", "linear_attention")
+FEED_FORWARDS = ("dense", "moe", "expert_share")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,6 +118,22 @@ class TransformerConfig:
     # bytes as the plain psum, but the reduce leg hides behind MXU
     # work).  No-op at tp=1, so single-chip programs are unchanged.
     collective_matmul: bool = False
+    # One period of the layer pattern, ((mixer, feed-forward), ...) out of
+    # MIXERS x FEED_FORWARDS; n_layers is a multiple of its length.  None
+    # is every layer alike: attention, then dense or (n_experts > 0) moe.
+    layer_pattern: Optional[Tuple[Tuple[str, str], ...]] = None
+    # Size of an attention head where it is not d_model / n_heads (a
+    # chip's share of the heads keeps the model's head size).
+    head_size: Optional[int] = None
+    linear_attention: Optional[KdaConfig] = None
+    experts: Optional[ExpertShare] = None
+    # An output head of its own, ``params["head"]`` [d, V], instead of the
+    # embedding's transpose.
+    tie_embeddings: bool = True
+    # Tokens a block of the loss's head: logits and cross entropy are
+    # computed (and recomputed in the backward pass) a block at a time and
+    # [tokens, V] never exists.  0 = all at once.
+    head_block: int = 0
 
     def __post_init__(self):
         if self.sp_mode not in ("ring", "ulysses"):
@@ -108,10 +146,32 @@ class TransformerConfig:
         if self.logits_dtype not in ("auto", "bf16", "f32"):
             raise ValueError("logits_dtype must be 'auto', 'bf16' or "
                              "'f32', got %r" % (self.logits_dtype,))
+        for mixer, ffn in self.pattern:
+            if mixer not in MIXERS or ffn not in FEED_FORWARDS:
+                raise ValueError("layer_pattern pairs a mixer of %s with a "
+                                 "feed-forward of %s, not %r"
+                                 % (MIXERS, FEED_FORWARDS, (mixer, ffn)))
+            if (mixer == "linear_attention" and not self.linear_attention) \
+                    or (ffn == "expert_share" and not self.experts):
+                raise ValueError("%r needs its configuration"
+                                 % ((mixer, ffn),))
+        if self.n_layers % len(self.pattern):
+            raise ValueError("%d layers are no whole number of periods of %d"
+                             % (self.n_layers, len(self.pattern)))
+        if self.layer_pattern is not None and (self.fused_qkv
+                                               or self.fused_gate):
+            raise ValueError("fused_qkv / fused_gate pack the weights of "
+                             "the default layers only")
+
+    @property
+    def pattern(self) -> Tuple[Tuple[str, str], ...]:
+        if self.layer_pattern is not None:
+            return self.layer_pattern
+        return (("attention", "dense" if self.n_experts == 0 else "moe"),)
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
 
     @property
     def act_dtype(self):
@@ -127,43 +187,112 @@ class TransformerConfig:
 # Parameters
 # --------------------------------------------------------------------------
 
+def _normal(key, shape, fan_in, dtype):
+    return (jax.random.normal(key, shape) / math.sqrt(fan_in)).astype(dtype)
+
+
+def _init_layers(key, cfg: TransformerConfig, mixer: str, ffn: str, n: int):
+    """``n`` stacked layers of one (mixer, feed-forward) kind."""
+    pd = jnp.dtype(cfg.param_dtype)
+    d, hd = cfg.d_model, cfg.head_dim
+    qh, kvh, f = cfg.n_heads, cfg.n_kv_heads, cfg.d_ff
+    keys = jax.random.split(key, 12)
+    norm = partial(_normal, dtype=pd)
+
+    layers = {"ln1": jnp.ones((n, d), pd), "ln2": jnp.ones((n, d), pd)}
+    if mixer == "linear_attention":
+        layers.update(init_kda_params(keys[1], d, cfg.linear_attention, n,
+                                      pd))
+    else:
+        layers.update({
+            "wq": norm(keys[1], (n, d, qh * hd), d),
+            "wk": norm(keys[2], (n, d, kvh * hd), d),
+            "wv": norm(keys[3], (n, d, kvh * hd), d),
+            "wo": norm(keys[4], (n, qh * hd, d), qh * hd),
+        })
+        if mixer == "gated_nope_attention":
+            layers["wg"] = norm(jax.random.fold_in(key, 12),
+                                (n, d, qh * hd), d)
+    if ffn == "dense":
+        layers.update({
+            "w1": norm(keys[5], (n, d, f), d),
+            "w3": norm(keys[6], (n, d, f), d),
+            "w2": norm(keys[7], (n, f, d), f),
+        })
+    elif ffn == "moe":
+        e = cfg.n_experts
+        layers.update({
+            "router": norm(keys[8], (n, d, e), d),
+            "we1": norm(keys[9], (n, e, d, f), d),
+            "we3": norm(keys[10], (n, e, d, f), d),
+            "we2": norm(keys[11], (n, e, f, d), f),
+        })
+    else:
+        layers.update(init_expert_share_params(keys[8], cfg.experts, n, pd))
+    return layers
+
+
 def init_params(key, cfg: TransformerConfig):
     """Layer-stacked parameter pytree (host-side, full/unsharded)."""
     pd = jnp.dtype(cfg.param_dtype)
-    d, hd = cfg.d_model, cfg.head_dim
-    qh, kvh, f, L = cfg.n_heads, cfg.n_kv_heads, cfg.d_ff, cfg.n_layers
-    keys = jax.random.split(key, 12)
-
-    def norm(k, shape, fan_in):
-        return (jax.random.normal(k, shape) / math.sqrt(fan_in)).astype(pd)
-
+    d = cfg.d_model
     params = {
-        "embed": norm(keys[0], (cfg.vocab_size, d), d),
+        "embed": _normal(jax.random.split(key, 12)[0], (cfg.vocab_size, d),
+                         d, pd),
         "ln_f": jnp.ones((d,), pd),
-        "layers": {
-            "ln1": jnp.ones((L, d), pd),
-            "ln2": jnp.ones((L, d), pd),
-            "wq": norm(keys[1], (L, d, qh * hd), d),
-            "wk": norm(keys[2], (L, d, kvh * hd), d),
-            "wv": norm(keys[3], (L, d, kvh * hd), d),
-            "wo": norm(keys[4], (L, qh * hd, d), qh * hd),
-        },
     }
-    if cfg.n_experts == 0:
-        params["layers"].update({
-            "w1": norm(keys[5], (L, d, f), d),
-            "w3": norm(keys[6], (L, d, f), d),
-            "w2": norm(keys[7], (L, f, d), f),
+    if not cfg.tie_embeddings:
+        params["head"] = _normal(jax.random.fold_in(key, 12),
+                                 (d, cfg.vocab_size), d, pd)
+    if cfg.layer_pattern is None:
+        params["layers"] = _init_layers(key, cfg, *cfg.pattern[0],
+                                        cfg.n_layers)
+    else:
+        n = cfg.n_layers // len(cfg.pattern)
+        params["layers"] = tuple(
+            _init_layers(jax.random.fold_in(key, at), cfg, mixer, ffn, n)
+            for at, (mixer, ffn) in enumerate(cfg.pattern))
+    return params
+
+
+def _layer_specs(cfg: TransformerConfig, mixer: str, ffn: str):
+    from jax.sharding import PartitionSpec as P
+    tp, sp = cfg.tp_axis, cfg.sp_axis
+    specs = {"ln1": P(None, None), "ln2": P(None, None)}
+    if mixer == "linear_attention":
+        specs.update(kda_param_specs(tp))
+    else:
+        specs.update({
+            "wq": P(None, None, tp),
+            "wk": P(None, None, tp),
+            "wv": P(None, None, tp),
+            "wo": P(None, tp, None),
+        })
+        if mixer == "gated_nope_attention":
+            specs["wg"] = P(None, None, tp)
+    if ffn == "dense":
+        specs.update({
+            "w1": P(None, None, tp),
+            "w3": P(None, None, tp),
+            "w2": P(None, tp, None),
+        })
+    elif ffn == "moe":
+        specs.update({
+            "router": P(None, None, None),
+            "we1": P(None, sp, None, None),
+            "we3": P(None, sp, None, None),
+            "we2": P(None, sp, None, None),
         })
     else:
-        e = cfg.n_experts
-        params["layers"].update({
-            "router": norm(keys[8], (L, d, e), d),
-            "we1": norm(keys[9], (L, e, d, f), d),
-            "we3": norm(keys[10], (L, e, d, f), d),
-            "we2": norm(keys[11], (L, e, f, d), f),
-        })
-    return params
+        # The share is what this chip holds: nothing of it is split again.
+        specs.update({name: P(None, None, None, None)
+                      for name in ("we1", "we3", "we2")})
+        specs["router"] = P(None, None, None)
+        specs["router_bias"] = P(None, None)
+        if cfg.experts.d_shared:
+            specs.update({name: P(None, None, None)
+                          for name in ("ws1", "ws3", "ws2")})
+    return specs
 
 
 def param_specs(cfg: TransformerConfig):
@@ -173,32 +302,13 @@ def param_specs(cfg: TransformerConfig):
     tp; experts sharded over the sequence/expert axis; norms replicated.
     """
     from jax.sharding import PartitionSpec as P
-    tp, sp = cfg.tp_axis, cfg.sp_axis
-    specs = {
-        "embed": P(tp, None),
-        "ln_f": P(None),
-        "layers": {
-            "ln1": P(None, None),
-            "ln2": P(None, None),
-            "wq": P(None, None, tp),
-            "wk": P(None, None, tp),
-            "wv": P(None, None, tp),
-            "wo": P(None, tp, None),
-        },
-    }
-    if cfg.n_experts == 0:
-        specs["layers"].update({
-            "w1": P(None, None, tp),
-            "w3": P(None, None, tp),
-            "w2": P(None, tp, None),
-        })
-    else:
-        specs["layers"].update({
-            "router": P(None, None, None),
-            "we1": P(None, sp, None, None),
-            "we3": P(None, sp, None, None),
-            "we2": P(None, sp, None, None),
-        })
+    tp = cfg.tp_axis
+    specs = {"embed": P(tp, None), "ln_f": P(None)}
+    if not cfg.tie_embeddings:
+        specs["head"] = P(None, tp)
+    blocks = tuple(_layer_specs(cfg, mixer, ffn)
+                   for mixer, ffn in cfg.pattern)
+    specs["layers"] = blocks[0] if cfg.layer_pattern is None else blocks
     return specs
 
 
@@ -299,27 +409,46 @@ def _attention_block(x, lp, cfg: TransformerConfig, cos, sin, sp_size):
         v = (x @ lp["wv"].astype(x.dtype)).reshape(b, s, -1, hd)
     q = _rope(cos, sin, q)
     k = _rope(cos, sin, k)
+    attn = _causal_attention(q, k, v, cfg, sp_size).reshape(b, s, -1)
+    # Row-sharded wo: partial sums live on each tp shard.
+    return _row_parallel_product(attn, lp["wo"].astype(x.dtype), cfg)
+
+
+def _causal_attention(q, k, v, cfg: TransformerConfig, sp_size):
+    """Causal softmax attention over ``[B, S, heads, head_dim]`` by
+    whichever form the layout calls for."""
     if sp_size > 1 and cfg.sp_mode == "ulysses":
         from ..parallel.ulysses import ulysses_attention
         attn_fn = None
         if _use_flash_attention():
             from ..ops.pallas_kernels import flash_attention as attn_fn
-        attn = ulysses_attention(q, k, v, axis_name=cfg.sp_axis,
+        return ulysses_attention(q, k, v, axis_name=cfg.sp_axis,
                                  causal=True, attn_fn=attn_fn)
-    elif sp_size > 1:
-        attn = ring_attention(q, k, v, axis_name=cfg.sp_axis, causal=True)
-    elif _use_flash_attention():
+    if sp_size > 1:
+        return ring_attention(q, k, v, axis_name=cfg.sp_axis, causal=True)
+    if _use_flash_attention():
         # Pallas fused attention on TPU (ops/pallas_kernels.py):
         # O(seq) HBM forward + Pallas backward kernels (dq, dk/dv);
         # measured ~5x over XLA autodiff at seq 8192 on one chip
         # (docs/benchmarks.md)
         from ..ops.pallas_kernels import flash_attention
-        attn = flash_attention(q, k, v, causal=True)
-    else:
-        attn = local_attention(q, k, v, causal=True)
-    attn = attn.reshape(b, s, -1)
-    # Row-sharded wo: partial sums live on each tp shard.
-    return _row_parallel_product(attn, lp["wo"].astype(x.dtype), cfg)
+        return flash_attention(q, k, v, causal=True)
+    return local_attention(q, k, v, causal=True)
+
+
+@jax.named_scope(scopes.ATTENTION)
+def _gated_nope_attention_block(x, lp, cfg: TransformerConfig, sp_size):
+    """Softmax attention with no positional encoding (the causal mask is
+    all the order it sees) and a sigmoid gate, from a projection of its
+    own, on every element of the heads' output before ``wo``."""
+    b, s, _ = x.shape
+    hd = cfg.head_dim
+    q, k, v = ((x @ lp[name].astype(x.dtype)).reshape(b, s, -1, hd)
+               for name in ("wq", "wk", "wv"))
+    attn = _causal_attention(q, k, v, cfg, sp_size).reshape(b, s, -1)
+    gate = jax.nn.sigmoid((x @ lp["wg"].astype(x.dtype)).astype(jnp.float32))
+    return _row_parallel_product((attn * gate).astype(x.dtype),
+                                 lp["wo"].astype(x.dtype), cfg)
 
 
 def _row_parallel_product(x, w, cfg: TransformerConfig):
@@ -360,12 +489,36 @@ def _moe_block(h, lp, cfg: TransformerConfig, sp_size):
     return y.reshape(b, s, d), aux
 
 
-def forward(params, tokens, cfg: TransformerConfig):
-    """Per-shard forward: tokens [B_loc, S_loc] -> (logits_local, aux).
+def _mix(h, lp, cfg: TransformerConfig, mixer, cos, sin, sp_size):
+    if mixer == "attention":
+        return _attention_block(h, lp, cfg, cos, sin, sp_size)
+    if mixer == "gated_nope_attention":
+        return _gated_nope_attention_block(h, lp, cfg, sp_size)
+    if sp_size > 1:
+        raise ValueError("a linear-attention layer keeps a state along the "
+                         "sequence: the sequence cannot be split over %r"
+                         % cfg.sp_axis)
+    return lax.psum(linear_attention_block(h, lp, cfg.linear_attention),
+                    cfg.tp_axis)
 
-    Must run inside a shard_map over a mesh containing
-    (dp_axis, sp_axis, tp_axis).  logits are [B, S, V/tp] in f32.
-    """
+
+def _feed_forward(h, lp, cfg: TransformerConfig, ffn, sp_size):
+    """(output, auxiliary loss or None, tokens of each expert)."""
+    if ffn == "dense":
+        return _dense_ffn(h, lp, cfg), None, ()
+    if ffn == "moe":
+        y, aux = _moe_block(h, lp, cfg, sp_size)
+        return y, aux, ()
+    b, s, d = h.shape
+    y, counts = expert_share_ffn(lp, h.reshape(b * s, d), cfg.experts)
+    return y.reshape(b, s, d), None, (counts,)
+
+
+def hidden(params, tokens, cfg: TransformerConfig):
+    """Per-shard decoder up to the final norm: tokens [B_loc, S_loc] ->
+    (x [B, S, d], aux, expert counts).  ``aux`` is the ``moe`` layers'
+    load-balancing losses summed; the counts are one ``[periods,
+    n_experts]`` array for each ``expert_share`` layer of the period."""
     sp_size = lax.axis_size(cfg.sp_axis)
     s_loc = tokens.shape[1]
     pos = lax.axis_index(cfg.sp_axis) * s_loc + jnp.arange(s_loc)
@@ -391,29 +544,33 @@ def forward(params, tokens, cfg: TransformerConfig):
         layers["w13"] = jnp.concatenate(
             [layers.pop("w1"), layers.pop("w3")], axis=-1)
 
-    def layer(carry, lp):
+    def layer(mixer, ffn, carry, lp):
         x, aux = carry
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        x = x + _attention_block(h, lp, cfg, cos, sin, sp_size)
+        x = x + _mix(h, lp, cfg, mixer, cos, sin, sp_size)
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.n_experts == 0:
-            x = x + _dense_ffn(h, lp, cfg)
-        else:
-            y, a = _moe_block(h, lp, cfg, sp_size)
-            x = x + y
-            aux = aux + a
-        return (x, aux), None
+        y, a, counts = _feed_forward(h, lp, cfg, ffn, sp_size)
+        return (x + y, aux if a is None else aux + a), counts
 
+    layer_fns = [partial(layer, mixer, ffn) for mixer, ffn in cfg.pattern]
     if cfg.remat:
-        pol = {"full": None,
+        # "full" keeps nothing but what a block names as dearer to compute
+        # again than to keep (the delta rule's walk along the sequence).
+        pol = {"full": jax.checkpoint_policies.save_only_these_names(
+                   *kda_saved_names),
                "dots": jax.checkpoint_policies.dots_saveable,
                "dots_no_batch":
                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
                }[cfg.remat_policy]
-        layer_fn = (jax.checkpoint(layer, policy=pol) if pol is not None
-                    else jax.checkpoint(layer))
-    else:
-        layer_fn = layer
+        layer_fns = [jax.checkpoint(fn, policy=pol) for fn in layer_fns]
+
+    def period(carry, lps):
+        counts = ()
+        for fn, lp in zip(layer_fns, lps):
+            carry, c = fn(carry, lp)
+            counts += c
+        return carry, counts
+
     # The MoE aux accumulator acquires V:(dp, sp) from the routed
     # tokens; the carry must enter with the same varying axes under
     # vma tracking (guarded no-op in untracked traces).
@@ -421,9 +578,14 @@ def forward(params, tokens, cfg: TransformerConfig):
     aux0 = pvary_missing(jnp.zeros((), jnp.float32),
                          (cfg.dp_axis, cfg.sp_axis)) \
         if cfg.n_experts else jnp.zeros((), jnp.float32)
-    (x, aux), _ = lax.scan(layer_fn, (x, aux0), layers,
-                           unroll=max(1, cfg.scan_unroll))
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+    (x, aux), counts = lax.scan(
+        period, (x, aux0), layers if isinstance(layers, tuple) else (layers,),
+        unroll=max(1, cfg.scan_unroll))
+    return rms_norm(x, params["ln_f"], cfg.norm_eps), aux, counts
+
+
+def _logits(x, params, cfg: TransformerConfig):
+    """[.., d] -> [.., V/tp] float32."""
     # Vocab projection dtype: bf16 operands with f32 accumulation only
     # on the flash path ("auto"); with the chunked-XLA attention
     # fallback the bf16 form collapses throughput ~12x (159k -> 13.6k
@@ -432,25 +594,64 @@ def forward(params, tokens, cfg: TransformerConfig):
     bf16_logits = (cfg.logits_dtype == "bf16"
                    or (cfg.logits_dtype == "auto"
                        and _use_flash_attention()))
+
+    def head(dtype):
+        if cfg.tie_embeddings:
+            return params["embed"].astype(dtype).T
+        return params["head"].astype(dtype)
+
+    if bf16_logits:
+        return jnp.matmul(x.astype(cfg.act_dtype), head(cfg.act_dtype),
+                          preferred_element_type=jnp.float32)
+    return x.astype(jnp.float32) @ head(jnp.float32)
+
+
+def forward(params, tokens, cfg: TransformerConfig):
+    """Per-shard forward: tokens [B_loc, S_loc] -> (logits_local, aux).
+
+    Must run inside a shard_map over a mesh containing
+    (dp_axis, sp_axis, tp_axis).  logits are [B, S, V/tp] in f32.
+    """
+    x, aux, _ = hidden(params, tokens, cfg)
     with jax.named_scope(scopes.HEAD):
-        if bf16_logits:
-            logits = jnp.matmul(
-                x.astype(cfg.act_dtype),
-                params["embed"].astype(cfg.act_dtype).T,
-                preferred_element_type=jnp.float32)
-        else:
-            logits = (x.astype(jnp.float32)
-                      @ params["embed"].astype(jnp.float32).T)
+        logits = _logits(x, params, cfg)
     return logits, aux / cfg.n_layers
+
+
+@jax.named_scope(scopes.HEAD)
+def _blocked_nll_sum(x, targets, params, cfg: TransformerConfig):
+    """Sum of the tokens' nll, ``cfg.head_block`` tokens at a time: a
+    block's logits live from its product to its cross entropy, in the
+    forward pass and again in the backward pass."""
+    d = x.shape[-1]
+    x, targets = x.reshape(-1, d), targets.reshape(-1)
+    if x.shape[0] % cfg.head_block:
+        raise ValueError("%d tokens are no whole number of head blocks of "
+                         "%d" % (x.shape[0], cfg.head_block))
+
+    @jax.checkpoint
+    def block(xs):
+        x_b, t_b = xs
+        return vocab_parallel_cross_entropy(
+            _logits(x_b, params, cfg), t_b, cfg.tp_axis).sum()
+
+    return lax.map(block, (x.reshape(-1, cfg.head_block, d),
+                           targets.reshape(-1, cfg.head_block))).sum()
 
 
 def loss_fn(params, batch, cfg: TransformerConfig):
     """Per-shard mean nll (+ MoE aux); psum-averaged over dp and sp."""
     tokens, targets = batch["tokens"], batch["targets"]
-    logits, aux = forward(params, tokens, cfg)
-    with jax.named_scope(scopes.HEAD):
-        nll = vocab_parallel_cross_entropy(logits, targets, cfg.tp_axis)
-    loss = nll.mean() + cfg.aux_loss_weight * aux
+    if cfg.head_block:
+        x, aux, _ = hidden(params, tokens, cfg)
+        aux = aux / cfg.n_layers
+        nll_mean = _blocked_nll_sum(x, targets, params, cfg) / targets.size
+    else:
+        logits, aux = forward(params, tokens, cfg)
+        with jax.named_scope(scopes.HEAD):
+            nll = vocab_parallel_cross_entropy(logits, targets, cfg.tp_axis)
+        nll_mean = nll.mean()
+    loss = nll_mean + cfg.aux_loss_weight * aux
     return lax.pmean(loss, (cfg.dp_axis, cfg.sp_axis))
 
 

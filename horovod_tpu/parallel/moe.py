@@ -10,17 +10,29 @@ compiled step.
 All functions run inside a shard_map body.  Shapes per shard:
 tokens ``x: [T, d]``; experts_per_shard local experts; global expert count
 E = ep_size * experts_per_shard.
+
+``expert_share_ffn`` (second half of this file) is the layer a chip runs
+when it holds some of a layer's experts: told which ids it holds, it
+routes over all of them, drops nothing and computes its own experts' part
+by dense products over blocks of the (token, expert) pairs sorted by
+expert, beside a shared expert.  It has no exchange yet: the other
+experts' part is left out, as on one chip of an expert-parallel group
+whose tokens all stay at home.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..common import scopes
+from .ring_attention import pvary_missing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,3 +159,218 @@ def aux_load_balance_loss(gates, dispatch):
     frac_tokens = dispatch.any(-1).astype(jnp.float32).mean(0)
     frac_gates = gates.mean(0)
     return e * jnp.sum(frac_tokens * frac_gates)
+
+
+# --------------------------------------------------------------------------
+# A chip's share of an expert layer, dropless
+# --------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class ExpertShare:
+    """Which experts of a layer live here.  The router is ``n_experts``
+    wide whatever ``count`` is."""
+    n_experts: int
+    first: int                  # id of the first expert held here
+    count: int                  # experts held here, ids first..first+count-1
+    top_k: int
+    d_model: int
+    d_ff: int                   # width of a routed expert
+    d_shared: int               # width of the shared expert (0 = none)
+    routed_scaling: float = 1.0
+    # Rows of one block of sorted pairs: what one pass of the expert
+    # products takes.  A block never spans two experts.
+    block_rows: int = 512
+
+    def __post_init__(self):
+        if not 0 <= self.first <= self.first + self.count <= self.n_experts:
+            raise ValueError("experts %d..%d are not among %d"
+                             % (self.first, self.first + self.count - 1,
+                                self.n_experts))
+
+
+def init_expert_share_params(key, share: ExpertShare, n: int,
+                             dtype=jnp.float32):
+    """``n`` stacked layers of the held experts, the router and the
+    shared expert (SwiGLU each).  ``router_bias`` is the family's
+    load-balancing buffer: added to the scores where the experts are
+    chosen and nowhere else, so no gradient reaches it; it starts at zero
+    and is set from outside (a checkpoint, or a calibration)."""
+    ks = jax.random.split(key, 7)
+    d, f, fs = share.d_model, share.d_ff, share.d_shared
+
+    def norm(k, shape, fan_in):
+        return (jax.random.normal(k, shape) / math.sqrt(fan_in)).astype(dtype)
+
+    params = {"router": norm(ks[0], (n, d, share.n_experts), d),
+              "router_bias": jnp.zeros((n, share.n_experts), dtype),
+              "we1": norm(ks[1], (n, share.count, d, f), d),
+              "we3": norm(ks[2], (n, share.count, d, f), d),
+              "we2": norm(ks[3], (n, share.count, f, d), f)}
+    if fs:
+        params.update(ws1=norm(ks[4], (n, d, fs), d),
+                      ws3=norm(ks[5], (n, d, fs), d),
+                      ws2=norm(ks[6], (n, fs, d), fs))
+    return params
+
+
+def route(x, router, bias, share: ExpertShare):
+    """Sigmoid scores in float32 over every expert of the layer; the
+    ``top_k`` experts with the largest score + bias; their scores (without
+    the bias) renormalised to sum to ``routed_scaling``.  Returns (weights
+    ``[T, k]`` float32, ids ``[T, k]``)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, ids = lax.top_k(scores + bias.astype(jnp.float32), share.top_k)
+    top = jnp.take_along_axis(scores, ids, axis=-1)
+    return top / top.sum(-1, keepdims=True) * share.routed_scaling, ids
+
+
+def _sorted_pairs(ids, share: ExpertShare):
+    """The (token, choice) pairs that chose a held expert, sorted by
+    expert: ``order`` (indices into the flattened ``[T * k]`` pairs, held
+    ones first), and how many pairs every expert of the layer got."""
+    flat = ids.reshape(-1)
+    local = flat - share.first
+    local = jnp.where((local >= 0) & (local < share.count), local,
+                      share.count)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    loads = jnp.sum(flat[:, None] == jnp.arange(share.n_experts)[None, :],
+                    axis=0, dtype=jnp.int32)
+    return order, loads
+
+
+def _swiglu(x, w1, w3, w2):
+    return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
+
+
+def _zeros(shape, dtype, like):
+    """Zeros that vary over the mesh axes ``like`` varies over: what a
+    loop's carry has to start as under ``check_vma``."""
+    return pvary_missing(jnp.zeros(shape, dtype), tuple(jax.typeof(like).vma))
+
+
+def _plan(sizes, rows: int):
+    """How the sorted pairs fall into blocks of ``rows`` rows of one expert
+    each: an expert with ``n`` pairs takes ``ceil(n / rows)`` blocks, so a
+    block never spans two experts and every product is dense.  Returns the
+    running count of pairs and of blocks after each expert."""
+    return jnp.cumsum(sizes), jnp.cumsum((sizes + rows - 1) // rows)
+
+
+def _block(i, order, sizes, plan, rows: int):
+    """Block ``i`` of the sorted pairs.  Returns its expert, each row's
+    place among the flattened ``[T * k]`` pairs, and which rows are real
+    (an expert's last block is part empty)."""
+    ends, block_ends = plan
+    expert = jnp.sum(i >= block_ends)
+    nth = i - jnp.where(expert > 0, block_ends[expert - 1], 0)
+    at = ends[expert] - sizes[expert] + nth * rows \
+        + jnp.arange(rows, dtype=jnp.int32)
+    return (expert, order[jnp.minimum(at, order.shape[0] - 1)],
+            at < ends[expert])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(top_k, rows, x, we1, we3, we2, weights, order, sizes):
+    """``y[t] = sum over t's held choices of weight * expert(x[t])``,
+    block by block over the sorted pairs: a step pays for the blocks
+    that hold pairs (``0.2 T`` rows where routing is even), and ``k T``
+    rows are still right."""
+    return _routed_fwd(top_k, rows, x, we1, we3, we2, weights, order,
+                       sizes)[0]
+
+
+def _routed_fwd(top_k, rows, x, we1, we3, we2, weights, order, sizes):
+    flat_w = weights.reshape(-1)
+    plan = _plan(sizes, rows)
+
+    def block(i, y):
+        with jax.named_scope(scopes.ROUTER):
+            expert, pairs, real = _block(i, order, sizes, plan, rows)
+            tokens = pairs // top_k
+            x_rows = x[tokens]
+            w_rows = jnp.where(real, flat_w[pairs], 0.0)
+        with jax.named_scope(scopes.EXPERTS):
+            out = _swiglu(x_rows, we1[expert].astype(x.dtype),
+                          we3[expert].astype(x.dtype),
+                          we2[expert].astype(x.dtype))
+        with jax.named_scope(scopes.ROUTER):
+            return y.at[tokens].add(
+                (out.astype(jnp.float32) * w_rows[:, None]).astype(y.dtype))
+
+    # A token's sum has at most top_k terms: it is kept at x's precision.
+    y = lax.fori_loop(0, plan[1][-1], block, _zeros(x.shape, x.dtype, x))
+    return y, (x, we1, we3, we2, weights, order, sizes)
+
+
+def _routed_bwd(top_k, rows, res, dy):
+    """Each block's products are computed anew and differentiated on the
+    spot, so nothing of a block outlives it."""
+    x, we1, we3, we2, weights, order, sizes = res
+    flat_w = weights.reshape(-1)
+    plan = _plan(sizes, rows)
+
+    def expert_of(x_rows, w1, w3, w2):
+        return _swiglu(x_rows, w1.astype(x.dtype), w3.astype(x.dtype),
+                       w2.astype(x.dtype))
+
+    def block(i, carry):
+        dx, d1, d3, d2, dw = carry
+        with jax.named_scope(scopes.ROUTER):
+            expert, pairs, real = _block(i, order, sizes, plan, rows)
+            tokens = pairs // top_k
+            x_rows = x[tokens]
+            w_rows = jnp.where(real, flat_w[pairs], 0.0)
+            dy_rows = dy[tokens].astype(jnp.float32)
+        with jax.named_scope(scopes.EXPERTS):
+            out, vjp = jax.vjp(expert_of, x_rows, we1[expert], we3[expert],
+                               we2[expert])
+            dx_rows, g1, g3, g2 = vjp(
+                (dy_rows * w_rows[:, None]).astype(out.dtype))
+        with jax.named_scope(scopes.ROUTER):
+            dw_rows = jnp.where(
+                real, jnp.sum(out.astype(jnp.float32) * dy_rows, -1), 0.0)
+            return (dx.at[tokens].add(dx_rows.astype(dx.dtype)),
+                    d1.at[expert].add(g1), d3.at[expert].add(g3),
+                    d2.at[expert].add(g2), dw.at[pairs].add(dw_rows))
+
+    init = (_zeros(x.shape, x.dtype, x),
+            _zeros(we1.shape, we1.dtype, x), _zeros(we3.shape, we3.dtype, x),
+            _zeros(we2.shape, we2.dtype, x),
+            _zeros(flat_w.shape, jnp.float32, x))
+    dx, d1, d3, d2, dw = lax.fori_loop(0, plan[1][-1], block, init)
+    return (dx, d1, d3, d2,
+            dw.reshape(weights.shape).astype(weights.dtype), None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+@jax.named_scope(scopes.MOE)
+def expert_share_ffn(params, x, share: ExpertShare):
+    """The held experts' and the shared expert's part of a
+    mixture-of-experts layer over ``x`` ``[T, d]``:
+
+        y = sum_{e held, e among t's top_k} w_e * SwiGLU_e(x) + Shared(x)
+
+    Nothing is dropped for any routing, and no tensor has an expert or a
+    capacity axis.  Returns ``y`` and how many tokens every expert of the
+    layer got (``[n_experts]``, a device value; the held experts' are
+    ``[first:first + count]``)."""
+    with jax.named_scope(scopes.ROUTER):
+        weights, ids = route(x, params["router"], params["router_bias"],
+                             share)
+        order, loads = _sorted_pairs(ids, share)
+        sizes = lax.dynamic_slice_in_dim(loads, share.first, share.count)
+        vma = tuple(jax.typeof(x).vma)
+        held = [pvary_missing(params[name], vma)
+                for name in ("we1", "we3", "we2")]
+    y = _routed(share.top_k, share.block_rows, x, *held, weights, order,
+                sizes)
+    if share.d_shared:
+        with jax.named_scope(scopes.SHARED_EXPERT):
+            y = y + _swiglu(x, params["ws1"].astype(x.dtype),
+                            params["ws3"].astype(x.dtype),
+                            params["ws2"].astype(x.dtype))
+    return y, loads

@@ -1,0 +1,395 @@
+"""The hybrid decoder (``models/transformer.py`` with a layer pattern:
+gated NoPE softmax layers, delta-rule linear-attention layers, a chip's
+share of a dropless expert layer) against the plain float32 reference kept
+with the benchmark (``yardstick/builders/solar_open2.py``), at a small size
+on the CPU with seeded weights."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from horovod_tpu.models import transformer
+from horovod_tpu.models.linear_attention import (KdaConfig, init_kda_params,
+                                                 kda_chunked,
+                                                 linear_attention_block)
+from horovod_tpu.parallel.moe import (ExpertShare, expert_share_ffn,
+                                      init_expert_share_params)
+from yardstick.builders import solar_open2 as reference
+
+
+def small_cell(heads=2, kv_heads=1, first_expert=3, dtype="float32"):
+    """hidden 64, heads of 16, 8 experts, 2 a token, 2 held, one period of
+    4 layers, sequences of 128: the cell's files in small."""
+    config = {
+        "hidden_size": 64, "head_dim": 16, "vocab_size": 256,
+        "num_hidden_layers": 4, "num_attention_heads": heads,
+        "num_key_value_heads": kv_heads, "intermediate_size": 128,
+        "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 16,
+                               "num_heads": heads, "num_kv_heads": None},
+        "moe_intermediate_size": 32, "n_routed_experts": 2,
+        "published": {"n_routed_experts": 8}, "num_experts_per_tok": 2,
+        "n_shared_experts": 1, "routed_scaling_factor": 1,
+        "rms_norm_eps": 1e-5, "gqa_interval": 3, "gqa_layers": [0, 4, 8],
+        "first_k_dense_replace": 0, "tie_word_embeddings": False,
+        "activation_dtype": dtype, "param_dtype": "float32",
+        "assumed_sizes": {"gate_rank": 16},
+        "held": {"first_expert": first_expert},
+    }
+    spec = {"seq_len": 128, "batch_per_chip": 2, "delta_rule_chunk": 16,
+            "expert_block_rows": 16, "head_block": 64}
+    return {"name": "small", "config": config, "spec": spec}
+
+
+def program_loss_and_grads(cfg, params, batch, mesh_shape=(1, 1, 1)):
+    mesh = jax.sharding.Mesh(
+        np.asarray(jax.devices()[:math.prod(mesh_shape)]).reshape(mesh_shape),
+        (cfg.dp_axis, cfg.sp_axis, cfg.tp_axis))
+    specs = transformer.param_specs(cfg)
+    rows = {k: P(cfg.dp_axis, cfg.sp_axis) for k in batch}
+    fn = jax.jit(jax.shard_map(
+        jax.value_and_grad(lambda p, b: transformer.loss_fn(p, b, cfg)),
+        mesh=mesh, in_specs=(specs, rows), out_specs=(P(), specs),
+        check_vma=True))
+    return fn(params, batch)
+
+
+def worst(a, b):
+    """Largest difference between two trees' leaves, each against the
+    largest entry of the second's leaf."""
+    return max(jax.tree.leaves(jax.tree.map(
+        lambda x, y: float(np.abs(x - y).max() / (np.abs(y).max() + 1e-30)),
+        jax.device_get(a), jax.device_get(b))))
+
+
+# -- the whole model ---------------------------------------------------------
+
+def test_program_matches_reference_loss_and_every_gradient():
+    cell = small_cell()
+    cfg = reference._model_config(cell)
+    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    batch = reference.make_batch(cell, 1, 2)
+    loss, grads = program_loss_and_grads(cfg, params, batch)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        lambda p: reference.reference_loss_fn(
+            p, batch["tokens"], batch["targets"], cell["config"])))(params)
+    assert abs(float(loss) - float(ref_loss)) < 2e-5 * float(ref_loss)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    ref_flat = jax.tree.leaves(ref_grads)
+    assert len(flat) == len(ref_flat) == 3 + 4 * 10 + 3 * 15 + 5
+    for (path, g), r in zip(flat, ref_flat):
+        if path[-1].key == "router_bias":     # chooses experts, no gradient
+            assert float(jnp.abs(g).max()) == float(jnp.abs(r).max()) == 0
+            continue
+        assert float(jnp.abs(r).max()) > 0, path      # every leaf is used
+        assert float(jnp.abs(g - r).max()) \
+            < 2e-3 * float(jnp.abs(r).max()), path
+
+
+def test_bf16_activations_stay_near_the_reference():
+    cell = small_cell(dtype="bfloat16")
+    cfg = reference._model_config(cell)
+    params = transformer.init_params(jax.random.PRNGKey(2), cfg)
+    batch = reference.make_batch(cell, 3, 2)
+    loss, _ = program_loss_and_grads(cfg, params, batch)
+    ref_loss = reference.reference_loss(params, batch, cell["config"])
+    assert abs(float(loss) - ref_loss) < 5e-3 * ref_loss
+
+
+def test_head_shares_over_tp_add_up_to_the_uncut_model():
+    """Two tensor-parallel shards hold half the heads of every mixer and
+    half the vocabulary each; their parts add up (psum over tp) to what
+    one shard with all of them gives: loss and every gradient."""
+    cell = small_cell(heads=4, kv_heads=2)
+    cfg = reference._model_config(cell)
+    params = transformer.init_params(jax.random.PRNGKey(4), cfg)
+    batch = reference.make_batch(cell, 5, 2)
+    whole = program_loss_and_grads(cfg, params, batch, (1, 1, 1))
+    halves = program_loss_and_grads(cfg, params, batch, (1, 1, 2))
+    assert abs(float(whole[0]) - float(halves[0])) < 1e-5 * float(whole[0])
+    assert worst(halves[1], whole[1]) < 1e-3
+
+
+@pytest.fixture(scope="module")
+def prepared():
+    """The small cell as the builder sets it before the first step: the
+    balancing buffers fitted to a load profile and the head fitted to the
+    batch, both by the reference (``prepare``)."""
+    cell = small_cell()
+    cell["spec"]["expert_load_profile"] = [1.5, 0.5]
+    cfg = reference._model_config(cell)
+    batch = reference.make_batch(cell, 9, 2)
+    params, loss_ref, loads = reference.prepare(
+        transformer.init_params(jax.random.PRNGKey(8), cfg),
+        batch["tokens"], batch["targets"], cell)
+    return cell, cfg, batch, params, loss_ref, loads
+
+
+def test_the_fitted_buffers_give_the_program_the_cells_loads(prepared):
+    """The loads are the profile's (an expert of the small cell spans six
+    blocks of 16 rows, another two), in the reference that fitted the
+    buffers and in the program that only reads them, and the program's
+    loss is the reference's."""
+    cell, cfg, batch, params, loss_ref, loads = prepared
+    goal = reference.load_targets(cell, batch["tokens"].size)
+    assert goal[3:5].tolist() == [96.0, 32.0] and goal.sum() == 2 * 256
+    assert np.abs(loads - goal).max() <= 2
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1, 1),
+                             (cfg.dp_axis, cfg.sp_axis, cfg.tp_axis))
+    counts = jax.jit(jax.shard_map(
+        lambda p, t: jax.tree.map(
+            lambda c: lax.psum(c, (cfg.dp_axis, cfg.sp_axis)),
+            transformer.hidden(p, t, cfg)[2]), mesh=mesh,
+        in_specs=(transformer.param_specs(cfg), P(cfg.dp_axis, cfg.sp_axis)),
+        out_specs=P(), check_vma=True))(params, batch["tokens"])
+    assert np.abs(np.concatenate(counts) - loads).max() <= 1
+    loss, _ = program_loss_and_grads(cfg, params, batch)
+    assert abs(float(loss) - loss_ref) < 2e-5 * loss_ref
+
+
+@pytest.mark.parametrize("part", reference.WITHOUT)
+def test_the_fitted_head_tells_a_left_out_part(prepared, part):
+    """Under the head fitted to its batch the loss reads the mean squared
+    angle between the hidden states compared: the reference without one
+    part is far outside the tolerance the benchmark's cell is held to."""
+    cell, cfg, batch, params, loss_ref, _ = prepared
+    without = reference.reference_loss_fn(
+        params, batch["tokens"], batch["targets"], cell["config"],
+        without=(part,))
+    assert float(without) - loss_ref > 10 * reference.LOSS_RTOL * loss_ref
+
+
+def test_train_step_runs_the_pattern_and_the_loss_falls():
+    import optax
+
+    import horovod_tpu.jax as hvd
+    hvd.init()
+    try:
+        cell = small_cell(dtype="bfloat16")
+        cfg = reference._model_config(cell)
+        mesh = hvd.create_mesh((2, 1, 1), ("dp", "sp", "tp"),
+                               jax.devices()[:2])
+        build, shard = transformer.make_train_step(cfg, mesh,
+                                                   optax.adamw(1e-3))
+        step, params, opt = build(transformer.init_params(
+            jax.random.PRNGKey(6), cfg))
+        batch = shard(reference.make_batch(cell, 7, 4))
+        losses = []
+        for _ in range(4):
+            params, opt, loss = step(params, opt, batch)
+            losses.append(float(loss))
+        assert all(map(math.isfinite, losses)) and losses[-1] < losses[0]
+    finally:
+        hvd.shutdown()
+
+
+def test_default_config_is_the_program_it_was():
+    """``TransformerConfig()`` has no pattern: one stacked dict of
+    attention + dense layers under one scan, the loss PR 26's tree gave
+    for this seed and batch."""
+    cfg = transformer.TransformerConfig()
+    assert cfg.pattern == (("attention", "dense"),)
+    assert cfg.layer_pattern is None and cfg.head_block == 0
+    small = transformer.TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=128)
+    params = transformer.init_params(jax.random.PRNGKey(0), small)
+    assert sorted(params) == ["embed", "layers", "ln_f"]
+    assert sorted(params["layers"]) == ["ln1", "ln2", "w1", "w2", "w3",
+                                        "wk", "wo", "wq", "wv"]
+    tokens = np.random.default_rng(0).integers(0, 256, (4, 32), np.int32)
+    batch = {"tokens": tokens, "targets": np.roll(tokens, -1, 1)}
+    loss, _ = program_loss_and_grads(small, params, batch, (2, 2, 2))
+    assert abs(float(loss) - 5.919988632202148) < 1e-5
+    jaxpr = str(jax.make_jaxpr(lambda p, t: jax.shard_map(
+        lambda p, t: transformer.forward(p, t, small)[0],
+        mesh=jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1, 1),
+                               ("dp", "sp", "tp")),
+        in_specs=(transformer.param_specs(small), P("dp", "sp")),
+        out_specs=P("dp", "sp", "tp"))(p, t))(params, tokens))
+    assert jaxpr.count("scan[") == 1 and "checkpoint" not in jaxpr
+
+
+def test_a_pattern_refuses_what_it_cannot_run():
+    with pytest.raises(ValueError, match="layer_pattern"):
+        transformer.TransformerConfig(layer_pattern=(("rope", "dense"),))
+    with pytest.raises(ValueError, match="needs its configuration"):
+        transformer.TransformerConfig(
+            layer_pattern=(("linear_attention", "dense"),))
+    with pytest.raises(ValueError, match="whole number of periods"):
+        transformer.TransformerConfig(
+            n_layers=3, layer_pattern=(("attention", "dense"),) * 2)
+
+
+# -- the delta rule ------------------------------------------------------------
+
+def delta_rule_inputs(key, decay, b=2, s=64, h=3, d=16):
+    ks = jax.random.split(key, 5)
+    q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True) for x in
+            (jax.random.normal(kk, (b, s, h, d)) for kk in ks[:2]))
+    v = jax.random.normal(ks[2], (b, s, h, d))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, s, h, d)))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)) + 1.0)
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("segment", [2, 16])
+@pytest.mark.parametrize("decay", [0.05, 3.0, 40.0])
+def test_chunked_delta_rule_matches_the_recurrence(decay, segment):
+    """Chunk 16 over four chunks, in segments of two chunks or in one,
+    beta up to 2, decays from next to none to e^-40 a step
+    (``exp(-cumsum)`` would overflow inside one chunk): output and the
+    gradient of every input."""
+    args = delta_rule_inputs(jax.random.PRNGKey(int(decay)), decay)
+    assert float(args[4].max()) > 1.5
+
+    def plain(*a):
+        return jax.vmap(reference.kda_recurrence)(*a)
+
+    def chunked(*a):
+        return kda_chunked(*a, 16, segment=segment)
+
+    out, want = chunked(*args), plain(*args)
+    assert float(jnp.abs(out - want).max()) < 1e-5
+    weight = jax.random.normal(jax.random.PRNGKey(9), out.shape)
+    grads = jax.grad(lambda *a: (chunked(*a) * weight).sum(),
+                     argnums=range(5))(*args)
+    wants = jax.grad(lambda *a: (plain(*a) * weight).sum(),
+                     argnums=range(5))(*args)
+    for g, w in zip(grads, wants):
+        assert bool(jnp.isfinite(g).all())
+        assert float(jnp.abs(g - w).max()) < 1e-4 * float(jnp.abs(w).max())
+
+
+def test_delta_rule_refuses_a_ragged_sequence():
+    args = delta_rule_inputs(jax.random.PRNGKey(0), 1.0, s=40)
+    with pytest.raises(ValueError, match="not a multiple"):
+        kda_chunked(*args, 16)
+
+
+def test_linear_mixer_head_shares_add_up_to_the_uncut_mixer():
+    cfg = KdaConfig(n_heads=4, head_size=16, gate_rank=16, chunk=16)
+    lp = jax.tree.map(lambda w: w[0], init_kda_params(
+        jax.random.PRNGKey(1), 64, cfg, 1, jnp.float32))
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 64, 64))
+    whole = linear_attention_block(x, lp, cfg)
+    half = KdaConfig(n_heads=2, head_size=16, gate_rank=16, chunk=16)
+
+    def share(at):
+        cols = slice(at * half.width, (at + 1) * half.width)
+        heads = slice(at * 2, (at + 1) * 2)
+        part = {name: w[..., cols] for name, w in lp.items()
+                if name in ("wq", "wk", "wv", "conv_q", "conv_k", "conv_v",
+                            "w_fb", "w_gb", "decay_bias")}
+        part.update(w_fa=lp["w_fa"], w_ga=lp["w_ga"], o_norm=lp["o_norm"],
+                    w_beta=lp["w_beta"][:, heads], a_log=lp["a_log"][heads],
+                    wo=lp["wo"][cols])
+        return linear_attention_block(x, part, half)
+
+    assert float(jnp.abs(share(0) + share(1) - whole).max()) \
+        < 1e-5 * float(jnp.abs(whole).max())
+
+
+# -- the expert layer ------------------------------------------------------------
+
+def masked_loop(params, x, share, shared=True):
+    """The share's part of the layer with no sort and no blocks: every
+    held expert over every token under a mask."""
+    hi = lax.Precision.HIGHEST
+    scores = jax.nn.sigmoid(jnp.dot(x, params["router"], precision=hi))
+    _, ids = lax.top_k(scores + params["router_bias"], share.top_k)
+    top = jnp.take_along_axis(scores, ids, -1)
+    weights = top / top.sum(-1, keepdims=True) * share.routed_scaling
+
+    def swiglu(w1, w3, w2):
+        return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
+
+    y = swiglu(params["ws1"], params["ws3"], params["ws2"]) if shared \
+        else jnp.zeros_like(x)
+    for j in range(share.count):
+        w_j = jnp.where(ids == share.first + j, weights, 0.0).sum(-1)
+        y = y + w_j[:, None] * swiglu(params["we1"][j], params["we3"][j],
+                                      params["we2"][j])
+    return y, (ids[..., None] == jnp.arange(share.n_experts)).sum((0, 1))
+
+
+def expert_layer(routing, tokens=96):
+    share = ExpertShare(n_experts=8, first=2, count=3, top_k=3, d_model=32,
+                        d_ff=24, d_shared=24, block_rows=16)
+    params = jax.tree.map(lambda w: w[0], init_expert_share_params(
+        jax.random.PRNGKey(3), share, 1))
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(4), (tokens, 32)))
+    pull = jnp.zeros((8,))
+    if routing == "every token picks only held experts":
+        pull = pull.at[2:5].set(1.0)
+    elif routing == "one expert gets every token":
+        pull = pull.at[3].set(1.0)
+    elif routing == "the balancing bias chooses":
+        # scores lie in (0, 1): a bias of 2 wins whatever the scores are,
+        # and the chosen experts' weights are still their scores'
+        params["router_bias"] = jnp.zeros((8,)).at[jnp.array([0, 4, 7])].set(
+            2.0)
+    # x is positive, so a column raised by a constant wins every token.
+    params["router"] = params["router"] + pull[None, :]
+    return share, params, x
+
+
+@pytest.mark.parametrize("routing", [
+    "uniform", "every token picks only held experts",
+    "one expert gets every token", "the balancing bias chooses"])
+def test_expert_share_matches_the_masked_loop(routing):
+    share, params, x = expert_layer(routing)
+    y, loads = jax.jit(lambda p, x: expert_share_ffn(p, x, share))(params, x)
+    want, want_loads = masked_loop(params, x, share)
+    assert loads.tolist() == want_loads.tolist()
+    assert int(loads.sum()) == share.top_k * x.shape[0]
+    held = loads[share.first:share.first + share.count]
+    if routing == "every token picks only held experts":
+        assert int(held.sum()) == share.top_k * x.shape[0]     # no drop
+    elif routing == "one expert gets every token":
+        assert int(held[1]) == x.shape[0]
+    elif routing == "the balancing bias chooses":
+        assert loads.tolist() == [x.shape[0] if e in (0, 4, 7) else 0
+                                  for e in range(8)]
+    else:
+        assert 0 < int(held.sum()) < share.top_k * x.shape[0]
+    assert float(jnp.abs(y - want).max()) < 1e-5 * float(jnp.abs(want).max())
+    weight = jax.random.normal(jax.random.PRNGKey(5), y.shape)
+    grads = jax.grad(lambda p, x: (expert_share_ffn(p, x, share)[0]
+                                   * weight).sum(), argnums=(0, 1))(params, x)
+    wants = jax.grad(lambda p, x: (masked_loop(p, x, share)[0]
+                                   * weight).sum(), argnums=(0, 1))(params, x)
+    assert worst(grads, wants) < 1e-4
+
+
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """Four shares of two experts, the shared expert counted once, against
+    all eight experts under one mask loop."""
+    whole = ExpertShare(n_experts=8, first=0, count=8, top_k=2, d_model=32,
+                        d_ff=24, d_shared=24, block_rows=16)
+    params = jax.tree.map(lambda w: w[0], init_expert_share_params(
+        jax.random.PRNGKey(6), whole, 1))
+    x = jax.random.normal(jax.random.PRNGKey(7), (64, 32))
+    want, _ = masked_loop(params, x, whole)
+    total, seen = 0.0, 0
+    for first in range(0, 8, 2):
+        part = ExpertShare(n_experts=8, first=first, count=2, top_k=2,
+                           d_model=32, d_ff=24,
+                           d_shared=24 if first == 0 else 0, block_rows=16)
+        held = dict(params, **{name: params[name][first:first + 2]
+                               for name in ("we1", "we3", "we2")})
+        y, loads = expert_share_ffn(held, x, part)
+        total, seen = total + y, seen + int(loads[first:first + 2].sum())
+    assert seen == 2 * x.shape[0]
+    assert float(jnp.abs(total - want).max()) \
+        < 1e-5 * float(jnp.abs(want).max())
+
+
+def test_expert_share_refuses_ids_outside_the_layer():
+    with pytest.raises(ValueError, match="not among"):
+        ExpertShare(n_experts=8, first=7, count=2, top_k=2, d_model=8,
+                    d_ff=8, d_shared=0)
