@@ -24,13 +24,15 @@ Phases, opened by the step builders (``jax/data_parallel.py``,
 Blocks inside the model, both passes: ``ATTENTION`` (softmax attention
 with its projections), ``HEAD`` (logits and cross entropy),
 ``LINEAR_ATTENTION`` (a delta-rule mixer whole; ``KDA_CORE`` inside it is
-the chunked recurrence alone), ``MOE`` (an expert layer whole; inside it
-``ROUTER`` is scores, top-k, the sort and the rows' gather and scatter,
-``EXPERTS`` the routed experts' matrix products alone, ``SHARED_EXPERT``
-the expert every token takes).  Kernels, one ``pallas_call`` each: ``FLASH_FWD``,
-``FLASH_DQ``, ``FLASH_DKV``, ``FLASH_BWD_ONEPASS``; ``kernel_name`` gives
-the same words as the ``name=`` of the call (``hvd_flash_fwd``), which is
-what the trace viewer prints for a Mosaic kernel.
+the chunked recurrence alone, kernels or XLA form), ``MOE`` (an expert
+layer whole; inside it ``ROUTER`` is scores, top-k, the sort and the rows'
+gather and scatter, ``EXPERTS`` the routed experts' matrix products alone,
+``SHARED_EXPERT`` the expert every token takes).  Kernels, one
+``pallas_call`` each: ``FLASH_FWD``, ``FLASH_DQ``, ``FLASH_DKV``,
+``FLASH_BWD_ONEPASS``; ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two,
+inside ``KDA_CORE``); ``kernel_name`` gives the same words as the ``name=``
+of the call (``hvd_flash_fwd``), which is what the trace viewer prints for
+a Mosaic kernel.
 """
 
 from __future__ import annotations
@@ -50,6 +52,8 @@ FLASH_FWD = "hvd.flash_fwd"
 FLASH_DQ = "hvd.flash_dq"
 FLASH_DKV = "hvd.flash_dkv"
 FLASH_BWD_ONEPASS = "hvd.flash_bwd_onepass"
+KDA_FWD = "hvd.kda_fwd"
+KDA_BWD = "hvd.kda_bwd"
 
 
 def kernel_name(scope: str) -> str:

@@ -22,10 +22,16 @@ with ``P`` as ``A`` but of q against k.  Everything of a chunk that does
 not need ``S`` is matrix products over many chunks at once; only
 ``S' = M S + N`` is a scan over chunks.  No exponent is ever positive
 (``_decayed_gram``), so a strong decay neither overflows nor loses the
-near steps.  The backward pass is autodiff through this form.  State and
-decays are float32 and the products run at full float32 precision: they
-are a hundredth of the step's operations and the state is reused 128
-times a sequence.
+near steps.  State and decays are float32 and the products run at full
+float32 precision: they are a hundredth of the step's operations and the
+state is reused 128 times a sequence.
+
+Two forms, chosen by shape (``kda_chunked``).  Heads that fill the 128
+lanes take the Pallas kernels of ``ops/kda_kernels.py``: a forward kernel
+that keeps the head's state and a chunk's matrices in VMEM, and a custom
+VJP whose backward kernel walks the segments in reverse.  Other heads
+(the tests' small configurations) take the XLA form below, whose backward
+pass is autodiff through ``_segment``; it is also the kernels' oracle.
 
 ``linear_attention_block`` is the whole mixer: projections, a causal
 depthwise convolution, the core, a gated RMSNorm over each head and the
@@ -36,6 +42,7 @@ attention's; the sequence is not split (the state would have to travel).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -44,6 +51,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..common import scopes
+from ..ops import kda_kernels
 from ..parallel.ring_attention import pvary_missing
 
 HI = lax.Precision.HIGHEST
@@ -224,16 +232,12 @@ def _segment(state, q, k, v, g, beta):
         + jnp.einsum("...ri,...ie->...re", p, w, precision=HI)
 
 
-def kda_chunked(q, k, v, g, beta, chunk: int, segment: int = 16):
-    """The recurrence at the top of this file over ``[B, S, H, D]``
-    (``beta`` ``[B, S, H]``), float32 in and out, ``S`` a multiple of
-    ``chunk``.  The sequence is walked ``segment`` chunks at a time and a
-    segment's chunk-local matrices are computed anew in the backward
-    pass: what outlives a segment is its ``[B, H, D, D]`` start state."""
+def kda_chunked_xla(q, k, v, g, beta, chunk: int, segment: int = 16):
+    """``kda_chunked`` in XLA operations, for the shapes the kernels do not
+    take.  A segment's chunk-local matrices are computed anew in the
+    backward pass, which is autodiff through ``_segment``: what outlives
+    a segment is its ``[B, H, D, D]`` start state."""
     bsz, s, h, d = q.shape
-    if s % chunk:
-        raise ValueError("the delta rule runs in chunks of %d steps; a "
-                         "sequence of %d is not a multiple" % (chunk, s))
     n = s // chunk
     per = math.gcd(n, segment)
 
@@ -248,9 +252,6 @@ def kda_chunked(q, k, v, g, beta, chunk: int, segment: int = 16):
                           tuple(jax.typeof(args[0]).vma))
 
     def walk(state, xs):
-        # Kept by a layer's recomputation (models/transformer.py: hidden),
-        # so that a layer recomputed in the backward pass does not walk
-        # the sequence a third time: 72 MiB a layer at 2 x 8192 tokens.
         state = checkpoint_name(state, SAVED[0])
         state, o = jax.checkpoint(_segment)(state, *xs)
         return state, checkpoint_name(o, SAVED[1])
@@ -259,6 +260,50 @@ def kda_chunked(q, k, v, g, beta, chunk: int, segment: int = 16):
     # [segments, B, H, per, C, D] -> [B, S, H, D]
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 4).reshape(bsz, s, h, d)
     return o * (1.0 / math.sqrt(d))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda_kernels(q, k, kb, vb, g, chunk, per, d):
+    """``ops/kda_kernels.py`` over ``[B, S, H D]``, with ``kb = beta k``
+    and ``vb = beta v`` so that autodiff outside takes ``beta``'s part."""
+    return kda_kernels.forward(q, k, kb, vb, g, chunk, per, d)[0]
+
+
+def _kda_kernels_fwd(q, k, kb, vb, g, chunk, per, d):
+    o, starts = kda_kernels.forward(q, k, kb, vb, g, chunk, per, d)
+    return checkpoint_name(o, SAVED[1]), \
+        (q, k, kb, vb, g, checkpoint_name(starts, SAVED[0]))
+
+
+def _kda_kernels_bwd(chunk, per, d, res, do):
+    return kda_kernels.backward(*res[:5], do, res[5], chunk, per, d)
+
+
+_kda_kernels.defvjp(_kda_kernels_fwd, _kda_kernels_bwd)
+
+
+def kda_chunked(q, k, v, g, beta, chunk: int, segment: int = 16):
+    """The recurrence at the top of this file over ``[B, S, H, D]``
+    (``beta`` ``[B, S, H]``), float32 out, ``S`` a multiple of ``chunk``.
+    The sequence is walked ``segment`` chunks at a time; a layer's
+    recomputation keeps the state each segment starts from and the output
+    (``SAVED``: 8 + 64 MiB a layer at 2 x 8192 tokens), so that a layer
+    recomputed in the backward pass does not walk the sequence again.
+    Shapes choose the form: heads that fill the lanes take the kernels."""
+    bsz, s, h, d = q.shape
+    if s % chunk:
+        raise ValueError("the delta rule runs in chunks of %d steps; a "
+                         "sequence of %d is not a multiple" % (chunk, s))
+    if not kda_kernels.takes(d, chunk):
+        return kda_chunked_xla(q, k, v, g, beta, chunk, segment)
+
+    def rows(x):
+        return x.astype(jnp.float32).reshape(bsz, s, h * d)
+
+    by = beta.astype(jnp.float32)[..., None]
+    o = _kda_kernels(rows(q), rows(k), rows(by * k), rows(by * v), rows(g),
+                     chunk, math.gcd(s // chunk, segment), d)
+    return o.reshape(bsz, s, h, d)
 
 
 # --------------------------------------------------------------------------

@@ -1,0 +1,374 @@
+"""Pallas TPU kernels for the chunked gated delta rule
+(``models/linear_attention.py`` has the recurrence and the XLA form).
+
+A chunk of ``C`` steps of two heads a grid step (of one where the heads
+are odd): a head's state (kept transposed, ``Z = S^T``, so a channel's
+decay scales a lane) and every chunk-local matrix stay in VMEM.  The
+kernels take ``kb = beta k`` and ``vb = beta v`` beside ``k``, so ``beta``
+itself never enters them:
+
+    N = tril(kb k^T . decay, -1)       P = tril(q k^T . decay)
+    [u | w_k] = (I + N)^-1 [vb | kb e^G]
+    w = u - w_k S      o = D^-1/2 ((q e^G) S + P w)
+    S' = Diag(e^{G_C}) S + (k e^{G_C - G})^T w
+
+**Decayed products with no positive exponent.**  ``decay[r, i] =
+exp(G_r - G_i)`` per channel cannot be split into a row's and a column's
+factor without overflow, unless the split point lies between ``i`` and
+``r``.  The chunk is halved again and again: at the level of blocks of
+``s`` rows an entry ``(r, i)`` with ``r`` in an odd block and ``i`` in the
+even block before it is split at the odd block's first row, so the level
+is one matrix product of ``a e^{x}`` against ``b e^{x}`` under a mask, with
+``x`` a sum of log-decays (never a difference of running sums) that a
+constant 0/1 matrix takes from ``g``; every entry below the diagonal
+belongs to exactly one level.  ``(I + N)^-1`` climbs the same levels:
+``X_2s = X_s - X_s (N . level_s) X_s``, which is the block formula
+``[[a, 0], [c, b]]^-1 = [[a^-1, 0], [-b^-1 c a^-1, b^-1]]`` on every pair
+of blocks at once.
+
+The backward kernel walks the segments in reverse; inside one it first
+sweeps forward and leaves in VMEM what every chunk starts from and
+computes before it meets the state (the state, the decays' exponentials,
+``P``, ``(I + N)^-1``, ``u``, ``w_k``: 14 MiB for two heads and 16
+chunks), then takes the chunks in reverse with the state's gradient
+carried in VMEM.
+
+Float32 throughout, every product at ``Precision.HIGHEST`` (Mosaic's six
+bfloat16 passes); the sums of log-decays alone take three, exactly
+(``_sums``).  On the v5e most of a chunk's time is those passes and the
+cuts of their operands; the ten dependent 64 x 64 products of the inverse
+are two fifths of the forward kernel (``PERF.md``, PR 28).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+
+from ..common import scopes
+from ..common.device import on_tpu
+from ..parallel.ring_attention import pvary_missing
+from .pallas_kernels import _sds
+
+HI = lax.Precision.HIGHEST
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _dot(a, b, dims=_NN):
+    return lax.dot_general(a, b, (dims, ((), ())), precision=HI,
+                           preferred_element_type=jnp.float32)
+
+
+def _eye(c):
+    rows = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    return jnp.where(rows == cols, 1.0, 0.0)
+
+
+def takes(head_size: int, chunk: int) -> bool:
+    """The shapes the kernels are written for: a head that fills the 128
+    lanes, a chunk that halves down to single rows and fills sublanes."""
+    return head_size % 128 == 0 and chunk >= 8 and chunk & (chunk - 1) == 0
+
+
+@functools.lru_cache(maxsize=None)
+def _constants(c: int):
+    """``sums`` ``[(L + 2) C, C]``: for each of the ``L = log2 C`` levels
+    (blocks of C/2 ... 1 rows) the 0/1 matrix that takes a row's exponent
+    from ``g`` (rows of an odd block: the log-decays after the block's
+    first row up to the row; rows of an even block: those after the row up
+    to the next block's first), then the running sum up to a row and the
+    sum after it.  ``masks`` ``[L, 2 C, C]``: each level's entries for
+    ``[P; N]`` stacked."""
+    r = np.arange(c)
+    j = r[None, :]
+    sums, masks = [], []
+    s = c // 2
+    while s >= 1:
+        block = r // s
+        odd = (block % 2 == 1)[:, None]
+        first, following = (block * s)[:, None], ((block + 1) * s)[:, None]
+        sums.append(np.where(odd, (j > first) & (j <= r[:, None]),
+                             (j > r[:, None]) & (j <= following)))
+        level = odd & (block[None, :] == block[:, None] - 1)
+        masks.append(np.concatenate([level, level]))
+        s //= 2
+    sums += [j <= r[:, None], j > r[:, None]]
+    return (np.concatenate(sums).astype(jnp.bfloat16),
+            np.stack(masks).astype(np.float32))
+
+
+def _sums(sums, x, dims=_NN):
+    """``sums`` (0 or 1, bfloat16) against float32 ``x`` in three passes,
+    no less exact than ``HIGHEST``'s six: ``x`` is cut into the three
+    bfloat16 pieces that add up to it and each of their products with a 0
+    or a 1 is exact."""
+    d = x.shape[1]
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    low = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    parts = lax.dot_general(sums, jnp.concatenate([hi, mid, low], axis=1),
+                            (dims, ((), ())),
+                            preferred_element_type=jnp.float32)
+    return parts[:, :d] + (parts[:, d:2 * d] + parts[:, 2 * d:])
+
+
+def _exponentials(g, sums):
+    """``exp`` of every sum of log-decays ``_constants`` lists, ``[(L + 2)
+    C, D]``."""
+    return jnp.exp(_sums(sums[:], g))
+
+
+def _decays(e, c):
+    """(each level's factors, ``e^G``, ``e^{G_C - G}``), ``[C, D]`` each."""
+    levels = e.shape[0] // c - 2
+    return ([e[l * c:(l + 1) * c] for l in range(levels)],
+            e[levels * c:(levels + 1) * c], e[(levels + 1) * c:])
+
+
+def _local(q, k, kb, vb, decays, masks):
+    """What a chunk computes before it meets the state: ``P``,
+    ``(I + N)^-1``, ``u``, ``w_k``."""
+    c, d = k.shape
+    levels = masks.shape[0]
+    e_lvl, e_in, _ = decays
+    both = jnp.concatenate([q, kb])
+    pn = 0.0
+    for l in range(levels):
+        pn = pn + masks[l] * _dot(
+            both * jnp.concatenate([e_lvl[l], e_lvl[l]]), k * e_lvl[l], _NT)
+    n = pn[c:]
+    eye = _eye(c)
+    x = eye - masks[levels - 1, c:] * n
+    for l in range(levels - 2, -1, -1):
+        x = x - _dot(_dot(x, masks[l, c:] * n), x)
+    solved = _dot(x, jnp.concatenate([vb, kb * e_in], axis=1))
+    # P alone has a diagonal: a step's own key, undecayed.
+    p = pn[:c] + eye * jnp.sum(q * k, axis=1, keepdims=True)
+    return p, x, solved[:, :d], solved[:, d:]
+
+
+def _advance(z, k, u, w_k, decays):
+    """(pseudo-values, state after the chunk) from the state before it."""
+    _, e_in, e_out = decays
+    w = u - _dot(w_k, z, _NT)
+    return w, z * e_in[-1:] + _dot(w, k * e_out, _TN)
+
+
+def _fwd_kernel(sums_ref, masks_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
+                o_ref, starts_ref, z_scr, *, per: int, scale: float):
+    t = pl.program_id(2)
+    d = z_scr.shape[-1]
+
+    @pl.when(t == 0)
+    def _():
+        z_scr[:] = jnp.zeros_like(z_scr)
+
+    @pl.when(t % per == 0)
+    def _():
+        starts_ref[0, :, 0] = z_scr[:]
+
+    # Under a condition like the rest: the interpreter, run inside a
+    # shard_map that checks what varies over the mesh, only takes a
+    # kernel's constants beside its varying blocks inside one.
+    @pl.when(t >= 0)
+    def _():
+        # The heads of a step share nothing: their chains of small
+        # dependent products fill each other's waits.
+        for i in range(z_scr.shape[0]):
+            at = slice(i * d, (i + 1) * d)
+            q, k = q_ref[0, :, at], k_ref[0, :, at]
+            decays = _decays(_exponentials(g_ref[0, :, at], sums_ref),
+                             q.shape[0])
+            p, _, u, w_k = _local(q, k, kb_ref[0, :, at], vb_ref[0, :, at],
+                                  decays, masks_ref)
+            z = z_scr[i]
+            w, z_scr[i] = _advance(z, k, u, w_k, decays)
+            o_ref[0, :, at] = scale * (_dot(q * decays[1], z, _NT)
+                                       + _dot(p, w))
+
+
+def _bwd_kernel(sums_ref, masks_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
+                do_ref, starts_ref, dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref,
+                z_scr, dz_scr, e_scr, p_scr, x_scr, u_scr, wk_scr, *,
+                per: int, scale: float):
+    j, t = pl.program_id(2), pl.program_id(3)
+    masks = masks_ref
+    levels = masks.shape[0]
+    heads, d = dz_scr.shape[:2]
+    c = q_ref.shape[1]
+
+    @pl.when(jnp.logical_and(j == 0, t == 0))
+    def _():
+        dz_scr[:] = jnp.zeros_like(dz_scr)
+
+    @pl.when(t == 0)
+    def _():
+        z_scr[:, 0] = starts_ref[0, :, 0]
+
+    @pl.when(t < per)
+    def _sweep():
+        # Forward again over the segment: the state every chunk starts
+        # from and what a chunk computes before it meets the state.
+        for i in range(heads):
+            at = slice(i * d, (i + 1) * d)
+            k = k_ref[0, :, at]
+            e = _exponentials(g_ref[0, :, at], sums_ref)
+            decays = _decays(e, c)
+            p, x, u, w_k = _local(q_ref[0, :, at], k, kb_ref[0, :, at],
+                                  vb_ref[0, :, at], decays, masks)
+            e_scr[i, t], p_scr[i, t], x_scr[i, t] = e, p, x
+            u_scr[i, t], wk_scr[i, t] = u, w_k
+            z_scr[i, t + 1] = _advance(z_scr[i, t], k, u, w_k, decays)[1]
+
+    def _back_chunk(i, t, at):
+        q, k, kb = q_ref[0, :, at], k_ref[0, :, at], kb_ref[0, :, at]
+        e_lvl, e_in, e_out = _decays(e_scr[i, t], c)
+        p, x, u, w_k = p_scr[i, t], x_scr[i, t], u_scr[i, t], wk_scr[i, t]
+        z, dz_next = z_scr[i, t], dz_scr[i]
+        whole = e_in[-1:]
+        w = u - _dot(w_k, z, _NT)
+        do = scale * do_ref[0, :, at]
+        q_in, k_out = q * e_in, k * e_out
+        dq_in = _dot(do, z)
+        dw = _dot(p, do, _TN) + _dot(k_out, dz_next, _NT)
+        dk_out = _dot(w, dz_next)
+        d_whole = jnp.sum(z * dz_next, axis=0, keepdims=True) * whole
+        dz_scr[i] = dz_next * whole + _dot(do, q_in, _TN) \
+            - _dot(dw, w_k, _TN)
+        # [u | w_k] = X [vb | kb e^G]; dN = -X^T dX X^T, below the diagonal.
+        back = _dot(x, jnp.concatenate([dw, -_dot(dw, z)], axis=1), _TN)
+        dvb, d_rhs = back[:, :d], back[:, d:]
+        dp = _dot(do, w, _NT)
+        dpn = jnp.concatenate([
+            dp, -_dot(back, jnp.concatenate([u, w_k], axis=1), _NT)])
+        diag = jnp.sum(_eye(c) * dp, axis=1, keepdims=True)
+        dq = dq_in * e_in + diag * k
+        dk = dk_out * e_out + diag * q
+        dkb = d_rhs * e_in
+        both = jnp.concatenate([q, kb])
+        dx = []
+        for l in range(levels):
+            e = e_lvl[l]
+            m = masks[l] * dpn
+            d_left = _dot(m, k * e)
+            d_right = _dot(m, both * jnp.concatenate([e, e]), _TN)
+            dq = dq + d_left[:c] * e
+            dkb = dkb + d_left[c:] * e
+            dk = dk + d_right * e
+            dx.append((d_left[:c] * q + d_left[c:] * kb + d_right * k) * e)
+        dx.append((dq_in * q + d_rhs * kb) * e_in)
+        dx.append(dk_out * k * e_out)
+        dq_ref[0, :, at], dk_ref[0, :, at] = dq, dk
+        dkb_ref[0, :, at], dvb_ref[0, :, at] = dkb, dvb
+        dg_ref[0, :, at] = _sums(sums_ref[:], jnp.concatenate(dx), _TN) \
+            + d_whole
+
+    @pl.when(t >= per)
+    def _back():
+        for i in range(heads):
+            _back_chunk(i, 2 * per - 1 - t, slice(i * d, (i + 1) * d))
+
+
+def _constant_inputs(c, like):
+    vma = tuple(jax.typeof(like).vma)
+    return tuple(pvary_missing(jnp.asarray(x), vma) for x in _constants(c))
+
+
+def _heads_a_step(h):
+    return 2 if h % 2 == 0 else 1
+
+
+def _whole(shape):
+    return pl.BlockSpec(shape, lambda *_: (0,) * len(shape))
+
+
+def _params(interpret, grid_rank, vmem_bytes):
+    if interpret:
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel")
+        + ("arbitrary",) * (grid_rank - 2),
+        vmem_limit_bytes=vmem_bytes)
+
+
+@jax.named_scope(scopes.KDA_FWD)
+def forward(q, k, kb, vb, g, chunk, per, d):
+    """``[B, S, H D]`` each -> (o ``[B, S, H D]``, the transposed state
+    every segment of ``per`` chunks starts from ``[B, H, S / (per C), D,
+    D]``)."""
+    from jax.experimental.pallas import tpu as pltpu
+    bsz, s, width = q.shape
+    h, n = width // d, s // chunk
+    interpret = not on_tpu()
+    sums, masks = _constant_inputs(chunk, q)
+    heads = _heads_a_step(h)
+    row = pl.BlockSpec((1, chunk, heads * d), lambda b, i, t: (b, t, i))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, per=per, scale=1.0 / math.sqrt(d)),
+        grid=(bsz, h // heads, n),
+        in_specs=[_whole(sums.shape), _whole(masks.shape)] + [row] * 5,
+        out_specs=[row, pl.BlockSpec((1, heads, 1, d, d),
+                                     lambda b, i, t: (b, i, t // per, 0, 0))],
+        out_shape=[_sds((bsz, s, width), jnp.float32, q),
+                   _sds((bsz, h, n // per, d, d), jnp.float32, q)],
+        scratch_shapes=[pltpu.VMEM((heads, d, d), jnp.float32)],
+        compiler_params=_params(interpret, 3, 32 << 20),
+        interpret=interpret,
+        name=scopes.kernel_name(scopes.KDA_FWD),
+    )(sums, masks, q, k, kb, vb, g)
+
+
+@jax.named_scope(scopes.KDA_BWD)
+def backward(q, k, kb, vb, g, do, starts, chunk, per, d):
+    """Gradients of q, k, kb, vb, g, ``[B, S, H D]`` each."""
+    from jax.experimental.pallas import tpu as pltpu
+    bsz, s, width = q.shape
+    h, n = width // d, s // chunk
+    segments = n // per
+    interpret = not on_tpu()
+    sums, masks = _constant_inputs(chunk, q)
+
+    def chunk_of(j, t):
+        # Steps 0 .. per - 1 sweep forward over the segment, steps per ..
+        # 2 per - 1 take its chunks last to first.
+        first = (segments - 1 - j) * per
+        return first + jnp.where(t < per, t, 2 * per - 1 - t)
+
+    def chunk_back(j, t):
+        # What only the way back reads or writes waits at the last chunk.
+        return (segments - 1 - j) * per + per - 1 - jnp.maximum(t - per, 0)
+
+    heads = _heads_a_step(h)
+    swept = pl.BlockSpec((1, chunk, heads * d),
+                         lambda b, i, j, t: (b, chunk_of(j, t), i))
+    back = pl.BlockSpec((1, chunk, heads * d),
+                        lambda b, i, j, t: (b, chunk_back(j, t), i))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, per=per, scale=1.0 / math.sqrt(d)),
+        grid=(bsz, h // heads, segments, 2 * per),
+        in_specs=[_whole(sums.shape), _whole(masks.shape)] + [swept] * 5
+        + [back, pl.BlockSpec((1, heads, 1, d, d), lambda b, i, j, t:
+                              (b, i, segments - 1 - j, 0, 0))],
+        out_specs=[back] * 5,
+        out_shape=[_sds((bsz, s, width), jnp.float32, q)] * 5,
+        scratch_shapes=[pltpu.VMEM((heads, per + 1, d, d), jnp.float32),
+                        pltpu.VMEM((heads, d, d), jnp.float32),
+                        pltpu.VMEM((heads, per, sums.shape[0], d),
+                                   jnp.float32),
+                        pltpu.VMEM((heads, per, chunk, chunk), jnp.float32),
+                        pltpu.VMEM((heads, per, chunk, chunk), jnp.float32),
+                        pltpu.VMEM((heads, per, chunk, d), jnp.float32),
+                        pltpu.VMEM((heads, per, chunk, d), jnp.float32)],
+        compiler_params=_params(interpret, 4, 48 << 20),
+        interpret=interpret,
+        name=scopes.kernel_name(scopes.KDA_BWD),
+    )(sums, masks, q, k, kb, vb, g, do, starts)

@@ -16,7 +16,9 @@ from jax.sharding import PartitionSpec as P
 from horovod_tpu.models import transformer
 from horovod_tpu.models.linear_attention import (KdaConfig, init_kda_params,
                                                  kda_chunked,
+                                                 kda_chunked_xla,
                                                  linear_attention_block)
+from horovod_tpu.ops import kda_kernels
 from horovod_tpu.parallel.moe import (ExpertShare, expert_share_ffn,
                                       init_expert_share_params)
 from yardstick.builders import solar_open2 as reference
@@ -237,21 +239,30 @@ def delta_rule_inputs(key, decay, b=2, s=64, h=3, d=16):
     return q, k, v, g, beta
 
 
+# (heads, head size, chunk): heads of 16 take the XLA form; heads of 128
+# take the kernels (interpreted here), two heads a grid step or one.
+FORMS = {"xla": (3, 16, 16), "kernels": (2, 128, 16),
+         "kernels, one head a step": (1, 128, 8)}
+
+
 @pytest.mark.parametrize("segment", [2, 16])
 @pytest.mark.parametrize("decay", [0.05, 3.0, 40.0])
-def test_chunked_delta_rule_matches_the_recurrence(decay, segment):
-    """Chunk 16 over four chunks, in segments of two chunks or in one,
-    beta up to 2, decays from next to none to e^-40 a step
-    (``exp(-cumsum)`` would overflow inside one chunk): output and the
-    gradient of every input."""
-    args = delta_rule_inputs(jax.random.PRNGKey(int(decay)), decay)
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_chunked_delta_rule_matches_the_recurrence(form, decay, segment):
+    """Four chunks or more, in segments of two chunks or in one, beta up
+    to 2, decays from next to none to e^-40 a step (``exp(-cumsum)`` would
+    overflow inside one chunk): output and the gradient of every input,
+    in the XLA form and through the kernels."""
+    h, d, chunk = FORMS[form]
+    args = delta_rule_inputs(jax.random.PRNGKey(int(decay)), decay, h=h, d=d)
     assert float(args[4].max()) > 1.5
+    assert kda_kernels.takes(d, chunk) == (form != "xla")
 
     def plain(*a):
         return jax.vmap(reference.kda_recurrence)(*a)
 
     def chunked(*a):
-        return kda_chunked(*a, 16, segment=segment)
+        return kda_chunked(*a, chunk, segment=segment)
 
     out, want = chunked(*args), plain(*args)
     assert float(jnp.abs(out - want).max()) < 1e-5
@@ -265,8 +276,47 @@ def test_chunked_delta_rule_matches_the_recurrence(decay, segment):
         assert float(jnp.abs(g - w).max()) < 1e-4 * float(jnp.abs(w).max())
 
 
-def test_delta_rule_refuses_a_ragged_sequence():
-    args = delta_rule_inputs(jax.random.PRNGKey(0), 1.0, s=40)
+@pytest.mark.parametrize("decay", [0.05, 3.0, 40.0])
+def test_delta_rule_kernels_match_the_xla_form(decay):
+    """The same inputs down both forms, bfloat16 values as the mixer
+    hands them over: output and gradients agree to float32 rounding."""
+    q, k, v, g, beta = delta_rule_inputs(jax.random.PRNGKey(7), decay, b=1,
+                                         h=2, d=128)
+    args = (q, k, v.astype(jnp.bfloat16), g, beta)
+    weight = jax.random.normal(jax.random.PRNGKey(8), q.shape)
+
+    def both(form):
+        def loss(*a):
+            out = form(*a, 16, segment=2)
+            return (out * weight).sum(), out
+        grads, out = jax.grad(loss, argnums=range(5), has_aux=True)(*args)
+        return (out,) + grads
+
+    for got, want in zip(both(kda_chunked), both(kda_chunked_xla)):
+        assert got.dtype == want.dtype
+        close = 1e-5 if want.dtype == jnp.float32 else 1e-2    # v's: bf16
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        assert float(jnp.abs(got - want).max()) \
+            < close * float(jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("head,chunk,kernels", [
+    (16, 16, False), (128, 16, True), (256, 64, True), (128, 4, False),
+    (128, 24, False), (64, 64, False)])
+def test_shapes_choose_the_delta_rules_form(head, chunk, kernels):
+    """Heads that fill the 128 lanes and a chunk that halves down to
+    single rows take the kernels; anything else the XLA form.  No option
+    chooses."""
+    assert kda_kernels.takes(head, chunk) == kernels
+    args = delta_rule_inputs(jax.random.PRNGKey(0), 1.0, b=1, s=2 * chunk,
+                             h=1, d=head)
+    jaxpr = str(jax.make_jaxpr(lambda *a: kda_chunked(*a, chunk))(*args))
+    assert ("hvd_kda_fwd" in jaxpr) == kernels
+
+
+@pytest.mark.parametrize("head", [16, 128])
+def test_delta_rule_refuses_a_ragged_sequence(head):
+    args = delta_rule_inputs(jax.random.PRNGKey(0), 1.0, s=40, h=1, d=head)
     with pytest.raises(ValueError, match="not a multiple"):
         kda_chunked(*args, 16)
 

@@ -5,9 +5,10 @@ Every step builder's lowered and compiled text holds operations under
 ``hvd.optimizer``; the in-program gradient all-reduce sits under
 ``hvd.exchange``; the models' attention and head carry their scopes in
 both passes; each flash ``pallas_call`` sits under its kernel scope and
-carries its ``name``.  The optimized HLO of the executable is where the
-benchmark reads the scopes (``yardstick/scopes.py``), so that text is what
-is checked here.  CPU world, tiny sizes.
+carries its ``name``, and so do the delta rule's two under the core's.
+The optimized HLO of the executable is where the benchmark reads the scopes
+(``yardstick/scopes.py``), so that text is what is checked here.  CPU
+world, tiny sizes.
 """
 
 import os
@@ -81,6 +82,28 @@ def _transformer():
     return step, (params, opt_state, batch)
 
 
+def _pattern():
+    """A period of a delta-rule layer (heads of 128, which take the
+    kernels) and a softmax layer, every layer recomputed."""
+    from horovod_tpu.models.linear_attention import KdaConfig
+    cfg = transformer.TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=64, max_seq=64, dtype="float32", remat=True,
+        layer_pattern=(("linear_attention", "dense"),
+                       ("attention", "dense")),
+        linear_attention=KdaConfig(n_heads=2, head_size=128, gate_rank=8,
+                                   chunk=8))
+    build, shard_batch = transformer.make_train_step(
+        cfg, _mesh((4, 1, 2), ("dp", "sp", "tp")), optax.adam(1e-2))
+    step, params, opt_state = build(
+        transformer.init_params(jax.random.PRNGKey(0), cfg))
+    tokens = np.random.RandomState(0).randint(
+        0, 64, size=(4, 32)).astype(np.int32)
+    batch = shard_batch({"tokens": tokens,
+                         "targets": np.roll(tokens, -1, axis=1)})
+    return step, (params, opt_state, batch)
+
+
 BUILDERS = {
     "make_data_parallel_step":
         lambda: _linear(hvd.make_data_parallel_step),
@@ -88,6 +111,7 @@ BUILDERS = {
     "make_finetune_step-classification": lambda: _bert("classification"),
     "make_finetune_step-mlm": lambda: _bert("mlm"),
     "transformer.make_train_step": _transformer,
+    "transformer.make_train_step-pattern": _pattern,
 }
 
 
@@ -154,6 +178,26 @@ def test_model_blocks_carry_their_scope_in_both_passes(texts, builder,
     names = [n for n in _op_names(texts(builder)[1]) if block in n]
     assert any(BACKWARD in n for n in names)
     assert any(FORWARD in n and BACKWARD not in n for n in names)
+
+
+@pytest.mark.parametrize("kernel,backward", [(scopes.KDA_FWD, False),
+                                             (scopes.KDA_BWD, True)])
+def test_delta_rule_kernels_sit_under_the_core_in_their_pass(texts, kernel,
+                                                             backward):
+    """Each of the two kernels under ``hvd.linear_attention/hvd.kda_core``
+    with its ``name=``, in the lowered and in the compiled text: the
+    forward one in the forward pass alone (a layer recomputed in the
+    backward pass keeps the core's output and does not run it again), the
+    backward one in the backward pass alone."""
+    path = "/".join([scopes.LINEAR_ATTENTION, scopes.KDA_CORE, kernel,
+                     scopes.kernel_name(kernel)])
+    lowered, compiled = texts("transformer.make_train_step-pattern")
+    assert path + "/pallas_call" in lowered
+    names = [n for n in _op_names(compiled) if path in n]
+    phased = [n for n in names if FORWARD in n]
+    assert phased
+    assert all((BACKWARD in n) == backward for n in phased)
+    assert backward or not any("checkpoint" in n for n in names)
 
 
 def _pallas_calls(jaxpr, found):
@@ -228,4 +272,4 @@ def test_one_vocabulary():
     for use in uses:
         assert use.startswith("scopes.") and use[7:] in constants, use
     assert all(re.fullmatch(r"hvd\.[a-z_]+", v) for v in constants.values())
-    assert len(set(constants.values())) == len(constants) == 15
+    assert len(set(constants.values())) == len(constants) == 17
