@@ -22,6 +22,7 @@ from __future__ import annotations
 import logging
 import os
 import socket
+import threading
 from typing import Optional
 
 from . import faultline
@@ -172,6 +173,44 @@ def _teardown_barrier() -> bool:
         return False
 
 
+def _wait_for_members_to_leave():
+    """The process that holds the coordination service (rank 0) leaves
+    last.  A member whose disconnect finds the service gone is killed by
+    the runtime (LOG(FATAL) in the client: "Failed to disconnect from
+    coordination service"), and a recoverable world has no shutdown
+    barrier of the runtime's own to hold the service back: every member
+    is past the teardown barrier here, but on a loaded host they run
+    seconds apart, and the elastic driver then blacklists the host of a
+    healthy worker.  ``get_live_nodes`` returns once every other task has
+    disconnected or is dead; a member killed between the barrier and its
+    disconnect is only found dead by its heartbeat (100 s), so the wait
+    is bounded at 30 s."""
+    import jax
+    from jax._src import distributed as _dist
+    gs = _dist.global_state
+    if (gs.service is None or gs.client is None
+            or not jax.config.jax_enable_recoverability):
+        # Without recoverability the runtime's shutdown barrier already
+        # keeps the service up until every member has called shutdown
+        # (and asking for the live tasks would wait on it forever).
+        return
+
+    def ask():
+        try:
+            gs.client.get_live_nodes(list(range(gs.num_processes)))
+        except Exception as exc:  # noqa: BLE001 - best-effort teardown
+            LOG.debug("waiting for the members to leave: %s", exc)
+
+    waiter = threading.Thread(target=ask, daemon=True,
+                              name="hvd-teardown-wait")
+    waiter.start()
+    waiter.join(30.0)
+    if waiter.is_alive():
+        LOG.warning("a member has not left the distributed runtime 30 s "
+                    "after the teardown barrier; stopping the "
+                    "coordination service under it")
+
+
 # Abandoned runtime objects, kept alive deliberately: letting the
 # client/service of a BROKEN world be destroyed (or calling their
 # shutdown) runs the coordination-service disconnect, and a disconnect
@@ -217,6 +256,7 @@ def shutdown_jax_distributed():
         synchronized = _teardown_barrier()
         faultline.site("hvd.shutdown.post_barrier")
         if synchronized:
+            _wait_for_members_to_leave()
             try:
                 jax.distributed.shutdown()
             except Exception:  # noqa: BLE001 - best-effort teardown

@@ -353,7 +353,7 @@ class ElasticDriver:
         """All target slots checked in: assign ranks and open the world
         (caller holds the lock)."""
         self._kv.reset()
-        self._port_base = util.find_free_ports(1)[0]
+        self._port_base = util.find_free_port_base(len(self._target))
         rendezvous_addr = "%s:%d" % (self._driver_host(), self._kv.port)
         hosts_in_order: List[str] = []
         for host, _ in self._target:

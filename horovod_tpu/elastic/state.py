@@ -119,10 +119,20 @@ class State:
             raise WorkerDrained()
 
     def check_host_updates(self):
-        """Raise HostsUpdatedInterrupt if the driver notified us of a
-        world change since the last check."""
+        """Raise HostsUpdatedInterrupt if the driver notified a member
+        of a world change since the last check.  Every member leaves at
+        the same commit (the reference's rule): the driver's notices
+        reach the workers one after another, and a member that left a
+        commit before its peer waits in the old world's shutdown while
+        the peer waits for it in the next step's collective."""
         nm = notification_manager()
-        if nm.has_update():
+        updated = nm.has_update()
+        if nm.active and basics.is_initialized() and basics.size() > 1:
+            from ..ops.api import MAX, allreduce
+            updated = bool(np.asarray(allreduce(
+                np.asarray([updated], np.int32), op=MAX,
+                name="elastic.hosts_updated")).reshape(-1)[0])
+        if updated:
             nm.consume_update()
             raise HostsUpdatedInterrupt(skip_sync=False)
 

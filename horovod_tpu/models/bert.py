@@ -31,8 +31,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..common import scopes
-from .transformer import (_sharded_embed_lookup, _use_flash_attention,
-                          opt_spec_tree, vocab_parallel_cross_entropy)
+from ..ops import pallas_kernels
+from .transformer import (_sharded_embed_lookup, opt_spec_tree,
+                          vocab_parallel_cross_entropy)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,9 +166,8 @@ def _attention(h, lp, cfg: BertConfig, mask):
          + lp["bk"].astype(h.dtype)).reshape(b, s, -1, hd)
     v = (h @ lp["wv"].astype(h.dtype)
          + lp["bv"].astype(h.dtype)).reshape(b, s, -1, hd)
-    if mask is None and _use_flash_attention():
-        from ..ops.pallas_kernels import flash_attention
-        attn = flash_attention(q, k, v, causal=False)
+    if mask is None and pallas_kernels.use_flash_attention():
+        attn = pallas_kernels.flash_attention(q, k, v, causal=False)
     else:
         # XLA path with an additive bias for padding keys.
         qf = q.astype(jnp.float32) / math.sqrt(hd)

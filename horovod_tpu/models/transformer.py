@@ -21,13 +21,14 @@ Everything is a pure function over a params pytree with layer-stacked
 leaves ``[L, ...]`` consumed by ``lax.scan`` (single-layer trace, static
 shapes, bf16 activations on the MXU, optional ``jax.checkpoint`` remat).
 
-A model whose layers differ gives ``layer_pattern``: one period of
-(mixer, feed-forward) pairs out of ``MIXERS`` and ``FEED_FORWARDS``.  The
-scan then runs over periods, ``params["layers"]`` is a tuple with one
-stacked dict for each layer of the period, and each layer is recomputed on
-its own.  The block functions of the kinds that need more than a few lines
-live beside their mechanism (``models/linear_attention.py``,
-``parallel/moe.py``).
+What a layer is made of is said in one place, ``layer_pattern``: one
+period of (mixer, feed-forward) pairs out of ``MIXERS`` and
+``FEED_FORWARDS``.  The scan runs over periods, ``params["layers"]`` is a
+tuple with one stacked dict for each layer of the period, and each layer is
+recomputed on its own.  The block functions of the kinds that need more
+than a few lines live beside their mechanism (``models/linear_attention.py``,
+``parallel/moe.py``); a new architecture is one more kind there and an entry
+of the pattern here.
 """
 
 from __future__ import annotations
@@ -42,9 +43,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..common import scopes
+from ..ops import pallas_kernels
 from ..parallel.moe import (ExpertShare, MoeConfig, expert_share_ffn,
                             init_expert_share_params, moe_ffn)
-from ..parallel.ring_attention import local_attention, ring_attention
+from ..parallel.ring_attention import (local_attention, pvary_missing,
+                                       ring_attention)
 from .linear_attention import SAVED as kda_saved_names
 from .linear_attention import (KdaConfig, init_kda_params, kda_param_specs,
                                linear_attention_block)
@@ -80,7 +83,7 @@ class TransformerConfig:
     # saves only no-batch-dim dots (weights-side products).  Measured
     # per-policy on the flagship config in docs/benchmarks.md.
     remat_policy: str = "full"
-    # MoE (0 experts = dense).
+    # Size of the ``moe`` feed-forward, for a pattern that holds one.
     n_experts: int = 0
     top_k: int = 2
     capacity_factor: float = 1.25
@@ -95,23 +98,6 @@ class TransformerConfig:
     # n_kv_heads/tp — must both divide by sp; composes with flash
     # attention)
     sp_mode: str = "ring"
-    # Projection fusion: concatenate the per-shard wq|wk|wv (and
-    # w1|w3) weight slices ONCE per step before the layer scan, so
-    # each layer issues one [d, (q+2kv)·hd] (resp. [d, 2f]) matmul
-    # instead of three (two).  Host param layout is unchanged — the
-    # packing happens inside the shard_map body on the local slices,
-    # so it is correct for any tp degree.
-    fused_qkv: bool = False
-    fused_gate: bool = False
-    # Vocab-projection dtype: "f32" (safe default), "bf16" (bf16
-    # operands, f32 accumulation), or "auto" = bf16 only when the
-    # Pallas flash-attention path is active — a bf16 vocab einsum
-    # measured ~3% faster on the flash path but collapses the
-    # chunked-XLA attention fallback ~12x (an XLA fusion/layout
-    # interaction, docs/benchmarks.md), so it must never ride with it.
-    logits_dtype: str = "auto"
-    # lax.scan unroll factor over the layer stack (1 = no unroll).
-    scan_unroll: int = 1
     # Latency-hiding TP matmuls (parallel/collective_matmul.py): the
     # row-parallel wo / w2 products run as an overlapped
     # matmul+reduce-scatter ring followed by a tiled all_gather (same
@@ -119,9 +105,8 @@ class TransformerConfig:
     # work).  No-op at tp=1, so single-chip programs are unchanged.
     collective_matmul: bool = False
     # One period of the layer pattern, ((mixer, feed-forward), ...) out of
-    # MIXERS x FEED_FORWARDS; n_layers is a multiple of its length.  None
-    # is every layer alike: attention, then dense or (n_experts > 0) moe.
-    layer_pattern: Optional[Tuple[Tuple[str, str], ...]] = None
+    # MIXERS x FEED_FORWARDS; n_layers is a multiple of its length.
+    layer_pattern: Tuple[Tuple[str, str], ...] = (("attention", "dense"),)
     # Size of an attention head where it is not d_model / n_heads (a
     # chip's share of the heads keeps the model's head size).
     head_size: Optional[int] = None
@@ -143,10 +128,7 @@ class TransformerConfig:
             raise ValueError("remat_policy must be 'full', 'dots' or "
                              "'dots_no_batch', got %r"
                              % (self.remat_policy,))
-        if self.logits_dtype not in ("auto", "bf16", "f32"):
-            raise ValueError("logits_dtype must be 'auto', 'bf16' or "
-                             "'f32', got %r" % (self.logits_dtype,))
-        for mixer, ffn in self.pattern:
+        for mixer, ffn in self.layer_pattern:
             if mixer not in MIXERS or ffn not in FEED_FORWARDS:
                 raise ValueError("layer_pattern pairs a mixer of %s with a "
                                  "feed-forward of %s, not %r"
@@ -155,19 +137,9 @@ class TransformerConfig:
                     or (ffn == "expert_share" and not self.experts):
                 raise ValueError("%r needs its configuration"
                                  % ((mixer, ffn),))
-        if self.n_layers % len(self.pattern):
+        if self.n_layers % len(self.layer_pattern):
             raise ValueError("%d layers are no whole number of periods of %d"
-                             % (self.n_layers, len(self.pattern)))
-        if self.layer_pattern is not None and (self.fused_qkv
-                                               or self.fused_gate):
-            raise ValueError("fused_qkv / fused_gate pack the weights of "
-                             "the default layers only")
-
-    @property
-    def pattern(self) -> Tuple[Tuple[str, str], ...]:
-        if self.layer_pattern is not None:
-            return self.layer_pattern
-        return (("attention", "dense" if self.n_experts == 0 else "moe"),)
+                             % (self.n_layers, len(self.layer_pattern)))
 
     @property
     def head_dim(self) -> int:
@@ -244,14 +216,10 @@ def init_params(key, cfg: TransformerConfig):
     if not cfg.tie_embeddings:
         params["head"] = _normal(jax.random.fold_in(key, 12),
                                  (d, cfg.vocab_size), d, pd)
-    if cfg.layer_pattern is None:
-        params["layers"] = _init_layers(key, cfg, *cfg.pattern[0],
-                                        cfg.n_layers)
-    else:
-        n = cfg.n_layers // len(cfg.pattern)
-        params["layers"] = tuple(
-            _init_layers(jax.random.fold_in(key, at), cfg, mixer, ffn, n)
-            for at, (mixer, ffn) in enumerate(cfg.pattern))
+    n = cfg.n_layers // len(cfg.layer_pattern)
+    params["layers"] = tuple(
+        _init_layers(jax.random.fold_in(key, at), cfg, mixer, ffn, n)
+        for at, (mixer, ffn) in enumerate(cfg.layer_pattern))
     return params
 
 
@@ -306,9 +274,8 @@ def param_specs(cfg: TransformerConfig):
     specs = {"embed": P(tp, None), "ln_f": P(None)}
     if not cfg.tie_embeddings:
         specs["head"] = P(None, tp)
-    blocks = tuple(_layer_specs(cfg, mixer, ffn)
-                   for mixer, ffn in cfg.pattern)
-    specs["layers"] = blocks[0] if cfg.layer_pattern is None else blocks
+    specs["layers"] = tuple(_layer_specs(cfg, mixer, ffn)
+                            for mixer, ffn in cfg.layer_pattern)
     return specs
 
 
@@ -375,38 +342,13 @@ def vocab_parallel_cross_entropy(logits_local, targets, tp_axis: str):
     return lse - tgt  # [B, S] per-token nll
 
 
-def _use_flash_attention() -> bool:
-    """Pallas flash attention on the TPU backend; plain XLA attention
-    on the CPU test world, where the interpreted kernel is too slow for
-    training loops (set HOROVOD_FLASH_ATTENTION=0/1 to force).  Any
-    other backend is an error (``common/device.py``)."""
-    import os
-    flag = os.environ.get("HOROVOD_FLASH_ATTENTION")
-    if flag is not None:
-        return flag not in ("0", "false", "False")
-    from ..common.device import on_tpu
-    return on_tpu()
-
-
 @jax.named_scope(scopes.ATTENTION)
 def _attention_block(x, lp, cfg: TransformerConfig, cos, sin, sp_size):
     b, s, _ = x.shape
     hd = cfg.head_dim
-    if "wqkv" in lp:
-        # Fused projection: one matmul, split at the LOCAL q/k/v
-        # boundaries (exact for any tp: the per-shard fused width is
-        # (qh + 2·kvh)·hd/tp and the ratios are preserved).
-        qkv = x @ lp["wqkv"].astype(x.dtype)
-        tot = qkv.shape[-1]
-        q_sz = tot * cfg.n_heads // (cfg.n_heads + 2 * cfg.n_kv_heads)
-        kv_sz = (tot - q_sz) // 2
-        q = qkv[..., :q_sz].reshape(b, s, -1, hd)
-        k = qkv[..., q_sz:q_sz + kv_sz].reshape(b, s, -1, hd)
-        v = qkv[..., q_sz + kv_sz:].reshape(b, s, -1, hd)
-    else:
-        q = (x @ lp["wq"].astype(x.dtype)).reshape(b, s, -1, hd)
-        k = (x @ lp["wk"].astype(x.dtype)).reshape(b, s, -1, hd)
-        v = (x @ lp["wv"].astype(x.dtype)).reshape(b, s, -1, hd)
+    q = (x @ lp["wq"].astype(x.dtype)).reshape(b, s, -1, hd)
+    k = (x @ lp["wk"].astype(x.dtype)).reshape(b, s, -1, hd)
+    v = (x @ lp["wv"].astype(x.dtype)).reshape(b, s, -1, hd)
     q = _rope(cos, sin, q)
     k = _rope(cos, sin, k)
     attn = _causal_attention(q, k, v, cfg, sp_size).reshape(b, s, -1)
@@ -419,20 +361,18 @@ def _causal_attention(q, k, v, cfg: TransformerConfig, sp_size):
     whichever form the layout calls for."""
     if sp_size > 1 and cfg.sp_mode == "ulysses":
         from ..parallel.ulysses import ulysses_attention
-        attn_fn = None
-        if _use_flash_attention():
-            from ..ops.pallas_kernels import flash_attention as attn_fn
+        attn_fn = (pallas_kernels.flash_attention
+                   if pallas_kernels.use_flash_attention() else None)
         return ulysses_attention(q, k, v, axis_name=cfg.sp_axis,
                                  causal=True, attn_fn=attn_fn)
     if sp_size > 1:
         return ring_attention(q, k, v, axis_name=cfg.sp_axis, causal=True)
-    if _use_flash_attention():
+    if pallas_kernels.use_flash_attention():
         # Pallas fused attention on TPU (ops/pallas_kernels.py):
         # O(seq) HBM forward + Pallas backward kernels (dq, dk/dv);
         # measured ~5x over XLA autodiff at seq 8192 on one chip
         # (docs/benchmarks.md)
-        from ..ops.pallas_kernels import flash_attention
-        return flash_attention(q, k, v, causal=True)
+        return pallas_kernels.flash_attention(q, k, v, causal=True)
     return local_attention(q, k, v, causal=True)
 
 
@@ -469,13 +409,8 @@ def _row_parallel_product(x, w, cfg: TransformerConfig):
 
 
 def _dense_ffn(h, lp, cfg: TransformerConfig):
-    if "w13" in lp:
-        ag = h @ lp["w13"].astype(h.dtype)
-        a, g = jnp.split(ag, 2, axis=-1)
-        a = jax.nn.silu(a)
-    else:
-        a = jax.nn.silu(h @ lp["w1"].astype(h.dtype))
-        g = h @ lp["w3"].astype(h.dtype)
+    a = jax.nn.silu(h @ lp["w1"].astype(h.dtype))
+    g = h @ lp["w3"].astype(h.dtype)
     return _row_parallel_product(a * g, lp["w2"].astype(h.dtype), cfg)
 
 
@@ -530,19 +465,7 @@ def hidden(params, tokens, cfg: TransformerConfig):
         # The RS+AG ring's all_gather output is vma-varying over tp
         # (identical values, but the tracker cannot prove it); the
         # scan carry must enter with the same varying axes.
-        from ..parallel.ring_attention import pvary_missing
         x = pvary_missing(x, (cfg.tp_axis,))
-
-    layers = params["layers"]
-    if cfg.fused_qkv:
-        layers = dict(layers)
-        layers["wqkv"] = jnp.concatenate(
-            [layers.pop("wq"), layers.pop("wk"), layers.pop("wv")],
-            axis=-1)
-    if cfg.fused_gate and cfg.n_experts == 0:
-        layers = dict(layers)
-        layers["w13"] = jnp.concatenate(
-            [layers.pop("w1"), layers.pop("w3")], axis=-1)
 
     def layer(mixer, ffn, carry, lp):
         x, aux = carry
@@ -552,7 +475,8 @@ def hidden(params, tokens, cfg: TransformerConfig):
         y, a, counts = _feed_forward(h, lp, cfg, ffn, sp_size)
         return (x + y, aux if a is None else aux + a), counts
 
-    layer_fns = [partial(layer, mixer, ffn) for mixer, ffn in cfg.pattern]
+    layer_fns = [partial(layer, mixer, ffn)
+                 for mixer, ffn in cfg.layer_pattern]
     if cfg.remat:
         # "full" keeps nothing but what a block names as dearer to compute
         # again than to keep (the delta rule's walk along the sequence).
@@ -574,36 +498,20 @@ def hidden(params, tokens, cfg: TransformerConfig):
     # The MoE aux accumulator acquires V:(dp, sp) from the routed
     # tokens; the carry must enter with the same varying axes under
     # vma tracking (guarded no-op in untracked traces).
-    from ..parallel.ring_attention import pvary_missing
-    aux0 = pvary_missing(jnp.zeros((), jnp.float32),
-                         (cfg.dp_axis, cfg.sp_axis)) \
-        if cfg.n_experts else jnp.zeros((), jnp.float32)
-    (x, aux), counts = lax.scan(
-        period, (x, aux0), layers if isinstance(layers, tuple) else (layers,),
-        unroll=max(1, cfg.scan_unroll))
+    aux0 = jnp.zeros((), jnp.float32)
+    if any(ffn == "moe" for _, ffn in cfg.layer_pattern):
+        aux0 = pvary_missing(aux0, (cfg.dp_axis, cfg.sp_axis))
+    (x, aux), counts = lax.scan(period, (x, aux0), params["layers"])
     return rms_norm(x, params["ln_f"], cfg.norm_eps), aux, counts
 
 
 def _logits(x, params, cfg: TransformerConfig):
-    """[.., d] -> [.., V/tp] float32."""
-    # Vocab projection dtype: bf16 operands with f32 accumulation only
-    # on the flash path ("auto"); with the chunked-XLA attention
-    # fallback the bf16 form collapses throughput ~12x (159k -> 13.6k
-    # tok/s at seq 2048, v5e — an XLA fusion/layout interaction), so
-    # f32 stays the fallback-path form.
-    bf16_logits = (cfg.logits_dtype == "bf16"
-                   or (cfg.logits_dtype == "auto"
-                       and _use_flash_attention()))
-
-    def head(dtype):
-        if cfg.tie_embeddings:
-            return params["embed"].astype(dtype).T
-        return params["head"].astype(dtype)
-
-    if bf16_logits:
-        return jnp.matmul(x.astype(cfg.act_dtype), head(cfg.act_dtype),
-                          preferred_element_type=jnp.float32)
-    return x.astype(jnp.float32) @ head(jnp.float32)
+    """[.., d] -> [.., V/tp] float32: operands in the activations' dtype,
+    accumulated in float32."""
+    head = (params["embed"].astype(cfg.act_dtype).T if cfg.tie_embeddings
+            else params["head"].astype(cfg.act_dtype))
+    return jnp.matmul(x.astype(cfg.act_dtype), head,
+                      preferred_element_type=jnp.float32)
 
 
 def forward(params, tokens, cfg: TransformerConfig):
@@ -619,38 +527,35 @@ def forward(params, tokens, cfg: TransformerConfig):
 
 
 @jax.named_scope(scopes.HEAD)
-def _blocked_nll_sum(x, targets, params, cfg: TransformerConfig):
+def _nll_sum(x, targets, params, cfg: TransformerConfig):
     """Sum of the tokens' nll, ``cfg.head_block`` tokens at a time: a
     block's logits live from its product to its cross entropy, in the
-    forward pass and again in the backward pass."""
+    forward pass and again in the backward pass.  0 is one block of all
+    the tokens, with no loop and nothing computed again."""
     d = x.shape[-1]
     x, targets = x.reshape(-1, d), targets.reshape(-1)
-    if x.shape[0] % cfg.head_block:
-        raise ValueError("%d tokens are no whole number of head blocks of "
-                         "%d" % (x.shape[0], cfg.head_block))
 
-    @jax.checkpoint
     def block(xs):
         x_b, t_b = xs
         return vocab_parallel_cross_entropy(
             _logits(x_b, params, cfg), t_b, cfg.tp_axis).sum()
 
-    return lax.map(block, (x.reshape(-1, cfg.head_block, d),
-                           targets.reshape(-1, cfg.head_block))).sum()
+    if not cfg.head_block:
+        return block((x, targets))
+    if x.shape[0] % cfg.head_block:
+        raise ValueError("%d tokens are no whole number of head blocks of "
+                         "%d" % (x.shape[0], cfg.head_block))
+    return lax.map(jax.checkpoint(block),
+                   (x.reshape(-1, cfg.head_block, d),
+                    targets.reshape(-1, cfg.head_block))).sum()
 
 
 def loss_fn(params, batch, cfg: TransformerConfig):
     """Per-shard mean nll (+ MoE aux); psum-averaged over dp and sp."""
     tokens, targets = batch["tokens"], batch["targets"]
-    if cfg.head_block:
-        x, aux, _ = hidden(params, tokens, cfg)
-        aux = aux / cfg.n_layers
-        nll_mean = _blocked_nll_sum(x, targets, params, cfg) / targets.size
-    else:
-        logits, aux = forward(params, tokens, cfg)
-        with jax.named_scope(scopes.HEAD):
-            nll = vocab_parallel_cross_entropy(logits, targets, cfg.tp_axis)
-        nll_mean = nll.mean()
+    x, aux, _ = hidden(params, tokens, cfg)
+    aux = aux / cfg.n_layers
+    nll_mean = _nll_sum(x, targets, params, cfg) / targets.size
     loss = nll_mean + cfg.aux_loss_weight * aux
     return lax.pmean(loss, (cfg.dp_axis, cfg.sp_axis))
 
@@ -687,21 +592,16 @@ def opt_spec_tree(opt_state, params_host, specs):
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer,
-                    donate: bool = True, split_optimizer: bool = False):
+                    donate: bool = True):
     """Jitted SPMD train step over ``mesh`` (axes dp/sp/tp as configured).
 
     Returns ``(build, shard_batch)``; ``build(params_host)`` returns
     ``(step, params, opt_state)`` with
     ``step(params, opt_state, batch) -> (params, opt_state, loss)``.
     Gradients are psum'ed over (dp, sp) — tp/ep-sharded leaves stay
-    sharded, the framework's DP story fused into the compiled program.
-
-    ``split_optimizer=True`` compiles the backward and the optimizer
-    update as TWO programs called back to back — the anti-lever: it
-    exists to MEASURE what fusing the update into the step is worth
-    (the fused default lets XLA overlap the elementwise update with
-    the tail of the backward and skip materializing the full gradient
-    pytree between programs).
+    sharded, the framework's DP story fused into the compiled program;
+    the optimizer's update is part of the same program, so XLA overlaps
+    it with the tail of the backward pass.
     """
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -709,7 +609,6 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer,
     specs = param_specs(cfg)
     batch_spec = {"tokens": P(cfg.dp_axis, cfg.sp_axis),
                   "targets": P(cfg.dp_axis, cfg.sp_axis)}
-    opt_specs = None  # filled after init
 
     def local_grad(params, batch):
         # vma-tracked AD (check_vma=True below) differentiates the
@@ -728,45 +627,21 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer,
             return optax.apply_updates(params, updates), opt_state
 
     def local_step(params, opt_state, batch):
-        # Composed from the same two pieces the split path jits
-        # separately, so the fused/split A/B always measures program
-        # structure, never diverged math.
         loss, grads = local_grad(params, batch)
         params, opt_state = local_update(params, opt_state, grads)
         return params, opt_state, loss
-
-    def _opt_spec_tree(opt_state, params_host):
-        return opt_spec_tree(opt_state, params_host, specs)
 
     def build(params_host):
         params = jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
             params_host, specs)
         opt_state = optimizer.init(params_host)
-        o_specs = _opt_spec_tree(opt_state, params_host)
+        o_specs = opt_spec_tree(opt_state, params_host, specs)
         opt_state = jax.tree.map(
             lambda x, s: jax.device_put(jnp.asarray(x),
                                         NamedSharding(mesh, s))
             if hasattr(x, "shape") else x,
             opt_state, o_specs)
-        if split_optimizer:
-            g_mapped = jax.shard_map(
-                local_grad, mesh=mesh,
-                in_specs=(specs, batch_spec),
-                out_specs=(P(), specs), check_vma=True)
-            u_mapped = jax.shard_map(
-                local_update, mesh=mesh,
-                in_specs=(specs, o_specs, specs),
-                out_specs=(specs, o_specs), check_vma=True)
-            g_step = jax.jit(g_mapped)
-            u_step = jax.jit(u_mapped,
-                             donate_argnums=(0, 1, 2) if donate else ())
-
-            def step(params, opt_state, batch):
-                loss, grads = g_step(params, batch)
-                params, opt_state = u_step(params, opt_state, grads)
-                return params, opt_state, loss
-            return step, params, opt_state
         mapped = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(specs, o_specs, batch_spec),
@@ -776,7 +651,6 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer,
         return step, params, opt_state
 
     def shard_batch(batch):
-        from jax.sharding import NamedSharding
         return jax.tree.map(
             lambda x, s: jax.device_put(jnp.asarray(x),
                                         NamedSharding(mesh, s)),

@@ -654,6 +654,14 @@ def flash_attention(q, k, v, causal: bool = True):
     return _flash_attention(q, k, v, causal)
 
 
+def use_flash_attention() -> bool:
+    """Whether a model calls ``flash_attention``: on the TPU, where it is
+    compiled.  On the CPU test world the models take their XLA attention,
+    because the interpreted kernel is too slow for a training loop.  Any
+    other backend is an error (``common/device.py``)."""
+    return on_tpu()
+
+
 # ---------------------------------------------------------------------------
 # flash block autotune (the kernel-parameter leg of the autotune plane)
 # ---------------------------------------------------------------------------

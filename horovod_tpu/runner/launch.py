@@ -226,7 +226,7 @@ def gloo_run(args, hosts: List[util.HostInfo],
     server = RendezvousServer(secret=secret)
     port = server.start()
     rendezvous_addr = "127.0.0.1:%d" % port
-    port_base = util.find_free_ports(1)[0]
+    port_base = util.find_free_port_base(np_)
     common = build_common_env(args, env)
 
     procs: List[safe_shell_exec.ManagedProcess] = []

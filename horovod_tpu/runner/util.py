@@ -231,15 +231,44 @@ def routable_ip() -> str:
             return "127.0.0.1"
 
 
-def find_free_ports(n: int, host: str = "127.0.0.1") -> List[int]:
-    socks, ports = [], []
+def _bindable(host: str, port: int) -> bool:
+    s = socket.socket()
     try:
-        for _ in range(n):
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+        s.bind((host, port))
+        return True
+    except OSError:
+        return False
+    finally:
+        s.close()
+
+
+def find_free_port_base(size: int, host: str = "127.0.0.1") -> int:
+    """A port base for a world of ``size`` ranks: the tcp core binds
+    ``[base, base + size)`` and the launchers derive the jax coordinator's
+    port (``base + size + 101``) and libtpu's (``base + size + 201 + i``)
+    from it.  Seconds pass between this call and a worker's bind, so the
+    base is drawn from under the range the kernel itself draws from for
+    outgoing connections and binds to port 0: a number from that range
+    (bind 0, close, hand it on) can go to any connection of any process
+    on the box meanwhile, and of the block only the first port had ever
+    been looked at — with five worlds forming at once one formation in
+    twenty lost a rank to it ("native core init failed")."""
+    try:
+        with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+            floor = int(f.read().split()[0])
+    except (OSError, ValueError, IndexError):
+        floor = 32768
+    top = floor - size - 400          # the derived ports stay under it too
+    for _ in range(256):
+        if top - 6000 >= 1024:
+            base = top - 6000 + _secrets.randbelow(6000)
+        else:                         # no room under the kernel's range
             s = socket.socket()
             s.bind((host, 0))
-            socks.append(s)
-            ports.append(s.getsockname()[1])
-    finally:
-        for s in socks:
+            base = s.getsockname()[1]
             s.close()
-    return ports
+        if all(_bindable(host, base + i) for i in range(size)):
+            return base
+    raise RuntimeError("no block of %d free ports found on %s"
+                       % (size, host))

@@ -19,7 +19,7 @@ def test_bench_emits_one_valid_json_line():
     env["HVD_TPU_BENCH_IMAGE"] = "32"
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
+        capture_output=True, text=True, timeout=300, env=env, cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
     assert len(lines) == 1, "exactly one JSON line expected: %r" % lines
@@ -125,7 +125,7 @@ def test_allreduce_bw_fault_leg_self_attributes():
          "--eager", "--cpu-devices", "2", "--sizes-mb", "0.25",
          "--iters", "2", "--warmup", "1",
          "--fault", "mh.leg.drop:drop@times=1"],
-        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
+        capture_output=True, text=True, timeout=60, env=env, cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     recs = [json.loads(ln) for ln in proc.stdout.splitlines()
             if ln.strip().startswith("{")]
@@ -159,7 +159,7 @@ def test_allreduce_bw_fast_path_leg_self_attributes():
          os.path.join(REPO, "benchmarks", "allreduce_bw.py"),
          "--eager", "--cpu-devices", "2", "--sizes-mb", "0.25",
          "--iters", "4", "--warmup", "2", "--fast-path", "on"],
-        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
+        capture_output=True, text=True, timeout=60, env=env, cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     recs = [json.loads(ln) for ln in proc.stdout.splitlines()
             if ln.strip().startswith("{")]
@@ -182,7 +182,7 @@ def test_allreduce_bw_fast_path_leg_self_attributes():
          os.path.join(REPO, "benchmarks", "allreduce_bw.py"),
          "--eager", "--cpu-devices", "2", "--sizes-mb", "0.25",
          "--iters", "2", "--warmup", "1", "--fast-path", "off"],
-        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
+        capture_output=True, text=True, timeout=60, env=env, cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     recs = [json.loads(ln) for ln in proc.stdout.splitlines()
             if ln.strip().startswith("{")]
@@ -201,7 +201,7 @@ def test_flash_roofline_smoke_schema():
         [sys.executable,
          os.path.join(REPO, "benchmarks", "flash_roofline.py"),
          "--cpu-smoke"],
-        capture_output=True, text=True, timeout=560, env=env, cwd=REPO)
+        capture_output=True, text=True, timeout=90, env=env, cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     recs = [json.loads(ln) for ln in proc.stdout.splitlines()
             if ln.strip().startswith("{")]

@@ -190,30 +190,44 @@ def test_train_step_runs_the_pattern_and_the_loss_falls():
 
 
 def test_default_config_is_the_program_it_was():
-    """``TransformerConfig()`` has no pattern: one stacked dict of
-    attention + dense layers under one scan, the loss PR 26's tree gave
-    for this seed and batch."""
+    """``TransformerConfig()`` is a pattern of one pair: a tuple of one
+    stacked dict of attention + dense layers under one scan, with no loop
+    over the head and nothing recomputed in it."""
     cfg = transformer.TransformerConfig()
-    assert cfg.pattern == (("attention", "dense"),)
-    assert cfg.layer_pattern is None and cfg.head_block == 0
+    assert cfg.layer_pattern == (("attention", "dense"),)
+    assert cfg.head_block == 0 and not cfg.remat
     small = transformer.TransformerConfig(
         vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=128)
     params = transformer.init_params(jax.random.PRNGKey(0), small)
     assert sorted(params) == ["embed", "layers", "ln_f"]
-    assert sorted(params["layers"]) == ["ln1", "ln2", "w1", "w2", "w3",
-                                        "wk", "wo", "wq", "wv"]
+    layers, = params["layers"]
+    assert sorted(layers) == ["ln1", "ln2", "w1", "w2", "w3",
+                              "wk", "wo", "wq", "wv"]
     tokens = np.random.default_rng(0).integers(0, 256, (4, 32), np.int32)
     batch = {"tokens": tokens, "targets": np.roll(tokens, -1, 1)}
     loss, _ = program_loss_and_grads(small, params, batch, (2, 2, 2))
-    assert abs(float(loss) - 5.919988632202148) < 1e-5
-    jaxpr = str(jax.make_jaxpr(lambda p, t: jax.shard_map(
-        lambda p, t: transformer.forward(p, t, small)[0],
-        mesh=jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1, 1),
-                               ("dp", "sp", "tp")),
-        in_specs=(transformer.param_specs(small), P("dp", "sp")),
-        out_specs=P("dp", "sp", "tp"))(p, t))(params, tokens))
-    assert jaxpr.count("scan[") == 1 and "checkpoint" not in jaxpr
+    # Pinned in PR 29: the default's layers are drawn from fold_in(key, 0)
+    # as every pattern's are, and the head multiplies bfloat16 operands on
+    # the CPU as on the chip (5.919988632202148 before both).
+    assert abs(float(loss) - 6.235895156860352) < 1e-5
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1, 1),
+                             ("dp", "sp", "tp"))
+    specs = transformer.param_specs(small)
+
+    def traced(fn, out_specs):
+        return str(jax.make_jaxpr(jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=(specs, {k: P("dp", "sp") for k in batch}),
+            out_specs=out_specs))(params, batch))
+
+    logits = traced(lambda p, b: transformer.forward(p, b["tokens"], small)[0],
+                    P("dp", "sp", "tp"))
+    grads = traced(jax.grad(lambda p, b: transformer.loss_fn(p, b, small)),
+                   specs)
+    # one scan forward, one more for the backward pass, nothing recomputed
+    assert logits.count("scan[") == 1 and grads.count("scan[") == 2
+    assert "checkpoint" not in logits + grads
 
 
 def test_a_pattern_refuses_what_it_cannot_run():

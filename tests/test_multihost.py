@@ -14,7 +14,7 @@ WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)), "utils",
                       "multihost_worker.py")
 
 
-def _spawn_multihost(size, local_devices=4, extra_env=None, timeout=240,
+def _spawn_multihost(size, local_devices=4, extra_env=None, timeout=120,
                      worker=WORKER):
     env = {"HOROVOD_CONTROLLER": "multihost",
            "TEST_LOCAL_DEVICES": str(local_devices)}

@@ -9,21 +9,26 @@ known-good entry point instead of improvisation (VERDICT r4 Next #7).
 
 import json
 import os
-import subprocess
 import sys
+
+from tests.utils.spawn import run_world
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_podcheck_smoke_artifact_schema(tmp_path):
     out = tmp_path / "podcheck.json"
-    proc = subprocess.run(
+    proc = run_world(
         [sys.executable, os.path.join(REPO, "benchmarks", "podcheck.py"),
          "--cpu-smoke", "--skip-autotune", "--out", str(out)],
-        cwd=REPO, timeout=600,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-    assert proc.returncode == 0, proc.stdout.decode()[-2000:]
-    art = json.loads(out.read_text())
+        timeout=300, cwd=REPO)
+    art = json.loads(out.read_text()) if out.exists() else {}
+    # A section is a child of its own: what it wrote is in the artifact.
+    assert proc.returncode == 0, "%s\n%s\n%s" % (
+        proc.stdout[-2000:], proc.stderr[-2000:], "\n".join(
+            "---- section %s (rc=%s) ----\n%s"
+            % (sec["name"], sec.get("rc"), sec.get("tail"))
+            for sec in art.get("sections", ()) if "rc" in sec))
     # BENCH_r*.json schema head.
     for key in ("metric", "value", "unit", "vs_baseline", "target",
                 "pass", "sections", "smoke", "link_gbps"):

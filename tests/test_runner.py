@@ -4,8 +4,7 @@ exec, rendezvous auth, and a real static end-to-end run on localhost
 
 import os
 
-from tests.utils.spawn import scaled_timeout
-import subprocess
+from tests.utils.spawn import run_world, scaled_timeout
 import sys
 import time
 
@@ -114,9 +113,8 @@ def test_package_import_is_framework_free(tmp_path):
     env = dict(os.environ)
     env.pop("JAX_PLATFORMS", None)
     env["PYTHONPATH"] = "%s%s%s" % (tmp_path, os.pathsep, REPO)
-    proc = subprocess.run([sys.executable, "-c", code],
-                          capture_output=True, text=True, timeout=scaled_timeout(120),
-                          env=env, cwd=REPO)
+    proc = run_world([sys.executable, "-c", code], timeout=120, env=env,
+                     cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "LAZY_OK" in proc.stdout
 
@@ -317,10 +315,10 @@ def test_rpc_drop_and_recover_end_to_end():
     env = _worker_env()
     env["HVD_TPU_FAULT"] = "runner.rpc.request:drop@times=2"
     env["HOROVOD_RPC_RETRY_BACKOFF"] = "0.05"
-    proc = subprocess.run(
+    proc = run_world(
         [sys.executable, "-m", "horovod_tpu.runner", "-np", "2",
          sys.executable, "-c", script],
-        capture_output=True, text=True, timeout=scaled_timeout(180),
+        timeout=90,
         env=env, cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     for r in range(2):
@@ -342,10 +340,10 @@ def test_rpc_retry_exhaustion_fails_loudly():
     env["HOROVOD_RPC_RETRY_BACKOFF"] = "0.05"
     env["HOROVOD_RPC_DEADLINE"] = "5"
     t0 = time.monotonic()
-    proc = subprocess.run(
+    proc = run_world(
         [sys.executable, "-m", "horovod_tpu.runner", "-np", "2",
          sys.executable, "-c", script],
-        capture_output=True, text=True, timeout=scaled_timeout(120),
+        timeout=120,
         env=env, cwd=REPO)
     assert proc.returncode != 0
     assert "UNREACHED" not in proc.stdout
@@ -439,10 +437,10 @@ def test_static_run_end_to_end():
         "assert hvd.local_size() == 3\n"
         "print('RANK_OK', hvd.rank())\n"
         "hvd.shutdown()\n")
-    proc = subprocess.run(
+    proc = run_world(
         [sys.executable, "-m", "horovod_tpu.runner", "-np", "3",
          sys.executable, "-c", script],
-        capture_output=True, text=True, timeout=scaled_timeout(180), env=_worker_env(),
+        timeout=90, env=_worker_env(),
         cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     for r in range(3):
@@ -458,10 +456,10 @@ def test_static_run_failure_tears_down_world():
         "    raise SystemExit(3)\n"
         "time.sleep(600)\n")
     t0 = time.monotonic()
-    proc = subprocess.run(
+    proc = run_world(
         [sys.executable, "-m", "horovod_tpu.runner", "-np", "2",
          sys.executable, "-c", script],
-        capture_output=True, text=True, timeout=scaled_timeout(120), env=_worker_env(),
+        timeout=60, env=_worker_env(),
         cwd=REPO)
     assert proc.returncode != 0
     assert time.monotonic() - t0 < 60

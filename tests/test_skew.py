@@ -10,7 +10,6 @@ planned-removal path, and a recovered world.
 
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -19,7 +18,7 @@ import urllib.request
 import pytest
 
 from horovod_tpu.common import metrics, skew
-from tests.utils.spawn import scaled_timeout
+from tests.utils.spawn import run_world
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -636,12 +635,12 @@ train(state)
         # can SIGKILL the straggler mid-teardown otherwise).
         "HOROVOD_PREEMPT_GRACE_SECS": "20",
     })
-    proc = subprocess.run(
+    proc = run_world(
         [sys.executable, "-m", "horovod_tpu.runner", "--multihost",
          "-H", "127.0.0.1:1,127.0.0.2:1", "--min-np", "1",
          "--max-np", "2",
          sys.executable, str(script)],
-        capture_output=True, text=True, timeout=scaled_timeout(600),
+        timeout=300,
         env=env, cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     # Every batch finished; the straggler's respawn recovered too.

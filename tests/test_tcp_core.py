@@ -12,7 +12,7 @@ WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)), "utils",
                       "tcp_worker.py")
 
 
-def _spawn_world(size, scenario, extra_env=None, timeout=120):
+def _spawn_world(size, scenario, extra_env=None, timeout=60):
     env = {"TEST_SCENARIO": scenario}
     env.update(extra_env or {})
     return spawn_world(WORKER, size, extra_env=env, timeout=timeout)
@@ -161,7 +161,7 @@ def test_tcp_hierarchical_big_allgather():
     _assert_ok(_spawn_world(4, "big_allgather", extra_env={
         "HOROVOD_HIERARCHICAL_ALLREDUCE": "1",
         "HVD_TPU_HOST_OF_RANK": "0,0,1,1",
-    }, timeout=180))
+    }))
 
 
 def test_tcp_hierarchical_allgather_own_knob():
@@ -171,14 +171,14 @@ def test_tcp_hierarchical_allgather_own_knob():
         "HOROVOD_HIERARCHICAL_ALLREDUCE": "0",
         "HOROVOD_HIERARCHICAL_ALLGATHER": "1",
         "HVD_TPU_HOST_OF_RANK": "0,0,1,1",
-    }, timeout=180))
+    }))
 
 
 EXTERNAL_WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                "utils", "external_worker.py")
 
 
-def _spawn_external_world(size, scenario, timeout=120):
+def _spawn_external_world(size, scenario, timeout=60):
     return spawn_world(EXTERNAL_WORKER, size,
                        extra_env={"TEST_SCENARIO": scenario},
                        timeout=timeout)
@@ -230,7 +230,7 @@ def _sanitized_lib(kind):
     try:
         subprocess.run(["make", "-s", "-j", "SANITIZE=%s" % kind],
                        cwd=CORE_DIR, check=True, capture_output=True,
-                       timeout=600)
+                       timeout=300)
     except Exception:
         return None
     lib = os.path.join(CORE_DIR, "libhvdtpu_core_%s.so" % kind)
@@ -256,7 +256,7 @@ def test_tcp_collectives_under_tsan():
             "halt_on_error=1 exitcode=66 suppressions=%s" % supp,
     })
     _assert_ok(_spawn_world(2, "collectives", extra_env=env,
-                            timeout=300))
+                            timeout=150))
 
 
 @pytest.mark.slow
@@ -274,4 +274,4 @@ def test_tcp_collectives_under_asan():
         "ASAN_OPTIONS": "halt_on_error=1:exitcode=66:detect_leaks=0",
     })
     _assert_ok(_spawn_world(2, "collectives", extra_env=env,
-                            timeout=300))
+                            timeout=150))

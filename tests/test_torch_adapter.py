@@ -192,7 +192,7 @@ hvd.shutdown()
     proc = subprocess.run(
         [sys.executable, "-m", "horovod_tpu.runner", "-np", "2",
          sys.executable, str(script)],
-        capture_output=True, text=True, timeout=240, env=_env(), cwd=REPO)
+        capture_output=True, text=True, timeout=120, env=_env(), cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "TORCH_OK 0" in proc.stdout
     assert "TORCH_OK 1" in proc.stdout

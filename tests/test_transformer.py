@@ -1,6 +1,8 @@
 """Flagship transformer tests: dense dp/sp/tp training, MoE variant,
 single-device equivalence."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,9 +10,14 @@ import optax
 import pytest
 from jax.sharding import Mesh
 
-from horovod_tpu.models.transformer import (TransformerConfig, forward,
-                                            init_params, loss_fn,
-                                            make_train_step)
+from horovod_tpu.models import bert, transformer
+from horovod_tpu.models.linear_attention import KdaConfig
+from horovod_tpu.models.transformer import (FEED_FORWARDS, MIXERS,
+                                            TransformerConfig, init_params,
+                                            loss_fn, make_train_step,
+                                            param_specs)
+from horovod_tpu.ops import pallas_kernels
+from horovod_tpu.parallel.moe import ExpertShare
 
 VOCAB = 64
 
@@ -23,7 +30,7 @@ def _cfg(**kw):
 
 
 def _mesh(shape, names):
-    devs = np.asarray(jax.devices()).reshape(shape)
+    devs = np.asarray(jax.devices()[:math.prod(shape)]).reshape(shape)
     return Mesh(devs, names)
 
 
@@ -50,7 +57,8 @@ def test_dense_transformer_trains_dp_sp_tp(hvd_world):
 
 
 def test_moe_transformer_trains(hvd_world):
-    cfg = _cfg(n_experts=4, top_k=2, capacity_factor=2.0, d_ff=32)
+    cfg = _cfg(layer_pattern=(("attention", "moe"),), n_experts=4, top_k=2,
+               capacity_factor=2.0, d_ff=32)
     mesh = _mesh((2, 2, 2), ("dp", "sp", "tp"))
     build, shard_batch = make_train_step(cfg, mesh, optax.adam(1e-2))
     params = init_params(jax.random.PRNGKey(1), cfg)
@@ -111,36 +119,6 @@ def test_remat_matches_no_remat(hvd_world):
 
     np.testing.assert_allclose(gradnorm(cfg), gradnorm(cfg_plain),
                                rtol=1e-4)
-
-
-def test_split_optimizer_matches_fused_step(hvd_world):
-    """The split-two-programs anti-lever (backward and optimizer
-    update jitted separately) must produce the same loss and params as
-    the fused step on a real dp/sp/tp mesh — otherwise the fusion A/B
-    it exists for measures diverged math, not program structure."""
-    cfg = _cfg()
-    params_host = init_params(jax.random.PRNGKey(9), cfg)
-    rng = np.random.RandomState(9)
-    batch_np = _batch(rng, 4, 16)
-    mesh = _mesh((2, 2, 2), ("dp", "sp", "tp"))
-
-    def run(split):
-        build, shard_batch = make_train_step(
-            cfg, mesh, optax.adam(1e-2), donate=False,
-            split_optimizer=split)
-        step, params, opt_state = build(params_host)
-        loss = None
-        for _ in range(2):
-            params, opt_state, loss = step(
-                params, opt_state, shard_batch(batch_np))
-        pn = float(optax.global_norm(jax.tree.map(
-            lambda x: jnp.asarray(x, jnp.float32), params)))
-        return float(loss), pn
-
-    l_f, p_f = run(False)
-    l_s, p_s = run(True)
-    np.testing.assert_allclose(l_s, l_f, rtol=1e-5)
-    np.testing.assert_allclose(p_s, p_f, rtol=1e-5)
 
 
 def test_collective_matmul_matches_psum(hvd_world):
@@ -204,36 +182,6 @@ def test_sharded_gradients_match_single_device(hvd_world):
     np.testing.assert_allclose(g8, g1, rtol=1e-4)
 
 
-def test_fused_projections_match_unfused(hvd_world):
-    """fused_qkv/fused_gate only repack the per-shard weight slices —
-    loss and gradients must be identical to the three-matmul form,
-    including under tp sharding (the local-boundary split)."""
-    cfg_f = _cfg(fused_qkv=True, fused_gate=True)
-    cfg_u = _cfg(fused_qkv=False, fused_gate=False)
-    params = init_params(jax.random.PRNGKey(7), cfg_u)
-    rng = np.random.RandomState(7)
-    batch = _batch(rng, 2, 16)
-    mesh = _mesh((2, 2, 2), ("dp", "sp", "tp"))
-    from jax.sharding import PartitionSpec as P
-    from horovod_tpu.models.transformer import param_specs
-
-    def loss_and_gradnorm(c):
-        f = jax.jit(jax.shard_map(
-            jax.value_and_grad(lambda p, b: loss_fn(p, b, c)),
-            mesh=mesh,
-            in_specs=(param_specs(c),
-                      {"tokens": P("dp", "sp"), "targets": P("dp", "sp")}),
-            out_specs=(P(), param_specs(c)), check_vma=False))
-        loss, g = f(params, batch)
-        return float(loss), float(optax.global_norm(
-            jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), g)))
-
-    lf, gf = loss_and_gradnorm(cfg_f)
-    lu, gu = loss_and_gradnorm(cfg_u)
-    np.testing.assert_allclose(lf, lu, rtol=1e-6)
-    np.testing.assert_allclose(gf, gu, rtol=1e-5)
-
-
 def test_ulysses_sp_matches_ring(hvd_world):
     # same model, same batch: ulysses (alltoall head exchange) must
     # produce the same loss surface as ring SP. heads=4 % sp=2 == 0.
@@ -253,3 +201,84 @@ def test_ulysses_sp_matches_ring(hvd_world):
         losses[mode] = float(loss)
     assert np.isclose(losses["ring"], losses["ulysses"],
                       rtol=1e-4), losses
+
+
+@pytest.mark.parametrize("ffn", FEED_FORWARDS)
+@pytest.mark.parametrize("mixer", MIXERS)
+def test_every_pair_of_the_pattern_builds_and_steps(mixer, ffn):
+    """Every (mixer, feed-forward) pair is an entry of the pattern and
+    nothing else: parameters and their specs are tuples of one structure,
+    and one step of the one program moves every leaf by a finite
+    gradient."""
+    cfg = _cfg(
+        layer_pattern=((mixer, ffn),), n_experts=4, top_k=2,
+        capacity_factor=2.0,
+        linear_attention=KdaConfig(n_heads=2, head_size=16, gate_rank=8,
+                                   chunk=8),
+        experts=ExpertShare(n_experts=4, first=1, count=2, top_k=2,
+                            d_model=32, d_ff=16, d_shared=16,
+                            block_rows=8))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    specs = param_specs(cfg)
+    assert isinstance(params["layers"], tuple) and len(params["layers"]) == 1
+    assert jax.tree.structure(params) == jax.tree.structure(
+        specs, is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
+    build, shard_batch = make_train_step(
+        cfg, _mesh((1, 1, 1), ("dp", "sp", "tp")), optax.sgd(1.0),
+        donate=False)
+    step, placed, opt_state = build(params)
+    stepped, _, loss = step(placed, opt_state,
+                            shard_batch(_batch(np.random.RandomState(0),
+                                               2, 16)))
+    assert np.isfinite(float(loss))
+    # sgd at rate 1: a leaf's gradient is what the step took from it.
+    grads = jax.tree.map(lambda a, b: np.asarray(a) - np.asarray(b),
+                         params, jax.device_get(stepped))
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for path, g in flat:
+        assert np.isfinite(g).all(), path
+        if path[-1].key != "router_bias":   # chooses experts, no gradient
+            assert np.abs(g).max() > 0, path
+
+
+def test_both_models_take_the_kernel_choice_from_ops(monkeypatch):
+    """``ops/pallas_kernels.use_flash_attention`` is the one place that
+    says whether a model calls the flash kernel: turned round, the decoder
+    and BERT both follow."""
+    calls = []
+
+    def flash(q, k, v, causal=True):
+        calls.append(causal)
+        return jnp.zeros_like(q)
+
+    monkeypatch.setattr(pallas_kernels, "flash_attention", flash)
+    cfg = _cfg()
+    bcfg = bert.BertConfig(vocab_size=VOCAB, d_model=32, n_layers=1,
+                           n_heads=4, d_ff=64, max_seq=16, dtype="float32")
+    tokens = _batch(np.random.RandomState(0), 2, 16)["tokens"]
+    mesh = _mesh((1, 1, 1), ("dp", "sp", "tp"))
+
+    def trace(model):
+        from jax.sharding import PartitionSpec as P
+        del calls[:]
+        if model is transformer:
+            jax.eval_shape(jax.shard_map(
+                lambda p, t: transformer.forward(p, t, cfg)[0], mesh=mesh,
+                in_specs=(param_specs(cfg), P("dp", "sp")),
+                out_specs=P("dp", "sp", "tp")),
+                init_params(jax.random.PRNGKey(0), cfg), tokens)
+        else:
+            jax.eval_shape(jax.shard_map(
+                lambda p, t: bert.encode(p, t, bcfg), mesh=mesh,
+                in_specs=(bert.param_specs(bcfg), P("dp", None)),
+                out_specs=P("dp", None, None)),
+                bert.init_params(jax.random.PRNGKey(0), bcfg), tokens)
+        return list(calls)
+
+    assert not pallas_kernels.use_flash_attention()    # the CPU test world
+    assert trace(transformer) == trace(bert) == []
+    monkeypatch.setattr(pallas_kernels, "use_flash_attention", lambda: True)
+    causal = trace(transformer)
+    assert causal and all(causal)
+    bidirectional = trace(bert)
+    assert bidirectional and not any(bidirectional)

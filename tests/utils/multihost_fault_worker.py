@@ -21,6 +21,7 @@ arrival-lag inversion common/skew.py scores)."""
 
 import os
 import sys
+import time
 
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
@@ -69,6 +70,15 @@ def main():
                             op=hvd.Sum, name="inj")
     except HorovodInternalError as exc:
         print("FAULT_LOUD %d: %s" % (r, exc), flush=True)
+        # Every rank stamps a collective's deadline on its own clock, and
+        # on a loaded box the ranks run seconds apart: a rank that left
+        # the moment its own deadline fired would be a disconnected
+        # member to a peer whose deadline has not come yet.  Stay for
+        # one more deadline (nothing, where the test sets none), and
+        # rank 0 for two: it holds the coordination service, and the
+        # runtime kills whoever is still connected when that goes.
+        time.sleep((2 if r == 0 else 1) * float(
+            os.environ.get("HOROVOD_COLLECTIVE_TIMEOUT_SECS") or 0))
         # Loud failure is a legitimate outcome under injection; the
         # world is poisoned, so skip hvd.shutdown()'s collective
         # teardown and exit with the designated code.

@@ -12,6 +12,7 @@ import signal
 import socket
 import subprocess
 import sys
+import time
 
 
 def kill_proc_tree(proc):
@@ -44,9 +45,54 @@ def scaled_timeout(seconds: float) -> float:
     return seconds * max(scale, 0.1)
 
 
-_SLOT_PORTS = 1200  # ports per (worker, shard) slot
-_SLOT_COUNT = 31    # 27100 + 31*1200 = 64300 < 65535
-_BASE_FLOOR = 27100
+def _text(data) -> str:
+    if data is None:
+        return ""
+    return data if isinstance(data, str) else data.decode(errors="replace")
+
+
+def world_timed_out(what, limit, outputs):
+    """The failure of a world that outlived its limit: a
+    ``TimeoutExpired`` alone throws away what the children had written,
+    which is the only record of where the world stood."""
+    return AssertionError(
+        "%s timed out after %.0f s; what its processes had written:\n%s"
+        % (what, limit, "\n".join(
+            "---- %s stdout ----\n%s\n---- %s stderr ----\n%s"
+            % (name, _text(out)[-20000:], name, _text(err)[-20000:])
+            for name, out, err in outputs)))
+
+
+def run_world(cmd, timeout, **kwargs):
+    """``subprocess.run(cmd, capture_output=True, text=True)`` under
+    ``scaled_timeout(timeout)`` for a command that starts processes of
+    its own (a launcher and its workers).  At the limit the whole
+    process group dies, not the launcher alone, and the failure shows
+    what every process had written (``world_timed_out``)."""
+    limit = scaled_timeout(timeout)
+    proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
+                            stderr=subprocess.PIPE, text=True,
+                            start_new_session=True, **kwargs)
+    try:
+        out, err = proc.communicate(timeout=limit)
+    except subprocess.TimeoutExpired:
+        kill_proc_tree(proc)
+        out, err = proc.communicate()
+        raise world_timed_out(" ".join(map(str, cmd)), limit,
+                              [("launcher", out, err)]) from None
+    return subprocess.CompletedProcess(cmd, proc.returncode, out, err)
+
+
+# The harness's ports lie under the kernel's own range (32768-60999 here,
+# /proc/sys/net/ipv4/ip_local_port_range): there every outgoing connection
+# and every bind to port 0 of any process on the box draws a port at
+# random, and one drawn between the probe below and the world's own bind
+# takes a rank's port from under it ("native core init failed").  The
+# launcher draws its own bases from the 6,000 ports above them
+# (runner/util.py: find_free_port_base).
+_SLOT_PORTS = 500   # ports per (worker, shard) slot
+_SLOT_COUNT = 31    # 10500 + 31*500 = 26000
+_BASE_FLOOR = 10500
 
 
 def _initial_port_base() -> int:
@@ -57,7 +103,7 @@ def _initial_port_base() -> int:
     # collision-free for up to 8 workers x 3 shards concurrently on
     # one host (and any single dimension up to 31); beyond capacity
     # slots wrap, degrading to probe-time detection rather than
-    # overflowing the 65535 port ceiling.
+    # running into the kernel's range.
     worker = os.environ.get("PYTEST_XDIST_WORKER", "")
     idx = int(worker[2:]) if worker.startswith("gw") and \
         worker[2:].isdigit() else 0
@@ -73,12 +119,14 @@ _port_base = [_initial_port_base()]
 def free_port_block(size, extra_offsets=()):
     """A base where [base, base+size) plus any extra offsets bind."""
     hi = max(size, *extra_offsets) if extra_offsets else size
+    floor = _initial_port_base()
     for _ in range(200):
         _port_base[0] += size + 30
-        # A long run can walk past the port ceiling — wrap back to the
-        # slot floor (binds below still confirm actual freeness).
-        if _port_base[0] + hi > 65000:
-            _port_base[0] = _initial_port_base()
+        # A file of many worlds walks its slot round and round and never
+        # into a neighbour's, whose worlds form at the same moment (the
+        # binds below confirm that the last lap's world has gone).
+        if _port_base[0] + hi >= floor + _SLOT_PORTS:
+            _port_base[0] = floor
         base = _port_base[0]
         socks = []
         try:
@@ -97,10 +145,14 @@ def free_port_block(size, extra_offsets=()):
     raise RuntimeError("no free port block found")
 
 
-def spawn_world(worker, size, extra_env=None, timeout=240, retry=True,
+def spawn_world(worker, size, extra_env=None, timeout=120, retry=True,
                 extra_port_offsets=(), pop_env=()):
-    """Run `worker` as `size` rank processes; returns [(rc, out, err)]."""
-    timeout = scaled_timeout(timeout)
+    """Run `worker` as `size` rank processes; returns [(rc, out, err)].
+    ``timeout`` is the limit of one attempt at the whole world; a world
+    that outlives it is tried once more, so the call waits at most twice
+    that (keep it at 150 s or under) and then fails with what every rank
+    had written (``world_timed_out``)."""
+    unscaled, timeout = timeout, scaled_timeout(timeout)
     base = free_port_block(size, extra_port_offsets)
     procs = []
     for rank in range(size):
@@ -133,23 +185,27 @@ def spawn_world(worker, size, extra_env=None, timeout=240, retry=True,
             stdout=subprocess.PIPE, stderr=subprocess.PIPE,
             start_new_session=True))
     outs = []
+    deadline = time.monotonic() + timeout
     for p in procs:
         try:
-            out, err = p.communicate(timeout=timeout)
+            out, err = p.communicate(
+                timeout=max(deadline - time.monotonic(), 0.1))
         except subprocess.TimeoutExpired:
             for q in procs:
                 kill_proc_tree(q)
-            for q in procs:
-                try:
-                    q.communicate(timeout=10)
-                except Exception:  # noqa: BLE001 - best-effort reap
-                    pass
-            if retry:
-                return spawn_world(worker, size, extra_env, timeout,
-                                   retry=False,
-                                   extra_port_offsets=extra_port_offsets,
-                                   pop_env=pop_env)
-            raise
+            failure = world_timed_out(
+                "a world of %d of %s" % (size, worker), timeout,
+                [("rank %d (rc=%d)" % (rank, rc), out, err)
+                 for rank, (rc, out, err) in enumerate(outs)]
+                + [("rank %d" % rank, *q.communicate())
+                   for rank, q in enumerate(procs) if rank >= len(outs)])
+            if not retry:
+                raise failure from None
+            print("retrying once: %s" % failure, file=sys.stderr)
+            return spawn_world(worker, size, extra_env, unscaled,
+                               retry=False,
+                               extra_port_offsets=extra_port_offsets,
+                               pop_env=pop_env)
         outs.append((p.returncode, out.decode(), err.decode()))
     return outs
 

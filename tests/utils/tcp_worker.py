@@ -2,8 +2,14 @@
 test_tcp_core.py as a real subprocess world, the way the reference tests
 run under `horovodrun -np 2 pytest` with Gloo-on-localhost)."""
 
+import faulthandler
 import os
 import sys
+
+# A world of these has been seen to stop without a word (four ranks, under
+# load, one file run in ten): before the harness's limit every thread says
+# where it stands, and the harness shows it (spawn.py: world_timed_out).
+faulthandler.dump_traceback_later(40, exit=False)
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", ".."))
