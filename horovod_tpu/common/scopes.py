@@ -25,9 +25,12 @@ Blocks inside the model, both passes: ``ATTENTION`` (softmax attention
 with its projections), ``HEAD`` (logits and cross entropy),
 ``LINEAR_ATTENTION`` (a delta-rule mixer whole; ``KDA_CORE`` inside it is
 the chunked recurrence alone, kernels or XLA form), ``MOE`` (an expert
-layer whole; inside it ``ROUTER`` is scores, top-k, the sort and the rows'
-gather and scatter, ``EXPERTS`` the routed experts' matrix products alone,
-``SHARED_EXPERT`` the expert every token takes).  Kernels, one
+layer whole; inside it ``ROUTER`` is the scores, the choice, the sort of
+the pairs by expert, the blocks' indices and weights and, under
+``ROUTER_ROWS``, the row movement alone: each block's gathers of its
+tokens' rows and the write of its rows by the ``hvd_moe_combine`` kernel;
+``EXPERTS`` the routed experts' matrix products alone, ``SHARED_EXPERT``
+the expert every token takes).  Kernels, one
 ``pallas_call`` each: ``FLASH_FWD``, ``FLASH_DQ``, ``FLASH_DKV``,
 ``FLASH_BWD_ONEPASS``; ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two,
 inside ``KDA_CORE``); ``kernel_name`` gives the same words as the ``name=``
@@ -46,6 +49,7 @@ LINEAR_ATTENTION = "hvd.linear_attention"
 KDA_CORE = "hvd.kda_core"
 MOE = "hvd.moe"
 ROUTER = "hvd.router"
+ROUTER_ROWS = "hvd.router_rows"
 EXPERTS = "hvd.experts"
 SHARED_EXPERT = "hvd.shared_expert"
 FLASH_FWD = "hvd.flash_fwd"

@@ -44,6 +44,7 @@ from jax import lax
 
 from ..common import scopes
 from ..ops import pallas_kernels
+from ..parallel.moe import SAVED as moe_saved_names
 from ..parallel.moe import (ExpertShare, MoeConfig, expert_share_ffn,
                             init_expert_share_params, moe_ffn)
 from ..parallel.ring_attention import (local_attention, pvary_missing,
@@ -479,9 +480,10 @@ def hidden(params, tokens, cfg: TransformerConfig):
                  for mixer, ffn in cfg.layer_pattern]
     if cfg.remat:
         # "full" keeps nothing but what a block names as dearer to compute
-        # again than to keep (the delta rule's walk along the sequence).
+        # again than to keep (the delta rule's walk along the sequence, an
+        # expert layer's choice and sort).
         pol = {"full": jax.checkpoint_policies.save_only_these_names(
-                   *kda_saved_names),
+                   *kda_saved_names, *moe_saved_names),
                "dots": jax.checkpoint_policies.dots_saveable,
                "dots_no_batch":
                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,

@@ -30,8 +30,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..common import scopes
+from ..ops import moe_kernels
 from .ring_attention import pvary_missing
 
 
@@ -186,6 +188,10 @@ class ExpertShare:
             raise ValueError("experts %d..%d are not among %d"
                              % (self.first, self.first + self.count - 1,
                                 self.n_experts))
+        if not 0 < self.top_k <= self.n_experts:
+            # A token's choices are distinct: the row writes rest on it.
+            raise ValueError("a token cannot choose %d of %d experts"
+                             % (self.top_k, self.n_experts))
 
 
 def init_expert_share_params(key, share: ExpertShare, n: int,
@@ -213,23 +219,64 @@ def init_expert_share_params(key, share: ExpertShare, n: int,
     return params
 
 
+# ``checkpoint_name``s of what a recomputed layer keeps of its routing: the
+# chosen ids, their sort by expert and the loads are integers (1 MiB a
+# layer at 16384 tokens choosing 8), so a layer recomputed in the backward
+# pass neither chooses nor sorts again.
+SAVED = ("moe_ids", "moe_order", "moe_loads")
+
+
+def _top_k_ids(values, k: int):
+    """``lax.top_k(values, k)[1]`` as ``k`` rounds of arg-max and mask
+    instead of a sort of every row: the largest first, the first index on
+    a tie."""
+    cols = lax.broadcasted_iota(jnp.int32, values.shape, values.ndim - 1)
+    ids = []
+    for _ in range(k):
+        best = jnp.argmax(values, axis=-1).astype(jnp.int32)[..., None]
+        ids.append(best)
+        values = jnp.where(cols == best, -jnp.inf, values)
+    return jnp.concatenate(ids, axis=-1)
+
+
+def _take(scores, ids):
+    """``scores[t, ids[t, j]]`` as a sum over the experts under a mask (one
+    term is not zero, so it is exact): its gradient is the same mask, where
+    a gather's is a scatter-add of ``k T`` scalars."""
+    cols = lax.broadcasted_iota(jnp.int32, (1,) * ids.ndim + scores.shape[-1:],
+                                ids.ndim)
+    return jnp.sum(jnp.where(ids[..., None] == cols, scores[..., None, :],
+                             0.0), axis=-1)
+
+
 def route(x, router, bias, share: ExpertShare):
     """Sigmoid scores in float32 over every expert of the layer; the
     ``top_k`` experts with the largest score + bias; their scores (without
     the bias) renormalised to sum to ``routed_scaling``.  Returns (weights
-    ``[T, k]`` float32, ids ``[T, k]``)."""
+    ``[T, k]`` float32, ids ``[T, k]``; the ids carry a ``checkpoint_name``,
+    ``SAVED``).
+
+    The product is float32 at ``Precision.HIGHEST`` whatever ``x`` is.  Of
+    its six bfloat16 passes the TPU's compiler drops the three that
+    multiply the middle and low terms of an ``x`` that arrives in bfloat16,
+    which are zero (0.69 ms for ``[16384, 4096] x [4096, 320]``, the MXU's
+    peak for three passes): a three-pass form written out by hand took as
+    long and 60 MiB more (``PERF.md``, PR 30)."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
-    _, ids = lax.top_k(scores + bias.astype(jnp.float32), share.top_k)
-    top = jnp.take_along_axis(scores, ids, axis=-1)
+    ids = checkpoint_name(_top_k_ids(
+        lax.stop_gradient(scores) + bias.astype(jnp.float32), share.top_k),
+        SAVED[0])
+    top = _take(scores, ids)
     return top / top.sum(-1, keepdims=True) * share.routed_scaling, ids
 
 
 def _sorted_pairs(ids, share: ExpertShare):
     """The (token, choice) pairs that chose a held expert, sorted by
     expert: ``order`` (indices into the flattened ``[T * k]`` pairs, held
-    ones first), and how many pairs every expert of the layer got."""
+    ones first), and how many pairs every expert of the layer got.  The
+    sort is stable, so one expert's pairs keep their order: ascending."""
     flat = ids.reshape(-1)
     local = flat - share.first
     local = jnp.where((local >= 0) & (local < share.count), local,
@@ -237,7 +284,7 @@ def _sorted_pairs(ids, share: ExpertShare):
     order = jnp.argsort(local, stable=True).astype(jnp.int32)
     loads = jnp.sum(flat[:, None] == jnp.arange(share.n_experts)[None, :],
                     axis=0, dtype=jnp.int32)
-    return order, loads
+    return checkpoint_name(order, SAVED[1]), checkpoint_name(loads, SAVED[2])
 
 
 def _swiglu(x, w1, w3, w2):
@@ -258,17 +305,31 @@ def _plan(sizes, rows: int):
     return jnp.cumsum(sizes), jnp.cumsum((sizes + rows - 1) // rows)
 
 
-def _block(i, order, sizes, plan, rows: int):
-    """Block ``i`` of the sorted pairs.  Returns its expert, each row's
-    place among the flattened ``[T * k]`` pairs, and which rows are real
-    (an expert's last block is part empty)."""
+def _block(i, order, sizes, plan, rows: int, top_k: int):
+    """Block ``i`` of the sorted pairs.  Returns its expert, its rows'
+    places among the flattened ``[T * k]`` pairs and the tokens they are
+    read from (in range for every row), which rows are real (an expert's
+    last block is part empty), and the token and the pair each row is
+    written to.
+
+    The real rows' tokens are distinct and strictly ascending: the sort by
+    expert is stable, so an expert's pairs come in the order of the
+    flattened pairs, and a token chooses an expert at most once.  A padded
+    row is written nowhere: its token ``T + row`` and its pair ``T k + row``
+    are out of range, so the block's indices stay distinct and ascending,
+    and what writes by them (``moe_kernels.combine``, the weights'
+    gradient's scatter) drops it."""
     ends, block_ends = plan
     expert = jnp.sum(i >= block_ends)
     nth = i - jnp.where(expert > 0, block_ends[expert - 1], 0)
-    at = ends[expert] - sizes[expert] + nth * rows \
-        + jnp.arange(rows, dtype=jnp.int32)
-    return (expert, order[jnp.minimum(at, order.shape[0] - 1)],
-            at < ends[expert])
+    row = jnp.arange(rows, dtype=jnp.int32)
+    start = ends[expert] - sizes[expert] + nth * rows
+    real = start + row < ends[expert]
+    pairs = order[jnp.minimum(start + row, order.shape[0] - 1)]
+    source = pairs // top_k
+    return (expert, pairs, source, real,
+            jnp.where(real, source, order.shape[0] // top_k + row),
+            jnp.where(real, pairs, order.shape[0] + row))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -276,7 +337,10 @@ def _routed(top_k, rows, x, we1, we3, we2, weights, order, sizes):
     """``y[t] = sum over t's held choices of weight * expert(x[t])``,
     block by block over the sorted pairs: a step pays for the blocks
     that hold pairs (``0.2 T`` rows where routing is even), and ``k T``
-    rows are still right."""
+    rows are still right.  A block gathers its rows of ``x``, multiplies,
+    and adds its rows into ``y`` at their tokens: no two rows of a block
+    share a token (``_block``), so the adds are conflict-free writes, one
+    DMA a row in any order (``ops/moe_kernels.py``)."""
     return _routed_fwd(top_k, rows, x, we1, we3, we2, weights, order,
                        sizes)[0]
 
@@ -287,20 +351,25 @@ def _routed_fwd(top_k, rows, x, we1, we3, we2, weights, order, sizes):
 
     def block(i, y):
         with jax.named_scope(scopes.ROUTER):
-            expert, pairs, real = _block(i, order, sizes, plan, rows)
-            tokens = pairs // top_k
-            x_rows = x[tokens]
+            expert, pairs, source, real, token, _ = _block(
+                i, order, sizes, plan, rows, top_k)
             w_rows = jnp.where(real, flat_w[pairs], 0.0)
+            with jax.named_scope(scopes.ROUTER_ROWS):
+                x_rows = x[source]
         with jax.named_scope(scopes.EXPERTS):
             out = _swiglu(x_rows, we1[expert].astype(x.dtype),
                           we3[expert].astype(x.dtype),
                           we2[expert].astype(x.dtype))
         with jax.named_scope(scopes.ROUTER):
-            return y.at[tokens].add(
-                (out.astype(jnp.float32) * w_rows[:, None]).astype(y.dtype))
+            out = (out.astype(jnp.float32) * w_rows[:, None]).astype(y.dtype)
+            with jax.named_scope(scopes.ROUTER_ROWS):
+                return moe_kernels.combine(y, out, token)
 
     # A token's sum has at most top_k terms: it is kept at x's precision.
-    y = lax.fori_loop(0, plan[1][-1], block, _zeros(x.shape, x.dtype, x))
+    y = lax.fori_loop(0, plan[1][-1], block,
+                      _zeros(moe_kernels.as_rows(x.shape), x.dtype, x))
+    with jax.named_scope(scopes.ROUTER), jax.named_scope(scopes.ROUTER_ROWS):
+        y = y.reshape(x.shape)
     return y, (x, we1, we3, we2, weights, order, sizes)
 
 
@@ -318,11 +387,12 @@ def _routed_bwd(top_k, rows, res, dy):
     def block(i, carry):
         dx, d1, d3, d2, dw = carry
         with jax.named_scope(scopes.ROUTER):
-            expert, pairs, real = _block(i, order, sizes, plan, rows)
-            tokens = pairs // top_k
-            x_rows = x[tokens]
+            expert, pairs, source, real, token, pair = _block(
+                i, order, sizes, plan, rows, top_k)
             w_rows = jnp.where(real, flat_w[pairs], 0.0)
-            dy_rows = dy[tokens].astype(jnp.float32)
+            with jax.named_scope(scopes.ROUTER_ROWS):
+                x_rows = x[source]
+                dy_rows = dy[source].astype(jnp.float32)
         with jax.named_scope(scopes.EXPERTS):
             out, vjp = jax.vjp(expert_of, x_rows, we1[expert], we3[expert],
                                we2[expert])
@@ -331,15 +401,25 @@ def _routed_bwd(top_k, rows, res, dy):
         with jax.named_scope(scopes.ROUTER):
             dw_rows = jnp.where(
                 real, jnp.sum(out.astype(jnp.float32) * dy_rows, -1), 0.0)
-            return (dx.at[tokens].add(dx_rows.astype(dx.dtype)),
-                    d1.at[expert].add(g1), d3.at[expert].add(g3),
-                    d2.at[expert].add(g2), dw.at[pairs].add(dw_rows))
+            with jax.named_scope(scopes.ROUTER_ROWS):
+                dx = moe_kernels.combine(dx, dx_rows.astype(dx.dtype), token)
+            # What the compiler cannot see of these indices and ``_block``
+            # says.  (``indices_are_sorted`` is as true of ``pair`` and is
+            # left unsaid: XLA's TPU scatter takes 13 times as long when
+            # told, ``PERF.md`` PR 30.)
+            return (dx, d1.at[expert].add(g1, unique_indices=True),
+                    d3.at[expert].add(g3, unique_indices=True),
+                    d2.at[expert].add(g2, unique_indices=True),
+                    dw.at[pair].add(dw_rows, mode="drop",
+                                    unique_indices=True))
 
-    init = (_zeros(x.shape, x.dtype, x),
+    init = (_zeros(moe_kernels.as_rows(x.shape), x.dtype, x),
             _zeros(we1.shape, we1.dtype, x), _zeros(we3.shape, we3.dtype, x),
             _zeros(we2.shape, we2.dtype, x),
             _zeros(flat_w.shape, jnp.float32, x))
     dx, d1, d3, d2, dw = lax.fori_loop(0, plan[1][-1], block, init)
+    with jax.named_scope(scopes.ROUTER), jax.named_scope(scopes.ROUTER_ROWS):
+        dx = dx.reshape(x.shape)
     return (dx, d1, d3, d2,
             dw.reshape(weights.shape).astype(weights.dtype), None, None)
 

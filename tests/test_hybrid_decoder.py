@@ -18,7 +18,9 @@ from horovod_tpu.models.linear_attention import (KdaConfig, init_kda_params,
                                                  kda_chunked,
                                                  kda_chunked_xla,
                                                  linear_attention_block)
-from horovod_tpu.ops import kda_kernels
+from horovod_tpu.common import scopes
+from horovod_tpu.ops import kda_kernels, moe_kernels
+from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.moe import (ExpertShare, expert_share_ffn,
                                       init_expert_share_params)
 from yardstick.builders import solar_open2 as reference
@@ -397,6 +399,15 @@ def expert_layer(routing, tokens=96):
         # and the chosen experts' weights are still their scores'
         params["router_bias"] = jnp.zeros((8,)).at[jnp.array([0, 4, 7])].set(
             2.0)
+    elif routing == "part-empty blocks beside full ones, the last pair held":
+        # Expert 3 takes every token (six full blocks of 16), expert 4 full
+        # blocks and a part-empty one, and the last token's last choice is
+        # expert 4: the flattened pairs end in a held one, which is where a
+        # padded row aimed at the nearest pair in range would land.
+        pull = pull.at[3].set(1.0)
+        x = x.at[-1].set(0.0).at[-1, 0].set(1.0)
+        params["router"] = params["router"].at[0].set(
+            jnp.array([-9.0, -9.0, -9.0, 0.0, -3.0, -9.0, -9.0, 2.0]))
     # x is positive, so a column raised by a constant wins every token.
     params["router"] = params["router"] + pull[None, :]
     return share, params, x
@@ -404,7 +415,8 @@ def expert_layer(routing, tokens=96):
 
 @pytest.mark.parametrize("routing", [
     "uniform", "every token picks only held experts",
-    "one expert gets every token", "the balancing bias chooses"])
+    "one expert gets every token", "the balancing bias chooses",
+    "part-empty blocks beside full ones, the last pair held"])
 def test_expert_share_matches_the_masked_loop(routing):
     share, params, x = expert_layer(routing)
     y, loads = jax.jit(lambda p, x: expert_share_ffn(p, x, share))(params, x)
@@ -414,6 +426,12 @@ def test_expert_share_matches_the_masked_loop(routing):
     held = loads[share.first:share.first + share.count]
     if routing == "every token picks only held experts":
         assert int(held.sum()) == share.top_k * x.shape[0]     # no drop
+    elif routing.startswith("part-empty blocks"):
+        _, ids = moe.route(x, params["router"], params["router_bias"], share)
+        assert int(ids[-1, -1]) == 4
+        rows = share.block_rows
+        assert int(held[1]) == x.shape[0] and x.shape[0] % rows == 0
+        assert int(held[2]) > rows and int(held[2]) % rows
     elif routing == "one expert gets every token":
         assert int(held[1]) == x.shape[0]
     elif routing == "the balancing bias chooses":
@@ -428,6 +446,140 @@ def test_expert_share_matches_the_masked_loop(routing):
     wants = jax.grad(lambda p, x: (masked_loop(p, x, share)[0]
                                    * weight).sum(), argnums=(0, 1))(params, x)
     assert worst(grads, wants) < 1e-4
+
+
+@pytest.mark.parametrize("ties", [
+    "no ties", "repeated columns", "every column the same",
+    "a bias that ties two experts"])
+def test_the_choice_is_top_ks_in_its_order(ties):
+    """Eight maxima instead of a sort: ``lax.top_k``'s ids in its order,
+    the first index on a tie."""
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(11),
+                                              (64, 20)))
+    bias = jnp.zeros((20,))
+    if ties == "repeated columns":
+        scores = scores.at[:, 7].set(scores[:, 2]).at[:, 19].set(scores[:, 2])
+    elif ties == "every column the same":
+        scores = jnp.broadcast_to(scores[:, :1], scores.shape)
+    elif ties == "a bias that ties two experts":
+        scores = scores.at[:, 5].set(0.25).at[:, 13].set(0.75)
+        bias = bias.at[5].set(2.5).at[13].set(2.0)
+    want = lax.top_k(scores + bias, 6)[1]
+    got = jax.jit(lambda v: moe._top_k_ids(v, 6))(scores + bias)
+    assert got.dtype == jnp.int32 and got.tolist() == want.tolist()
+    if ties == "a bias that ties two experts":
+        assert got[:, :2].tolist() == [[5, 13]] * 64
+    # ... and through the router: the same experts with the same weights.
+    share = ExpertShare(n_experts=20, first=0, count=20, top_k=6, d_model=20,
+                        d_ff=8, d_shared=0)
+    logits = jnp.log(scores) - jnp.log1p(-scores)
+    weights, ids = moe.route(logits, jnp.eye(20), bias, share)
+    top = jnp.take_along_axis(jax.nn.sigmoid(logits), ids, -1)
+    assert float(jnp.abs(weights - top / top.sum(-1, keepdims=True)).max()) \
+        < 1e-6
+    if ties != "a bias that ties two experts":     # sigmoid(logit) rounds
+        assert ids.tolist() == want.tolist()
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_router_product_is_float32_at_highest(dtype):
+    """Whatever the tokens' dtype, the scores are one float32 product at
+    ``Precision.HIGHEST`` (of a bfloat16 ``x`` the TPU's compiler drops the
+    passes that multiply zeros by itself; ``PERF.md``, PR 30): as exact as
+    float64 to 1e-6, and bfloat16 tokens choose the experts and the weights
+    their float32 copy would."""
+    hi = lax.Precision.HIGHEST
+    x = jax.random.normal(jax.random.PRNGKey(12), (256, 96)).astype(dtype)
+    router = jax.random.normal(jax.random.PRNGKey(13), (96, 40)) / 9.0
+    share = ExpertShare(n_experts=40, first=0, count=4, top_k=4, d_model=96,
+                        d_ff=8, d_shared=0)
+    bias = jnp.zeros((40,))
+    jaxpr = jax.make_jaxpr(lambda x, w: moe.route(x, w, bias, share))(
+        x, router).jaxpr
+    dots = [e for e in _equations(jaxpr) if e[0] == "dot_general"]
+    assert len(dots) == 1
+    _, _, params, dtypes = dots[0]
+    assert dtypes == [jnp.float32, jnp.float32]
+    assert params["precision"] == (hi, hi)
+    weights, ids = moe.route(x, router, bias, share)
+    assert weights.dtype == jnp.float32
+    exact = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64)
+                                  @ np.asarray(router, np.float64))))
+    want = np.take_along_axis(exact, np.asarray(ids), -1)
+    want = want / want.sum(-1, keepdims=True)
+    assert np.abs(np.asarray(weights) - want).max() < 1e-6
+    again, ids_again = moe.route(x.astype(jnp.float32), router, bias, share)
+    assert ids.tolist() == ids_again.tolist()
+    assert weights.tolist() == again.tolist()
+    # ... and the gradients of both operands are the float32 product's.
+    cot = jax.random.normal(jax.random.PRNGKey(14), weights.shape)
+    grads = jax.grad(lambda x, w: (moe.route(x, w, bias, share)[0]
+                                   * cot).sum(), argnums=(0, 1))(x, router)
+    wants = jax.grad(
+        lambda x, w: (moe.route(x.astype(jnp.float32), w, bias, share)[0]
+                      * cot).sum(), argnums=(0, 1))(x, router)
+    assert grads[0].dtype == x.dtype and grads[1].dtype == jnp.float32
+    assert worst(jax.tree.map(lambda g: g.astype(jnp.float32), grads),
+                 jax.tree.map(lambda g: g.astype(jnp.float32), wants)) < 1e-5
+
+
+def _equations(jaxpr, recomputed=False, under="", found=None):
+    """(primitive, name stack, params, input dtypes) of every equation,
+    nested jaxprs included (an inner equation's name stack starts at the
+    equation that holds it); with ``recomputed``, of those alone that sit
+    inside a ``jax.checkpoint``'s equation: a layer run again in the
+    backward pass."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        stack = "%s/%s" % (under, eqn.source_info.name_stack)
+        if not recomputed:
+            found.append((eqn.primitive.name, stack, eqn.params,
+                          [v.aval.dtype for v in eqn.invars
+                           if hasattr(v.aval, "dtype")]))
+        inside = recomputed and eqn.primitive.name != "remat2"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _equations(inner, inside, stack, found)
+    return found
+
+
+def test_the_router_tells_the_compiler_what_it_knows():
+    """The form of a pattern step, read from its jaxpr: the blocks' rows
+    are written by the combine kernel, every scatter-add left under the
+    router says that its indices are distinct (a block's pairs, an expert's
+    slab), and a layer run again in the backward pass neither sorts nor
+    chooses again (it kept the ids, the order and the loads)."""
+    cell = small_cell(dtype="bfloat16")
+    cfg = reference._model_config(cell)
+    params = transformer.init_params(jax.random.PRNGKey(15), cfg)
+    batch = reference.make_batch(cell, 16, 2)
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1, 1),
+                             (cfg.dp_axis, cfg.sp_axis, cfg.tp_axis))
+    rows = {k: P(cfg.dp_axis, cfg.sp_axis) for k in batch}
+    specs = transformer.param_specs(cfg)
+    step = jax.shard_map(
+        jax.value_and_grad(lambda p, b: transformer.loss_fn(p, b, cfg)),
+        mesh=mesh, in_specs=(specs, rows), out_specs=(P(), specs))
+    jaxpr = jax.make_jaxpr(step)(params, batch).jaxpr
+    router = [e for e in _equations(jaxpr) if scopes.ROUTER in e[1]]
+    adds = [e for e in router if e[0] == "scatter-add"]
+    # The weights' gradient and the three weight slabs, backward.
+    assert len(adds) >= 4
+    assert all(e[2]["unique_indices"] for e in adds)
+    # y's rows forward, dx's rows backward.
+    writes = [e for e in router if e[0] == "pallas_call"]
+    assert len(writes) >= 2
+    assert all(scopes.ROUTER_ROWS in e[1] and moe_kernels.COMBINE in e[1]
+               for e in writes)
+    chosen = {"sort", "argmax", "top_k"}
+    assert {"sort", "argmax"} <= {e[0] for e in router}
+    again = [e for e in _equations(jaxpr, recomputed=True)
+             if scopes.ROUTER in e[1]]
+    assert any(e[0] == "dot_general" for e in again)    # the scores, again
+    assert not [e for e in again if e[0] in chosen]
 
 
 def test_expert_shares_add_up_to_the_uncut_layer():
@@ -456,4 +608,10 @@ def test_expert_shares_add_up_to_the_uncut_layer():
 def test_expert_share_refuses_ids_outside_the_layer():
     with pytest.raises(ValueError, match="not among"):
         ExpertShare(n_experts=8, first=7, count=2, top_k=2, d_model=8,
+                    d_ff=8, d_shared=0)
+
+
+def test_expert_share_refuses_more_choices_than_experts():
+    with pytest.raises(ValueError, match="cannot choose 9 of 8"):
+        ExpertShare(n_experts=8, first=0, count=2, top_k=9, d_model=8,
                     d_ff=8, d_shared=0)

@@ -5,7 +5,8 @@ Every step builder's lowered and compiled text holds operations under
 ``hvd.optimizer``; the in-program gradient all-reduce sits under
 ``hvd.exchange``; the models' attention and head carry their scopes in
 both passes; each flash ``pallas_call`` sits under its kernel scope and
-carries its ``name``, and so do the delta rule's two under the core's.
+carries its ``name``, and so do the delta rule's two under the core's and
+the expert layer's row writes under the router's.
 The optimized HLO of the executable is where the benchmark reads the scopes
 (``yardstick/scopes.py``), so that text is what is checked here.  CPU
 world, tiny sizes.
@@ -24,7 +25,7 @@ from jax.sharding import Mesh
 import horovod_tpu.jax as hvd
 from horovod_tpu.common import scopes
 from horovod_tpu.models import bert, transformer
-from horovod_tpu.ops import pallas_bn, pallas_kernels
+from horovod_tpu.ops import moe_kernels, pallas_bn, pallas_kernels
 
 FORWARD = "jvp(%s)" % scopes.MODEL
 BACKWARD = "transpose(jvp(%s))" % scopes.MODEL
@@ -84,15 +85,19 @@ def _transformer():
 
 def _pattern():
     """A period of a delta-rule layer (heads of 128, which take the
-    kernels) and a softmax layer, every layer recomputed."""
+    kernels) with a share of an expert layer and a softmax layer, every
+    layer recomputed."""
     from horovod_tpu.models.linear_attention import KdaConfig
+    from horovod_tpu.parallel.moe import ExpertShare
     cfg = transformer.TransformerConfig(
         vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=64, max_seq=64, dtype="float32", remat=True,
-        layer_pattern=(("linear_attention", "dense"),
+        layer_pattern=(("linear_attention", "expert_share"),
                        ("attention", "dense")),
         linear_attention=KdaConfig(n_heads=2, head_size=128, gate_rank=8,
-                                   chunk=8))
+                                   chunk=8),
+        experts=ExpertShare(n_experts=8, first=2, count=3, top_k=2,
+                            d_model=32, d_ff=16, d_shared=16, block_rows=8))
     build, shard_batch = transformer.make_train_step(
         cfg, _mesh((4, 1, 2), ("dp", "sp", "tp")), optax.adam(1e-2))
     step, params, opt_state = build(
@@ -200,6 +205,24 @@ def test_delta_rule_kernels_sit_under_the_core_in_their_pass(texts, kernel,
     assert backward or not any("checkpoint" in n for n in names)
 
 
+@pytest.mark.parametrize("backward", [False, True])
+def test_the_blocks_rows_sit_under_the_router_in_both_passes(texts, backward):
+    """The dispatch gathers and the combine kernel of an expert layer's
+    blocks under ``hvd.moe/hvd.router/hvd.router_rows``, the kernel with
+    its ``name=``, in the forward and in the backward pass."""
+    lowered, compiled = texts("transformer.make_train_step-pattern")
+    # hvd.moe/<the blocks' loop>/hvd.router/hvd.router_rows/...
+    path = re.compile("%s/[^;]*%s/%s/" % tuple(map(re.escape, (
+        scopes.MOE, scopes.ROUTER, scopes.ROUTER_ROWS))))
+    kernel = "%s/%s/" % (scopes.ROUTER_ROWS, moe_kernels.COMBINE)
+    assert kernel in lowered
+    rows = [n for n in _op_names(compiled) if scopes.ROUTER_ROWS in n]
+    assert rows and all(path.search(n) for n in rows)
+    mine = [n for n in rows if FORWARD in n and (BACKWARD in n) == backward]
+    assert any(kernel in n for n in mine)
+    assert any(n.endswith(scopes.ROUTER_ROWS + "/gather") for n in mine)
+
+
 def _pallas_calls(jaxpr, found):
     """(name stack, ``name=``) of every ``pallas_call`` in a jaxpr,
     nested jaxprs included."""
@@ -272,4 +295,4 @@ def test_one_vocabulary():
     for use in uses:
         assert use.startswith("scopes.") and use[7:] in constants, use
     assert all(re.fullmatch(r"hvd\.[a-z_]+", v) for v in constants.values())
-    assert len(set(constants.values())) == len(constants) == 17
+    assert len(set(constants.values())) == len(constants) == 18
