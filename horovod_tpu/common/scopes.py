@@ -32,7 +32,9 @@ tokens' rows and the write of its rows by the ``hvd_moe_combine`` kernel;
 ``EXPERTS`` the routed experts' matrix products alone, ``SHARED_EXPERT``
 the expert every token takes).  Kernels, one
 ``pallas_call`` each: ``FLASH_FWD``, ``FLASH_DQ``, ``FLASH_DKV``,
-``FLASH_BWD_ONEPASS``; ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two,
+``FLASH_BWD_ONEPASS``, and ``FLASH_WINDOW_FWD``, ``FLASH_WINDOW_DQ``,
+``FLASH_WINDOW_DKV`` for the same three under a window (a sliding layer's
+whole block is ``WINDOW_ATTENTION``, inside ``ATTENTION``); ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two,
 inside ``KDA_CORE``); ``kernel_name`` gives the same words as the ``name=``
 of the call (``hvd_flash_fwd``), which is what the trace viewer prints for
 a Mosaic kernel.
@@ -56,6 +58,10 @@ FLASH_FWD = "hvd.flash_fwd"
 FLASH_DQ = "hvd.flash_dq"
 FLASH_DKV = "hvd.flash_dkv"
 FLASH_BWD_ONEPASS = "hvd.flash_bwd_onepass"
+WINDOW_ATTENTION = "hvd.window_attention"
+FLASH_WINDOW_FWD = "hvd.flash_window_fwd"
+FLASH_WINDOW_DQ = "hvd.flash_window_dq"
+FLASH_WINDOW_DKV = "hvd.flash_window_dkv"
 KDA_FWD = "hvd.kda_fwd"
 KDA_BWD = "hvd.kda_bwd"
 

@@ -25,14 +25,20 @@ What a layer is made of is said in one place, ``layer_pattern``: one
 period of (mixer, feed-forward) pairs out of ``MIXERS`` and
 ``FEED_FORWARDS``.  The scan runs over periods, ``params["layers"]`` is a
 tuple with one stacked dict for each layer of the period, and each layer is
-recomputed on its own.  The block functions of the kinds that need more
+recomputed on its own.  ``leading_layers`` are pairs that run once ahead of
+the scan (``params["leading"]``: a model whose first layers differ from its
+periods).  The block functions of the kinds that need more
 than a few lines live beside their mechanism (``models/linear_attention.py``,
 ``parallel/moe.py``); a new architecture is one more kind there and an entry
-of the pattern here.
+of the pattern here.  Softmax attention is one block whatever the kind:
+what a kind of layer fixes of it (head counts, window, rotary table, gate)
+is a ``SoftmaxAttention``, which a pattern's entry may hold in the mixer's
+place; ``attention`` and ``gated_nope_attention`` name two of its settings.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -55,13 +61,62 @@ from .linear_attention import (KdaConfig, init_kda_params, kda_param_specs,
 
 # Kinds a layer is made of.  ``attention``: RoPE softmax attention (GQA);
 # ``gated_nope_attention``: the same with no positional encoding and an
-# element-wise sigmoid gate on the heads' output; ``linear_attention``: the
-# gated delta rule (``cfg.linear_attention``).  ``dense``: SwiGLU; ``moe``:
+# element-wise sigmoid gate on the heads' output (both at the model's
+# ``n_heads`` / ``n_kv_heads``; a ``SoftmaxAttention`` in the mixer's place
+# says its own); ``linear_attention``: the gated delta rule
+# (``cfg.linear_attention``).  ``dense``: SwiGLU; ``moe``:
 # capacity-factor experts with an all-to-all over ``sp``;
 # ``expert_share``: this chip's share of a dropless expert layer beside a
 # shared expert (``cfg.experts``).
 MIXERS = ("attention", "gated_nope_attention", "linear_attention")
 FEED_FORWARDS = ("dense", "moe", "expert_share")
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """One rotary table.  ``share`` of every head rotates, its first
+    dimensions (``partial_rotary_factor``); the rest passes through.
+    ``factor`` other than 1 is YaRN: the frequencies that turn fewer than
+    ``beta_slow`` times over ``original_max_seq`` positions are divided by
+    ``factor``, those that turn more than ``beta_fast`` times stay, a
+    linear ramp between; cos and sin are scaled by ``attention_factor``."""
+    theta: float = 10000.0
+    share: float = 1.0
+    factor: float = 1.0
+    original_max_seq: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if not 0 < self.share <= 1:
+            raise ValueError("a rotary table covers a share of (0, 1] of a "
+                             "head, not %r" % (self.share,))
+        if self.factor != 1.0 and self.original_max_seq <= 0:
+            raise ValueError("YaRN by %r needs the positions it was "
+                             "stretched from" % (self.factor,))
+
+
+@dataclasses.dataclass(frozen=True)
+class SoftmaxAttention:
+    """What a kind of layer fixes of softmax attention: query and
+    key/value heads on this tp shard group, the ``window`` of keys a query
+    sees (None: every key before it), its rotary table (None: no
+    positional encoding) and whether a sigmoid gate from a projection of
+    its own multiplies the heads' output before ``wo``."""
+    n_heads: int
+    n_kv_heads: int
+    window: Optional[int] = None
+    rope: Optional[Rope] = Rope()
+    gate: bool = False
+
+    def __post_init__(self):
+        if self.n_kv_heads < 1 or self.n_heads % self.n_kv_heads:
+            raise ValueError("%d key/value heads do not divide %d query "
+                             "heads" % (self.n_kv_heads, self.n_heads))
+        if self.window is not None and self.window < 1:
+            raise ValueError("a window of %r keys holds not even the query "
+                             "itself" % (self.window,))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,8 +161,12 @@ class TransformerConfig:
     # work).  No-op at tp=1, so single-chip programs are unchanged.
     collective_matmul: bool = False
     # One period of the layer pattern, ((mixer, feed-forward), ...) out of
-    # MIXERS x FEED_FORWARDS; n_layers is a multiple of its length.
-    layer_pattern: Tuple[Tuple[str, str], ...] = (("attention", "dense"),)
+    # MIXERS (or a SoftmaxAttention) x FEED_FORWARDS; n_layers less the
+    # leading layers is a multiple of its length.
+    layer_pattern: Tuple[Tuple[object, str], ...] = (("attention", "dense"),)
+    # Pairs of the same kinds that run once, each with parameters of its
+    # own, ahead of the scanned periods.
+    leading_layers: Tuple[Tuple[object, str], ...] = ()
     # Size of an attention head where it is not d_model / n_heads (a
     # chip's share of the heads keeps the model's head size).
     head_size: Optional[int] = None
@@ -129,22 +188,46 @@ class TransformerConfig:
             raise ValueError("remat_policy must be 'full', 'dots' or "
                              "'dots_no_batch', got %r"
                              % (self.remat_policy,))
-        for mixer, ffn in self.layer_pattern:
-            if mixer not in MIXERS or ffn not in FEED_FORWARDS:
-                raise ValueError("layer_pattern pairs a mixer of %s with a "
-                                 "feed-forward of %s, not %r"
+        for mixer, ffn in self.pairs:
+            if not (isinstance(mixer, SoftmaxAttention) or mixer in MIXERS) \
+                    or ffn not in FEED_FORWARDS:
+                raise ValueError("layer_pattern pairs a mixer of %s (or a "
+                                 "SoftmaxAttention) with a feed-forward of "
+                                 "%s, not %r"
                                  % (MIXERS, FEED_FORWARDS, (mixer, ffn)))
             if (mixer == "linear_attention" and not self.linear_attention) \
                     or (ffn == "expert_share" and not self.experts):
                 raise ValueError("%r needs its configuration"
                                  % ((mixer, ffn),))
-        if self.n_layers % len(self.layer_pattern):
+        periodic = self.n_layers - len(self.leading_layers)
+        if periodic < 0 or periodic % len(self.layer_pattern):
             raise ValueError("%d layers are no whole number of periods of %d"
-                             % (self.n_layers, len(self.layer_pattern)))
+                             " after %d leading ones"
+                             % (self.n_layers, len(self.layer_pattern),
+                                len(self.leading_layers)))
 
     @property
     def head_dim(self) -> int:
         return self.head_size or self.d_model // self.n_heads
+
+    @property
+    def pairs(self):
+        """The (mixer, feed-forward) pairs of the leading layers, then of
+        one period."""
+        return self.leading_layers + self.layer_pattern
+
+    def softmax_kind(self, mixer) -> Optional[SoftmaxAttention]:
+        """The setting of the softmax block a mixer stands for; None for a
+        mixer that is no softmax attention."""
+        if isinstance(mixer, SoftmaxAttention):
+            return mixer
+        if mixer == "attention":
+            return SoftmaxAttention(self.n_heads, self.n_kv_heads,
+                                    rope=Rope(theta=self.rope_theta))
+        if mixer == "gated_nope_attention":
+            return SoftmaxAttention(self.n_heads, self.n_kv_heads,
+                                    rope=None, gate=True)
+        return None
 
     @property
     def act_dtype(self):
@@ -167,23 +250,24 @@ def _normal(key, shape, fan_in, dtype):
 def _init_layers(key, cfg: TransformerConfig, mixer: str, ffn: str, n: int):
     """``n`` stacked layers of one (mixer, feed-forward) kind."""
     pd = jnp.dtype(cfg.param_dtype)
-    d, hd = cfg.d_model, cfg.head_dim
-    qh, kvh, f = cfg.n_heads, cfg.n_kv_heads, cfg.d_ff
+    d, hd, f = cfg.d_model, cfg.head_dim, cfg.d_ff
     keys = jax.random.split(key, 12)
     norm = partial(_normal, dtype=pd)
 
     layers = {"ln1": jnp.ones((n, d), pd), "ln2": jnp.ones((n, d), pd)}
-    if mixer == "linear_attention":
+    kind = cfg.softmax_kind(mixer)
+    if kind is None:
         layers.update(init_kda_params(keys[1], d, cfg.linear_attention, n,
                                       pd))
     else:
+        qh, kvh = kind.n_heads, kind.n_kv_heads
         layers.update({
             "wq": norm(keys[1], (n, d, qh * hd), d),
             "wk": norm(keys[2], (n, d, kvh * hd), d),
             "wv": norm(keys[3], (n, d, kvh * hd), d),
             "wo": norm(keys[4], (n, qh * hd, d), qh * hd),
         })
-        if mixer == "gated_nope_attention":
+        if kind.gate:
             layers["wg"] = norm(jax.random.fold_in(key, 12),
                                 (n, d, qh * hd), d)
     if ffn == "dense":
@@ -217,10 +301,16 @@ def init_params(key, cfg: TransformerConfig):
     if not cfg.tie_embeddings:
         params["head"] = _normal(jax.random.fold_in(key, 12),
                                  (d, cfg.vocab_size), d, pd)
-    n = cfg.n_layers // len(cfg.layer_pattern)
+    n = (cfg.n_layers - len(cfg.leading_layers)) // len(cfg.layer_pattern)
     params["layers"] = tuple(
         _init_layers(jax.random.fold_in(key, at), cfg, mixer, ffn, n)
         for at, (mixer, ffn) in enumerate(cfg.layer_pattern))
+    if cfg.leading_layers:
+        # Stacks of one layer: the same leaves, specs and block functions.
+        lead = jax.random.fold_in(key, 13)
+        params["leading"] = tuple(
+            _init_layers(jax.random.fold_in(lead, at), cfg, mixer, ffn, 1)
+            for at, (mixer, ffn) in enumerate(cfg.leading_layers))
     return params
 
 
@@ -228,7 +318,8 @@ def _layer_specs(cfg: TransformerConfig, mixer: str, ffn: str):
     from jax.sharding import PartitionSpec as P
     tp, sp = cfg.tp_axis, cfg.sp_axis
     specs = {"ln1": P(None, None), "ln2": P(None, None)}
-    if mixer == "linear_attention":
+    kind = cfg.softmax_kind(mixer)
+    if kind is None:
         specs.update(kda_param_specs(tp))
     else:
         specs.update({
@@ -237,7 +328,7 @@ def _layer_specs(cfg: TransformerConfig, mixer: str, ffn: str):
             "wv": P(None, None, tp),
             "wo": P(None, tp, None),
         })
-        if mixer == "gated_nope_attention":
+        if kind.gate:
             specs["wg"] = P(None, None, tp)
     if ffn == "dense":
         specs.update({
@@ -277,6 +368,9 @@ def param_specs(cfg: TransformerConfig):
         specs["head"] = P(None, tp)
     specs["layers"] = tuple(_layer_specs(cfg, mixer, ffn)
                             for mixer, ffn in cfg.layer_pattern)
+    if cfg.leading_layers:
+        specs["leading"] = tuple(_layer_specs(cfg, mixer, ffn)
+                                 for mixer, ffn in cfg.leading_layers)
     return specs
 
 
@@ -291,18 +385,53 @@ def rms_norm(x, scale, eps):
 
 
 def _rope(cos, sin, x):
-    """Rotate pairs (x interleaved as [..., 2*k])."""
+    """Rotate pairs (x interleaved as [..., 2*k]); a table narrower than
+    the head rotates the head's first dimensions and passes the rest."""
+    rotary = 2 * cos.shape[-1]
+    if rotary < x.shape[-1]:
+        return jnp.concatenate([_rope(cos, sin, x[..., :rotary]),
+                                x[..., rotary:]], axis=-1)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin,
                             x1 * sin + x2 * cos], axis=-1)
 
 
-def rope_tables(positions, head_dim: int, theta: float, dtype):
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
-                                           dtype=jnp.float32) / head_dim))
+def yarn_ramp(rope: Rope, rotary: int):
+    """How far each of the ``rotary / 2`` frequencies is interpolated
+    (divided by ``rope.factor``): 0 up to the dimension that turns
+    ``beta_fast`` times over the original positions, 1 from the one that
+    turns ``beta_slow`` times, linear between."""
+    import numpy as np
+
+    def dimension(turns):
+        return rotary * math.log(rope.original_max_seq
+                                 / (turns * 2 * math.pi)) \
+            / (2 * math.log(rope.theta))
+
+    low = max(math.floor(dimension(rope.beta_fast)), 0)
+    high = min(math.ceil(dimension(rope.beta_slow)), rotary - 1)
+    if low == high:
+        high += 0.001
+    return np.clip((np.arange(rotary // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+
+
+def rope_tables(positions, head_dim: int, rope: Rope, dtype):
+    rotary = int(head_dim * rope.share)
+    inv_freq = 1.0 / (rope.theta ** (jnp.arange(0, rotary, 2,
+                                                dtype=jnp.float32) / rotary))
+    if rope.factor != 1.0:
+        ramp = yarn_ramp(rope, rotary)
+        inv_freq = inv_freq / rope.factor * ramp + inv_freq * (1 - ramp)
     ang = positions[:, None].astype(jnp.float32) * inv_freq[None, :]
-    return (jnp.cos(ang)[None, :, None, :].astype(dtype),
-            jnp.sin(ang)[None, :, None, :].astype(dtype))
+
+    def table(fn):
+        values = fn(ang)
+        if rope.attention_factor != 1.0:
+            values = values * rope.attention_factor
+        return values[None, :, None, :].astype(dtype)
+
+    return table(jnp.cos), table(jnp.sin)
 
 
 def _sharded_embed_lookup(embed_local, tokens, tp_axis: str):
@@ -344,22 +473,41 @@ def vocab_parallel_cross_entropy(logits_local, targets, tp_axis: str):
 
 
 @jax.named_scope(scopes.ATTENTION)
-def _attention_block(x, lp, cfg: TransformerConfig, cos, sin, sp_size):
-    b, s, _ = x.shape
-    hd = cfg.head_dim
-    q = (x @ lp["wq"].astype(x.dtype)).reshape(b, s, -1, hd)
-    k = (x @ lp["wk"].astype(x.dtype)).reshape(b, s, -1, hd)
-    v = (x @ lp["wv"].astype(x.dtype)).reshape(b, s, -1, hd)
-    q = _rope(cos, sin, q)
-    k = _rope(cos, sin, k)
-    attn = _causal_attention(q, k, v, cfg, sp_size).reshape(b, s, -1)
-    # Row-sharded wo: partial sums live on each tp shard.
-    return _row_parallel_product(attn, lp["wo"].astype(x.dtype), cfg)
+def _softmax_attention_block(x, lp, cfg: TransformerConfig,
+                             kind: SoftmaxAttention, tables, sp_size):
+    """Softmax attention with its projections, as ``kind`` sets it: the
+    heads are what the weights hold; a rotary table turns q and k, or none
+    does (the causal mask is then all the order it sees); a gate is a
+    sigmoid, from a projection of its own, on every element of the heads'
+    output before ``wo``.  A layer with a window sits under
+    ``hvd.window_attention`` as well."""
+    with jax.named_scope(scopes.WINDOW_ATTENTION) if kind.window \
+            else contextlib.nullcontext():
+        b, s, _ = x.shape
+        hd = cfg.head_dim
+        q, k, v = ((x @ lp[name].astype(x.dtype)).reshape(b, s, -1, hd)
+                   for name in ("wq", "wk", "wv"))
+        if kind.rope is not None:
+            cos, sin = tables[kind.rope]
+            q = _rope(cos, sin, q)
+            k = _rope(cos, sin, k)
+        attn = _causal_attention(q, k, v, cfg, kind.window,
+                                 sp_size).reshape(b, s, -1)
+        if kind.gate:
+            gate = jax.nn.sigmoid(
+                (x @ lp["wg"].astype(x.dtype)).astype(jnp.float32))
+            attn = (attn * gate).astype(x.dtype)
+        # Row-sharded wo: partial sums live on each tp shard.
+        return _row_parallel_product(attn, lp["wo"].astype(x.dtype), cfg)
 
 
-def _causal_attention(q, k, v, cfg: TransformerConfig, sp_size):
+def _causal_attention(q, k, v, cfg: TransformerConfig, window, sp_size):
     """Causal softmax attention over ``[B, S, heads, head_dim]`` by
     whichever form the layout calls for."""
+    if sp_size > 1 and window is not None:
+        raise ValueError("a window of %d keys crosses the shards of a "
+                         "sequence split over %r: neither the ring nor the "
+                         "head exchange carries it" % (window, cfg.sp_axis))
     if sp_size > 1 and cfg.sp_mode == "ulysses":
         from ..parallel.ulysses import ulysses_attention
         attn_fn = (pallas_kernels.flash_attention
@@ -373,23 +521,9 @@ def _causal_attention(q, k, v, cfg: TransformerConfig, sp_size):
         # O(seq) HBM forward + Pallas backward kernels (dq, dk/dv);
         # measured ~5x over XLA autodiff at seq 8192 on one chip
         # (docs/benchmarks.md)
-        return pallas_kernels.flash_attention(q, k, v, causal=True)
-    return local_attention(q, k, v, causal=True)
-
-
-@jax.named_scope(scopes.ATTENTION)
-def _gated_nope_attention_block(x, lp, cfg: TransformerConfig, sp_size):
-    """Softmax attention with no positional encoding (the causal mask is
-    all the order it sees) and a sigmoid gate, from a projection of its
-    own, on every element of the heads' output before ``wo``."""
-    b, s, _ = x.shape
-    hd = cfg.head_dim
-    q, k, v = ((x @ lp[name].astype(x.dtype)).reshape(b, s, -1, hd)
-               for name in ("wq", "wk", "wv"))
-    attn = _causal_attention(q, k, v, cfg, sp_size).reshape(b, s, -1)
-    gate = jax.nn.sigmoid((x @ lp["wg"].astype(x.dtype)).astype(jnp.float32))
-    return _row_parallel_product((attn * gate).astype(x.dtype),
-                                 lp["wo"].astype(x.dtype), cfg)
+        return pallas_kernels.flash_attention(q, k, v, causal=True,
+                                              window=window)
+    return local_attention(q, k, v, causal=True, window=window)
 
 
 def _row_parallel_product(x, w, cfg: TransformerConfig):
@@ -425,11 +559,10 @@ def _moe_block(h, lp, cfg: TransformerConfig, sp_size):
     return y.reshape(b, s, d), aux
 
 
-def _mix(h, lp, cfg: TransformerConfig, mixer, cos, sin, sp_size):
-    if mixer == "attention":
-        return _attention_block(h, lp, cfg, cos, sin, sp_size)
-    if mixer == "gated_nope_attention":
-        return _gated_nope_attention_block(h, lp, cfg, sp_size)
+def _mix(h, lp, cfg: TransformerConfig, mixer, tables, sp_size):
+    kind = cfg.softmax_kind(mixer)
+    if kind is not None:
+        return _softmax_attention_block(h, lp, cfg, kind, tables, sp_size)
     if sp_size > 1:
         raise ValueError("a linear-attention layer keeps a state along the "
                          "sequence: the sequence cannot be split over %r"
@@ -453,12 +586,17 @@ def _feed_forward(h, lp, cfg: TransformerConfig, ffn, sp_size):
 def hidden(params, tokens, cfg: TransformerConfig):
     """Per-shard decoder up to the final norm: tokens [B_loc, S_loc] ->
     (x [B, S, d], aux, expert counts).  ``aux`` is the ``moe`` layers'
-    load-balancing losses summed; the counts are one ``[periods,
+    load-balancing losses summed; the counts are one ``[1, n_experts]``
+    array for each leading ``expert_share`` layer, then one ``[periods,
     n_experts]`` array for each ``expert_share`` layer of the period."""
     sp_size = lax.axis_size(cfg.sp_axis)
     s_loc = tokens.shape[1]
     pos = lax.axis_index(cfg.sp_axis) * s_loc + jnp.arange(s_loc)
-    cos, sin = rope_tables(pos, cfg.head_dim, cfg.rope_theta, cfg.act_dtype)
+    # One table for each distinct one among the kinds, not one a layer.
+    kinds = [cfg.softmax_kind(mixer) for mixer, _ in cfg.pairs]
+    tables = {rope: rope_tables(pos, cfg.head_dim, rope, cfg.act_dtype)
+              for rope in dict.fromkeys(kind.rope for kind in kinds
+                                        if kind and kind.rope)}
 
     x = _sharded_embed_lookup(params["embed"], tokens, cfg.tp_axis)
     x = x.astype(cfg.act_dtype)
@@ -471,13 +609,12 @@ def hidden(params, tokens, cfg: TransformerConfig):
     def layer(mixer, ffn, carry, lp):
         x, aux = carry
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        x = x + _mix(h, lp, cfg, mixer, cos, sin, sp_size)
+        x = x + _mix(h, lp, cfg, mixer, tables, sp_size)
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
         y, a, counts = _feed_forward(h, lp, cfg, ffn, sp_size)
         return (x + y, aux if a is None else aux + a), counts
 
-    layer_fns = [partial(layer, mixer, ffn)
-                 for mixer, ffn in cfg.layer_pattern]
+    layer_fns = [partial(layer, mixer, ffn) for mixer, ffn in cfg.pairs]
     if cfg.remat:
         # "full" keeps nothing but what a block names as dearer to compute
         # again than to keep (the delta rule's walk along the sequence, an
@@ -490,9 +627,9 @@ def hidden(params, tokens, cfg: TransformerConfig):
                }[cfg.remat_policy]
         layer_fns = [jax.checkpoint(fn, policy=pol) for fn in layer_fns]
 
-    def period(carry, lps):
+    def run(fns, carry, lps):
         counts = ()
-        for fn, lp in zip(layer_fns, lps):
+        for fn, lp in zip(fns, lps):
             carry, c = fn(carry, lp)
             counts += c
         return carry, counts
@@ -501,10 +638,16 @@ def hidden(params, tokens, cfg: TransformerConfig):
     # tokens; the carry must enter with the same varying axes under
     # vma tracking (guarded no-op in untracked traces).
     aux0 = jnp.zeros((), jnp.float32)
-    if any(ffn == "moe" for _, ffn in cfg.layer_pattern):
+    if any(ffn == "moe" for _, ffn in cfg.pairs):
         aux0 = pvary_missing(aux0, (cfg.dp_axis, cfg.sp_axis))
-    (x, aux), counts = lax.scan(period, (x, aux0), params["layers"])
-    return rms_norm(x, params["ln_f"], cfg.norm_eps), aux, counts
+    n_lead = len(cfg.leading_layers)
+    carry, lead_counts = run(
+        layer_fns[:n_lead], (x, aux0),
+        jax.tree.map(lambda w: w[0], params.get("leading", ())))
+    (x, aux), counts = lax.scan(partial(run, layer_fns[n_lead:]), carry,
+                                params["layers"])
+    return (rms_norm(x, params["ln_f"], cfg.norm_eps), aux,
+            tuple(c[None] for c in lead_counts) + counts)
 
 
 def _logits(x, params, cfg: TransformerConfig):

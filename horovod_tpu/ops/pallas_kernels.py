@@ -43,15 +43,86 @@ _NEG_INF = -1e30
 # flash attention
 # ---------------------------------------------------------------------------
 
+# A window of ``w`` keys (sliding-window attention): query ``i`` sees key
+# ``j`` when ``i - w < j <= i``, itself among them.  The kernels below run
+# their inner grid axis over the blocks that meet that band and no others:
+# the axis has as many steps as the widest query (key) block needs, a step
+# adds the block's first live partner to its index, and an index past the
+# band's end is held at the end, so the pipeline fetches nothing new for a
+# step the kernel skips.
+
+def _band_first_k(j, block_q: int, block_k: int, window: int):
+    """The first key block that query block ``j`` meets."""
+    return jnp.maximum(j * block_q - (window - 1), 0) // block_k
+
+
+def _band_last_k(j, block_q: int, block_k: int):
+    return (j * block_q + block_q - 1) // block_k
+
+
+def _band_first_q(t, block_q: int, block_k: int):
+    """The first query block that meets key block ``t``."""
+    return (t * block_k) // block_q
+
+
+def _band_last_q(t, block_q: int, block_k: int, window: int, nq: int):
+    return jnp.minimum((t * block_k + block_k - 1 + window - 1) // block_q,
+                       nq - 1)
+
+
+def _band_steps(seq: int, block_q: int, block_k: int, window: int):
+    """(key blocks the widest query block meets, query blocks the widest
+    key block is met by): the lengths of the banded grids' inner axes."""
+    nq, nk = seq // block_q, seq // block_k
+    k_steps = max(
+        (j * block_q + block_q - 1) // block_k
+        - max(j * block_q - (window - 1), 0) // block_k + 1
+        for j in range(nq))
+    q_steps = max(
+        min((t * block_k + block_k - 1 + window - 1) // block_q, nq - 1)
+        - (t * block_k) // block_q + 1
+        for t in range(nk))
+    return k_steps, q_steps
+
+
+def _seen(rows, cols, window):
+    seen = cols <= rows
+    if window is not None:
+        seen = jnp.logical_and(seen, cols > rows - window)
+    return seen
+
+
+def _seen_in_block(j, kb, block_q: int, block_k: int, window):
+    """Which scores of block ``(j, kb)`` the mask keeps.  Under a window
+    that is one unsigned comparison: how far a row is ahead of a column,
+    ``0 <= rows - cols < window``, where a column ahead of its row wraps
+    round to a large number (the kernels are bound by the vector unit, and
+    two comparisons and their conjunction cost them a fifth more time)."""
+    shape = (block_q, block_k)
+    if window is None:
+        rows = j * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        cols = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        return cols <= rows
+    ahead = (j * block_q - kb * block_k) + (
+        jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        - jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    return jax.lax.bitcast_convert_type(ahead, jnp.uint32) \
+        < jnp.uint32(window)
+
+
 def _flash_attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
                        l_scr, acc_scr, *, block_q: int, block_k: int,
-                       causal: bool):
+                       causal: bool, window=None):
     # grid = (bh, nq, nk): K/V stream through VMEM one block per inner
     # step (double-buffered by the Pallas pipeline); the online-softmax
     # state (m, l, acc) persists in VMEM scratch across the inner axis.
+    # Under a window the inner axis counts from the band's first block.
     j = pl.program_id(1)
     t = pl.program_id(2)
     nk = pl.num_programs(2)
+
+    kb = t if window is None else \
+        _band_first_k(j, block_q, block_k, window) + t
 
     @pl.when(t == 0)
     def _init():
@@ -62,7 +133,7 @@ def _flash_attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
     # causal: blocks entirely above the diagonal contribute nothing
     block_live = jnp.logical_or(
         jnp.logical_not(causal),
-        t * block_k <= j * block_q + block_q - 1)
+        kb * block_k <= j * block_q + block_q - 1)
 
     @pl.when(block_live)
     def _update():
@@ -75,11 +146,11 @@ def _flash_attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (BQ, BK)
         if causal:
-            rows = j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = t * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(cols <= rows, s, _NEG_INF)
+            # A row that a banded block hides whole reads exp(0) here; the
+            # block that holds its diagonal comes later and its correction
+            # exp(-1e30 - m) wipes that out.
+            s = jnp.where(_seen_in_block(j, kb, block_q, block_k, window),
+                          s, _NEG_INF)
         m_prev = m_scr[:]
         l_prev = l_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -100,6 +171,39 @@ def _flash_attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
         lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
 
 
+class _HeadOf:
+    """Head ``g`` of a ref that holds several, for a kernel written for one:
+    the kernels read and write a head's block whole (``ref[0]``) and a
+    scratch whole (``ref[:]``), and both are ``ref[g]`` here.  (A view,
+    ``ref.at[g]``, is refused by Mosaic for the row statistics' unit lane
+    dimension.)"""
+
+    def __init__(self, ref, g: int):
+        self.ref, self.g = ref, g
+        self.shape, self.dtype = ref.shape[1:], ref.dtype
+
+    def __getitem__(self, whole):
+        return self.ref[self.g]
+
+    def __setitem__(self, whole, value):
+        self.ref[self.g] = value
+
+
+def _heads_a_step(kernel, heads: int):
+    """``kernel`` (written for blocks of one flat head) over blocks and
+    scratch of ``heads``, a head after another.  A banded call's grid steps
+    are short (two key blocks a query block where a full call has nine), so
+    what a step costs whatever it computes weighs more, and several heads a
+    step divide it."""
+    if heads == 1:
+        return kernel
+
+    def body(*refs):
+        for g in range(heads):
+            kernel(*(_HeadOf(ref, g) for ref in refs))
+    return body
+
+
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct inheriting ``like``'s varying-manual-axes so
     pallas_call outputs type-check inside ``check_vma=True`` shard_maps
@@ -107,54 +211,81 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-@jax.named_scope(scopes.FLASH_FWD)
+def _k_spec(block_q: int, block_k: int, d: int, window, heads: int = 1):
+    """Key/value blocks of a grid ``(bh, query block, key step)``: every
+    key block in turn, or under a window the band's blocks (its last one
+    held for the steps a narrower query block has left over)."""
+    if window is None:
+        return pl.BlockSpec((heads, block_k, d), lambda i, j, t: (i, t, 0))
+
+    def index(i, j, t):
+        return (i, jnp.minimum(
+            _band_first_k(j, block_q, block_k, window) + t,
+            _band_last_k(j, block_q, block_k)), 0)
+    return pl.BlockSpec((heads, block_k, d), index)
+
+
+def _k_steps(seq: int, block_q: int, block_k: int, window):
+    return seq // block_k if window is None \
+        else _band_steps(seq, block_q, block_k, window)[0]
+
+
 def _flash_attention_fwd_flat(q, k, v, *, causal: bool, block_q: int,
-                              block_k: int, interpret: bool):
-    """(BH, S, D) → ((BH, S, D) output, (BH, S, 1) lse), D lane-padded."""
+                              block_k: int, interpret: bool, window=None,
+                              heads: int = 1):
+    """(BH, S, D) → ((BH, S, D) output, (BH, S, 1) lse), D lane-padded.
+    ``heads`` flat heads a grid step (``_heads_a_step``)."""
     from jax.experimental.pallas import tpu as pltpu
     bh, seq, d = q.shape
-    grid = (bh, seq // block_q, seq // block_k)
+    banded = window is not None
     kernel = functools.partial(
         _flash_attn_kernel, block_q=block_q, block_k=block_k,
-        causal=causal)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, t, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, t, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-            # unit lane dim keeps the (sublane, lane) tiling legal and
-            # broadcasts against (block_q, block_k) scores directly
-            pl.BlockSpec((1, block_q, 1), lambda i, j, t: (i, j, 0)),
-        ],
-        out_shape=[
-            _sds((bh, seq, d), q.dtype, q),
-            _sds((bh, seq, 1), jnp.float32, q),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name=scopes.kernel_name(scopes.FLASH_FWD),
-    )(q, k, v)
+        causal=causal, window=window)
+    kspec = _k_spec(block_q, block_k, d, window, heads)
+    slab = () if heads == 1 else (heads,)
+    with jax.named_scope(scopes.FLASH_WINDOW_FWD) if banded \
+            else jax.named_scope(scopes.FLASH_FWD):
+        return pl.pallas_call(
+            _heads_a_step(kernel, heads),
+            grid=(bh // heads, seq // block_q,
+                  _k_steps(seq, block_q, block_k, window)),
+            in_specs=[
+                pl.BlockSpec((heads, block_q, d), lambda i, j, t: (i, j, 0)),
+                kspec,
+                kspec,
+            ],
+            out_specs=[
+                pl.BlockSpec((heads, block_q, d), lambda i, j, t: (i, j, 0)),
+                # unit lane dim keeps the (sublane, lane) tiling legal and
+                # broadcasts against (block_q, block_k) scores directly
+                pl.BlockSpec((heads, block_q, 1), lambda i, j, t: (i, j, 0)),
+            ],
+            out_shape=[
+                _sds((bh, seq, d), q.dtype, q),
+                _sds((bh, seq, 1), jnp.float32, q),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM(slab + (block_q, 1), jnp.float32),
+                pltpu.VMEM(slab + (block_q, 1), jnp.float32),
+                pltpu.VMEM(slab + (block_q, d), jnp.float32),
+            ],
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name=scopes.kernel_name(scopes.FLASH_WINDOW_FWD if banded
+                                    else scopes.FLASH_FWD),
+        )(q, k, v)
 
 
-def _reference_attention(q, k, v, causal: bool):
+def _reference_attention(q, k, v, causal: bool, window=None):
     """Plain attention on (B, S, H, D): the single oracle shared with
     the model's non-TPU path and the SP tests."""
     from ..parallel.ring_attention import local_attention
-    return local_attention(q, k, v, causal=causal)
+    return local_attention(q, k, v, causal=causal, window=window)
 
 
-def _chunked_attention_bwd(q, k, v, g, causal: bool, block_q: int):
+def _chunked_attention_bwd(q, k, v, g, causal: bool, block_q: int,
+                           window=None):
     """Memory-efficient attention backward: iterate q blocks, so peak
     extra memory is O(block_q·seq) per (batch,head) instead of the
     O(seq²) score matrix (the standard flash-attention backward
@@ -176,7 +307,7 @@ def _chunked_attention_bwd(q, k, v, g, causal: bool, block_q: int):
         if causal:
             rows = start + jnp.arange(block_q)[:, None]
             cols = jnp.arange(s)[None, :]
-            s_blk = jnp.where(cols <= rows, s_blk, _NEG_INF)
+            s_blk = jnp.where(_seen(rows, cols, window), s_blk, _NEG_INF)
         p = jax.nn.softmax(s_blk, axis=-1)             # (B,H,BQ,S)
         dv = dv + jnp.einsum("bhqk,bhqd->bhkd", p, g_blk)
         dp = jnp.einsum("bhqd,bhkd->bhqk", g_blk, vf)
@@ -197,6 +328,7 @@ def _chunked_attention_bwd(q, k, v, g, causal: bool, block_q: int):
 # the measured winner of the on-device block sweep.  Env overrides
 # still win (an explicit A/B must never be silently retuned); the
 # default chains below are only the cold fallback.
+# A call under a window keeps its own pins, ``(seq, d_pad, window)``.
 _TUNED_BLOCKS: dict = {}
 
 _BLOCK_Q_DEFAULTS = (512, 256, 128, 64)
@@ -205,10 +337,11 @@ _BLOCK_K_DEFAULTS = (1024, 512, 256, 128, 64)
 
 def export_tuned_blocks() -> dict:
     """The pinned-block registry as a JSON-safe dict
-    (``"<seq>x<d_pad>" -> [block_q, block_k]``) — the flash-block leg
+    (``"<seq>x<d_pad>" -> [block_q, block_k]``, a window's pins
+    ``"<seq>x<d_pad>x<window>"``) — the flash-block leg
     of the persistent plan cache (``utils/plancache.py``), so kernel
     and collective plans persist in one plane."""
-    return {"%dx%d" % key: [int(bq), int(bk)]
+    return {"x".join("%d" % v for v in key): [int(bq), int(bk)]
             for key, (bq, bk) in _TUNED_BLOCKS.items()}
 
 
@@ -221,28 +354,42 @@ def seed_tuned_blocks(blocks: dict):
     loudly — a corrupt plan must never pin an invalid block shape."""
     for key, pair in (blocks or {}).items():
         try:
-            s, d_pad = (int(v) for v in str(key).split("x"))
+            s, d_pad, *window = (int(v) for v in str(key).split("x"))
             bq, bk = int(pair[0]), int(pair[1])
-            if min(bq, bk) < 64 or bq % 16 or bk % 16 or s % bq or s % bk:
+            if min(bq, bk) < 64 or bq % 16 or bk % 16 or s % bq or s % bk \
+                    or len(window) > 1:
                 raise ValueError("invalid block pair")
-            _TUNED_BLOCKS[(s, d_pad)] = (bq, bk)
+            _TUNED_BLOCKS[(s, d_pad, *window)] = (bq, bk)
         except (ValueError, TypeError, IndexError):
             LOG.warning("ignoring malformed tuned-block entry %r: %r",
                         key, pair)
+
+
+# Flat heads a grid step of a banded call: the most of these that divides
+# the heads there are (``_heads_a_step``; PERF.md, PR 31 has the sweep on
+# the chip).  A full call keeps one.
+_WINDOW_HEADS_A_STEP = (4, 2, 1)
+
+
+def _heads_of(flat_heads: int, window) -> int:
+    return 1 if window is None else next(
+        g for g in _WINDOW_HEADS_A_STEP if flat_heads % g == 0)
 
 
 def _d_pad(d: int) -> int:
     return max(128, ((d + 127) // 128) * 128)
 
 
-def _plan(s: int, d: int):
+def _plan(s: int, d: int, window=None):
     """Block plan shared by fwd and bwd.  Large tiles amortize
     per-grid-step overhead; MXU tiles are 128-aligned so any divisor
     ≥64 works.  The head dim is lane-padded to 128 (zero columns add 0
     to every dot product).  Precedence: HVD_TPU_FLASH_BLOCK_Q/K env
     overrides (must divide the sequence length) > blocks pinned by
     ``autotune_flash_blocks`` (the measured sweep) > the default
-    chains."""
+    chains.  Under a window the chains stop at the window: a block pair
+    is computed whole wherever the band touches it, so a 1024-wide key
+    block over a band of 512 does twice the work."""
     import os
 
     def _env_block(name, tuned, dflt_chain):
@@ -262,10 +409,13 @@ def _plan(s: int, d: int):
             return b
         if tuned is not None:
             return tuned
-        return next((b for b in dflt_chain if s % b == 0), None)
+        return next((b for b in dflt_chain if s % b == 0 and b <= cap),
+                    None)
 
     d_pad = _d_pad(d)
-    tuned = _TUNED_BLOCKS.get((s, d_pad))
+    tuned = _TUNED_BLOCKS.get((s, d_pad) if window is None
+                              else (s, d_pad, window))
+    cap = s if window is None else max(64, window)
     block_q = _env_block("HVD_TPU_FLASH_BLOCK_Q",
                          tuned[0] if tuned else None, _BLOCK_Q_DEFAULTS)
     block_k = _env_block("HVD_TPU_FLASH_BLOCK_K",
@@ -293,25 +443,26 @@ def _from_flat(x, b, h, d, like):
     return jnp.swapaxes(x, 1, 2).astype(like.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _flash_attention(q, k, v, causal):
-    return _flash_attention_impl(q, k, v, causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_attention(q, k, v, causal, window):
+    return _flash_attention_impl(q, k, v, causal, window)
 
 
-def _flash_attention_impl(q, k, v, causal):
-    return _flash_fwd(q, k, v, causal)[0]
+def _flash_attention_impl(q, k, v, causal, window):
+    return _flash_fwd(q, k, v, causal, window)[0]
 
 
-def _flash_fwd(q, k, v, causal):
+def _flash_fwd(q, k, v, causal, window):
     b, s, h, d = q.shape
-    block_q, block_k, d_pad, pre_scale = _plan(s, d)
+    block_q, block_k, d_pad, pre_scale = _plan(s, d, window)
     if block_q is None or block_k is None:
-        out = _reference_attention(q, k, v, causal)
+        out = _reference_attention(q, k, v, causal, window)
         return out, (q, k, v, None, None)
     out, lse = _flash_attention_fwd_flat(
         _to_flat(q * pre_scale, d_pad), _to_flat(k, d_pad),
         _to_flat(v, d_pad), causal=causal, block_q=block_q,
-        block_k=block_k, interpret=not on_tpu())
+        block_k=block_k, interpret=not on_tpu(), window=window,
+        heads=_heads_of(b * h, window))
     out = out[:, :, :d].reshape(b, h, s, d)
     out = jnp.swapaxes(out, 1, 2)
     return out, (q, k, v, out, lse)
@@ -319,12 +470,14 @@ def _flash_fwd(q, k, v, causal):
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, block_q: int, block_k: int,
-                         causal: bool):
+                         causal: bool, window=None):
     # grid = (bh, nq, nk): K/V stream along the inner axis while this
     # q block's dq accumulates in VMEM scratch (mirror of the fwd).
     j = pl.program_id(1)
     t = pl.program_id(2)
     nk = pl.num_programs(2)
+    kb = t if window is None else \
+        _band_first_k(j, block_q, block_k, window) + t
 
     @pl.when(t == 0)
     def _init():
@@ -332,7 +485,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
     block_live = jnp.logical_or(
         jnp.logical_not(causal),
-        t * block_k <= j * block_q + block_q - 1)
+        kb * block_k <= j * block_q + block_q - 1)
 
     @pl.when(block_live)
     def _update():
@@ -343,11 +496,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         # softmax from saved stats: p = exp(s - lse)
         p = jnp.exp(s - lse_ref[0])
         if causal:
-            rows = j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = t * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            p = jnp.where(cols <= rows, p, 0.0)
+            p = jnp.where(_seen_in_block(j, kb, block_q, block_k, window),
+                          p, 0.0)
         dp = jax.lax.dot_general(
             g_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # (BQ, BK)
@@ -366,14 +516,18 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                          block_q: int, block_k: int, causal: bool):
+                          block_q: int, block_k: int, causal: bool,
+                          window=None, n_q_blocks=None):
     # grid = (bh, nk, nq): Q/G stream along the inner axis while this
-    # k block's dk/dv accumulate in VMEM scratch.
+    # k block's dk/dv accumulate in VMEM scratch.  Under a window the inner
+    # axis counts from the first query block that meets this key block and
+    # a step past the band's last (or the sequence's last) is skipped.
     t = pl.program_id(1)
-    j = pl.program_id(2)
+    u = pl.program_id(2)
     nq = pl.num_programs(2)
+    j = u if window is None else _band_first_q(t, block_q, block_k) + u
 
-    @pl.when(j == 0)
+    @pl.when(u == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -381,6 +535,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
     block_live = jnp.logical_or(
         jnp.logical_not(causal),
         j * block_q + block_q - 1 >= t * block_k)
+    if window is not None:
+        block_live = jnp.logical_and(
+            block_live,
+            j <= _band_last_q(t, block_q, block_k, window, n_q_blocks))
 
     @pl.when(block_live)
     def _update():
@@ -390,11 +548,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
             preferred_element_type=jnp.float32)             # (BQ, BK)
         p = jnp.exp(s - lse_ref[0])
         if causal:
-            rows = j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = t * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            p = jnp.where(cols <= rows, p, 0.0)
+            p = jnp.where(_seen_in_block(j, t, block_q, block_k, window),
+                          p, 0.0)
         dv_scr[:] += jax.lax.dot_general(
             p.astype(g_ref.dtype), g_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # (BK, D)
@@ -408,7 +563,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
             ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # (BK, D)
 
-    @pl.when(j == nq - 1)
+    @pl.when(u == nq - 1)
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -416,54 +571,78 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
 
 def _flash_attention_bwd_flat(q, k, v, g, lse, delta, *, causal: bool,
                               block_q: int, block_k: int,
-                              interpret: bool):
+                              interpret: bool, window=None, heads: int = 1):
     """Flat (BH, S, D) backward via the two Pallas kernels above;
-    returns (dq, dk, dv) with dq still in the fwd's q scaling."""
+    returns (dq, dk, dv) with dq still in the fwd's q scaling.  ``heads``
+    flat heads a grid step (``_heads_a_step``)."""
     from jax.experimental.pallas import tpu as pltpu
     bh, seq, d = q.shape
-    qspec = pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, t, 0))
-    rowspec = pl.BlockSpec((1, block_q, 1), lambda i, j, t: (i, j, 0))
-    with jax.named_scope(scopes.FLASH_DQ):
+    banded = window is not None
+    slab = () if heads == 1 else (heads,)
+    qspec = pl.BlockSpec((heads, block_q, d), lambda i, j, t: (i, j, 0))
+    kspec = _k_spec(block_q, block_k, d, window, heads)
+    rowspec = pl.BlockSpec((heads, block_q, 1), lambda i, j, t: (i, j, 0))
+    with jax.named_scope(scopes.FLASH_WINDOW_DQ) if banded \
+            else jax.named_scope(scopes.FLASH_DQ):
         dq = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
-                              block_k=block_k, causal=causal),
-            grid=(bh, seq // block_q, seq // block_k),
+            _heads_a_step(functools.partial(
+                _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
+                causal=causal, window=window), heads),
+            grid=(bh // heads, seq // block_q,
+                  _k_steps(seq, block_q, block_k, window)),
             in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-            out_specs=pl.BlockSpec((1, block_q, d),
+            out_specs=pl.BlockSpec((heads, block_q, d),
                                    lambda i, j, t: (i, j, 0)),
             out_shape=_sds((bh, seq, d), q.dtype, q),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM(slab + (block_q, d), jnp.float32)],
             compiler_params=None if interpret else pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
-            name=scopes.kernel_name(scopes.FLASH_DQ),
+            name=scopes.kernel_name(scopes.FLASH_WINDOW_DQ if banded
+                                    else scopes.FLASH_DQ),
         )(q, k, v, g, lse, delta)
 
-    # dkv grid: (bh, k block, q block) — inner axis streams q.
-    qspec2 = pl.BlockSpec((1, block_q, d), lambda i, t, j: (i, j, 0))
-    kspec2 = pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0))
-    rowspec2 = pl.BlockSpec((1, block_q, 1), lambda i, t, j: (i, j, 0))
-    with jax.named_scope(scopes.FLASH_DKV):
+    # dkv grid: (bh, k block, q block) — inner axis streams q: every query
+    # block in turn, or under a window the ones that meet the key block
+    # (the last one held for the steps a narrower key block has left over).
+    nq = seq // block_q
+    if window is None:
+        q_steps = nq
+
+        def q_at(t, u):
+            return u
+    else:
+        q_steps = _band_steps(seq, block_q, block_k, window)[1]
+
+        def q_at(t, u):
+            return jnp.minimum(
+                _band_first_q(t, block_q, block_k) + u,
+                _band_last_q(t, block_q, block_k, window, nq))
+    qspec2 = pl.BlockSpec((heads, block_q, d),
+                          lambda i, t, u: (i, q_at(t, u), 0))
+    kspec2 = pl.BlockSpec((heads, block_k, d), lambda i, t, j: (i, t, 0))
+    rowspec2 = pl.BlockSpec((heads, block_q, 1),
+                            lambda i, t, u: (i, q_at(t, u), 0))
+    with jax.named_scope(scopes.FLASH_WINDOW_DKV) if banded \
+            else jax.named_scope(scopes.FLASH_DKV):
         dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
-                              block_k=block_k, causal=causal),
-            grid=(bh, seq // block_k, seq // block_q),
+            _heads_a_step(functools.partial(
+                _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
+                causal=causal, window=window, n_q_blocks=nq), heads),
+            grid=(bh // heads, seq // block_k, q_steps),
             in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
-            out_specs=[
-                pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
-                pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
-            ],
+            out_specs=[kspec2, kspec2],
             out_shape=[
                 _sds((bh, seq, d), k.dtype, k),
                 _sds((bh, seq, d), v.dtype, v),
             ],
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM(slab + (block_k, d), jnp.float32),
+                            pltpu.VMEM(slab + (block_k, d), jnp.float32)],
             compiler_params=None if interpret else pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
-            name=scopes.kernel_name(scopes.FLASH_DKV),
+            name=scopes.kernel_name(scopes.FLASH_WINDOW_DKV if banded
+                                    else scopes.FLASH_DKV),
         )(q, k, v, g, lse, delta)
     return dq, dk, dv
 
@@ -571,7 +750,7 @@ def _flash_attention_bwd_onepass_flat(q, k, v, g, lse, delta, *,
     return jnp.sum(dqp, axis=1), dk, dv
 
 
-def _flash_bwd_chunked(causal, res, g):
+def _flash_bwd_chunked(causal, window, res, g):
     q, k, v = res
     b, s, h, _ = q.shape
     # bigger blocks = fewer scan steps (measured 23% faster at 2048 vs
@@ -586,17 +765,19 @@ def _flash_bwd_chunked(causal, res, g):
                   if bq <= cap and s % bq == 0), None)
     if block is None:  # irregular/large: direct vjp on the reference
         _, vjp = jax.vjp(
-            lambda q_, k_, v_: _reference_attention(q_, k_, v_, causal),
+            lambda q_, k_, v_: _reference_attention(q_, k_, v_, causal,
+                                                    window),
             q, k, v)
         return vjp(g)
-    return _chunked_attention_bwd(q, k, v, g, causal, block)
+    return _chunked_attention_bwd(q, k, v, g, causal, block, window)
 
 
-def _flash_bwd(causal, res, g):
+def _flash_bwd(causal, window, res, g):
     q, k, v, o, lse = res
     if lse is None:  # fwd fell back to plain XLA attention
         _, vjp = jax.vjp(
-            lambda q_, k_, v_: _reference_attention(q_, k_, v_, causal),
+            lambda q_, k_, v_: _reference_attention(q_, k_, v_, causal,
+                                                    window),
             q, k, v)
         return vjp(g)
     import os
@@ -611,18 +792,22 @@ def _flash_bwd(causal, res, g):
             "'chunked', got %r" % choice)
     if choice == "chunked":
         # A/B escape hatch (docs/benchmarks.md records the comparison).
-        return _flash_bwd_chunked(causal, (q, k, v), g)
+        return _flash_bwd_chunked(causal, window, (q, k, v), g)
     b, s, h, d = q.shape
-    block_q, block_k, d_pad, pre_scale = _plan(s, d)
+    block_q, block_k, d_pad, pre_scale = _plan(s, d, window)
     # delta = rowsum(g ⊙ o): the softmax-jacobian correction term,
     # cheap in XLA (one elementwise pass).  Unit lane dim to match the
     # lse layout.
     delta = jnp.sum(jnp.swapaxes(g, 1, 2).astype(jnp.float32)
                     * jnp.swapaxes(o, 1, 2).astype(jnp.float32),
                     axis=-1).reshape(b * h, s, 1)
+    # The one-pass form writes a dq partial for every key block: it serves
+    # no window, whose calls take the two kernels whatever the choice.
     bwd_flat = (_flash_attention_bwd_onepass_flat
-                if choice == "pallas_onepass"
-                else _flash_attention_bwd_flat)
+                if choice == "pallas_onepass" and window is None
+                else functools.partial(_flash_attention_bwd_flat,
+                                       window=window,
+                                       heads=_heads_of(b * h, window)))
     dq, dk, dv = bwd_flat(
         _to_flat(q * pre_scale, d_pad), _to_flat(k, d_pad),
         _to_flat(v, d_pad), _to_flat(g, d_pad), lse, delta,
@@ -641,17 +826,26 @@ def _flash_bwd(causal, res, g):
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, causal: bool = True):
+def flash_attention(q, k, v, causal: bool = True, window=None):
     """Fused blocked attention, layout ``(batch, seq, heads, dim)``
     (the framework's attention layout).  Differentiable; compiled
     Pallas on TPU, interpreted elsewhere.  Sequences not divisible by
     64 fall back to plain XLA attention.  GQA (kv_heads < heads) is
-    handled by repeating KV head groups."""
+    handled by repeating KV head groups.  ``window``: a causal query sees
+    its last ``window`` keys, itself among them; the kernels then visit
+    the blocks of that band alone, under scopes and names of their own
+    (``hvd.flash_window_*``)."""
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window of %r keys needs a causal mask and "
+                             "at least the query itself" % (window,))
+        if window >= q.shape[1]:
+            window = None           # the band is the whole triangle
     if k.shape[2] != q.shape[2]:
         rep = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    return _flash_attention(q, k, v, causal)
+    return _flash_attention(q, k, v, causal, window)
 
 
 def use_flash_attention() -> bool:

@@ -121,9 +121,10 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     return out.astype(q.dtype)
 
 
-def local_attention(q, k, v, causal: bool = True):
+def local_attention(q, k, v, causal: bool = True, window=None):
     """Single-shard reference attention (same math, no ring) — used by the
-    dense model when sp=1 and by tests as the ground truth."""
+    dense model when sp=1 and by tests as the ground truth.  ``window``: a
+    causal query sees its last ``window`` keys, itself among them."""
     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
@@ -133,6 +134,9 @@ def local_attention(q, k, v, causal: bool = True):
     if causal:
         s_q, s_kv = q.shape[1], k.shape[1]
         mask = jnp.arange(s_kv)[None, :] <= jnp.arange(s_q)[:, None]
+        if window is not None:
+            mask &= jnp.arange(s_kv)[None, :] \
+                > jnp.arange(s_q)[:, None] - window
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))

@@ -247,7 +247,7 @@ def test_both_models_take_the_kernel_choice_from_ops(monkeypatch):
     and BERT both follow."""
     calls = []
 
-    def flash(q, k, v, causal=True):
+    def flash(q, k, v, causal=True, window=None):
         calls.append(causal)
         return jnp.zeros_like(q)
 
