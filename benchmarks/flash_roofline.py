@@ -12,7 +12,7 @@ command:
    attention shape via ``autotune_flash_blocks`` (fwd and bwd TFLOP/s
    per candidate, the kernel-parameter leg of the autotune plane),
 3. A/Bs the backward STRUCTURE at the winning blocks: two-pass dq/dkv
-   kernels vs the fused one-pass (dq partials + XLA reduce) vs the
+   kernels vs the one kernel (dq of a whole head kept in VMEM) vs the
    chunked-XLA escape hatch — end to end through ``jax.grad`` of the
    public ``flash_attention``, exactly what a train step runs.
 

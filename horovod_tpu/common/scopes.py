@@ -32,8 +32,11 @@ tokens' rows and the write of its rows by the ``hvd_moe_combine`` kernel;
 ``EXPERTS`` the routed experts' matrix products alone, ``SHARED_EXPERT``
 the expert every token takes).  Kernels, one
 ``pallas_call`` each: ``FLASH_FWD``, ``FLASH_DQ``, ``FLASH_DKV``,
-``FLASH_BWD_ONEPASS``, and ``FLASH_WINDOW_FWD``, ``FLASH_WINDOW_DQ``,
-``FLASH_WINDOW_DKV`` for the same three under a window (a sliding layer's
+``FLASH_BWD_ONEPASS`` (the one backward kernel of a full call: dq, dk and
+dv; ``FLASH_DQ`` and ``FLASH_DKV`` are the two it replaces wherever dq of a
+head fits in VMEM), and ``FLASH_WINDOW_FWD``, ``FLASH_WINDOW_DQ``,
+``FLASH_WINDOW_DKV`` for the same under a window (a banded call's one
+backward kernel sits under ``FLASH_WINDOW_DKV``; a sliding layer's
 whole block is ``WINDOW_ATTENTION``, inside ``ATTENTION``); ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two,
 inside ``KDA_CORE``); ``kernel_name`` gives the same words as the ``name=``
 of the call (``hvd_flash_fwd``), which is what the trace viewer prints for

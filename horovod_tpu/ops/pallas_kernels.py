@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from ..common import scopes
+from ..common import metrics, scopes
 from ..common.device import on_tpu
 
 LOG = logging.getLogger("horovod_tpu")
@@ -174,19 +174,23 @@ def _flash_attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
 class _HeadOf:
     """Head ``g`` of a ref that holds several, for a kernel written for one:
     the kernels read and write a head's block whole (``ref[0]``) and a
-    scratch whole (``ref[:]``), and both are ``ref[g]`` here.  (A view,
-    ``ref.at[g]``, is refused by Mosaic for the row statistics' unit lane
-    dimension.)"""
+    scratch whole (``ref[:]``), and both are ``ref[g]`` here; the rows of a
+    whole-head scratch (``ref[rows, :]``, a tuple) are ``ref[g, rows, :]``.
+    (A view, ``ref.at[g]``, is refused by Mosaic for the row statistics'
+    unit lane dimension.)"""
 
     def __init__(self, ref, g: int):
         self.ref, self.g = ref, g
         self.shape, self.dtype = ref.shape[1:], ref.dtype
 
-    def __getitem__(self, whole):
-        return self.ref[self.g]
+    def _at(self, index):
+        return (self.g,) + index if isinstance(index, tuple) else self.g
 
-    def __setitem__(self, whole, value):
-        self.ref[self.g] = value
+    def __getitem__(self, index):
+        return self.ref[self._at(index)]
+
+    def __setitem__(self, index, value):
+        self.ref[self._at(index)] = value
 
 
 def _heads_a_step(kernel, heads: int):
@@ -376,6 +380,67 @@ def _heads_of(flat_heads: int, window) -> int:
         g for g in _WINDOW_HEADS_A_STEP if flat_heads % g == 0)
 
 
+# The one backward kernel (``_flash_bwd_onepass_kernel``) holds dq of its
+# heads whole in VMEM: a float32 accumulator ``[seq, d_pad]`` a head and the
+# whole-head output block it is cast into at the end, twice (the pipeline's
+# two buffers).  A call takes it where that fits this budget and the two
+# kernels beyond it (the v5e has 128 MiB).  The limit the compiler is given
+# is those bytes and room for the blocks and the score-shaped products, and
+# no more: what a kernel may use XLA cannot lend to the fusions round it
+# (at a flat 64 MiB `mlm512` kept 67 fewer buffers in VMEM and gave back
+# 0.9 of the 1.2 ms the kernel won; PERF.md, PR 32).
+_ONEPASS_VMEM_BUDGET = 32 << 20
+_ONEPASS_VMEM_ROOM = 16 << 20
+
+
+def _onepass_vmem_bytes(heads: int, s: int, d_pad: int, itemsize: int):
+    return heads * s * d_pad * (4 + 2 * itemsize)
+
+
+def _onepass_heads(flat_heads: int, s: int, d_pad: int, itemsize: int,
+                   window):
+    """Flat heads a grid step of the one backward kernel: as many as the
+    other kernels of the call take (``_heads_of``) if their dq fits the
+    budget, else the most that does; None where one head's does not."""
+    most = _heads_of(flat_heads, window)
+    return next(
+        (g for g in _WINDOW_HEADS_A_STEP if most % g == 0
+         and _onepass_vmem_bytes(g, s, d_pad, itemsize)
+         <= _ONEPASS_VMEM_BUDGET), None)
+
+
+def _backward_form(flat_heads: int, s: int, d_pad: int, itemsize: int,
+                   window):
+    """``(form, heads a step)`` of a call's backward pass, read from
+    ``HVD_TPU_FLASH_BWD`` at TRACE time (under jit the choice is baked into
+    the compiled function: set it before the first train step, not between
+    steps).  Unset, the shape decides: the one kernel where dq of a grid
+    step's heads fits ``_ONEPASS_VMEM_BUDGET``, the two kernels beyond it.
+    ``pallas`` is the two kernels whatever the shape (the A/B hatch),
+    ``pallas_onepass`` the one kernel or an error where it does not fit,
+    ``chunked`` the XLA form.  Unknown values fail loudly so a typo can't
+    silently invalidate an A/B comparison."""
+    import os
+    choice = os.environ.get("HVD_TPU_FLASH_BWD")
+    if choice not in (None, "pallas", "pallas_onepass", "chunked"):
+        raise ValueError(
+            "HVD_TPU_FLASH_BWD must be 'pallas', 'pallas_onepass' or "
+            "'chunked', got %r" % choice)
+    if choice == "chunked":
+        return "chunked", 1
+    heads = None if choice == "pallas" else _onepass_heads(
+        flat_heads, s, d_pad, itemsize, window)
+    if heads is not None:
+        return "onepass", heads
+    if choice == "pallas_onepass":
+        raise ValueError(
+            "HVD_TPU_FLASH_BWD=pallas_onepass: dq of one flat head "
+            "(%d x %d float32 and its output block twice) does not fit the "
+            "%d MiB the one kernel may hold in VMEM; unset the variable "
+            "for the two kernels" % (s, d_pad, _ONEPASS_VMEM_BUDGET >> 20))
+    return "two_kernel", _heads_of(flat_heads, window)
+
+
 def _d_pad(d: int) -> int:
     return max(128, ((d + 127) // 128) * 128)
 
@@ -517,11 +582,19 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                           block_q: int, block_k: int, causal: bool,
-                          window=None, n_q_blocks=None):
+                          window=None, n_q_blocks=None, dq_ref=None,
+                          dq_scr=None):
     # grid = (bh, nk, nq): Q/G stream along the inner axis while this
     # k block's dk/dv accumulate in VMEM scratch.  Under a window the inner
     # axis counts from the first query block that meets this key block and
     # a step past the band's last (or the sequence's last) is skipped.
+    # With ``dq_ref`` this is the ONE backward kernel: dq of the whole flat
+    # head waits in ``dq_scr`` ([seq, d] float32) through the head's sweep,
+    # block (t, j) adds its ``ds k`` to the rows of query block j (for a
+    # query block the key blocks still come in ascending order, as in the
+    # dq kernel), and the head's last grid step casts it into ``dq_ref``, a
+    # whole-head output block: scores, exp, mask and ``dp`` are computed
+    # once, and no float32 dq and no partial ever lies in HBM.
     t = pl.program_id(1)
     u = pl.program_id(2)
     nq = pl.num_programs(2)
@@ -531,6 +604,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    if dq_ref is not None:
+        @pl.when(jnp.logical_and(t == 0, u == 0))
+        def _init_head():
+            dq_scr[:] = jnp.zeros_like(dq_scr)
 
     block_live = jnp.logical_or(
         jnp.logical_not(causal),
@@ -562,11 +640,21 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
         dk_scr[:] += jax.lax.dot_general(
             ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # (BK, D)
+        if dq_ref is not None:
+            rows = pl.ds(pl.multiple_of(j * block_q, block_q), block_q)
+            dq_scr[rows, :] += jax.lax.dot_general(
+                ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (BQ, D)
 
     @pl.when(u == nq - 1)
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    if dq_ref is not None:
+        @pl.when(jnp.logical_and(t == pl.num_programs(1) - 1, u == nq - 1))
+        def _finish_head():
+            dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _flash_attention_bwd_flat(q, k, v, g, lse, delta, *, causal: bool,
@@ -601,10 +689,29 @@ def _flash_attention_bwd_flat(q, k, v, g, lse, delta, *, causal: bool,
             name=scopes.kernel_name(scopes.FLASH_WINDOW_DQ if banded
                                     else scopes.FLASH_DQ),
         )(q, k, v, g, lse, delta)
+    with jax.named_scope(scopes.FLASH_WINDOW_DKV) if banded \
+            else jax.named_scope(scopes.FLASH_DKV):
+        dk, dv = _flash_bwd_by_key_block(
+            q, k, v, g, lse, delta, causal=causal, block_q=block_q,
+            block_k=block_k, interpret=interpret, window=window, heads=heads,
+            name=scopes.kernel_name(scopes.FLASH_WINDOW_DKV if banded
+                                    else scopes.FLASH_DKV))
+    return dq, dk, dv
 
-    # dkv grid: (bh, k block, q block) — inner axis streams q: every query
-    # block in turn, or under a window the ones that meet the key block
-    # (the last one held for the steps a narrower key block has left over).
+
+def _flash_bwd_by_key_block(q, k, v, g, lse, delta, *, causal: bool,
+                            block_q: int, block_k: int, interpret: bool,
+                            window, heads: int, name: str,
+                            with_dq: bool = False):
+    """The ``pallas_call`` whose grid is (bh, k block, q block): the inner
+    axis streams q, every query block in turn or under a window the ones
+    that meet the key block (the last one held for the steps a narrower key
+    block has left over).  (dk, dv), or with ``with_dq`` (dq, dk, dv) from
+    the one kernel, whose dq is a whole-head block that stays put through
+    the head's sweep."""
+    from jax.experimental.pallas import tpu as pltpu
+    bh, seq, d = q.shape
+    slab = () if heads == 1 else (heads,)
     nq = seq // block_q
     if window is None:
         q_steps = nq
@@ -623,131 +730,68 @@ def _flash_attention_bwd_flat(q, k, v, g, lse, delta, *, causal: bool,
     kspec2 = pl.BlockSpec((heads, block_k, d), lambda i, t, j: (i, t, 0))
     rowspec2 = pl.BlockSpec((heads, block_q, 1),
                             lambda i, t, u: (i, q_at(t, u), 0))
-    with jax.named_scope(scopes.FLASH_WINDOW_DKV) if banded \
-            else jax.named_scope(scopes.FLASH_DKV):
-        dk, dv = pl.pallas_call(
-            _heads_a_step(functools.partial(
-                _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                causal=causal, window=window, n_q_blocks=nq), heads),
-            grid=(bh // heads, seq // block_k, q_steps),
-            in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
-            out_specs=[kspec2, kspec2],
-            out_shape=[
-                _sds((bh, seq, d), k.dtype, k),
-                _sds((bh, seq, d), v.dtype, v),
-            ],
-            scratch_shapes=[pltpu.VMEM(slab + (block_k, d), jnp.float32),
-                            pltpu.VMEM(slab + (block_k, d), jnp.float32)],
-            compiler_params=None if interpret else pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
-            name=scopes.kernel_name(scopes.FLASH_WINDOW_DKV if banded
-                                    else scopes.FLASH_DKV),
-        )(q, k, v, g, lse, delta)
-    return dq, dk, dv
+    kernel = functools.partial(
+        _flash_bwd_onepass_kernel if with_dq else _flash_bwd_dkv_kernel,
+        block_q=block_q, block_k=block_k, causal=causal, window=window,
+        n_q_blocks=nq)
+    out_specs = [kspec2, kspec2]
+    out_shape = [_sds((bh, seq, d), k.dtype, k),
+                 _sds((bh, seq, d), v.dtype, v)]
+    scratch = [pltpu.VMEM(slab + (block_k, d), jnp.float32),
+               pltpu.VMEM(slab + (block_k, d), jnp.float32)]
+    semantics = ("parallel", "parallel", "arbitrary")
+    params = {}
+    if with_dq:
+        # Every key block of a head but its last leaves dq unfinished, so
+        # the key axis is no longer anybody's to split.
+        out_specs.insert(0, pl.BlockSpec((heads, seq, d),
+                                         lambda i, t, u: (i, 0, 0)))
+        out_shape.insert(0, _sds((bh, seq, d), q.dtype, q))
+        scratch.insert(0, pltpu.VMEM(slab + (seq, d), jnp.float32))
+        semantics = ("parallel", "arbitrary", "arbitrary")
+        params = {"vmem_limit_bytes": _ONEPASS_VMEM_ROOM + _onepass_vmem_bytes(
+            heads, seq, d, q.dtype.itemsize)}
+    return pl.pallas_call(
+        _heads_a_step(kernel, heads),
+        grid=(bh // heads, seq // block_k, q_steps),
+        in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=semantics, **params),
+        interpret=interpret,
+        name=name,
+    )(q, k, v, g, lse, delta)
 
 
 def _flash_bwd_onepass_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
-                              delta_ref, dqp_ref, dk_ref, dv_ref,
-                              dk_scr, dv_scr, *, block_q: int,
-                              block_k: int, causal: bool):
-    # grid = (bh, nk, nq): ONE kernel for dq/dk/dv.  Q/G stream along
-    # the inner axis while this k block's dk/dv accumulate in VMEM
-    # scratch (as in the two-pass dkv kernel); the dq contribution of
-    # each (k block, q block) tile is emitted as an f32 PARTIAL block
-    # (indexed by the k-block axis) and reduced outside the kernel.
-    # Trade measured on hardware, not assumed: Q/K/V/G are each read
-    # from HBM once per tile pair instead of twice (the two-pass cost),
-    # against nk x extra dq-partial HBM writes + one cheap XLA sum.
-    t = pl.program_id(1)
-    j = pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    block_live = jnp.logical_or(
-        jnp.logical_not(causal),
-        j * block_q + block_q - 1 >= t * block_k)
-
-    @pl.when(block_live)
-    def _update():
-        # q pre-scaled by 1/sqrt(d): s needs no per-block multiply.
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (BQ, BK)
-        p = jnp.exp(s - lse_ref[0])
-        if causal:
-            rows = j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = t * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            p = jnp.where(cols <= rows, p, 0.0)
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(g_ref.dtype), g_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (BK, D)
-        dp = jax.lax.dot_general(
-            g_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (BQ, BK)
-        ds = p * (dp - delta_ref[0])
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (BK, D)
-        dqp_ref[0, 0] = jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (BQ, D)
-
-    @pl.when(jnp.logical_not(block_live))
-    def _dead():
-        # Causal-dead tiles still own an output block in the partial
-        # array: write zeros or the sum reads uninitialized memory.
-        dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
-
-    @pl.when(j == nq - 1)
-    def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+                              delta_ref, dq_ref, dk_ref, dv_ref, dq_scr,
+                              dk_scr, dv_scr, **plan):
+    """ONE kernel for dq, dk and dv: the dk/dv kernel with dq of the whole
+    flat head kept in VMEM (refs in ``pallas_call``'s order)."""
+    _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                          dk_ref, dv_ref, dk_scr, dv_scr, dq_ref=dq_ref,
+                          dq_scr=dq_scr, **plan)
 
 
 def _flash_attention_bwd_onepass_flat(q, k, v, g, lse, delta, *,
                                       causal: bool, block_q: int,
-                                      block_k: int, interpret: bool):
-    """Flat (BH, S, D) backward via the single one-pass kernel above;
-    returns (dq_f32, dk, dv) with dq still in the fwd's q scaling (the
-    nk partial blocks are summed here, one cheap XLA reduce)."""
-    from jax.experimental.pallas import tpu as pltpu
-    bh, seq, d = q.shape
-    nk = seq // block_k
-    qspec = pl.BlockSpec((1, block_q, d), lambda i, t, j: (i, j, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0))
-    rowspec = pl.BlockSpec((1, block_q, 1), lambda i, t, j: (i, j, 0))
-    with jax.named_scope(scopes.FLASH_BWD_ONEPASS):
-        dqp, dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_onepass_kernel, block_q=block_q,
-                              block_k=block_k, causal=causal),
-            grid=(bh, nk, seq // block_q),
-            in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-            out_specs=[
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda i, t, j: (i, t, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
-                pl.BlockSpec((1, block_k, d), lambda i, t, j: (i, t, 0)),
-            ],
-            out_shape=[
-                _sds((bh, nk, seq, d), jnp.float32, q),
-                _sds((bh, seq, d), k.dtype, k),
-                _sds((bh, seq, d), v.dtype, v),
-            ],
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
-            compiler_params=None if interpret else pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-            interpret=interpret,
-            name=scopes.kernel_name(scopes.FLASH_BWD_ONEPASS),
-        )(q, k, v, g, lse, delta)
-    return jnp.sum(dqp, axis=1), dk, dv
+                                      block_k: int, interpret: bool,
+                                      window=None, heads: int = 1):
+    """Flat (BH, S, D) backward via the one kernel; returns (dq, dk, dv)
+    in the inputs' dtypes with dq still in the fwd's q scaling.  A banded
+    call's kernel sits under ``hvd.flash_window_dkv``: the scope that reads
+    the banded backward's time."""
+    banded = window is not None
+    with jax.named_scope(scopes.FLASH_WINDOW_DKV) if banded \
+            else jax.named_scope(scopes.FLASH_BWD_ONEPASS):
+        return _flash_bwd_by_key_block(
+            q, k, v, g, lse, delta, causal=causal, block_q=block_q,
+            block_k=block_k, interpret=interpret, window=window, heads=heads,
+            name=scopes.kernel_name(scopes.FLASH_WINDOW_DKV if banded
+                                    else scopes.FLASH_BWD_ONEPASS),
+            with_dq=True)
 
 
 def _flash_bwd_chunked(causal, window, res, g):
@@ -774,45 +818,40 @@ def _flash_bwd_chunked(causal, window, res, g):
 
 def _flash_bwd(causal, window, res, g):
     q, k, v, o, lse = res
+
+    def count(form):
+        # As the call is traced: once for every time the layer scan and the
+        # recomputation trace it, not once a step.
+        metrics.counter("hvd_flash_backward_calls_total", form=form,
+                        window=str(int(window is not None))).inc()
+
     if lse is None:  # fwd fell back to plain XLA attention
+        count("xla")
         _, vjp = jax.vjp(
             lambda q_, k_, v_: _reference_attention(q_, k_, v_, causal,
                                                     window),
             q, k, v)
         return vjp(g)
-    import os
-    # Read at TRACE time: under jit the choice is baked into the
-    # compiled function — set before the first train step, not between
-    # steps.  Unknown values fail loudly so a typo can't silently
-    # invalidate an A/B comparison.
-    choice = os.environ.get("HVD_TPU_FLASH_BWD", "pallas")
-    if choice not in ("pallas", "pallas_onepass", "chunked"):
-        raise ValueError(
-            "HVD_TPU_FLASH_BWD must be 'pallas', 'pallas_onepass' or "
-            "'chunked', got %r" % choice)
-    if choice == "chunked":
-        # A/B escape hatch (docs/benchmarks.md records the comparison).
-        return _flash_bwd_chunked(causal, window, (q, k, v), g)
     b, s, h, d = q.shape
     block_q, block_k, d_pad, pre_scale = _plan(s, d, window)
+    form, heads = _backward_form(b * h, s, d_pad, q.dtype.itemsize, window)
+    count(form)
+    if form == "chunked":
+        # A/B escape hatch (docs/benchmarks.md records the comparison).
+        return _flash_bwd_chunked(causal, window, (q, k, v), g)
     # delta = rowsum(g ⊙ o): the softmax-jacobian correction term,
     # cheap in XLA (one elementwise pass).  Unit lane dim to match the
     # lse layout.
     delta = jnp.sum(jnp.swapaxes(g, 1, 2).astype(jnp.float32)
                     * jnp.swapaxes(o, 1, 2).astype(jnp.float32),
                     axis=-1).reshape(b * h, s, 1)
-    # The one-pass form writes a dq partial for every key block: it serves
-    # no window, whose calls take the two kernels whatever the choice.
-    bwd_flat = (_flash_attention_bwd_onepass_flat
-                if choice == "pallas_onepass" and window is None
-                else functools.partial(_flash_attention_bwd_flat,
-                                       window=window,
-                                       heads=_heads_of(b * h, window)))
+    bwd_flat = (_flash_attention_bwd_onepass_flat if form == "onepass"
+                else _flash_attention_bwd_flat)
     dq, dk, dv = bwd_flat(
         _to_flat(q * pre_scale, d_pad), _to_flat(k, d_pad),
         _to_flat(v, d_pad), _to_flat(g, d_pad), lse, delta,
         causal=causal, block_q=block_q, block_k=block_k,
-        interpret=not on_tpu())
+        interpret=not on_tpu(), window=window, heads=heads)
     # The kernels differentiate w.r.t. the PRE-SCALED q, so
     # d(loss)/d(q) = dq_flat * pre_scale; dk comes out exact with no
     # correction (ds^T @ q_prescaled == scale * ds_raw^T @ q).  The
@@ -876,9 +915,10 @@ def flash_plan_info(s: int, d: int) -> dict:
         source = "fallback_xla"
     else:
         source = "default"
+    bwd = "xla" if source == "fallback_xla" else _backward_form(
+        1, s, d_pad, jnp.dtype(jnp.bfloat16).itemsize, None)[0]
     return {"block_q": block_q, "block_k": block_k, "d_pad": d_pad,
-            "source": source,
-            "bwd": os.environ.get("HVD_TPU_FLASH_BWD", "pallas")}
+            "source": source, "bwd": bwd}
 
 
 def flash_block_candidates(seq: int, d: int,
@@ -988,8 +1028,13 @@ def autotune_flash_blocks(seq: int, d: int, *, batch_heads: int = 8,
             delta = jnp.sum(g.astype(jnp.float32)
                             * out.astype(jnp.float32),
                             axis=-1, keepdims=True)
+            # the form a call of this shape takes (the one kernel where
+            # dq fits in VMEM), at one head a grid step
+            onepass = _backward_form(bh, seq, d_pad, q.dtype.itemsize,
+                                     None)[0] == "onepass"
             bwd = jax.jit(functools.partial(
-                _flash_attention_bwd_flat, causal=causal, block_q=bq,
+                _flash_attention_bwd_onepass_flat if onepass
+                else _flash_attention_bwd_flat, causal=causal, block_q=bq,
                 block_k=bk, interpret=interp))
             t_bwd = _time_device(bwd, (q, k, v, g, lse, delta), iters)
             total_t += t_bwd

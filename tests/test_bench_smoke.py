@@ -38,7 +38,7 @@ def test_bench_emits_one_valid_json_line():
     flash = lev["flash"]
     assert flash["source"] in ("env", "autotuned", "default",
                                "fallback_xla")
-    assert flash["bwd"] in ("pallas", "pallas_onepass", "chunked")
+    assert flash["bwd"] in ("onepass", "two_kernel", "chunked", "xla")
     assert "block_q" in flash and "block_k" in flash
     assert lev["hier"]["mode"] in ("auto", "on", "off")
     assert set(lev["hier"]["ops"]) == {

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.common import metrics
 from horovod_tpu.ops import pallas_kernels as pk
 from horovod_tpu.ops.pallas_kernels import (flash_attention,
                                             fused_scale_sum,
@@ -88,7 +89,7 @@ def test_flash_attention_grad_multiblock_grid(causal):
 
 
 # The r9 kernel grid: both Pallas backward structures (two-pass dq/dkv
-# and the fused one-pass with dq partials) across block shapes, causal
+# and the one kernel that keeps dq of a head in VMEM) across block shapes, causal
 # on/off, and a lane-padded vs exact head dim — the interpret-mode
 # numerics net under any kernel restructure.  Block shapes are driven
 # through the HVD_TPU_FLASH_BLOCK_Q/K hooks, exactly how an A/B or the
@@ -143,9 +144,9 @@ def test_flash_bwd_grid_exact_lane_dim(monkeypatch, variant):
 
 
 def test_flash_bwd_onepass_multiblock_grid(monkeypatch):
-    # s=192 -> 3x3 grid of 64-blocks: the one-pass kernel's scratch
-    # accumulation, dead-tile zero write, and partial-dq reduce across
-    # a grid where causal skipping actually fires.
+    # s=192 -> 3x3 grid of 64-blocks: the one kernel's dk/dv scratch and
+    # its whole-head dq accumulator across a grid where causal skipping
+    # actually fires (a dead tile adds nothing to its query block's rows).
     monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas_onepass")
     q, k, v = _qkv(b=1, s=192, h=2, d=32, seed=13)
 
@@ -161,6 +162,90 @@ def test_flash_bwd_onepass_multiblock_grid(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4,
                                    err_msg="d%s mismatch" % lbl)
+
+
+def _backward_kernels(q, k, v, **kw):
+    traced = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(flash_attention(*a, **kw) ** 2),
+        argnums=(0, 1, 2)))(q, k, v))
+    return {name for name in ("hvd_flash_dq", "hvd_flash_dkv",
+                              "hvd_flash_bwd_onepass", "hvd_flash_window_dq",
+                              "hvd_flash_window_dkv") if name in traced}
+
+
+def _backward_calls():
+    series = metrics.metrics_snapshot().get(
+        "hvd_flash_backward_calls_total", {}).get("series", ())
+    return {(row["labels"]["form"], row["labels"]["window"]): row["value"]
+            for row in series}
+
+
+@pytest.mark.parametrize("window", [None, 64])
+def test_flash_bwd_form_follows_the_shape_when_unset(monkeypatch, window):
+    """With ``HVD_TPU_FLASH_BWD`` unset the one kernel runs where dq of a
+    grid step's heads fits the VMEM budget and the two kernels where it
+    does not; ``pallas`` is the two kernels whatever the shape,
+    ``pallas_onepass`` refuses a shape that does not fit, and every traced
+    call is counted by its form."""
+    monkeypatch.delenv("HVD_TPU_FLASH_BWD", raising=False)
+    metrics.reset()
+    q, k, v = _qkv(b=1, s=128, h=2, d=32, seed=16)
+    banded = str(int(window is not None))
+    one = {"hvd_flash_window_dkv"} if window else {"hvd_flash_bwd_onepass"}
+    two = ({"hvd_flash_window_dq", "hvd_flash_window_dkv"} if window
+           else {"hvd_flash_dq", "hvd_flash_dkv"})
+    assert _backward_kernels(q, k, v, window=window) == one
+    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas")
+    assert _backward_kernels(q, k, v, window=window) == two
+    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "chunked")
+    assert _backward_kernels(q, k, v, window=window) == set()
+    # dq of one head: 128 x 128 float32 and its float32 output block twice
+    monkeypatch.setattr(pk, "_ONEPASS_VMEM_BUDGET", 128 * 128 * 12 - 1)
+    monkeypatch.delenv("HVD_TPU_FLASH_BWD")
+    assert _backward_kernels(q, k, v, window=window) == two
+    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas_onepass")
+    with pytest.raises(ValueError, match="does not fit"):
+        _backward_kernels(q, k, v, window=window)
+    assert _backward_calls() == {("onepass", banded): 1,
+                                 ("two_kernel", banded): 2,
+                                 ("chunked", banded): 1}
+
+
+def test_flash_bwd_heads_a_step_shrink_to_the_budget(monkeypatch):
+    """Under a window the one kernel takes as many heads a grid step as
+    the other kernels of the call where their dq fits, fewer where it does
+    not, and a ragged sequence's XLA backward is counted as such."""
+    a_head = 8192 * 128 * (4 + 2 * 2)           # bfloat16: 8 MiB
+    assert pk._ONEPASS_VMEM_BUDGET >= 4 * a_head
+    assert pk._backward_form(128, 8192, 128, 2, 512) == ("onepass", 4)
+    assert pk._backward_form(96, 8192, 128, 2, None) == ("onepass", 1)
+    assert pk._backward_form(128, 8192, 128, 4, 512) == ("onepass", 2)
+    monkeypatch.setattr(pk, "_ONEPASS_VMEM_BUDGET", a_head)
+    assert pk._backward_form(128, 8192, 128, 2, 512) == ("onepass", 1)
+    assert pk._backward_form(128, 16384, 128, 2, 512) == ("two_kernel", 4)
+    assert pk._backward_form(96, 16384, 128, 2, None) == ("two_kernel", 1)
+    metrics.reset()
+    q, k, v = _qkv(b=1, s=96, h=1, d=16, seed=17)
+    assert _backward_kernels(q, k, v) == set()
+    assert _backward_calls() == {("xla", "0"): 1}
+
+
+def test_flash_plan_info_tells_the_backward_form(monkeypatch):
+    for name in ("HVD_TPU_FLASH_BWD", "HVD_TPU_FLASH_BLOCK_Q",
+                 "HVD_TPU_FLASH_BLOCK_K"):
+        monkeypatch.delenv(name, raising=False)
+    assert pk.flash_plan_info(8192, 128)["bwd"] == "onepass"
+    assert pk.flash_plan_info(512, 64)["bwd"] == "onepass"
+    assert pk.flash_plan_info(100, 64)["bwd"] == "xla"
+    monkeypatch.setattr(pk, "_ONEPASS_VMEM_BUDGET", 1 << 20)
+    assert pk.flash_plan_info(8192, 128)["bwd"] == "two_kernel"
+    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "chunked")
+    assert pk.flash_plan_info(8192, 128)["bwd"] == "chunked"
+    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas")
+    assert pk.flash_plan_info(8192, 128)["bwd"] == "two_kernel"
+    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "onepass")
+    with pytest.raises(ValueError, match="HVD_TPU_FLASH_BWD"):
+        pk.flash_plan_info(8192, 128)
 
 
 def test_flash_bwd_unknown_variant_fails_loudly(monkeypatch):

@@ -239,15 +239,27 @@ def _pallas_calls(jaxpr, found):
     return found
 
 
-@pytest.mark.parametrize("variant,want", [
-    ("pallas", [scopes.FLASH_FWD, scopes.FLASH_DQ, scopes.FLASH_DKV]),
-    ("pallas_onepass", [scopes.FLASH_FWD, scopes.FLASH_BWD_ONEPASS])])
-def test_each_flash_kernel_sits_under_its_scope(monkeypatch, variant, want):
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", variant)
+@pytest.mark.parametrize("variant,window,want", [
+    ("pallas", None, [scopes.FLASH_FWD, scopes.FLASH_DQ, scopes.FLASH_DKV]),
+    ("pallas_onepass", None, [scopes.FLASH_FWD, scopes.FLASH_BWD_ONEPASS]),
+    (None, None, [scopes.FLASH_FWD, scopes.FLASH_BWD_ONEPASS]),
+    ("pallas", 64, [scopes.FLASH_WINDOW_FWD, scopes.FLASH_WINDOW_DQ,
+                    scopes.FLASH_WINDOW_DKV]),
+    (None, 64, [scopes.FLASH_WINDOW_FWD, scopes.FLASH_WINDOW_DKV])])
+def test_each_flash_kernel_sits_under_its_scope(monkeypatch, variant, window,
+                                                want):
+    """Unset, the backward is the one kernel: under its own scope for a
+    full call, under the banded dk/dv's for a call with a window (the scope
+    the benchmark reads the banded backward from)."""
+    if variant is None:
+        monkeypatch.delenv("HVD_TPU_FLASH_BWD", raising=False)
+    else:
+        monkeypatch.setenv("HVD_TPU_FLASH_BWD", variant)
     q = jnp.ones((1, 256, 2, 64), jnp.float32)
 
     def loss(q, k, v):
-        return pallas_kernels.flash_attention(q, k, v, causal=False).sum()
+        return pallas_kernels.flash_attention(
+            q, k, v, causal=window is not None, window=window).sum()
 
     calls = _pallas_calls(
         jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q).jaxpr, [])

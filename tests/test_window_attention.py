@@ -104,8 +104,9 @@ def test_grouped_queries_under_a_window(blocks, heads, kv_heads):
 
 @pytest.mark.parametrize("backward", ["pallas_onepass", "chunked"])
 def test_the_other_backward_forms_under_a_window(blocks, backward):
-    """The chunked form carries the mask; the one-pass form serves no
-    window, whose calls take the two kernels whatever the choice."""
+    """The chunked form carries the mask; the one kernel carries the band
+    and sits where the banded dk/dv kernel sat, so nothing runs under the
+    unbanded names or the banded dq's."""
     blocks(64, 64, backward)
     q, k, v, weight = inputs()
     fn = lambda *a: pk.flash_attention(*a, window=100)     # noqa: E731
@@ -115,7 +116,53 @@ def test_the_other_backward_forms_under_a_window(blocks, backward):
     traced = str(jax.make_jaxpr(jax.grad(
         lambda *a: (fn(*a) * weight).sum(), argnums=(0, 1, 2)))(q, k, v))
     assert "hvd_flash_bwd_onepass" not in traced
-    assert ("hvd_flash_window_dq" in traced) == (backward != "chunked")
+    assert "hvd_flash_window_dq" not in traced
+    assert ("hvd_flash_window_dkv" in traced) == (backward != "chunked")
+
+
+# The one backward kernel (dq of a whole head in VMEM): not causal, causal,
+# windows narrower and wider than a block and of laguna's 512; grids with
+# nq != nk; one, two and four heads a grid step (``step``: what the plan
+# makes of three, two or six, and four or eight flat heads under a window).
+@pytest.mark.parametrize(
+    "seq, heads, step, window, causal, block_q, block_k", [
+        (256, 2, 1, None, False, 64, 128), (256, 2, 1, None, True, 128, 64),
+        (256, 2, 1, None, True, 64, 64), (256, 2, 2, 48, True, 64, 64),
+        (256, 2, 2, 48, True, 128, 64), (256, 3, 1, 48, True, 64, 64),
+        (256, 4, 4, 100, True, 64, 128), (256, 6, 2, 100, True, 64, 64),
+        (256, 8, 4, 100, True, 128, 128), (1024, 2, 2, 512, True, 256, 512),
+        (1024, 8, 4, 512, True, 512, 256)])
+def test_the_one_backward_kernel_matches_every_gradient(
+        blocks, seq, heads, step, window, causal, block_q, block_k):
+    blocks(block_q, block_k, "pallas_onepass")
+    q, k, v, weight = inputs(seq=seq, heads=heads, kv_heads=heads, d=16)
+    assert pk._backward_form(heads, seq, 128, 4, window) == ("onepass", step)
+    traced = str(jax.make_jaxpr(jax.grad(lambda *a: (pk.flash_attention(
+        *a, causal=causal, window=window) * weight).sum(),
+        argnums=(0, 1, 2)))(q, k, v))
+    assert ("hvd_flash_bwd_onepass" in traced) == (window is None)
+    assert ("hvd_flash_window_dkv" in traced) == (window is not None)
+    assert "hvd_flash_dq" not in traced and "_window_dq" not in traced
+    got = out_and_grads(lambda *a: pk.flash_attention(
+        *a, causal=causal, window=window), q, k, v, weight)
+    want = out_and_grads(
+        (lambda *a: plain(*a, window)) if causal
+        else (lambda *a: local_attention(*a, causal=False)), q, k, v, weight)
+    assert off(got, want) < 1e-5
+
+
+@pytest.mark.parametrize("heads, kv_heads", [(6, 1), (8, 1)])
+def test_the_one_backward_kernel_under_grouped_queries(blocks, heads,
+                                                       kv_heads):
+    """Six and eight query heads a key/value head, as laguna's full and
+    sliding layers have them, with and without the band."""
+    blocks(64, 64, "pallas_onepass")
+    q, k, v, weight = inputs(seq=128, heads=heads, kv_heads=kv_heads, d=16)
+    for window in (None, 48):
+        got = out_and_grads(lambda *a: pk.flash_attention(*a, window=window),
+                            q, k, v, weight)
+        assert off(got, out_and_grads(lambda *a: plain(*a, window),
+                                      q, k, v, weight)) < 1e-5
 
 
 def test_a_ragged_sequence_takes_the_xla_form_with_its_window():
@@ -195,6 +242,13 @@ def test_several_heads_a_grid_step_are_the_heads_one_by_one(heads):
             q, k, v, g, lse, delta, **at))
 
     for got, want in zip(both(heads), both(1)):
+        assert jnp.array_equal(got, want)
+    # the one backward kernel: the two kernels' numbers to the bit
+    o, lse, *two = both(heads)
+    one = pk._flash_attention_bwd_onepass_flat(
+        q, k, v, g, lse, jnp.sum(g * o, -1, keepdims=True), causal=True,
+        block_q=64, block_k=64, interpret=True, window=100, heads=heads)
+    for got, want in zip(one, two):
         assert jnp.array_equal(got, want)
     traced = str(jax.make_jaxpr(lambda *a: pk._flash_attention_fwd_flat(
         *a, causal=True, block_q=64, block_k=64, interpret=True, window=100,
