@@ -24,9 +24,11 @@ Phases, opened by the step builders (``jax/data_parallel.py``,
 Blocks inside the model, both passes: ``ATTENTION`` (softmax attention
 with its projections), ``HEAD`` (logits and cross entropy),
 ``LINEAR_ATTENTION`` (a delta-rule mixer whole; ``KDA_CORE`` inside it is
-the chunked recurrence alone, kernels or XLA form), ``MOE`` (an expert
-layer whole; inside it ``ROUTER`` is the scores, the choice, the sort of
-the pairs by expert, the blocks' indices and weights and, under
+the chunked recurrence alone, kernels or XLA form), ``STATE_SPACE`` (a
+Mamba-2 mixer whole: projections, convolution, scan, gated norm;
+``SSD_CORE`` inside it is the chunked state-space scan alone), ``MOE`` (an
+expert layer whole; inside it ``ROUTER`` is the scores, the choice, the sort
+of the pairs by expert, the blocks' indices and weights and, under
 ``ROUTER_ROWS``, the row movement alone: each block's gathers of its
 tokens' rows and the write of its rows by the ``hvd_moe_combine`` kernel;
 ``EXPERTS`` the routed experts' matrix products alone, ``SHARED_EXPERT``
@@ -52,6 +54,8 @@ ATTENTION = "hvd.attention"
 HEAD = "hvd.head"
 LINEAR_ATTENTION = "hvd.linear_attention"
 KDA_CORE = "hvd.kda_core"
+STATE_SPACE = "hvd.state_space"
+SSD_CORE = "hvd.ssd_core"
 MOE = "hvd.moe"
 ROUTER = "hvd.router"
 ROUTER_ROWS = "hvd.router_rows"

@@ -310,7 +310,7 @@ def kda_chunked(q, k, v, g, beta, chunk: int, segment: int = 16):
 # The mixer
 # --------------------------------------------------------------------------
 
-def _causal_conv(x, taps):
+def causal_conv(x, taps):
     """Depthwise over time: ``y_t = sum_j taps[j] x_{t-(n-1)+j}``, zeros
     before the sequence's start.  ``x`` ``[B, S, W]``, ``taps`` ``[n, W]``."""
     n = taps.shape[0]
@@ -336,7 +336,7 @@ def linear_attention_block(x, lp, cfg: KdaConfig):
 
     def branch(name):
         y = x @ lp["w" + name].astype(x.dtype)
-        return jax.nn.silu(_causal_conv(y, lp["conv_" + name]))
+        return jax.nn.silu(causal_conv(y, lp["conv_" + name]))
 
     q, k = heads(_l2norm(heads(branch("q")))), heads(_l2norm(heads(branch("k"))))
     v = heads(branch("v"))

@@ -22,18 +22,23 @@ leaves ``[L, ...]`` consumed by ``lax.scan`` (single-layer trace, static
 shapes, bf16 activations on the MXU, optional ``jax.checkpoint`` remat).
 
 What a layer is made of is said in one place, ``layer_pattern``: one
-period of (mixer, feed-forward) pairs out of ``MIXERS`` and
-``FEED_FORWARDS``.  The scan runs over periods, ``params["layers"]`` is a
+period of (mixer, feed-forward) entries out of ``MIXERS`` and
+``FEED_FORWARDS``.  An entry with both is a pair, each sub-layer under a
+norm of its own (``ln1``, ``ln2``) with its own residual add; an entry
+whose mixer or whose feed-forward is None is a block of the one sub-layer
+that is there, under its one norm (a model whose blocks alternate instead
+of pairing).  The scan runs over periods, ``params["layers"]`` is a
 tuple with one stacked dict for each layer of the period, and each layer is
-recomputed on its own.  ``leading_layers`` are pairs that run once ahead of
-the scan (``params["leading"]``: a model whose first layers differ from its
-periods).  The block functions of the kinds that need more
+recomputed on its own.  ``leading_layers`` are entries that run once ahead
+of the scan (``params["leading"]``: a model whose first layers differ from
+its periods).  The block functions of the kinds that need more
 than a few lines live beside their mechanism (``models/linear_attention.py``,
-``parallel/moe.py``); a new architecture is one more kind there and an entry
-of the pattern here.  Softmax attention is one block whatever the kind:
-what a kind of layer fixes of it (head counts, window, rotary table, gate)
-is a ``SoftmaxAttention``, which a pattern's entry may hold in the mixer's
-place; ``attention`` and ``gated_nope_attention`` name two of its settings.
+``models/state_space.py``, ``parallel/moe.py``); a new architecture is one
+more kind there and an entry of the pattern here.  Softmax attention is one
+block whatever the kind: what a kind of layer fixes of it (head counts,
+window, rotary table, gate) is a ``SoftmaxAttention``, which a pattern's
+entry may hold in the mixer's place; ``attention`` and
+``gated_nope_attention`` name two of its settings.
 """
 
 from __future__ import annotations
@@ -58,17 +63,23 @@ from ..parallel.ring_attention import (local_attention, pvary_missing,
 from .linear_attention import SAVED as kda_saved_names
 from .linear_attention import (KdaConfig, init_kda_params, kda_param_specs,
                                linear_attention_block)
+from .state_space import SAVED as ssm_saved_names
+from .state_space import (SsmConfig, init_ssm_params, ssm_param_specs,
+                          state_space_block)
 
 # Kinds a layer is made of.  ``attention``: RoPE softmax attention (GQA);
 # ``gated_nope_attention``: the same with no positional encoding and an
 # element-wise sigmoid gate on the heads' output (both at the model's
 # ``n_heads`` / ``n_kv_heads``; a ``SoftmaxAttention`` in the mixer's place
 # says its own); ``linear_attention``: the gated delta rule
-# (``cfg.linear_attention``).  ``dense``: SwiGLU; ``moe``:
+# (``cfg.linear_attention``); ``state_space``: a Mamba-2 mixer
+# (``cfg.state_space``).  ``dense``: SwiGLU; ``moe``:
 # capacity-factor experts with an all-to-all over ``sp``;
 # ``expert_share``: this chip's share of a dropless expert layer beside a
-# shared expert (``cfg.experts``).
-MIXERS = ("attention", "gated_nope_attention", "linear_attention")
+# shared expert (``cfg.experts``).  None in either place: the block has no
+# such sub-layer.
+MIXERS = ("attention", "gated_nope_attention", "linear_attention",
+          "state_space")
 FEED_FORWARDS = ("dense", "moe", "expert_share")
 
 
@@ -161,16 +172,19 @@ class TransformerConfig:
     # work).  No-op at tp=1, so single-chip programs are unchanged.
     collective_matmul: bool = False
     # One period of the layer pattern, ((mixer, feed-forward), ...) out of
-    # MIXERS (or a SoftmaxAttention) x FEED_FORWARDS; n_layers less the
-    # leading layers is a multiple of its length.
-    layer_pattern: Tuple[Tuple[object, str], ...] = (("attention", "dense"),)
-    # Pairs of the same kinds that run once, each with parameters of its
+    # MIXERS (or a SoftmaxAttention) x FEED_FORWARDS, either of which may
+    # be None (not both); n_layers less the leading layers is a multiple of
+    # its length.
+    layer_pattern: Tuple[Tuple[object, Optional[str]], ...] = (
+        ("attention", "dense"),)
+    # Entries of the same kinds that run once, each with parameters of its
     # own, ahead of the scanned periods.
-    leading_layers: Tuple[Tuple[object, str], ...] = ()
+    leading_layers: Tuple[Tuple[object, Optional[str]], ...] = ()
     # Size of an attention head where it is not d_model / n_heads (a
     # chip's share of the heads keeps the model's head size).
     head_size: Optional[int] = None
     linear_attention: Optional[KdaConfig] = None
+    state_space: Optional[SsmConfig] = None
     experts: Optional[ExpertShare] = None
     # An output head of its own, ``params["head"]`` [d, V], instead of the
     # embedding's transpose.
@@ -189,13 +203,16 @@ class TransformerConfig:
                              "'dots_no_batch', got %r"
                              % (self.remat_policy,))
         for mixer, ffn in self.pairs:
-            if not (isinstance(mixer, SoftmaxAttention) or mixer in MIXERS) \
-                    or ffn not in FEED_FORWARDS:
+            if not (isinstance(mixer, SoftmaxAttention)
+                    or mixer in MIXERS + (None,)) \
+                    or ffn not in FEED_FORWARDS + (None,) \
+                    or (mixer is None and ffn is None):
                 raise ValueError("layer_pattern pairs a mixer of %s (or a "
                                  "SoftmaxAttention) with a feed-forward of "
-                                 "%s, not %r"
+                                 "%s, or holds one of them alone, not %r"
                                  % (MIXERS, FEED_FORWARDS, (mixer, ffn)))
             if (mixer == "linear_attention" and not self.linear_attention) \
+                    or (mixer == "state_space" and not self.state_space) \
                     or (ffn == "expert_share" and not self.experts):
                 raise ValueError("%r needs its configuration"
                                  % ((mixer, ffn),))
@@ -212,8 +229,8 @@ class TransformerConfig:
 
     @property
     def pairs(self):
-        """The (mixer, feed-forward) pairs of the leading layers, then of
-        one period."""
+        """The (mixer, feed-forward) entries of the leading layers, then
+        of one period."""
         return self.leading_layers + self.layer_pattern
 
     def softmax_kind(self, mixer) -> Optional[SoftmaxAttention]:
@@ -247,19 +264,25 @@ def _normal(key, shape, fan_in, dtype):
     return (jax.random.normal(key, shape) / math.sqrt(fan_in)).astype(dtype)
 
 
-def _init_layers(key, cfg: TransformerConfig, mixer: str, ffn: str, n: int):
-    """``n`` stacked layers of one (mixer, feed-forward) kind."""
+def _init_layers(key, cfg: TransformerConfig, mixer, ffn, n: int):
+    """``n`` stacked layers of one (mixer, feed-forward) kind: ``ln1`` and
+    the mixer's parameters where it has a mixer, ``ln2`` and the
+    feed-forward's where it has one."""
     pd = jnp.dtype(cfg.param_dtype)
     d, hd, f = cfg.d_model, cfg.head_dim, cfg.d_ff
     keys = jax.random.split(key, 12)
     norm = partial(_normal, dtype=pd)
 
-    layers = {"ln1": jnp.ones((n, d), pd), "ln2": jnp.ones((n, d), pd)}
+    layers = {name: jnp.ones((n, d), pd)
+              for name, part in (("ln1", mixer), ("ln2", ffn))
+              if part is not None}
     kind = cfg.softmax_kind(mixer)
-    if kind is None:
+    if mixer == "linear_attention":
         layers.update(init_kda_params(keys[1], d, cfg.linear_attention, n,
                                       pd))
-    else:
+    elif mixer == "state_space":
+        layers.update(init_ssm_params(keys[1], d, cfg.state_space, n, pd))
+    elif kind is not None:
         qh, kvh = kind.n_heads, kind.n_kv_heads
         layers.update({
             "wq": norm(keys[1], (n, d, qh * hd), d),
@@ -284,7 +307,7 @@ def _init_layers(key, cfg: TransformerConfig, mixer: str, ffn: str, n: int):
             "we3": norm(keys[10], (n, e, d, f), d),
             "we2": norm(keys[11], (n, e, f, d), f),
         })
-    else:
+    elif ffn == "expert_share":
         layers.update(init_expert_share_params(keys[8], cfg.experts, n, pd))
     return layers
 
@@ -314,14 +337,18 @@ def init_params(key, cfg: TransformerConfig):
     return params
 
 
-def _layer_specs(cfg: TransformerConfig, mixer: str, ffn: str):
+def _layer_specs(cfg: TransformerConfig, mixer, ffn):
     from jax.sharding import PartitionSpec as P
     tp, sp = cfg.tp_axis, cfg.sp_axis
-    specs = {"ln1": P(None, None), "ln2": P(None, None)}
+    specs = {name: P(None, None)
+             for name, part in (("ln1", mixer), ("ln2", ffn))
+             if part is not None}
     kind = cfg.softmax_kind(mixer)
-    if kind is None:
+    if mixer == "linear_attention":
         specs.update(kda_param_specs(tp))
-    else:
+    elif mixer == "state_space":
+        specs.update(ssm_param_specs())
+    elif kind is not None:
         specs.update({
             "wq": P(None, None, tp),
             "wk": P(None, None, tp),
@@ -343,15 +370,15 @@ def _layer_specs(cfg: TransformerConfig, mixer: str, ffn: str):
             "we3": P(None, sp, None, None),
             "we2": P(None, sp, None, None),
         })
-    else:
+    elif ffn == "expert_share":
         # The share is what this chip holds: nothing of it is split again.
         specs.update({name: P(None, None, None, None)
-                      for name in ("we1", "we3", "we2")})
+                      for name in cfg.experts.names("we")})
         specs["router"] = P(None, None, None)
         specs["router_bias"] = P(None, None)
         if cfg.experts.d_shared:
             specs.update({name: P(None, None, None)
-                          for name in ("ws1", "ws3", "ws2")})
+                          for name in cfg.experts.names("ws")})
     return specs
 
 
@@ -564,9 +591,14 @@ def _mix(h, lp, cfg: TransformerConfig, mixer, tables, sp_size):
     if kind is not None:
         return _softmax_attention_block(h, lp, cfg, kind, tables, sp_size)
     if sp_size > 1:
-        raise ValueError("a linear-attention layer keeps a state along the "
+        raise ValueError("a %s layer keeps a state along the "
                          "sequence: the sequence cannot be split over %r"
-                         % cfg.sp_axis)
+                         % (mixer.replace("_", "-"), cfg.sp_axis))
+    if mixer == "state_space":
+        if lax.axis_size(cfg.tp_axis) > 1:
+            raise ValueError("a state-space layer's heads are not split: "
+                             "%r has to be 1 wide" % cfg.tp_axis)
+        return state_space_block(h, lp, cfg.state_space)
     return lax.psum(linear_attention_block(h, lp, cfg.linear_attention),
                     cfg.tp_axis)
 
@@ -608,19 +640,24 @@ def hidden(params, tokens, cfg: TransformerConfig):
 
     def layer(mixer, ffn, carry, lp):
         x, aux = carry
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        x = x + _mix(h, lp, cfg, mixer, tables, sp_size)
-        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        y, a, counts = _feed_forward(h, lp, cfg, ffn, sp_size)
-        return (x + y, aux if a is None else aux + a), counts
+        counts = ()
+        if mixer is not None:
+            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+            x = x + _mix(h, lp, cfg, mixer, tables, sp_size)
+        if ffn is not None:
+            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+            y, a, counts = _feed_forward(h, lp, cfg, ffn, sp_size)
+            x, aux = x + y, aux if a is None else aux + a
+        return (x, aux), counts
 
     layer_fns = [partial(layer, mixer, ffn) for mixer, ffn in cfg.pairs]
     if cfg.remat:
         # "full" keeps nothing but what a block names as dearer to compute
-        # again than to keep (the delta rule's walk along the sequence, an
-        # expert layer's choice and sort).
+        # again than to keep (the delta rule's walk along the sequence, the
+        # state-space scan's chunk states, an expert layer's choice and
+        # sort).
         pol = {"full": jax.checkpoint_policies.save_only_these_names(
-                   *kda_saved_names, *moe_saved_names),
+                   *kda_saved_names, *ssm_saved_names, *moe_saved_names),
                "dots": jax.checkpoint_policies.dots_saveable,
                "dots_no_batch":
                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,

@@ -22,6 +22,7 @@ import functools
 import math
 
 import jax
+import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
@@ -32,10 +33,22 @@ COMBINE = "hvd_moe_combine"
 
 
 def as_rows(shape):
-    """``[T, d]`` as the shape ``combine`` takes ``y`` in."""
+    """``[T, d]`` as the shape ``combine`` takes ``y`` in.  A token's row
+    is whole tiles only where its sublanes are a multiple of 8: a ``d`` of
+    21 x 128 is carried as 24 sublanes, the last three zero
+    (``from_rows`` drops them)."""
     t, d = shape
-    lanes = 128 if d % 128 == 0 else d
-    return t, d // lanes, lanes
+    if d % 128:
+        return t, 1, d
+    sublanes = d // 128
+    return t, -(-sublanes // 8) * 8, 128
+
+
+def from_rows(y, shape):
+    """``y`` as ``as_rows(shape)`` carried it, back as ``shape``."""
+    t, d = shape
+    y = y.reshape(t, -1)
+    return y if y.shape[1] == d else y[:, :d]
 
 
 def _combine_kernel(tokens, y_in, rows, y, buf, sems, *, chunk):
@@ -68,12 +81,15 @@ def _combine_kernel(tokens, y_in, rows, y, buf, sems, *, chunk):
 
 
 def combine(y, rows, tokens):
-    """``y`` ``[T, s, l]`` with ``rows`` ``[R, s * l]`` added at ``tokens``
-    ``[R]``, in place.  The tokens in range are distinct; one out of range
-    (``>= T``) marks a row that is not written."""
+    """``y`` ``[T, s, l]`` with ``rows`` ``[R, d]`` (``d <= s * l``, zeros
+    behind) added at ``tokens`` ``[R]``, in place.  The tokens in range are
+    distinct; one out of range (``>= T``) marks a row that is not
+    written."""
     from jax.experimental.pallas import tpu as pltpu
     _, s, l = y.shape
-    n = rows.shape[0]
+    n, d = rows.shape
+    if d < s * l:                       # ``as_rows`` padded the sublanes
+        rows = jnp.pad(rows, ((0, 0), (0, s * l - d)))
     chunk = math.gcd(n, 128)
     return pl.pallas_call(
         functools.partial(_combine_kernel, chunk=chunk),

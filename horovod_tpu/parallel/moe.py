@@ -167,6 +167,19 @@ def aux_load_balance_loss(gates, dispatch):
 # A chip's share of an expert layer, dropless
 # --------------------------------------------------------------------------
 
+def _swiglu(x, w1, w3, w2):
+    return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
+
+
+def _relu2(x, w1, w2):
+    return jnp.square(jax.nn.relu(x @ w1)) @ w2
+
+
+# What an expert computes, by form: which matrices it has (the digits of
+# their names, in the order its function takes them) and the function.
+_FORMS = {"swiglu": ("132", _swiglu), "relu2": ("12", _relu2)}
+
+
 @dataclasses.dataclass(frozen=True)
 class ExpertShare:
     """Which experts of a layer live here.  The router is ``n_experts``
@@ -182,8 +195,16 @@ class ExpertShare:
     # Rows of one block of sorted pairs: what one pass of the expert
     # products takes.  A block never spans two experts.
     block_rows: int = 512
+    # What an expert (routed or shared) computes, the model's published
+    # activation: ``swiglu`` is three matrices, ``(silu(x w1) * (x w3))
+    # w2``; ``relu2`` two, ``relu(x w1)^2 w2``, and the tree then has no
+    # ``we3`` / ``ws3``.
+    form: str = "swiglu"
 
     def __post_init__(self):
+        if self.form not in _FORMS:
+            raise ValueError("an expert is one of %s, not %r"
+                             % (sorted(_FORMS), self.form))
         if not 0 <= self.first <= self.first + self.count <= self.n_experts:
             raise ValueError("experts %d..%d are not among %d"
                              % (self.first, self.first + self.count - 1,
@@ -193,11 +214,23 @@ class ExpertShare:
             raise ValueError("a token cannot choose %d of %d experts"
                              % (self.top_k, self.n_experts))
 
+    def names(self, prefix: str):
+        """The parameters of an expert of this form: ``we..`` the held
+        routed experts', ``ws..`` the shared expert's."""
+        return tuple(prefix + digit for digit in _FORMS[self.form][0])
+
+    @property
+    def expert(self):
+        """``f(x, *matrices)``: what one expert computes."""
+        return _FORMS[self.form][1]
+
 
 def init_expert_share_params(key, share: ExpertShare, n: int,
                              dtype=jnp.float32):
     """``n`` stacked layers of the held experts, the router and the
-    shared expert (SwiGLU each).  ``router_bias`` is the family's
+    shared expert (each of ``share.form``: ``we1, we3, we2`` / ``ws1, ws3,
+    ws2``, without the ``3`` where the form has two matrices).
+    ``router_bias`` is the family's
     load-balancing buffer: added to the scores where the experts are
     chosen and nowhere else, so no gradient reaches it; it starts at zero
     and is set from outside (a checkpoint, or a calibration)."""
@@ -207,15 +240,20 @@ def init_expert_share_params(key, share: ExpertShare, n: int,
     def norm(k, shape, fan_in):
         return (jax.random.normal(k, shape) / math.sqrt(fan_in)).astype(dtype)
 
+    def expert(prefix, keys, lead, width):
+        """The matrices of experts ``width`` wide: ``..1`` and ``..3`` up,
+        ``..2`` down, each with the key it has whatever the form."""
+        keys = dict(zip("132", keys))
+        shapes = {"1": (d, width), "3": (d, width), "2": (width, d)}
+        return {name: norm(keys[name[-1]], lead + shapes[name[-1]],
+                           shapes[name[-1]][0])
+                for name in share.names(prefix)}
+
     params = {"router": norm(ks[0], (n, d, share.n_experts), d),
               "router_bias": jnp.zeros((n, share.n_experts), dtype),
-              "we1": norm(ks[1], (n, share.count, d, f), d),
-              "we3": norm(ks[2], (n, share.count, d, f), d),
-              "we2": norm(ks[3], (n, share.count, f, d), f)}
+              **expert("we", ks[1:4], (n, share.count), f)}
     if fs:
-        params.update(ws1=norm(ks[4], (n, d, fs), d),
-                      ws3=norm(ks[5], (n, d, fs), d),
-                      ws2=norm(ks[6], (n, fs, d), fs))
+        params.update(expert("ws", ks[4:7], (n,), fs))
     return params
 
 
@@ -287,10 +325,6 @@ def _sorted_pairs(ids, share: ExpertShare):
     return checkpoint_name(order, SAVED[1]), checkpoint_name(loads, SAVED[2])
 
 
-def _swiglu(x, w1, w3, w2):
-    return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
-
-
 def _zeros(shape, dtype, like):
     """Zeros that vary over the mesh axes ``like`` varies over: what a
     loop's carry has to start as under ``check_vma``."""
@@ -332,34 +366,33 @@ def _block(i, order, sizes, plan, rows: int, top_k: int):
             jnp.where(real, pairs, order.shape[0] + row))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _routed(top_k, rows, x, we1, we3, we2, weights, order, sizes):
-    """``y[t] = sum over t's held choices of weight * expert(x[t])``,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _routed(top_k, rows, expert, x, held, weights, order, sizes):
+    """``y[t] = sum over t's held choices of weight * expert(x[t])``
+    (``held`` the experts' stacked matrices, in ``expert``'s order),
     block by block over the sorted pairs: a step pays for the blocks
     that hold pairs (``0.2 T`` rows where routing is even), and ``k T``
     rows are still right.  A block gathers its rows of ``x``, multiplies,
     and adds its rows into ``y`` at their tokens: no two rows of a block
     share a token (``_block``), so the adds are conflict-free writes, one
     DMA a row in any order (``ops/moe_kernels.py``)."""
-    return _routed_fwd(top_k, rows, x, we1, we3, we2, weights, order,
+    return _routed_fwd(top_k, rows, expert, x, held, weights, order,
                        sizes)[0]
 
 
-def _routed_fwd(top_k, rows, x, we1, we3, we2, weights, order, sizes):
+def _routed_fwd(top_k, rows, expert, x, held, weights, order, sizes):
     flat_w = weights.reshape(-1)
     plan = _plan(sizes, rows)
 
     def block(i, y):
         with jax.named_scope(scopes.ROUTER):
-            expert, pairs, source, real, token, _ = _block(
+            e, pairs, source, real, token, _ = _block(
                 i, order, sizes, plan, rows, top_k)
             w_rows = jnp.where(real, flat_w[pairs], 0.0)
             with jax.named_scope(scopes.ROUTER_ROWS):
                 x_rows = x[source]
         with jax.named_scope(scopes.EXPERTS):
-            out = _swiglu(x_rows, we1[expert].astype(x.dtype),
-                          we3[expert].astype(x.dtype),
-                          we2[expert].astype(x.dtype))
+            out = expert(x_rows, *(w[e].astype(x.dtype) for w in held))
         with jax.named_scope(scopes.ROUTER):
             out = (out.astype(jnp.float32) * w_rows[:, None]).astype(y.dtype)
             with jax.named_scope(scopes.ROUTER_ROWS):
@@ -369,34 +402,32 @@ def _routed_fwd(top_k, rows, x, we1, we3, we2, weights, order, sizes):
     y = lax.fori_loop(0, plan[1][-1], block,
                       _zeros(moe_kernels.as_rows(x.shape), x.dtype, x))
     with jax.named_scope(scopes.ROUTER), jax.named_scope(scopes.ROUTER_ROWS):
-        y = y.reshape(x.shape)
-    return y, (x, we1, we3, we2, weights, order, sizes)
+        y = moe_kernels.from_rows(y, x.shape)
+    return y, (x, held, weights, order, sizes)
 
 
-def _routed_bwd(top_k, rows, res, dy):
+def _routed_bwd(top_k, rows, expert, res, dy):
     """Each block's products are computed anew and differentiated on the
     spot, so nothing of a block outlives it."""
-    x, we1, we3, we2, weights, order, sizes = res
+    x, held, weights, order, sizes = res
     flat_w = weights.reshape(-1)
     plan = _plan(sizes, rows)
 
-    def expert_of(x_rows, w1, w3, w2):
-        return _swiglu(x_rows, w1.astype(x.dtype), w3.astype(x.dtype),
-                       w2.astype(x.dtype))
+    def expert_of(x_rows, *ws):
+        return expert(x_rows, *(w.astype(x.dtype) for w in ws))
 
     def block(i, carry):
-        dx, d1, d3, d2, dw = carry
+        dx, d_held, dw = carry
         with jax.named_scope(scopes.ROUTER):
-            expert, pairs, source, real, token, pair = _block(
+            e, pairs, source, real, token, pair = _block(
                 i, order, sizes, plan, rows, top_k)
             w_rows = jnp.where(real, flat_w[pairs], 0.0)
             with jax.named_scope(scopes.ROUTER_ROWS):
                 x_rows = x[source]
                 dy_rows = dy[source].astype(jnp.float32)
         with jax.named_scope(scopes.EXPERTS):
-            out, vjp = jax.vjp(expert_of, x_rows, we1[expert], we3[expert],
-                               we2[expert])
-            dx_rows, g1, g3, g2 = vjp(
+            out, vjp = jax.vjp(expert_of, x_rows, *(w[e] for w in held))
+            dx_rows, *grads = vjp(
                 (dy_rows * w_rows[:, None]).astype(out.dtype))
         with jax.named_scope(scopes.ROUTER):
             dw_rows = jnp.where(
@@ -407,20 +438,18 @@ def _routed_bwd(top_k, rows, res, dy):
             # says.  (``indices_are_sorted`` is as true of ``pair`` and is
             # left unsaid: XLA's TPU scatter takes 13 times as long when
             # told, ``PERF.md`` PR 30.)
-            return (dx, d1.at[expert].add(g1, unique_indices=True),
-                    d3.at[expert].add(g3, unique_indices=True),
-                    d2.at[expert].add(g2, unique_indices=True),
+            return (dx, tuple(d.at[e].add(g, unique_indices=True)
+                              for d, g in zip(d_held, grads)),
                     dw.at[pair].add(dw_rows, mode="drop",
                                     unique_indices=True))
 
     init = (_zeros(moe_kernels.as_rows(x.shape), x.dtype, x),
-            _zeros(we1.shape, we1.dtype, x), _zeros(we3.shape, we3.dtype, x),
-            _zeros(we2.shape, we2.dtype, x),
+            tuple(_zeros(w.shape, w.dtype, x) for w in held),
             _zeros(flat_w.shape, jnp.float32, x))
-    dx, d1, d3, d2, dw = lax.fori_loop(0, plan[1][-1], block, init)
+    dx, d_held, dw = lax.fori_loop(0, plan[1][-1], block, init)
     with jax.named_scope(scopes.ROUTER), jax.named_scope(scopes.ROUTER_ROWS):
-        dx = dx.reshape(x.shape)
-    return (dx, d1, d3, d2,
+        dx = moe_kernels.from_rows(dx, x.shape)
+    return (dx, d_held,
             dw.reshape(weights.shape).astype(weights.dtype), None, None)
 
 
@@ -432,8 +461,9 @@ def expert_share_ffn(params, x, share: ExpertShare):
     """The held experts' and the shared expert's part of a
     mixture-of-experts layer over ``x`` ``[T, d]``:
 
-        y = sum_{e held, e among t's top_k} w_e * SwiGLU_e(x) + Shared(x)
+        y = sum_{e held, e among t's top_k} w_e * Expert_e(x) + Shared(x)
 
+    with the experts of ``share.form``.
     Nothing is dropped for any routing, and no tensor has an expert or a
     capacity axis.  Returns ``y`` and how many tokens every expert of the
     layer got (``[n_experts]``, a device value; the held experts' are
@@ -444,13 +474,12 @@ def expert_share_ffn(params, x, share: ExpertShare):
         order, loads = _sorted_pairs(ids, share)
         sizes = lax.dynamic_slice_in_dim(loads, share.first, share.count)
         vma = tuple(jax.typeof(x).vma)
-        held = [pvary_missing(params[name], vma)
-                for name in ("we1", "we3", "we2")]
-    y = _routed(share.top_k, share.block_rows, x, *held, weights, order,
-                sizes)
+        held = tuple(pvary_missing(params[name], vma)
+                     for name in share.names("we"))
+    y = _routed(share.top_k, share.block_rows, share.expert, x, held,
+                weights, order, sizes)
     if share.d_shared:
         with jax.named_scope(scopes.SHARED_EXPERT):
-            y = y + _swiglu(x, params["ws1"].astype(x.dtype),
-                            params["ws3"].astype(x.dtype),
-                            params["ws2"].astype(x.dtype))
+            y = y + share.expert(x, *(params[name].astype(x.dtype)
+                                      for name in share.names("ws")))
     return y, loads

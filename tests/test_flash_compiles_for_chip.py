@@ -29,12 +29,14 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", cache)
 
 
-# laguna's full and sliding layers, pt8k's softmax layer, BERT's (head 64
-# padded), and float32 inputs, whose output block halves the heads a step.
+# laguna's full and sliding layers, pt8k's and nemotron's softmax layers,
+# BERT's (head 64 padded), and float32 inputs, whose output block halves the
+# heads a step.
 @pytest.mark.parametrize("flat_heads, seq, window, causal, dtype", [
     (96, 8192, None, True, jnp.bfloat16),
     (128, 8192, 512, True, jnp.bfloat16),
     (16, 8192, None, True, jnp.bfloat16),
+    (64, 8192, None, True, jnp.bfloat16),
     (64, 512, None, False, jnp.bfloat16),
     (8, 8192, 512, True, jnp.float32)])
 def test_the_one_backward_kernel_compiles_for_v5e(one_chip, flat_heads, seq,

@@ -307,4 +307,4 @@ def test_one_vocabulary():
     for use in uses:
         assert use.startswith("scopes.") and use[7:] in constants, use
     assert all(re.fullmatch(r"hvd\.[a-z_]+", v) for v in constants.values())
-    assert len(set(constants.values())) == len(constants) == 22
+    assert len(set(constants.values())) == len(constants) == 24

@@ -12,6 +12,7 @@ from jax.sharding import Mesh
 
 from horovod_tpu.models import bert, transformer
 from horovod_tpu.models.linear_attention import KdaConfig
+from horovod_tpu.models.state_space import SsmConfig
 from horovod_tpu.models.transformer import (FEED_FORWARDS, MIXERS,
                                             TransformerConfig, init_params,
                                             loss_fn, make_train_step,
@@ -215,6 +216,8 @@ def test_every_pair_of_the_pattern_builds_and_steps(mixer, ffn):
         capacity_factor=2.0,
         linear_attention=KdaConfig(n_heads=2, head_size=16, gate_rank=8,
                                    chunk=8),
+        state_space=SsmConfig(n_heads=4, head_size=8, n_groups=2,
+                              state_size=8, chunk=8),
         experts=ExpertShare(n_experts=4, first=1, count=2, top_k=2,
                             d_model=32, d_ff=16, d_shared=16,
                             block_rows=8))
