@@ -269,13 +269,18 @@ NAMES: Dict[str, Tuple[str, str]] = {
                    "baseline, labeled op + size_class (each trip "
                    "invalidates the class's routing entry and re-arms "
                    "the plan tuner exactly once)"),
-    # -- kernels (ops/pallas_kernels.py) --
+    # -- kernels (ops/pallas_kernels.py, ops/ssd_kernels.py) --
     "hvd_flash_backward_calls_total": (
         "counter", "backward passes of flash_attention by the form they "
                    "took, labeled form (onepass|two_kernel|chunked|xla) + "
                    "window (0|1); counted as a call is traced, so once "
                    "for every time a layer scan or a recomputation "
                    "traces it and never again for a compiled step"),
+    "hvd_ssd_scan_calls_total": (
+        "counter", "state-space scans (models/state_space.py: "
+                   "ssd_chunked) by the form their shapes took, labeled "
+                   "form (kernel|xla); counted as a scan is traced, like "
+                   "hvd_flash_backward_calls_total"),
     # -- cross-cutting --
     "stall_detected_total": (
         "counter", "stall-inspector warnings (a collective outlived "

@@ -26,7 +26,8 @@ with its projections), ``HEAD`` (logits and cross entropy),
 ``LINEAR_ATTENTION`` (a delta-rule mixer whole; ``KDA_CORE`` inside it is
 the chunked recurrence alone, kernels or XLA form), ``STATE_SPACE`` (a
 Mamba-2 mixer whole: projections, convolution, scan, gated norm;
-``SSD_CORE`` inside it is the chunked state-space scan alone), ``MOE`` (an
+``SSD_CORE`` inside it is the chunked state-space scan alone, kernels or XLA
+form), ``MOE`` (an
 expert layer whole; inside it ``ROUTER`` is the scores, the choice, the sort
 of the pairs by expert, the blocks' indices and weights and, under
 ``ROUTER_ROWS``, the row movement alone: each block's gathers of its
@@ -40,7 +41,8 @@ head fits in VMEM), and ``FLASH_WINDOW_FWD``, ``FLASH_WINDOW_DQ``,
 ``FLASH_WINDOW_DKV`` for the same under a window (a banded call's one
 backward kernel sits under ``FLASH_WINDOW_DKV``; a sliding layer's
 whole block is ``WINDOW_ATTENTION``, inside ``ATTENTION``); ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two,
-inside ``KDA_CORE``); ``kernel_name`` gives the same words as the ``name=``
+inside ``KDA_CORE``); ``SSD_FWD`` and ``SSD_BWD`` (the state-space scan's
+two, inside ``SSD_CORE``); ``kernel_name`` gives the same words as the ``name=``
 of the call (``hvd_flash_fwd``), which is what the trace viewer prints for
 a Mosaic kernel.
 """
@@ -71,6 +73,8 @@ FLASH_WINDOW_DQ = "hvd.flash_window_dq"
 FLASH_WINDOW_DKV = "hvd.flash_window_dkv"
 KDA_FWD = "hvd.kda_fwd"
 KDA_BWD = "hvd.kda_bwd"
+SSD_FWD = "hvd.ssd_fwd"
+SSD_BWD = "hvd.ssd_bwd"
 
 
 def kernel_name(scope: str) -> str:

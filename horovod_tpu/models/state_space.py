@@ -29,7 +29,18 @@ positive, so a strong decay neither overflows nor loses the near steps.
 ``dt``, the log-decays, their sums, the exponentials and the states are
 float32 whatever the activations are; the products take their operands in
 the activations' dtype and accumulate in float32, as the published kernels
-do.  The backward pass is autodiff through these products.
+do.
+
+Two forms compute it and shapes choose between them (``ssd_chunked``).
+Where the blocks tile the chip (``ops/ssd_kernels.py: takes``: a group's
+channels and the state whole lane tiles, a chunk of 128 or 256) two Pallas
+kernels behind a ``jax.custom_vjp`` walk the chunks with the group's state
+in VMEM, forward, and its gradient, backward: the carry is the recurrence
+``H_c = exp(cum_end) H_{c-1} + S_c`` itself, and neither ``L`` nor ``C B^T``
+nor a state leaves the chip but the states the backward kernel starts its
+chunks from.  Every other shape takes ``ssd_chunked_xla``, the products
+above in XLA operations with autodiff behind them, which is also what the
+kernels are tested against.
 
 ``state_space_block`` is the whole mixer: one input projection to the gate
 ``z``, the scan's ``x``, ``B``, ``C`` and ``dt``, a causal depthwise
@@ -43,23 +54,24 @@ tensor-parallel axis (one projection holds ``z``, ``x``, ``B``, ``C`` and
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 
-from ..common import scopes
+from ..common import metrics, scopes
+from ..ops import ssd_kernels
+from ..parallel.ring_attention import pvary_missing
 from .linear_attention import causal_conv
 
 HI = lax.Precision.HIGHEST
-# ``checkpoint_name``s of what a recomputed layer keeps of its scan: the
-# state every chunk starts from (float32 ``[B, chunks, H, P, N]``, 256 MiB a
-# layer at 2 x 8192 tokens), so that the backward pass builds neither the
-# chunks' own states nor their carry again: 3.3 ms of 558.5 a step over four
-# layers for 0.35 GiB of peak memory (``PERF.md``, PR 33).
-SAVED = ("ssd_chunk_states",)
+# ``checkpoint_name``s of what a recomputed layer keeps of its scan:
+# nothing.  The gate and the norm need ``y`` again, so the forward kernel
+# runs again in the backward pass whatever is kept, and it writes the
+# chunks' start states as it goes (``PERF.md``, PR 34).
+SAVED = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,16 +151,12 @@ def ssm_param_specs():
 # The scan
 # --------------------------------------------------------------------------
 
-def ssd_chunked(x, dt, a, b, c, d_skip, chunk: int):
-    """The recurrence at the top of this file, a chunk at a time.  ``x``
-    ``[B, S, H, P]``, ``dt`` ``[B, S, H]`` (positive), ``a`` ``[H]``
-    (negative), ``b``, ``c`` ``[B, S, G, N]``, ``d_skip`` ``[H]``; ``S`` a
-    multiple of ``chunk``.  Returns ``y`` ``[B, S, H, P]`` float32."""
+def ssd_chunked_xla(x, dt, a, b, c, d_skip, chunk: int):
+    """``ssd_chunked`` in XLA operations, for the shapes the kernels do not
+    take: every chunk's products at once, the carry over chunks one more
+    product, the backward pass autodiff through them."""
     bsz, s, h, p = x.shape
     g = b.shape[2]
-    if s % chunk:
-        raise ValueError("the state-space scan runs in chunks of %d steps; "
-                         "a sequence of %d is not a multiple" % (chunk, s))
     nc, per = s // chunk, h // g
     act = x.dtype
 
@@ -195,13 +203,76 @@ def ssd_chunked(x, dt, a, b, c, d_skip, chunk: int):
     start = jnp.einsum(
         "bgkce,begkpn->bcgkpn", between.reshape(bsz, g, per, nc, nc), own,
         precision=HI)
-    start = checkpoint_name(start, SAVED[0])
     y = y + jnp.einsum(
         "bctgn,bcgkpn->bctgkp", c, start.astype(act),
         preferred_element_type=jnp.float32) \
         * jnp.exp(cum).reshape(bsz, nc, chunk, g, per)[..., None]
     y = y + xg.astype(jnp.float32) \
         * d_skip.astype(jnp.float32).reshape(g, per)[:, :, None]
+    return y.reshape(bsz, s, h, p)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _ssd_kernels(x, scal, b, c, d_row, p):
+    """``ops/ssd_kernels.py`` over ``[B, S, H P]`` rows, with the running
+    sums and ``D`` a channel made outside so that autodiff takes ``A``'s,
+    ``dt``'s and ``D``'s parts."""
+    return ssd_kernels.forward(x, scal, b, c, d_row, p)[0]
+
+
+def _ssd_kernels_fwd(x, scal, b, c, d_row, p):
+    y, starts = ssd_kernels.forward(x, scal, b, c, d_row, p)
+    return y, (x, scal, b, c, d_row, starts)
+
+
+def _ssd_kernels_bwd(p, res, dy):
+    dx, dscal, db, dc, d_skip = ssd_kernels.backward(*res[:5], dy, res[5],
+                                                     p)
+    # [B, G, 8, K P] partial sums -> D's row [1, H P]
+    return dx, dscal, db, dc, jnp.sum(d_skip, axis=(0, 2)).reshape(1, -1)
+
+
+_ssd_kernels.defvjp(_ssd_kernels_fwd, _ssd_kernels_bwd)
+
+
+def ssd_chunked(x, dt, a, b, c, d_skip, chunk: int):
+    """The recurrence at the top of this file, a chunk at a time.  ``x``
+    ``[B, S, H, P]``, ``dt`` ``[B, S, H]`` (positive), ``a`` ``[H]``
+    (negative), ``b``, ``c`` ``[B, S, G, N]``, ``d_skip`` ``[H]``; ``S`` a
+    multiple of ``chunk``.  Returns ``y`` ``[B, S, H, P]`` float32.  Shapes
+    choose the form: groups and states that fill lane tiles take the
+    kernels."""
+    bsz, s, h, p = x.shape
+    g, n = b.shape[2:]
+    if s % chunk:
+        raise ValueError("the state-space scan runs in chunks of %d steps; "
+                         "a sequence of %d is not a multiple" % (chunk, s))
+    nc, per = s // chunk, h // g
+    kernel = ssd_kernels.takes(p, per, n, chunk)
+    # As the scan is traced: once for every time a layer scan or a
+    # recomputation traces it, not once a step.
+    metrics.counter("hvd_ssd_scan_calls_total",
+                    form="kernel" if kernel else "xla").inc()
+    if not kernel:
+        return ssd_chunked_xla(x, dt, a, b, c, d_skip, chunk)
+
+    def rows(v):            # [B, S, H] -> [B, G, chunks, K, Q]: steps on lanes
+        return jnp.transpose(v.reshape(bsz, nc, chunk, g, per),
+                             (0, 3, 1, 4, 2))
+
+    dt = dt.astype(jnp.float32)
+    cum = jnp.cumsum((dt * a.astype(jnp.float32)).reshape(bsz, nc, chunk, h),
+                     axis=2).reshape(bsz, s, h)             # <= 0, falling
+    fill = ssd_kernels.scalar_rows(per) - 2 * per
+    scal = jnp.pad(jnp.concatenate([rows(cum), rows(dt)], axis=3),
+                   ((0, 0),) * 3 + ((0, fill), (0, 0)))
+    # D is a parameter, whole on every shard: its row has to vary over the
+    # mesh axes the tokens vary over before it meets them in a kernel.
+    d_row = pvary_missing(jnp.repeat(d_skip.astype(jnp.float32), p)[None],
+                          tuple(jax.typeof(x).vma))
+    y = _ssd_kernels(x.reshape(bsz, s, h * p), scal,
+                     b.reshape(bsz, s, g * n), c.reshape(bsz, s, g * n),
+                     d_row, p)
     return y.reshape(bsz, s, h, p)
 
 

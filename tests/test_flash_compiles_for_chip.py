@@ -57,3 +57,53 @@ def test_the_one_backward_kernel_compiles_for_v5e(one_chip, flat_heads, seq,
     # dq, dk, dv in the inputs' dtype and nothing else: no partial in HBM
     assert [(o.shape, o.dtype) for o in compiled.out_info] \
         == [((flat_heads, seq, d_pad), jnp.dtype(dtype))] * 3
+
+
+# nemotron's scan (2 x 8192 tokens, 64 heads of 64 in 8 groups, state 128,
+# chunk 128, bfloat16) first; then what else ``ssd_kernels.takes`` says yes
+# to: a chunk of 256, float32 activations, a head a lane tile, four heads a
+# lane tile, a group of two heads (its scalars filled up to 8 rows), and the
+# widest group taken (1024 channels).
+@pytest.mark.parametrize("heads, head, groups, chunk, dtype", [
+    (64, 64, 8, 128, jnp.bfloat16),
+    (64, 64, 8, 256, jnp.bfloat16),
+    (64, 64, 8, 128, jnp.float32),
+    (32, 128, 8, 128, jnp.bfloat16),
+    (32, 32, 2, 128, jnp.bfloat16),
+    (16, 64, 8, 128, jnp.bfloat16),
+    (32, 64, 2, 128, jnp.bfloat16)])
+def test_the_state_space_kernels_compile_for_v5e(one_chip, monkeypatch,
+                                                 heads, head, groups, chunk,
+                                                 dtype):
+    from horovod_tpu.common import device
+    from horovod_tpu.ops import ssd_kernels as sk
+    bsz, seq, state = 2, 8192, 128
+    per = heads // groups
+    assert sk.takes(head, per, state, chunk)
+    # the kernels ask ``device.on_tpu()``, as ``rehearse.py compile`` patches
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    x = shape((bsz, seq, heads * head), dtype)
+    scal = shape((bsz, groups, seq // chunk, sk.scalar_rows(per), chunk),
+                 jnp.float32)
+    bc = shape((bsz, seq, groups * state), dtype)
+    skip = shape((1, heads * head), jnp.float32)
+    starts = shape((bsz, groups, seq // chunk, per * head, state),
+                   jnp.float32)
+    fwd = jax.jit(functools.partial(sk.forward, p=head)).lower(
+        x, scal, bc, bc, skip).compile()
+    assert fwd.as_text().count("tpu_custom_call") == 1
+    # y in float32 and the chunks' start states, nothing else
+    assert [(o.shape, o.dtype) for o in fwd.out_info] \
+        == [(x.shape, jnp.float32), (starts.shape, jnp.float32)]
+    bwd = jax.jit(functools.partial(sk.backward, p=head)).lower(
+        x, scal, bc, bc, skip, shape(x.shape, jnp.float32), starts).compile()
+    assert bwd.as_text().count("tpu_custom_call") == 1
+    # each gradient in its input's shape and dtype, and D's a channel as
+    # eight rows a (sequence, group): nothing of a step's size is partial
+    assert [(o.shape, o.dtype) for o in bwd.out_info] \
+        == [(v.shape, v.dtype) for v in (x, scal, bc, bc)] \
+        + [((bsz, groups, 8, per * head), jnp.float32)]

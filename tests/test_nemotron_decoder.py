@@ -385,6 +385,10 @@ def test_the_scan_and_the_mixer_carry_their_scopes():
                and "dot_general" in ln for ln in lines)
     assert any("/exp\"" in ln for ln in core) \
         and any("(cumsum)" in ln for ln in core)
+    # heads of 16 and a state of 16 take the XLA form: no kernel's scope
+    # (tests/test_ssd_kernels.py has the shapes that take the kernels)
+    assert not any(scopes.SSD_FWD in ln or scopes.SSD_BWD in ln
+                   for ln in lines)
     # the other blocks keep theirs
     for scope in (scopes.ATTENTION, scopes.MOE, scopes.ROUTER,
                   scopes.ROUTER_ROWS, scopes.EXPERTS, scopes.SHARED_EXPERT,

@@ -109,6 +109,28 @@ def _pattern():
     return step, (params, opt_state, batch)
 
 
+def _state_space():
+    """A Mamba-2 block whose shapes take the scan's kernels (heads of 64 in
+    groups that fill a lane tile, a state of 128, chunks of 128) before a
+    softmax layer, every block recomputed."""
+    from horovod_tpu.models.state_space import SsmConfig
+    cfg = transformer.TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=64, max_seq=128, dtype="float32", remat=True,
+        layer_pattern=(("state_space", None), ("attention", "dense")),
+        state_space=SsmConfig(n_heads=4, head_size=64, n_groups=2,
+                              state_size=128, chunk=128))
+    build, shard_batch = transformer.make_train_step(
+        cfg, _mesh((8, 1, 1), ("dp", "sp", "tp")), optax.adam(1e-2))
+    step, params, opt_state = build(
+        transformer.init_params(jax.random.PRNGKey(0), cfg))
+    tokens = np.random.RandomState(0).randint(
+        0, 64, size=(8, 128)).astype(np.int32)
+    batch = shard_batch({"tokens": tokens,
+                         "targets": np.roll(tokens, -1, axis=1)})
+    return step, (params, opt_state, batch)
+
+
 BUILDERS = {
     "make_data_parallel_step":
         lambda: _linear(hvd.make_data_parallel_step),
@@ -117,6 +139,7 @@ BUILDERS = {
     "make_finetune_step-mlm": lambda: _bert("mlm"),
     "transformer.make_train_step": _transformer,
     "transformer.make_train_step-pattern": _pattern,
+    "transformer.make_train_step-state_space": _state_space,
 }
 
 
@@ -203,6 +226,29 @@ def test_delta_rule_kernels_sit_under_the_core_in_their_pass(texts, kernel,
     assert phased
     assert all((BACKWARD in n) == backward for n in phased)
     assert backward or not any("checkpoint" in n for n in names)
+
+
+@pytest.mark.parametrize("kernel,backward", [(scopes.SSD_FWD, False),
+                                             (scopes.SSD_BWD, True)])
+def test_state_space_kernels_sit_under_the_core_in_their_pass(texts, kernel,
+                                                              backward):
+    """Each of the scan's two kernels under ``hvd.state_space/hvd.ssd_core``
+    with its ``name=``, in the lowered and in the compiled text.  The
+    backward one runs in the backward pass alone; the forward one in both,
+    because a recomputed block keeps nothing of its scan
+    (``state_space.SAVED``) and needs ``y`` again."""
+    path = "/".join([scopes.STATE_SPACE, scopes.SSD_CORE, kernel,
+                     scopes.kernel_name(kernel)])
+    lowered, compiled = texts("transformer.make_train_step-state_space")
+    assert path + "/pallas_call" in lowered
+    phased = [n for n in _op_names(compiled) if path in n and FORWARD in n]
+    assert phased
+    if backward:
+        assert all(BACKWARD in n for n in phased)
+    else:
+        assert any(BACKWARD not in n for n in phased)
+        assert any(BACKWARD in n and "rematted_computation" in n
+                   for n in phased)
 
 
 @pytest.mark.parametrize("backward", [False, True])
@@ -307,4 +353,4 @@ def test_one_vocabulary():
     for use in uses:
         assert use.startswith("scopes.") and use[7:] in constants, use
     assert all(re.fullmatch(r"hvd\.[a-z_]+", v) for v in constants.values())
-    assert len(set(constants.values())) == len(constants) == 24
+    assert len(set(constants.values())) == len(constants) == 26
