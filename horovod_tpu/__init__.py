@@ -20,6 +20,12 @@ so launcher-only hosts (``python -m horovod_tpu.runner``, including
 free.
 """
 
+import time as _time
+
+# Where hvd.import starts (epoch, perf_counter); horovod_tpu/jax/__init__.py
+# ends it.  Nothing heavier than the clock may be imported up here.
+_IMPORT_STARTED = (_time.time(), _time.perf_counter())
+
 __version__ = "0.1.0"
 
 # name -> (module, attr); attr None re-exports the symbol name itself.
@@ -48,7 +54,7 @@ for _mod, _names in (
     (".ops.engine", ("CollectiveHandle", "HorovodInternalError")),
     # Metrics plane: the live in-process snapshot (works without init —
     # the registry is process-local and always on).
-    (".common.metrics", ("metrics_snapshot",)),
+    (".common.metrics", ("metrics_snapshot", "span_records")),
 ):
     for _n in _names:
         _EXPORTS[_n] = (_mod, _n)

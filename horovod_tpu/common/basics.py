@@ -29,10 +29,12 @@ Controller modes (reference: MPI vs Gloo controller selection):
 from __future__ import annotations
 
 import atexit
+import contextlib
 import logging
 import threading
 from typing import List, Optional, Sequence
 
+from . import metrics, scopes
 from . import process_sets as _ps
 from .config import Config
 from .topology import Topology, inprocess_topology, multiprocess_topology
@@ -94,9 +96,12 @@ def init(devices: Optional[Sequence] = None,
         nm = notification_manager()
         nm.init()
         install_assignment(nm.rendezvous())
-    with _state.lock:
+    with _state.lock, contextlib.ExitStack() as spans:
         if _state.initialized:
             return
+        # hvd.init: what follows, once a world; its children below are
+        # where a slow start can hide (common/scopes.py, host spans).
+        spans.enter_context(metrics.span(scopes.INIT))
         config = Config.from_env()
         logging.basicConfig()
         LOG.setLevel(_LOG_LEVELS.get(config.log_level, logging.WARNING))
@@ -118,23 +123,32 @@ def init(devices: Optional[Sequence] = None,
             from .device import place_compile_cache
             place_compile_cache()
 
-        # Collective-plan plane (persistent autotuned plans): fresh
-        # state per init — an elastic re-init re-loads/adopts against
-        # the (possibly resized) world's fingerprint.
         from ..utils import plancache
-        plancache.reset()
+
+        def plan_bootstrap():
+            # Collective-plan plane (persistent autotuned plans): fresh
+            # state per init — an elastic re-init re-loads/adopts
+            # against the (possibly resized) world's fingerprint.
+            with metrics.span(scopes.INIT_PLAN):
+                plancache.reset()
+                plancache.bootstrap(config, _state.topology, mode)
 
         if mode == "inprocess":
             import jax
             from ..ops.engine import CollectiveEngine
-            devs = list(devices) if devices is not None else list(jax.devices())
+            with metrics.span(scopes.INIT_DEVICES):
+                # The runtime reaching the chip, when this is what first
+                # touches it.
+                devs = (list(devices) if devices is not None
+                        else list(jax.devices()))
             _state.topology = inprocess_topology(devs)
             # Plan bootstrap BEFORE the engine: the cached tuned
             # operating point must land in config before the cycle
             # loop reads it.
-            plancache.bootstrap(config, _state.topology, mode)
-            _state.engine = CollectiveEngine(
-                devs, config, timeline, _resolve_process_set_ranks)
+            plan_bootstrap()
+            with metrics.span(scopes.INIT_ENGINE):
+                _state.engine = CollectiveEngine(
+                    devs, config, timeline, _resolve_process_set_ranks)
             if config.autotune:
                 from ..utils.autotune import ParameterManager
                 _state.engine.parameter_manager = ParameterManager(
@@ -153,41 +167,43 @@ def init(devices: Optional[Sequence] = None,
                 # Payload plane first: join the global JAX runtime so
                 # jax.devices() spans the world before any mesh builds.
                 from .multihost import init_jax_distributed
-                init_jax_distributed(config, _state.topology.rank,
-                                     _state.topology.size)
+                with metrics.span(scopes.INIT_DEVICES):
+                    init_jax_distributed(config, _state.topology.rank,
+                                         _state.topology.size)
             # Plan bootstrap: rank 0 loads its cache and publishes to
             # the rendezvous KV; other members adopt the published
             # copy so every member routes identically (late joiners
             # and respawned workers warm-start from the pod's
             # best-known plan instead of re-tuning).
-            plancache.bootstrap(config, _state.topology, mode)
-            _state.tcp_core = TcpCore(_state.topology, config)
-            try:
-                _state.tcp_core.initialize()
-            except BaseException:
-                # Elastic re-init can race a world change; release the
-                # half-bootstrapped core so a retry starts clean.
+            plan_bootstrap()
+            with metrics.span(scopes.INIT_ENGINE):
+                _state.tcp_core = TcpCore(_state.topology, config)
                 try:
-                    _state.tcp_core.shutdown()
-                except Exception:  # noqa: BLE001
-                    pass
-                _state.tcp_core = None
-                raise
-            ws = plancache.tuned_warm_start()
-            if ws is not None:
-                # Native warm start, NOT gated on config.autotune: the
-                # controller reads params_->fusion_threshold() every
-                # negotiation round whether or not the tuner samples,
-                # so a rerun with autotuning off still runs AT the
-                # cached operating point (the natural "reuse the tuned
-                # plan" rerun).  Rank 0's coordinator broadcasts the
-                # values; a harmless store on workers.
-                _state.tcp_core.autotune_warm_start(*ws)
-            if mode == "multihost":
-                from ..ops.multihost import MultihostEngine
-                _state.mh_engine = MultihostEngine(
-                    _state.tcp_core, config, timeline,
-                    _resolve_process_set_ranks)
+                    _state.tcp_core.initialize()
+                except BaseException:
+                    # Elastic re-init can race a world change; release
+                    # the half-bootstrapped core so a retry starts clean.
+                    try:
+                        _state.tcp_core.shutdown()
+                    except Exception:  # noqa: BLE001
+                        pass
+                    _state.tcp_core = None
+                    raise
+                ws = plancache.tuned_warm_start()
+                if ws is not None:
+                    # Native warm start, NOT gated on config.autotune:
+                    # the controller reads params_->fusion_threshold()
+                    # every negotiation round whether or not the tuner
+                    # samples, so a rerun with autotuning off still runs
+                    # AT the cached operating point (the natural "reuse
+                    # the tuned plan" rerun).  Rank 0's coordinator
+                    # broadcasts the values; a harmless store on workers.
+                    _state.tcp_core.autotune_warm_start(*ws)
+                if mode == "multihost":
+                    from ..ops.multihost import MultihostEngine
+                    _state.mh_engine = MultihostEngine(
+                        _state.tcp_core, config, timeline,
+                        _resolve_process_set_ranks)
         else:
             raise ValueError("unknown controller mode %r" % mode)
 

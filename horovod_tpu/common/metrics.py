@@ -24,6 +24,14 @@ operator's Prometheus) can actually consume:
   corruption) to ``HOROVOD_METRICS_DIR``: atomic ``O_APPEND`` writes,
   rank-stamped, per-process monotonic ``seq``, mirrored into the
   ``events_total`` counter.  Unset dir = counters only, no IO.
+* **Host spans** — ``span(name)`` times a piece of the program's own
+  work on the host: at once an observation of the one histogram family
+  ``hvd_span_seconds{span}``, a record with a start, an end and a
+  parent in a bounded in-memory list (``span_records()``,
+  ``hvd.span_records()``), and a ``jax.profiler.TraceAnnotation``, so
+  that under any profile the span lies on the profiler's host lines,
+  on the device trace's clock.  The names are the host section of
+  ``common/scopes.py``.
 
 Label cardinality is bounded per family by
 ``HOROVOD_METRICS_MAX_SERIES`` (default 256): past the cap new label
@@ -42,13 +50,18 @@ in any test that touches the seam.
 
 from __future__ import annotations
 
+import collections
+import functools
+import itertools
 import json
 import logging
 import os
+import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
+from . import scopes
 from .envutil import env_int
 
 LOG = logging.getLogger("horovod_tpu.metrics")
@@ -281,6 +294,22 @@ NAMES: Dict[str, Tuple[str, str]] = {
                    "ssd_chunked) by the form their shapes took, labeled "
                    "form (kernel|xla); counted as a scan is traced, like "
                    "hvd_flash_backward_calls_total"),
+    # -- lifecycle: the program's own set-up, timed on the host --
+    "hvd_span_seconds": (
+        "histogram", "wall time of one host span, labeled span (the host "
+                     "section of common/scopes.py: hvd.init and its "
+                     "children, hvd.mesh, hvd.build_state, "
+                     "hvd.optimizer_init, hvd.broadcast, hvd.shard_batch, "
+                     "hvd.import, and JAX's compile stages as "
+                     "hvd.compile_trace|lower|backend|cache_read); the "
+                     "same spans with start, end and parent are "
+                     "hvd.span_records()"),
+    "hvd_compile_programs_total": (
+        "counter", "executables this process built or loaded since "
+                   "place_compile_cache() first ran, labeled cache (hit = "
+                   "read from the persistent compile cache, miss = "
+                   "compiled); one a backend_compile_duration event of "
+                   "JAX, so it equals the hvd.compile_backend records"),
     # -- cross-cutting --
     "stall_detected_total": (
         "counter", "stall-inspector warnings (a collective outlived "
@@ -575,6 +604,134 @@ def approx_quantile(model: Dict[str, Any], name: str, q: float,
     return 2.0 ** _HIST_EXP_MAX
 
 
+# -- host spans -------------------------------------------------------------
+
+# How many finished spans the process remembers; the oldest goes first.
+# A start-up leaves a few dozen spans and three or four compile records a
+# program (a few hundred in the transformer cells' set-up).
+SPAN_RECORDS_MAX = 8192
+
+
+class SpanRecord(NamedTuple):
+    """One finished span.  ``start`` and ``end`` are epoch seconds (the
+    length is ``time.perf_counter``'s); ``parent`` is the ``id`` of the
+    span that was open on the same thread, or None."""
+    id: int
+    parent: Optional[int]
+    name: str
+    start: float
+    end: float
+    attributes: Dict[str, Any]
+
+
+class _OpenSpans(threading.local):
+    """This thread's open span ids, innermost last."""
+
+    def __init__(self):
+        self.stack: List[int] = []
+
+
+_span_ids = itertools.count(1)      # next() is atomic under the GIL
+_span_records: "collections.deque[SpanRecord]" = collections.deque(
+    maxlen=SPAN_RECORDS_MAX)
+_span_open = _OpenSpans()
+
+
+def _span_name(name: str) -> str:
+    if name not in scopes.host_spans:
+        raise KeyError(
+            "span %r is not in the host section of common/scopes.py "
+            "(host_spans); name it there first" % (name,))
+    return name
+
+
+def _keep_span(record: SpanRecord):
+    histogram("hvd_span_seconds", span=record.name).observe(
+        record.end - record.start)
+    _span_records.append(record)
+
+
+def record_span(name: str, start: float, end: float, **attributes):
+    """A span that something else timed (JAX's compile stages, the
+    package's import), as the record and the observation ``span`` makes
+    of its own; the span open on this thread is its parent."""
+    stack = _span_open.stack
+    _keep_span(SpanRecord(
+        next(_span_ids), stack[-1] if stack else None, _span_name(name),
+        start, end, attributes))
+
+
+class span:
+    """``with metrics.span(scopes.INIT):`` or ``@metrics.span(...)`` round
+    a function: host time of the program's own work (module docstring).
+    ``attributes`` (counts: leaves placed, ...) go into the record and may
+    be added to while the span is open (``with ... as s:
+    s.attributes["leaves"] = n``).  The span closes and records whether
+    its body returns or raises."""
+
+    def __init__(self, name: str, **attributes):
+        self.name = _span_name(name)
+        self.attributes = attributes
+
+    def __enter__(self):
+        self._id = next(_span_ids)
+        stack = _span_open.stack
+        self._parent = stack[-1] if stack else None
+        stack.append(self._id)
+        self._annotation = None
+        if "jax" in sys.modules:
+            # Never the reason jax is imported: a tcp worker with host
+            # payloads only runs without it.
+            import jax
+            self._annotation = jax.profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
+        self._start = time.time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        seconds = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        _span_open.stack.pop()
+        _keep_span(SpanRecord(
+            self._id, self._parent, self.name, self._start,
+            self._start + seconds, self.attributes))
+        return False
+
+    def __call__(self, fn):
+        @functools.wraps(fn)
+        def timed(*args, **kwargs):
+            with span(self.name, **self.attributes):
+                return fn(*args, **kwargs)
+        return timed
+
+
+def span_records() -> List[SpanRecord]:
+    """The finished spans this process remembers, oldest first, as a copy
+    (``hvd.span_records()``).  Works before/without ``hvd.init()``."""
+    return list(_span_records)
+
+
+def span_self_seconds(records: List[SpanRecord]) -> Dict[int, float]:
+    """``{id: seconds}``: each record's length less what its children
+    among ``records`` cover of it (children may overlap one another: the
+    compile stages of nested ``jit``s do)."""
+    children: Dict[Optional[int], List[SpanRecord]] = {}
+    for r in records:
+        children.setdefault(r.parent, []).append(r)
+    out = {}
+    for r in records:
+        covered, reach = 0.0, r.start
+        for c in sorted(children.get(r.id, ()), key=lambda c: c.start):
+            lo, hi = max(c.start, reach), min(c.end, r.end)
+            if hi > lo:
+                covered += hi - lo
+                reach = hi
+        out[r.id] = (r.end - r.start) - covered
+    return out
+
+
 # -- Prometheus text rendering --------------------------------------------
 
 
@@ -789,10 +946,11 @@ def iter_events(d: Optional[str] = None, merged: bool = False):
 
 
 def reset():
-    """Drop every series, the journal fd cache and the seq counter
-    (tests)."""
+    """Drop every series, the finished spans, the journal fd cache and
+    the seq counter (tests)."""
     global _journal_seq, _journal_tag, _journal_warned
     _registry.reset()
+    _span_records.clear()
     with _journal_lock:
         for fd in _journal_fds.values():
             try:

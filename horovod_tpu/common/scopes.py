@@ -1,4 +1,6 @@
-"""The names the compiled step gives its own parts.
+"""The names the program gives its own parts: the compiled step's on the
+device (``jax.named_scope``), and its own work's on the host
+(``metrics.span``).
 
 A ``jax.named_scope`` is metadata written while a step is traced: every
 operation traced under it carries the scope in its ``op_name``
@@ -45,6 +47,36 @@ inside ``KDA_CORE``); ``SSD_FWD`` and ``SSD_BWD`` (the state-space scan's
 two, inside ``SSD_CORE``); ``kernel_name`` gives the same words as the ``name=``
 of the call (``hvd_flash_fwd``), which is what the trace viewer prints for
 a Mosaic kernel.
+
+Host spans (``common/metrics.py: span``; ``host_spans`` is the whole
+list, and a name outside it raises there).  They time the program's
+set-up, nothing inside a step; ``docs/observability.md`` ("Host spans")
+has the table:
+
+* ``IMPORT``       the package's first line to the last of
+                   ``horovod_tpu/jax/__init__.py``, once a process
+* ``INIT``         the body of ``hvd.init()`` that runs when not yet
+                   initialised; inside it ``INIT_DEVICES`` (the runtime
+                   reaching its devices: ``jax.devices()``, or
+                   ``jax.distributed`` in a multihost world),
+                   ``INIT_PLAN`` (the collective-plan cache) and
+                   ``INIT_ENGINE`` (the collective engine, or the native
+                   core and the multihost engine)
+* ``MESH``         ``jax/mesh.py: create_mesh``
+* ``BUILD_STATE``  a model's ``build(params_host)``: parameters and
+                   optimizer state placed on the mesh; inside it
+                   ``OPTIMIZER_INIT`` round ``optimizer.init`` (also
+                   what ``make_data_parallel_step`` hands back)
+* ``BROADCAST``    ``broadcast_parameters`` / ``_optimizer_state`` /
+                   ``_object``
+* ``SHARD_BATCH``  the three ``shard_batch``s
+* ``COMPILE_TRACE``, ``COMPILE_LOWER``, ``COMPILE_BACKEND``,
+  ``COMPILE_CACHE_READ``  JAX's own stage events kept as spans
+                   (``common/device.py: place_compile_cache``): tracing
+                   to a jaxpr, lowering to a module, building or loading
+                   the executable (the cache's key, read and load
+                   included), and inside that the read from the
+                   persistent cache
 """
 
 from __future__ import annotations
@@ -75,6 +107,28 @@ KDA_FWD = "hvd.kda_fwd"
 KDA_BWD = "hvd.kda_bwd"
 SSD_FWD = "hvd.ssd_fwd"
 SSD_BWD = "hvd.ssd_bwd"
+
+IMPORT = "hvd.import"
+INIT = "hvd.init"
+INIT_DEVICES = "hvd.init_devices"
+INIT_PLAN = "hvd.init_plan"
+INIT_ENGINE = "hvd.init_engine"
+MESH = "hvd.mesh"
+BUILD_STATE = "hvd.build_state"
+OPTIMIZER_INIT = "hvd.optimizer_init"
+BROADCAST = "hvd.broadcast"
+SHARD_BATCH = "hvd.shard_batch"
+COMPILE_TRACE = "hvd.compile_trace"
+COMPILE_LOWER = "hvd.compile_lower"
+COMPILE_BACKEND = "hvd.compile_backend"
+COMPILE_CACHE_READ = "hvd.compile_cache_read"
+
+# Lower case: every upper-case name of this module is one scope's or one
+# span's string, and tests walk them.
+host_spans = frozenset((
+    IMPORT, INIT, INIT_DEVICES, INIT_PLAN, INIT_ENGINE, MESH, BUILD_STATE,
+    OPTIMIZER_INIT, BROADCAST, SHARD_BATCH, COMPILE_TRACE, COMPILE_LOWER,
+    COMPILE_BACKEND, COMPILE_CACHE_READ))
 
 
 def kernel_name(scope: str) -> str:

@@ -15,7 +15,7 @@ from ..common.basics import (init, shutdown, is_initialized, rank, size,
                              mpi_built, nccl_built, ccl_built, ddl_built,
                              cuda_built, rocm_built, mpi_enabled,
                              mpi_threads_supported)
-from ..common.metrics import metrics_snapshot
+from ..common.metrics import metrics_snapshot, span_records
 from ..common.process_sets import (ProcessSet, global_process_set,
                                    add_process_set, remove_process_set,
                                    process_set_by_id, process_set_ids)
@@ -53,3 +53,19 @@ Min = MIN
 Max = MAX
 Product = PRODUCT
 Adasum = ADASUM
+
+
+def _record_import():
+    """``hvd.import``: the package's first line to here, once a process (a
+    record and an observation; too early to be a profiler annotation)."""
+    import time
+
+    from .. import _IMPORT_STARTED
+    from ..common import metrics, scopes
+    epoch, t0 = _IMPORT_STARTED
+    metrics.record_span(scopes.IMPORT, epoch,
+                        epoch + time.perf_counter() - t0)
+
+
+_record_import()
+del _record_import

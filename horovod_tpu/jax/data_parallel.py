@@ -29,7 +29,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..common import basics, scopes
+from ..common import basics, metrics, scopes
 from ..ops.xla_ops import AVERAGE, host_or_device
 from . import spmd
 from .compression import Compression
@@ -82,6 +82,7 @@ def _world_mesh():
     return basics._get_engine().collectives_for(0).mesh
 
 
+@metrics.span(scopes.SHARD_BATCH)
 def shard_batch(batch):
     """Device-put a pytree so leaf dim 0 is sharded across the world.
 
@@ -164,6 +165,7 @@ def make_data_parallel_step(loss_fn: Callable,
     donate_args = (0, 1) if donate else ()
     jitted = jax.jit(mapped, donate_argnums=donate_args)
 
+    @metrics.span(scopes.OPTIMIZER_INIT)
     def init(params):
         return dist_opt.init(params)
 

@@ -14,7 +14,7 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
-from ..common import basics
+from ..common import basics, metrics, scopes
 from ..common.process_sets import ProcessSet
 from ..ops import api as eager
 from ..ops.xla_ops import host_or_device
@@ -41,20 +41,21 @@ def broadcast_parameters(params, root_rank: int = 0,
     step afterwards, place them on the global mesh with
     ``data_parallel.replicate`` (see examples/multihost_pod_training.py).
     """
-    if basics._controller_is_spmd():
-        return _replicate(params)
     leaves, treedef = jax.tree.flatten(params)
-    handles = [eager.broadcast_async(
-        g, root_rank, name="broadcast_parameters/%d" % i,
-        process_set=process_set) for i, g in enumerate(leaves)]
-    return jax.tree.unflatten(treedef, [h.wait() for h in handles])
+    with metrics.span(scopes.BROADCAST, leaves=len(leaves)):
+        if basics._controller_is_spmd():
+            return _replicate(params)
+        handles = [eager.broadcast_async(
+            g, root_rank, name="broadcast_parameters/%d" % i,
+            process_set=process_set) for i, g in enumerate(leaves)]
+        return jax.tree.unflatten(treedef, [h.wait() for h in handles])
 
 
 def broadcast_optimizer_state(opt_state, root_rank: int = 0,
                               process_set: Optional[ProcessSet] = None):
     """Broadcast optax optimizer state (reference
     ``broadcast_optimizer_state``); same mechanics as parameters since
-    optax state is a pytree."""
+    optax state is a pytree, and the same ``hvd.broadcast`` span."""
     return broadcast_parameters(opt_state, root_rank, process_set)
 
 
@@ -64,18 +65,19 @@ def broadcast_object(obj: Any, root_rank: int = 0,
     """Pickle-broadcast an arbitrary python object from root to all ranks
     (reference ``hvd.broadcast_object``): the payload travels as a uint8
     tensor through the same collective path as tensors do."""
-    if basics._controller_is_spmd():
-        # Single controller: root's object IS the object; round-trip the
-        # bytes through a device broadcast for wire parity.
-        payload = np.frombuffer(pickle.dumps(obj), dtype=np.uint8)
-        size = basics.size()
-        stacked = np.tile(payload, (size, 1))
-        out = eager.broadcast(stacked, root_rank,
-                              name=name or "broadcast_object",
-                              process_set=process_set)
-        return pickle.loads(np.asarray(out).tobytes())
-    core = basics._get_tcp_core()
-    return core.broadcast_object(obj, root_rank, name=name)
+    with metrics.span(scopes.BROADCAST, leaves=1):
+        if basics._controller_is_spmd():
+            # Single controller: root's object IS the object; round-trip
+            # the bytes through a device broadcast for wire parity.
+            payload = np.frombuffer(pickle.dumps(obj), dtype=np.uint8)
+            size = basics.size()
+            stacked = np.tile(payload, (size, 1))
+            out = eager.broadcast(stacked, root_rank,
+                                  name=name or "broadcast_object",
+                                  process_set=process_set)
+            return pickle.loads(np.asarray(out).tobytes())
+        core = basics._get_tcp_core()
+        return core.broadcast_object(obj, root_rank, name=name)
 
 
 def allgather_object(obj: Any, name: Optional[str] = None,

@@ -25,9 +25,12 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from ..common import metrics, scopes
+
 __all__ = ["create_mesh", "create_hybrid_mesh"]
 
 
+@metrics.span(scopes.MESH)
 def create_mesh(axis_shapes: Sequence[int],
                 axis_names: Sequence[str],
                 devices: Optional[Sequence] = None) -> Mesh:

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common import scopes
+from ..common import metrics, scopes
 from ..ops import pallas_kernels
 from .transformer import (_sharded_embed_lookup, opt_spec_tree,
                           vocab_parallel_cross_entropy)
@@ -307,16 +307,20 @@ def make_finetune_step(cfg: BertConfig, mesh, optimizer,
         return params, opt_state, loss
 
     def build(params_host):
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-            params_host, specs)
-        opt_state = optimizer.init(params)
-        # Optimizer subtrees isomorphic to params inherit param specs.
-        o_specs = opt_spec_tree(opt_state, params_host, specs)
-        opt_state = jax.tree.map(
-            lambda x, s: jax.device_put(jnp.asarray(x),
-                                        NamedSharding(mesh, s))
-            if hasattr(x, "shape") else x, opt_state, o_specs)
+        with metrics.span(scopes.BUILD_STATE) as placed:
+            params = jax.tree.map(
+                lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+                params_host, specs)
+            with metrics.span(scopes.OPTIMIZER_INIT):
+                opt_state = optimizer.init(params)
+            # Optimizer subtrees isomorphic to params inherit param specs.
+            o_specs = opt_spec_tree(opt_state, params_host, specs)
+            opt_state = jax.tree.map(
+                lambda x, s: jax.device_put(jnp.asarray(x),
+                                            NamedSharding(mesh, s))
+                if hasattr(x, "shape") else x, opt_state, o_specs)
+            placed.attributes["leaves"] = len(
+                jax.tree.leaves((params, opt_state)))
 
         def make(batch_keys):
             bspec = {k: batch_specs[k] for k in batch_keys}
@@ -338,6 +342,7 @@ def make_finetune_step(cfg: BertConfig, mesh, optimizer,
 
         return step, params, opt_state
 
+    @metrics.span(scopes.SHARD_BATCH)
     def shard_batch(batch):
         from jax.sharding import NamedSharding
         return {k: jax.device_put(jnp.asarray(v),

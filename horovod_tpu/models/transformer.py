@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common import scopes
+from ..common import metrics, scopes
 from ..ops import pallas_kernels
 from ..parallel.moe import SAVED as moe_saved_names
 from ..parallel.moe import (ExpertShare, MoeConfig, expert_share_ffn,
@@ -814,16 +814,20 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer,
         return params, opt_state, loss
 
     def build(params_host):
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-            params_host, specs)
-        opt_state = optimizer.init(params_host)
-        o_specs = opt_spec_tree(opt_state, params_host, specs)
-        opt_state = jax.tree.map(
-            lambda x, s: jax.device_put(jnp.asarray(x),
-                                        NamedSharding(mesh, s))
-            if hasattr(x, "shape") else x,
-            opt_state, o_specs)
+        with metrics.span(scopes.BUILD_STATE) as placed:
+            params = jax.tree.map(
+                lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+                params_host, specs)
+            with metrics.span(scopes.OPTIMIZER_INIT):
+                opt_state = optimizer.init(params_host)
+            o_specs = opt_spec_tree(opt_state, params_host, specs)
+            opt_state = jax.tree.map(
+                lambda x, s: jax.device_put(jnp.asarray(x),
+                                            NamedSharding(mesh, s))
+                if hasattr(x, "shape") else x,
+                opt_state, o_specs)
+            placed.attributes["leaves"] = len(
+                jax.tree.leaves((params, opt_state)))
         mapped = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(specs, o_specs, batch_spec),
@@ -832,6 +836,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer,
         step = jax.jit(mapped, donate_argnums=(0, 1) if donate else ())
         return step, params, opt_state
 
+    @metrics.span(scopes.SHARD_BATCH)
     def shard_batch(batch):
         return jax.tree.map(
             lambda x, s: jax.device_put(jnp.asarray(x),

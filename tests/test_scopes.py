@@ -337,20 +337,40 @@ def test_make_data_parallel_step_returns_the_jit_object(hvd_world):
 
 
 def test_one_vocabulary():
-    """Every ``jax.named_scope`` in the program takes its name from
-    ``common/scopes.py``, and every name there is ``hvd.<word>``."""
+    """Every ``jax.named_scope`` and every ``metrics.span`` in the program
+    takes its name from ``common/scopes.py``, a device scope the one and a
+    host span the other, and every name there is ``hvd.<word>``."""
     root = os.path.dirname(os.path.abspath(hvd.__file__))
     root = os.path.dirname(root)
-    uses = []
+    uses, host_uses = [], []
     for dirpath, _, files in os.walk(root):
         for name in files:
             if not name.endswith(".py"):
                 continue
             with open(os.path.join(dirpath, name)) as f:
-                uses += re.findall(r"named_scope\(([^)]*)\)", f.read())
-    constants = {k: v for k, v in vars(scopes).items() if k.isupper()}
+                text = f.read()
+            uses += re.findall(r"named_scope\(([^)]*)\)", text)
+            if name != "metrics.py":        # the primitive's own module
+                host_uses += re.findall(
+                    r"metrics\.(?:span|record_span)\(\s*([^,)]*)", text)
+    constants = {k: v for k, v in vars(scopes).items()
+                 if k.isupper() and isinstance(v, str)}
+    host = {k: v for k, v in constants.items() if v in scopes.host_spans}
+    device = {k: v for k, v in constants.items() if k not in host}
     assert len(uses) >= 10
     for use in uses:
-        assert use.startswith("scopes.") and use[7:] in constants, use
+        assert use.startswith("scopes.") and use[7:] in device, use
+    # The one use by a variable: common/device.py keeps JAX's compile
+    # stages as spans through its table of their names.
+    from horovod_tpu.common import device as device_module
+    assert host_uses.count("name") == 1
+    host_uses.remove("name")
+    stages = set(device_module._COMPILE_STAGES.values())
+    assert len(host_uses) >= 14
+    for use in host_uses:
+        assert use.startswith("scopes.") and use[7:] in host, use
+    # Every host name is opened somewhere.
+    assert {host[use[7:]] for use in host_uses} | stages == scopes.host_spans
     assert all(re.fullmatch(r"hvd\.[a-z_]+", v) for v in constants.values())
-    assert len(set(constants.values())) == len(constants) == 26
+    assert len(set(constants.values())) == len(constants)
+    assert len(device) == 26 and len(host) == len(scopes.host_spans) == 14
