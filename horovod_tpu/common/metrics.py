@@ -289,6 +289,13 @@ NAMES: Dict[str, Tuple[str, str]] = {
                    "window (0|1); counted as a call is traced, so once "
                    "for every time a layer scan or a recomputation "
                    "traces it and never again for a compiled step"),
+    "hvd_flash_block_pairs_total": (
+        "counter", "block pairs a flat head's grid visits in a full "
+                   "(un-windowed) flash call, labeled kernel "
+                   "(fwd|dq|dkv|onepass) + kind (interior = seen whole, "
+                   "no mask; diagonal = cut by the causal mask); the "
+                   "call's schedule, counted as its kernel is traced, like "
+                   "hvd_flash_backward_calls_total"),
     "hvd_ssd_scan_calls_total": (
         "counter", "state-space scans (models/state_space.py: "
                    "ssd_chunked) by the form their shapes took, labeled "

@@ -25,6 +25,7 @@ from __future__ import annotations
 import functools
 import logging
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,89 @@ _NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------
+
+# A full call's grid is a SCHEDULE of block pairs, made as the call is traced:
+# the pairs that hold any work and no others (under the causal mask the ones
+# that meet the triangle: 72 of a head's 128 at 8192 positions in blocks of
+# 512 x 1024), a pair a step along one sequential grid axis.  The tables that
+# say which pair a step visits go in as scalar prefetch, the index maps read
+# the blocks from them, and in the forward kernel a pair the diagonal does
+# not cut takes the body without the mask.
+
+_FIRST, _LAST, _CROSSES = 1, 2, 4       # a pair's flags, as the kernels read them
+
+
+class _Pairs(NamedTuple):
+    """Block pairs in the order a grid visits them, a row after another."""
+    q: np.ndarray           # the pair's query block
+    k: np.ndarray           # and its key block
+    first: np.ndarray       # first of its row: the row's scratch starts here
+    last: np.ndarray        # last of its row: the row's output is written
+    crosses: np.ndarray     # the diagonal cuts the pair: it needs the mask
+
+
+@functools.lru_cache(maxsize=None)
+def _block_schedule(seq: int, block_q: int, block_k: int, causal: bool):
+    """``(by query block, by key block)``: the block pairs of a full call in
+    the two orders its kernels sweep them.  By query block (forward, dq) a
+    row is a query block with its key blocks ascending; by key block (dk/dv
+    and the one backward kernel) a key block with its query blocks
+    ascending.  A causal call has the pairs with a key at or before a query
+    and no others; a pair with a key after one of its queries crosses the
+    diagonal.  Without the mask every pair is there and none crosses."""
+    nq, nk = seq // block_q, seq // block_k
+
+    def live(j, t):
+        return not causal or t * block_k <= j * block_q + block_q - 1
+
+    def crosses(j, t):
+        return causal and t * block_k + block_k - 1 > j * block_q
+
+    def sweep(rows):
+        out = []
+        for row in rows:
+            row = [pair for pair in row if live(*pair)]
+            out += [(j, t, n == 0, n == len(row) - 1, crosses(j, t))
+                    for n, (j, t) in enumerate(row)]
+        return _Pairs(*(np.asarray(column) for column in zip(*out)))
+
+    return (sweep([[(j, t) for t in range(nk)] for j in range(nq)]),
+            sweep([[(j, t) for j in range(nq)] for t in range(nk)]))
+
+
+def _schedule_tables(pairs: _Pairs, kernel: str):
+    """The scalar-prefetch operands of a scheduled grid (query block, key
+    block and flags of every step), counted as they are made: once for every
+    time a call's kernel is traced, like ``hvd_flash_backward_calls_total``."""
+    diagonal = int(pairs.crosses.sum())
+    for kind, n in (("interior", len(pairs.q) - diagonal),
+                    ("diagonal", diagonal)):
+        metrics.counter("hvd_flash_block_pairs_total", kernel=kernel,
+                        kind=kind).inc(n)
+    flags = _FIRST * pairs.first + _LAST * pairs.last \
+        + _CROSSES * pairs.crosses
+    return tuple(jnp.asarray(table, jnp.int32)
+                 for table in (pairs.q, pairs.k, flags))
+
+
+def _scheduled_pair(q_blocks, k_blocks, flags):
+    """``(query block, key block, first, last, crosses)`` of the pair this
+    step of a scheduled grid ``(bh, pair)`` visits."""
+    step = pl.program_id(1)
+    flag = flags[step]
+    return (q_blocks[step], k_blocks[step], (flag & _FIRST) != 0,
+            (flag & _LAST) != 0, (flag & _CROSSES) != 0)
+
+
+def _scheduled_specs(block_q: int, block_k: int, d: int, heads: int):
+    """``(query, key, row statistics)`` BlockSpecs of a scheduled grid: the
+    tables say which block of the sequence a step takes."""
+    def spec(rows, width, table):
+        return pl.BlockSpec(
+            (heads, rows, width),
+            lambda i, step, *tables: (i, tables[table][step], 0))
+    return spec(block_q, d, 0), spec(block_k, d, 1), spec(block_q, 1, 0)
+
 
 # A window of ``w`` keys (sliding-window attention): query ``i`` sees key
 # ``j`` when ``i - w < j <= i``, itself among them.  The kernels below run
@@ -110,33 +194,32 @@ def _seen_in_block(j, kb, block_q: int, block_k: int, window):
         < jnp.uint32(window)
 
 
-def _flash_attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
-                       l_scr, acc_scr, *, block_q: int, block_k: int,
-                       causal: bool, window=None):
-    # grid = (bh, nq, nk): K/V stream through VMEM one block per inner
-    # step (double-buffered by the Pallas pipeline); the online-softmax
-    # state (m, l, acc) persists in VMEM scratch across the inner axis.
-    # Under a window the inner axis counts from the band's first block.
-    j = pl.program_id(1)
-    t = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _flash_attn_kernel(*refs, block_q: int, block_k: int, causal: bool,
+                       window=None):
+    # K/V stream through VMEM one block a step (double-buffered by the
+    # Pallas pipeline); the online-softmax state (m, l, acc) persists in
+    # VMEM scratch across a query block's steps.  A full call's grid is
+    # (bh, pair) with the schedule's tables ahead of the blocks; a banded
+    # call's is (bh, nq, step) and its inner axis counts from the band's
+    # first block.
+    banded = window is not None
+    if banded:
+        j = pl.program_id(1)
+        t = pl.program_id(2)
+        nk = pl.num_programs(2)
+        kb = _band_first_k(j, block_q, block_k, window) + t
+    else:
+        j, kb, first, last, crosses = _scheduled_pair(*refs[:3])
+        refs = refs[3:]
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
 
-    kb = t if window is None else \
-        _band_first_k(j, block_q, block_k, window) + t
-
-    @pl.when(t == 0)
+    @pl.when(t == 0 if banded else first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: blocks entirely above the diagonal contribute nothing
-    block_live = jnp.logical_or(
-        jnp.logical_not(causal),
-        kb * block_k <= j * block_q + block_q - 1)
-
-    @pl.when(block_live)
-    def _update():
+    def _update(masked: bool):
         # matmuls stay in the input dtype (bf16 hits the MXU at full
         # rate; accumulation is f32 via preferred_element_type)
         # q arrives PRE-SCALED by 1/sqrt(d) (one cheap (BH,S,D) pass
@@ -145,7 +228,7 @@ def _flash_attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (BQ, BK)
-        if causal:
+        if masked:
             # A row that a banded block hides whole reads exp(0) here; the
             # block that holds its diagonal comes later and its correction
             # exp(-1e30 - m) wipes that out.
@@ -162,7 +245,23 @@ def _flash_attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
             p.astype(v_ref.dtype), v_ref[0],
             preferred_element_type=jnp.float32)
 
-    @pl.when(t == nk - 1)
+    if banded:
+        # a step past the band's end (its block's index held) adds nothing
+        in_band = jnp.logical_or(
+            jnp.logical_not(causal),
+            kb * block_k <= j * block_q + block_q - 1)
+        pl.when(in_band)(lambda: _update(True))
+    elif causal:
+        # A pair the diagonal does not cut is seen whole and takes the body
+        # without the mask: 2.4 % of this kernel's time, which the vector
+        # unit sets.  (The backward kernels, nearer the MXU's time, gain
+        # nothing from a second body and mask every pair; PERF.md, PR 36.)
+        pl.when(crosses)(lambda: _update(True))
+        pl.when(jnp.logical_not(crosses))(lambda: _update(False))
+    else:
+        _update(False)
+
+    @pl.when(t == nk - 1 if banded else last)
     def _finish():
         o_ref[0] = (acc_scr[:] /
                     jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
@@ -193,9 +292,10 @@ class _HeadOf:
         self.ref[self._at(index)] = value
 
 
-def _heads_a_step(kernel, heads: int):
+def _heads_a_step(kernel, heads: int, tables: int = 0):
     """``kernel`` (written for blocks of one flat head) over blocks and
-    scratch of ``heads``, a head after another.  A banded call's grid steps
+    scratch of ``heads``, a head after another; a scheduled grid's
+    ``tables`` come first and are every head's.  A banded call's grid steps
     are short (two key blocks a query block where a full call has nine), so
     what a step costs whatever it computes weighs more, and several heads a
     step divide it."""
@@ -204,7 +304,8 @@ def _heads_a_step(kernel, heads: int):
 
     def body(*refs):
         for g in range(heads):
-            kernel(*(_HeadOf(ref, g) for ref in refs))
+            kernel(*refs[:tables],
+                   *(_HeadOf(ref, g) for ref in refs[tables:]))
     return body
 
 
@@ -216,12 +317,9 @@ def _sds(shape, dtype, like):
 
 
 def _k_spec(block_q: int, block_k: int, d: int, window, heads: int = 1):
-    """Key/value blocks of a grid ``(bh, query block, key step)``: every
-    key block in turn, or under a window the band's blocks (its last one
-    held for the steps a narrower query block has left over)."""
-    if window is None:
-        return pl.BlockSpec((heads, block_k, d), lambda i, j, t: (i, t, 0))
-
+    """Key/value blocks of a banded grid ``(bh, query block, key step)``:
+    the band's blocks (its last one held for the steps a narrower query
+    block has left over)."""
     def index(i, j, t):
         return (i, jnp.minimum(
             _band_first_k(j, block_q, block_k, window) + t,
@@ -229,9 +327,23 @@ def _k_spec(block_q: int, block_k: int, d: int, window, heads: int = 1):
     return pl.BlockSpec((heads, block_k, d), index)
 
 
-def _k_steps(seq: int, block_q: int, block_k: int, window):
-    return seq // block_k if window is None \
-        else _band_steps(seq, block_q, block_k, window)[0]
+def _query_sweep(seq: int, block_q: int, block_k: int, d: int, causal: bool,
+                 window, heads: int, kernel: str):
+    """The grid of a kernel that sweeps a query block's keys (forward, dq):
+    ``(tables, the axes after the heads', their semantics, query spec, key
+    spec, row-statistics spec)``.  A full call's is its schedule, a banded
+    call's every query block by the band's key steps."""
+    if window is None:
+        pairs = _block_schedule(seq, block_q, block_k, causal)[0]
+        return (_schedule_tables(pairs, kernel), (len(pairs.q),),
+                ("arbitrary",)) + _scheduled_specs(block_q, block_k, d, heads)
+    return ((), (seq // block_q, _band_steps(seq, block_q, block_k, window)[0]),
+            ("parallel", "arbitrary"),
+            pl.BlockSpec((heads, block_q, d), lambda i, j, t: (i, j, 0)),
+            _k_spec(block_q, block_k, d, window, heads),
+            # unit lane dim keeps the (sublane, lane) tiling legal and
+            # broadcasts against (block_q, block_k) scores directly
+            pl.BlockSpec((heads, block_q, 1), lambda i, j, t: (i, j, 0)))
 
 
 def _flash_attention_fwd_flat(q, k, v, *, causal: bool, block_q: int,
@@ -245,40 +357,33 @@ def _flash_attention_fwd_flat(q, k, v, *, causal: bool, block_q: int,
     kernel = functools.partial(
         _flash_attn_kernel, block_q=block_q, block_k=block_k,
         causal=causal, window=window)
-    kspec = _k_spec(block_q, block_k, d, window, heads)
+    tables, axes, semantics, qspec, kspec, rowspec = _query_sweep(
+        seq, block_q, block_k, d, causal, window, heads, "fwd")
     slab = () if heads == 1 else (heads,)
     with jax.named_scope(scopes.FLASH_WINDOW_FWD) if banded \
             else jax.named_scope(scopes.FLASH_FWD):
         return pl.pallas_call(
-            _heads_a_step(kernel, heads),
-            grid=(bh // heads, seq // block_q,
-                  _k_steps(seq, block_q, block_k, window)),
-            in_specs=[
-                pl.BlockSpec((heads, block_q, d), lambda i, j, t: (i, j, 0)),
-                kspec,
-                kspec,
-            ],
-            out_specs=[
-                pl.BlockSpec((heads, block_q, d), lambda i, j, t: (i, j, 0)),
-                # unit lane dim keeps the (sublane, lane) tiling legal and
-                # broadcasts against (block_q, block_k) scores directly
-                pl.BlockSpec((heads, block_q, 1), lambda i, j, t: (i, j, 0)),
-            ],
+            _heads_a_step(kernel, heads, len(tables)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(tables),
+                grid=(bh // heads,) + axes,
+                in_specs=[qspec, kspec, kspec],
+                out_specs=[qspec, rowspec],
+                scratch_shapes=[
+                    pltpu.VMEM(slab + (block_q, 1), jnp.float32),
+                    pltpu.VMEM(slab + (block_q, 1), jnp.float32),
+                    pltpu.VMEM(slab + (block_q, d), jnp.float32),
+                ]),
             out_shape=[
                 _sds((bh, seq, d), q.dtype, q),
                 _sds((bh, seq, 1), jnp.float32, q),
             ],
-            scratch_shapes=[
-                pltpu.VMEM(slab + (block_q, 1), jnp.float32),
-                pltpu.VMEM(slab + (block_q, 1), jnp.float32),
-                pltpu.VMEM(slab + (block_q, d), jnp.float32),
-            ],
             compiler_params=None if interpret else pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+                dimension_semantics=("parallel",) + semantics),
             interpret=interpret,
             name=scopes.kernel_name(scopes.FLASH_WINDOW_FWD if banded
                                     else scopes.FLASH_FWD),
-        )(q, k, v)
+        )(*tables, q, k, v)
 
 
 def _reference_attention(q, k, v, causal: bool, window=None):
@@ -533,34 +638,33 @@ def _flash_fwd(q, k, v, causal, window):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                         dq_ref, dq_scr, *, block_q: int, block_k: int,
-                         causal: bool, window=None):
-    # grid = (bh, nq, nk): K/V stream along the inner axis while this
-    # q block's dq accumulates in VMEM scratch (mirror of the fwd).
-    j = pl.program_id(1)
-    t = pl.program_id(2)
-    nk = pl.num_programs(2)
-    kb = t if window is None else \
-        _band_first_k(j, block_q, block_k, window) + t
+def _flash_bwd_dq_kernel(*refs, block_q: int, block_k: int, causal: bool,
+                         window=None):
+    # K/V stream a block a step while this q block's dq accumulates in VMEM
+    # scratch (mirror of the fwd, and its two grids).
+    banded = window is not None
+    if banded:
+        j = pl.program_id(1)
+        t = pl.program_id(2)
+        nk = pl.num_programs(2)
+        kb = _band_first_k(j, block_q, block_k, window) + t
+    else:
+        j, kb, first, last, _ = _scheduled_pair(*refs[:3])
+        refs = refs[3:]
+    q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
 
-    @pl.when(t == 0)
+    @pl.when(t == 0 if banded else first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    block_live = jnp.logical_or(
-        jnp.logical_not(causal),
-        kb * block_k <= j * block_q + block_q - 1)
-
-    @pl.when(block_live)
-    def _update():
+    def _update(masked: bool):
         # q pre-scaled by 1/sqrt(d): s needs no per-block multiply.
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # (BQ, BK)
         # softmax from saved stats: p = exp(s - lse)
         p = jnp.exp(s - lse_ref[0])
-        if causal:
+        if masked:
             p = jnp.where(_seen_in_block(j, kb, block_q, block_k, window),
                           p, 0.0)
         dp = jax.lax.dot_general(
@@ -574,20 +678,27 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # (BQ, D)
 
-    @pl.when(t == nk - 1)
+    if banded:
+        in_band = jnp.logical_or(
+            jnp.logical_not(causal),
+            kb * block_k <= j * block_q + block_q - 1)
+        pl.when(in_band)(lambda: _update(True))
+    else:
+        _update(causal)
+
+    @pl.when(t == nk - 1 if banded else last)
     def _finish():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
-                          delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                          block_q: int, block_k: int, causal: bool,
+def _flash_bwd_dkv_kernel(*refs, block_q: int, block_k: int, causal: bool,
                           window=None, n_q_blocks=None, dq_ref=None,
                           dq_scr=None):
-    # grid = (bh, nk, nq): Q/G stream along the inner axis while this
-    # k block's dk/dv accumulate in VMEM scratch.  Under a window the inner
-    # axis counts from the first query block that meets this key block and
-    # a step past the band's last (or the sequence's last) is skipped.
+    # Q/G stream a block a step while this k block's dk/dv accumulate in
+    # VMEM scratch.  A full call's grid is (bh, pair), its schedule by key
+    # block.  A banded call's is (bh, nk, step): the inner axis counts from
+    # the first query block that meets this key block and a step past the
+    # band's last (or the sequence's last) is skipped.
     # With ``dq_ref`` this is the ONE backward kernel: dq of the whole flat
     # head waits in ``dq_scr`` ([seq, d] float32) through the head's sweep,
     # block (t, j) adds its ``ds k`` to the rows of query block j (for a
@@ -595,37 +706,36 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
     # dq kernel), and the head's last grid step casts it into ``dq_ref``, a
     # whole-head output block: scores, exp, mask and ``dp`` are computed
     # once, and no float32 dq and no partial ever lies in HBM.
-    t = pl.program_id(1)
-    u = pl.program_id(2)
-    nq = pl.num_programs(2)
-    j = u if window is None else _band_first_q(t, block_q, block_k) + u
+    banded = window is not None
+    if banded:
+        t = pl.program_id(1)
+        u = pl.program_id(2)
+        nq = pl.num_programs(2)
+        j = _band_first_q(t, block_q, block_k) + u
+    else:
+        j, t, first, last, _ = _scheduled_pair(*refs[:3])
+        refs = refs[3:]
+    (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr,
+     dv_scr) = refs
 
-    @pl.when(u == 0)
+    @pl.when(u == 0 if banded else first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     if dq_ref is not None:
-        @pl.when(jnp.logical_and(t == 0, u == 0))
+        @pl.when(jnp.logical_and(t == 0, u == 0) if banded
+                 else pl.program_id(1) == 0)
         def _init_head():
             dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    block_live = jnp.logical_or(
-        jnp.logical_not(causal),
-        j * block_q + block_q - 1 >= t * block_k)
-    if window is not None:
-        block_live = jnp.logical_and(
-            block_live,
-            j <= _band_last_q(t, block_q, block_k, window, n_q_blocks))
-
-    @pl.when(block_live)
-    def _update():
+    def _update(masked: bool):
         # q pre-scaled by 1/sqrt(d): s needs no per-block multiply.
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # (BQ, BK)
         p = jnp.exp(s - lse_ref[0])
-        if causal:
+        if masked:
             p = jnp.where(_seen_in_block(j, t, block_q, block_k, window),
                           p, 0.0)
         dv_scr[:] += jax.lax.dot_general(
@@ -646,13 +756,25 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
                 ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (BQ, D)
 
-    @pl.when(u == nq - 1)
+    if banded:
+        in_band = jnp.logical_or(
+            jnp.logical_not(causal),
+            j * block_q + block_q - 1 >= t * block_k)
+        in_band = jnp.logical_and(
+            in_band,
+            j <= _band_last_q(t, block_q, block_k, window, n_q_blocks))
+        pl.when(in_band)(lambda: _update(True))
+    else:
+        _update(causal)
+
+    @pl.when(u == nq - 1 if banded else last)
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
     if dq_ref is not None:
-        @pl.when(jnp.logical_and(t == pl.num_programs(1) - 1, u == nq - 1))
+        @pl.when(jnp.logical_and(t == pl.num_programs(1) - 1, u == nq - 1)
+                 if banded else pl.program_id(1) == pl.num_programs(1) - 1)
         def _finish_head():
             dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -667,28 +789,28 @@ def _flash_attention_bwd_flat(q, k, v, g, lse, delta, *, causal: bool,
     bh, seq, d = q.shape
     banded = window is not None
     slab = () if heads == 1 else (heads,)
-    qspec = pl.BlockSpec((heads, block_q, d), lambda i, j, t: (i, j, 0))
-    kspec = _k_spec(block_q, block_k, d, window, heads)
-    rowspec = pl.BlockSpec((heads, block_q, 1), lambda i, j, t: (i, j, 0))
+    tables, axes, semantics, qspec, kspec, rowspec = _query_sweep(
+        seq, block_q, block_k, d, causal, window, heads, "dq")
     with jax.named_scope(scopes.FLASH_WINDOW_DQ) if banded \
             else jax.named_scope(scopes.FLASH_DQ):
         dq = pl.pallas_call(
             _heads_a_step(functools.partial(
                 _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                causal=causal, window=window), heads),
-            grid=(bh // heads, seq // block_q,
-                  _k_steps(seq, block_q, block_k, window)),
-            in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-            out_specs=pl.BlockSpec((heads, block_q, d),
-                                   lambda i, j, t: (i, j, 0)),
+                causal=causal, window=window), heads, len(tables)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(tables),
+                grid=(bh // heads,) + axes,
+                in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+                out_specs=qspec,
+                scratch_shapes=[
+                    pltpu.VMEM(slab + (block_q, d), jnp.float32)]),
             out_shape=_sds((bh, seq, d), q.dtype, q),
-            scratch_shapes=[pltpu.VMEM(slab + (block_q, d), jnp.float32)],
             compiler_params=None if interpret else pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+                dimension_semantics=("parallel",) + semantics),
             interpret=interpret,
             name=scopes.kernel_name(scopes.FLASH_WINDOW_DQ if banded
                                     else scopes.FLASH_DQ),
-        )(q, k, v, g, lse, delta)
+        )(*tables, q, k, v, g, lse, delta)
     with jax.named_scope(scopes.FLASH_WINDOW_DKV) if banded \
             else jax.named_scope(scopes.FLASH_DKV):
         dk, dv = _flash_bwd_by_key_block(
@@ -703,33 +825,37 @@ def _flash_bwd_by_key_block(q, k, v, g, lse, delta, *, causal: bool,
                             block_q: int, block_k: int, interpret: bool,
                             window, heads: int, name: str,
                             with_dq: bool = False):
-    """The ``pallas_call`` whose grid is (bh, k block, q block): the inner
-    axis streams q, every query block in turn or under a window the ones
-    that meet the key block (the last one held for the steps a narrower key
-    block has left over).  (dk, dv), or with ``with_dq`` (dq, dk, dv) from
-    the one kernel, whose dq is a whole-head block that stays put through
-    the head's sweep."""
+    """The ``pallas_call`` that sweeps a key block's queries: a full call's
+    grid is its schedule by key block, a banded call's (bh, k block, q
+    step), the query blocks that meet the key block (the last one held for
+    the steps a narrower key block has left over).  (dk, dv), or with
+    ``with_dq`` (dq, dk, dv) from the one kernel, whose dq is a whole-head
+    block that stays put through the head's sweep."""
     from jax.experimental.pallas import tpu as pltpu
     bh, seq, d = q.shape
     slab = () if heads == 1 else (heads,)
     nq = seq // block_q
     if window is None:
-        q_steps = nq
-
-        def q_at(t, u):
-            return u
+        pairs = _block_schedule(seq, block_q, block_k, causal)[1]
+        tables = _schedule_tables(pairs, "onepass" if with_dq else "dkv")
+        axes, semantics = (len(pairs.q),), ("arbitrary",)
+        qspec2, kspec2, rowspec2 = _scheduled_specs(block_q, block_k, d,
+                                                    heads)
     else:
-        q_steps = _band_steps(seq, block_q, block_k, window)[1]
-
         def q_at(t, u):
             return jnp.minimum(
                 _band_first_q(t, block_q, block_k) + u,
                 _band_last_q(t, block_q, block_k, window, nq))
-    qspec2 = pl.BlockSpec((heads, block_q, d),
-                          lambda i, t, u: (i, q_at(t, u), 0))
-    kspec2 = pl.BlockSpec((heads, block_k, d), lambda i, t, j: (i, t, 0))
-    rowspec2 = pl.BlockSpec((heads, block_q, 1),
-                            lambda i, t, u: (i, q_at(t, u), 0))
+        tables = ()
+        axes = (seq // block_k, _band_steps(seq, block_q, block_k, window)[1])
+        # Every key block of a head but its last leaves the one kernel's dq
+        # unfinished, so the key axis is then nobody's to split.
+        semantics = ("arbitrary" if with_dq else "parallel", "arbitrary")
+        qspec2 = pl.BlockSpec((heads, block_q, d),
+                              lambda i, t, u: (i, q_at(t, u), 0))
+        kspec2 = pl.BlockSpec((heads, block_k, d), lambda i, t, j: (i, t, 0))
+        rowspec2 = pl.BlockSpec((heads, block_q, 1),
+                                lambda i, t, u: (i, q_at(t, u), 0))
     kernel = functools.partial(
         _flash_bwd_onepass_kernel if with_dq else _flash_bwd_dkv_kernel,
         block_q=block_q, block_k=block_k, causal=causal, window=window,
@@ -739,40 +865,37 @@ def _flash_bwd_by_key_block(q, k, v, g, lse, delta, *, causal: bool,
                  _sds((bh, seq, d), v.dtype, v)]
     scratch = [pltpu.VMEM(slab + (block_k, d), jnp.float32),
                pltpu.VMEM(slab + (block_k, d), jnp.float32)]
-    semantics = ("parallel", "parallel", "arbitrary")
     params = {}
     if with_dq:
-        # Every key block of a head but its last leaves dq unfinished, so
-        # the key axis is no longer anybody's to split.
         out_specs.insert(0, pl.BlockSpec((heads, seq, d),
-                                         lambda i, t, u: (i, 0, 0)))
+                                         lambda i, *at: (i, 0, 0)))
         out_shape.insert(0, _sds((bh, seq, d), q.dtype, q))
         scratch.insert(0, pltpu.VMEM(slab + (seq, d), jnp.float32))
-        semantics = ("parallel", "arbitrary", "arbitrary")
         params = {"vmem_limit_bytes": _ONEPASS_VMEM_ROOM + _onepass_vmem_bytes(
             heads, seq, d, q.dtype.itemsize)}
     return pl.pallas_call(
-        _heads_a_step(kernel, heads),
-        grid=(bh // heads, seq // block_k, q_steps),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
-        out_specs=out_specs,
+        _heads_a_step(kernel, heads, len(tables)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(bh // heads,) + axes,
+            in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
+            out_specs=out_specs,
+            scratch_shapes=scratch),
         out_shape=out_shape,
-        scratch_shapes=scratch,
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=semantics, **params),
+            dimension_semantics=("parallel",) + semantics, **params),
         interpret=interpret,
         name=name,
-    )(q, k, v, g, lse, delta)
+    )(*tables, q, k, v, g, lse, delta)
 
 
-def _flash_bwd_onepass_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref,
-                              delta_ref, dq_ref, dk_ref, dv_ref, dq_scr,
-                              dk_scr, dv_scr, **plan):
+def _flash_bwd_onepass_kernel(*refs, **plan):
     """ONE kernel for dq, dk and dv: the dk/dv kernel with dq of the whole
-    flat head kept in VMEM (refs in ``pallas_call``'s order)."""
-    _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_scr, dv_scr, dq_ref=dq_ref,
-                          dq_scr=dq_scr, **plan)
+    flat head kept in VMEM (refs in ``pallas_call``'s order: a schedule's
+    tables, the six inputs, then dq ahead of dk and dv twice)."""
+    *inputs, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = refs
+    _flash_bwd_dkv_kernel(*inputs, dk_ref, dv_ref, dk_scr, dv_scr,
+                          dq_ref=dq_ref, dq_scr=dq_scr, **plan)
 
 
 def _flash_attention_bwd_onepass_flat(q, k, v, g, lse, delta, *,
@@ -870,10 +993,12 @@ def flash_attention(q, k, v, causal: bool = True, window=None):
     (the framework's attention layout).  Differentiable; compiled
     Pallas on TPU, interpreted elsewhere.  Sequences not divisible by
     64 fall back to plain XLA attention.  GQA (kv_heads < heads) is
-    handled by repeating KV head groups.  ``window``: a causal query sees
-    its last ``window`` keys, itself among them; the kernels then visit
-    the blocks of that band alone, under scopes and names of their own
-    (``hvd.flash_window_*``)."""
+    handled by repeating KV head groups.  A call's kernels visit the block
+    pairs that hold work and no others (``_block_schedule``: under the
+    causal mask the pairs that meet the triangle).  ``window``: a causal
+    query sees its last ``window`` keys, itself among them; the kernels
+    then visit the blocks of that band alone, under scopes and names of
+    their own (``hvd.flash_window_*``)."""
     if window is not None:
         if not causal or window < 1:
             raise ValueError("a window of %r keys needs a causal mask and "

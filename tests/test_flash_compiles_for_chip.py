@@ -1,4 +1,4 @@
-"""The one backward flash kernel compiled for a described v5e at the shapes
+"""The flash kernels compiled for a described v5e at the shapes
 the benchmark's cells run (no chip: ``jax.experimental.topologies``).  What
 Mosaic refuses (a slice off the tiling, more VMEM than the limit) it
 refuses here, which interpret mode cannot show.  A compile, not a speed."""
@@ -57,6 +57,50 @@ def test_the_one_backward_kernel_compiles_for_v5e(one_chip, flat_heads, seq,
     # dq, dk, dv in the inputs' dtype and nothing else: no partial in HBM
     assert [(o.shape, o.dtype) for o in compiled.out_info] \
         == [((flat_heads, seq, d_pad), jnp.dtype(dtype))] * 3
+
+
+# The full calls' grids are schedules of block pairs whose tables go in as
+# scalar prefetch and whose index maps read a block's index from them: the
+# forward kernel at the cells' shapes (laguna's full layers, nemotron's,
+# pt8k's, BERT's without a mask: one pair a head), square blocks and a query
+# block wider than the key block.
+@pytest.mark.parametrize("flat_heads, seq, causal, blocks", [
+    (96, 8192, True, None), (64, 8192, True, None), (16, 8192, True, None),
+    (64, 512, False, None), (16, 8192, True, (512, 512)),
+    (16, 8192, True, (1024, 512))])
+def test_the_scheduled_forward_kernel_compiles_for_v5e(one_chip, flat_heads,
+                                                       seq, causal, blocks):
+    block_q, block_k = blocks or pk._plan(seq, 128)[:2]
+    x = jax.ShapeDtypeStruct((flat_heads, seq, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(functools.partial(
+        pk._flash_attention_fwd_flat, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=False)).lower(x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert [(o.shape, o.dtype) for o in compiled.out_info] \
+        == [((flat_heads, seq, 128), jnp.dtype(jnp.bfloat16)),
+            ((flat_heads, seq, 1), jnp.dtype(jnp.float32))]
+
+
+# The two backward kernels, which a call past ``_ONEPASS_VMEM_BUDGET`` takes
+# (a sequence of 16384) and ``HVD_TPU_FLASH_BWD=pallas`` at any shape.
+@pytest.mark.parametrize("flat_heads, seq, causal", [
+    (96, 8192, True), (8, 16384, True), (64, 512, False)])
+def test_the_scheduled_two_backward_kernels_compile_for_v5e(one_chip,
+                                                            flat_heads, seq,
+                                                            causal):
+    block_q, block_k = pk._plan(seq, 128)[:2]
+    x = jax.ShapeDtypeStruct((flat_heads, seq, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((flat_heads, seq, 1), jnp.float32,
+                                sharding=one_chip)
+    compiled = jax.jit(functools.partial(
+        pk._flash_attention_bwd_flat, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=False)).lower(
+            x, x, x, x, rows, rows).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert [(o.shape, o.dtype) for o in compiled.out_info] \
+        == [((flat_heads, seq, 128), jnp.dtype(jnp.bfloat16))] * 3
 
 
 # nemotron's scan (2 x 8192 tokens, 64 heads of 64 in 8 groups, state 128,

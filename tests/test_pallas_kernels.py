@@ -69,9 +69,9 @@ def test_flash_attention_grad(causal):
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_grad_multiblock_grid(causal):
     # s=192 -> 3x3 grid of 64-blocks: exercises cross-block scratch
-    # accumulation, the init/finish grid boundaries, and the causal
-    # block-live skip in BOTH backward kernels (s=128 is a 1x1 grid
-    # where those paths degenerate).
+    # accumulation, the rows' first and last pairs, and a causal
+    # schedule shorter than the rectangle in BOTH backward kernels (s=128
+    # is one pair where those paths degenerate).
     q, k, v = _qkv(b=1, s=192, h=2, d=32, seed=7)
 
     def loss_flash(q_, k_, v_):
@@ -145,8 +145,8 @@ def test_flash_bwd_grid_exact_lane_dim(monkeypatch, variant):
 
 def test_flash_bwd_onepass_multiblock_grid(monkeypatch):
     # s=192 -> 3x3 grid of 64-blocks: the one kernel's dk/dv scratch and
-    # its whole-head dq accumulator across a grid where causal skipping
-    # actually fires (a dead tile adds nothing to its query block's rows).
+    # its whole-head dq accumulator across a schedule that leaves out the
+    # pairs above the diagonal (nothing is added to their query blocks' rows).
     monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas_onepass")
     q, k, v = _qkv(b=1, s=192, h=2, d=32, seed=13)
 
@@ -162,6 +162,163 @@ def test_flash_bwd_onepass_multiblock_grid(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4,
                                    err_msg="d%s mismatch" % lbl)
+
+
+# -- a full call's grid is a schedule of block pairs ---------------------------
+
+# the cells' plan; square blocks; a query block wider than its key block; one
+# pair a head (BERT); short sequences in the shapes the numerics below run
+SCHEDULES = [(8192, 512, 1024), (8192, 512, 512), (8192, 1024, 512),
+             (512, 512, 512), (256, 64, 128), (256, 128, 64), (192, 64, 64)]
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("seq, block_q, block_k", SCHEDULES)
+def test_the_schedule_visits_every_live_pair_once_in_both_orders(
+        seq, block_q, block_k, causal):
+    """By query block and by key block: the pairs that hold a seen score,
+    each once, a row's pairs together and ascending, the row's first and
+    last flagged, and ``crosses`` on the pairs that also hold a hidden one."""
+    rows, cols = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    seen = (cols <= rows) if causal else np.ones((seq, seq), bool)
+    blocks = seen.reshape(seq // block_q, block_q, seq // block_k, block_k)
+    live = {(j, t): not blocks[j, :, t].all()
+            for j in range(seq // block_q) for t in range(seq // block_k)
+            if blocks[j, :, t].any()}
+    by_query, by_key = pk._block_schedule(seq, block_q, block_k, causal)
+    for pairs, row_of, in_row in ((by_query, by_query.q, by_query.k),
+                                  (by_key, by_key.k, by_key.q)):
+        visited = list(zip(pairs.q.tolist(), pairs.k.tolist()))
+        assert len(visited) == len(set(visited)) and set(visited) == set(live)
+        assert [live[pair] for pair in visited] == pairs.crosses.tolist()
+        # rows in order and unbroken, each row's partners ascending
+        assert row_of.tolist() == sorted(row_of.tolist())
+        starts = np.flatnonzero(np.diff(row_of, prepend=-1))
+        ends = np.flatnonzero(np.diff(row_of, append=-1))
+        assert pairs.first.tolist() == np.isin(
+            np.arange(len(visited)), starts).tolist()
+        assert pairs.last.tolist() == np.isin(
+            np.arange(len(visited)), ends).tolist()
+        for a, b in zip(starts, ends):
+            assert (np.diff(in_row[a:b + 1]) == 1).all()
+    if not causal:
+        assert len(by_query.q) == (seq // block_q) * (seq // block_k)
+        assert not by_query.crosses.any() and not by_key.crosses.any()
+
+
+def test_the_schedule_at_the_cells_shape():
+    """8192 positions in blocks of 512 x 1024: 72 of the rectangle's 128
+    pairs, 16 of them on the diagonal; BERT's 512 without a mask: one."""
+    for pairs in pk._block_schedule(8192, *pk._plan(8192, 128)[:2], True):
+        assert (len(pairs.q), int(pairs.crosses.sum())) == (72, 16)
+    for pairs in pk._block_schedule(512, *pk._plan(512, 64)[:2], False):
+        assert (len(pairs.q), int(pairs.crosses.sum())) == (1, 0)
+        assert pairs.first.all() and pairs.last.all()
+
+
+def _lse(q, k, causal):
+    """log-sum-exp of the scaled, masked scores, ``[batch * heads, seq, 1]``."""
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if causal:
+        s = q.shape[1]
+        scores = jnp.where(jnp.arange(s)[None, :] <= jnp.arange(s)[:, None],
+                           scores, -jnp.inf)
+    return jax.nn.logsumexp(scores, -1).reshape(-1, q.shape[1], 1)
+
+
+# Output, log-sum-exp and the three gradients of a causal call whose
+# schedule has interior and diagonal pairs, rows of unequal length and (at
+# 128 x 64) two diagonal pairs a query block; the one backward kernel and
+# the two; once without the mask.
+@pytest.mark.parametrize("variant", ["pallas", "pallas_onepass"])
+@pytest.mark.parametrize("block_q, block_k, causal", [
+    (64, 128, True), (128, 64, True), (64, 64, True), (256, 256, True),
+    (64, 128, False)])
+def test_a_scheduled_call_matches_the_reference(monkeypatch, variant, block_q,
+                                                block_k, causal):
+    monkeypatch.setenv("HVD_TPU_FLASH_BWD", variant)
+    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", str(block_q))
+    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", str(block_k))
+    q, k, v = _qkv(b=1, s=256, h=2, d=32, seed=21)
+    weight = _qkv(b=1, s=256, h=2, d=32, seed=22)[0]
+    got, (*_, lse) = pk._flash_fwd(q, k, v, causal, None)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_reference_attention(q, k, v, causal)),
+        atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(_lse(q, k, causal)),
+                               atol=2e-5, rtol=2e-5)
+    g1 = jax.grad(lambda *a: jnp.sum(
+        flash_attention(*a, causal=causal) * weight), argnums=(0, 1, 2))(
+            q, k, v)
+    g2 = jax.grad(lambda *a: jnp.sum(
+        _reference_attention(*a, causal) * weight), argnums=(0, 1, 2))(
+            q, k, v)
+    for a, b, lbl in zip(g1, g2, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4,
+                                   err_msg="d%s mismatch" % lbl)
+
+
+def test_a_full_call_of_several_heads_a_step_is_the_heads_one_by_one():
+    """``heads`` flat heads a grid step under a schedule: the tables are
+    every head's, the numbers the same to the bit."""
+    rng = np.random.RandomState(23)
+    q, k, v, g = (jnp.asarray(rng.randn(4, 256, 32), jnp.float32)
+                  for _ in range(4))
+
+    def all_three(heads):
+        at = dict(causal=True, block_q=64, block_k=128, interpret=True,
+                  heads=heads)
+        o, lse = pk._flash_attention_fwd_flat(q, k, v, **at)
+        delta = jnp.sum(g * o, -1, keepdims=True)
+        return (o, lse) + tuple(pk._flash_attention_bwd_flat(
+            q, k, v, g, lse, delta, **at)) + tuple(
+                pk._flash_attention_bwd_onepass_flat(
+                    q, k, v, g, lse, delta, **at))
+
+    for got, want in zip(all_three(2), all_three(1)):
+        assert jnp.array_equal(got, want)
+    traced = str(jax.make_jaxpr(lambda *a: pk._flash_attention_fwd_flat(
+        *a, causal=True, block_q=64, block_k=128, interpret=True,
+        heads=2))(q, k, v))
+    assert "grid=(2, 6)" in traced         # two heads a step, six live pairs
+
+
+def _block_pairs():
+    series = metrics.metrics_snapshot().get(
+        "hvd_flash_block_pairs_total", {}).get("series", ())
+    return {(row["labels"]["kernel"], row["labels"]["kind"]): row["value"]
+            for row in series}
+
+
+def test_the_visited_pairs_are_counted_by_kernel_and_kind(monkeypatch):
+    """``hvd_flash_block_pairs_total``: what a flat head's grid visits, as a
+    call's kernels are traced; a banded call has no schedule and adds
+    nothing."""
+    for name in ("HVD_TPU_FLASH_BWD", "HVD_TPU_FLASH_BLOCK_Q",
+                 "HVD_TPU_FLASH_BLOCK_K"):
+        monkeypatch.delenv(name, raising=False)
+    x = jax.ShapeDtypeStruct((1, 8192, 1, 128), jnp.bfloat16)
+
+    def trace(**kw):
+        metrics.reset()
+        jax.eval_shape(jax.grad(lambda *a: jnp.sum(
+            flash_attention(*a, **kw).astype(jnp.float32)),
+            argnums=(0, 1, 2)), x, x, x)
+        return _block_pairs()
+
+    # the cells' full calls: the rectangle's grid would read 128
+    assert trace() == {("fwd", "interior"): 56, ("fwd", "diagonal"): 16,
+                       ("onepass", "interior"): 56,
+                       ("onepass", "diagonal"): 16}
+    assert trace(causal=False) == {("fwd", "interior"): 128,
+                                   ("fwd", "diagonal"): 0,
+                                   ("onepass", "interior"): 128,
+                                   ("onepass", "diagonal"): 0}
+    assert trace(window=512) == {}
+    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas")
+    assert trace() == {(kernel, kind): n for kernel in ("fwd", "dq", "dkv")
+                       for kind, n in (("interior", 56), ("diagonal", 16))}
 
 
 def _backward_kernels(q, k, v, **kw):

@@ -4,6 +4,7 @@ local_attention``) and the interpreted flash kernels
 masked softmax, forward and every gradient; which blocks the banded grids
 visit; and that ``window=None`` is the program it was."""
 
+import hashlib
 import math
 
 import jax
@@ -188,7 +189,8 @@ def test_no_window_is_the_program_it_was(blocks):
     before = traced()
     assert traced(window=None) == traced(window=SEQ) == before
     assert "hvd_flash_window" not in before and "hvd_flash_fwd" in before
-    assert "grid=(2, 4, 2)" in before         # every key block of 128
+    # the schedule's six live pairs of the eight a rectangle of 4 x 2 has
+    assert "grid=(2, 6)" in before
     a = out_and_grads(lambda *x: pk.flash_attention(*x), q, k, v, weight)
     b = out_and_grads(lambda *x: pk.flash_attention(*x, window=SEQ + 7),
                       q, k, v, weight)
@@ -256,6 +258,35 @@ def test_several_heads_a_grid_step_are_the_heads_one_by_one(heads):
     assert "grid=(%d, 4, 3)" % (4 // heads) in traced
     assert [pk._heads_of(n, 512) for n in (128, 96, 6, 7)] == [4, 4, 2, 1]
     assert pk._heads_of(128, None) == 1
+
+
+# sha256 of a banded call's lowered text (forward and the three gradients,
+# interpreted, ``as_text()``) at PR 35 (commit 836838e), before a full call's
+# grid became a schedule of block pairs: the banded kernels, their grids and
+# their index maps are the parent's to the letter.  ``(block_q, block_k,
+# HVD_TPU_FLASH_BWD, query heads)``; a PR that changes the banded path on
+# purpose computes these again.
+BANDED_PARENTS = {
+    (64, 64, "pallas", 2):
+        "201696d4cb0f5dff5f6a626610b6622715c3f82aa2c3ac588256a7d75bed3765",
+    (64, 128, "pallas_onepass", 4):
+        "32404b745495adfd9cb91c083ab0df63f89ea195e8e8cfecd349f15f0b648f68",
+    (128, 64, "pallas_onepass", 4):
+        "1c33044caf9d50a84d135904aea74814c0b81168874c8982627c035783077f5a",
+}
+
+
+@pytest.mark.parametrize("block_q, block_k, backward, heads",
+                         sorted(BANDED_PARENTS))
+def test_a_banded_call_lowers_to_the_parents_text(blocks, block_q, block_k,
+                                                  backward, heads):
+    blocks(block_q, block_k, backward)
+    q, k, v, weight = inputs(heads=heads)
+    text = jax.jit(jax.grad(
+        lambda *a: (pk.flash_attention(*a, window=100) * weight).sum(),
+        argnums=(0, 1, 2))).lower(q, k, v).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == BANDED_PARENTS[block_q, block_k, backward, heads]
 
 
 def test_the_banded_calls_carry_their_own_scopes_and_names(blocks):
