@@ -16,7 +16,9 @@ made from a seed and a few steps each:
                    ``models.transformer.make_train_step``; the lowered
                    step must hold the Mosaic kernels and no interpreted
                    one, and the flash forward and backward must agree
-                   with ``_reference_attention`` at the flagship shape.
+                   with ``_reference_attention`` at the flagship shape,
+                   and again with query/key heads of 192 over its values
+                   (a latent-attention call).
 * ``collectives``  the eager ``horovod_tpu.ops.api`` surface in
                    ``inprocess`` mode at a small and a large
                    (64 MiB per rank) size, every result against numpy.
@@ -430,6 +432,17 @@ def check_flash_against_reference():
         del os.environ["HVD_TPU_FLASH_BWD"]
     for name, got, want in zip("qkv", grads, want_grads):
         ok &= close("backward (two_kernel) d" + name, got, want)
+
+    # One latent-attention call: query/key heads of 192 over the same
+    # values (the kernels at two head sizes, their own names).
+    q, k = (jnp.asarray(
+        rng.standard_normal(shape[:3] + (192,), np.float32) * 0.5,
+        jnp.bfloat16) for _ in range(2))
+    (_, want_out), want_grads = weighted(pk._reference_attention)(q, k, v)
+    (_, out), grads = weighted(pk.flash_attention)(q, k, v)
+    ok &= close("forward 192x%d" % shape[3], out, want_out)
+    for name, got, want in zip("qkv", grads, want_grads):
+        ok &= close("backward 192x%d d%s" % (shape[3], name), got, want)
     assert ok, "flash attention disagrees with the reference on the chip"
     return form
 

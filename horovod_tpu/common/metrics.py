@@ -301,6 +301,12 @@ NAMES: Dict[str, Tuple[str, str]] = {
                    "ssd_chunked) by the form their shapes took, labeled "
                    "form (kernel|xla); counted as a scan is traced, like "
                    "hvd_flash_backward_calls_total"),
+    "hvd_latent_attention_calls_total": (
+        "counter", "latent-attention blocks (models/transformer.py: "
+                   "_latent_attention_block) by the form their attention "
+                   "took, labeled form (kernel = flash_attention at the "
+                   "two head sizes | xla = local_attention); counted as a "
+                   "block is traced, like hvd_flash_backward_calls_total"),
     # -- lifecycle: the program's own set-up, timed on the host --
     "hvd_span_seconds": (
         "histogram", "wall time of one host span, labeled span (the host "

@@ -42,7 +42,10 @@ dv; ``FLASH_DQ`` and ``FLASH_DKV`` are the two it replaces wherever dq of a
 head fits in VMEM), and ``FLASH_WINDOW_FWD``, ``FLASH_WINDOW_DQ``,
 ``FLASH_WINDOW_DKV`` for the same under a window (a banded call's one
 backward kernel sits under ``FLASH_WINDOW_DKV``; a sliding layer's
-whole block is ``WINDOW_ATTENTION``, inside ``ATTENTION``); ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two,
+whole block is ``WINDOW_ATTENTION``, inside ``ATTENTION``, and a
+latent-attention layer's ``LATENT_ATTENTION``, inside ``ATTENTION`` too: both
+projections of the latent, its norm, the rotary turns, the flash kernels,
+``wo``); ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two,
 inside ``KDA_CORE``); ``SSD_FWD`` and ``SSD_BWD`` (the state-space scan's
 two, inside ``SSD_CORE``); ``kernel_name`` gives the same words as the ``name=``
 of the call (``hvd_flash_fwd``), which is what the trace viewer prints for
@@ -100,6 +103,7 @@ FLASH_DQ = "hvd.flash_dq"
 FLASH_DKV = "hvd.flash_dkv"
 FLASH_BWD_ONEPASS = "hvd.flash_bwd_onepass"
 WINDOW_ATTENTION = "hvd.window_attention"
+LATENT_ATTENTION = "hvd.latent_attention"
 FLASH_WINDOW_FWD = "hvd.flash_window_fwd"
 FLASH_WINDOW_DQ = "hvd.flash_window_dq"
 FLASH_WINDOW_DKV = "hvd.flash_window_dkv"

@@ -38,7 +38,10 @@ more kind there and an entry of the pattern here.  Softmax attention is one
 block whatever the kind: what a kind of layer fixes of it (head counts,
 window, rotary table, gate) is a ``SoftmaxAttention``, which a pattern's
 entry may hold in the mixer's place; ``attention`` and
-``gated_nope_attention`` name two of its settings.
+``gated_nope_attention`` name two of its settings.  Multi-head latent
+attention (keys and values out of one low-rank latent, query/key heads of
+another size than the values', one rotary key for all heads) is a block of
+its own: a ``LatentAttention`` in the mixer's place.
 """
 
 from __future__ import annotations
@@ -131,6 +134,39 @@ class SoftmaxAttention:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """Multi-head latent attention (the ``deepseek_v3`` family's, without a
+    query latent): ``n_heads`` query heads of ``nope + rope_dim``, whose
+    last ``rope_dim`` dimensions the rotary table turns; keys and values
+    from one ``kv_rank``-wide latent a position (``wkv_a``, an RMSNorm,
+    ``wkv_b``: ``nope`` key dimensions and ``v_dim`` value dimensions a
+    head) beside ONE ``rope_dim``-wide rotary key a position that every
+    head shares.  Scores are scaled by ``(nope + rope_dim)^-1/2``, the
+    query/key size and not the value's."""
+    n_heads: int
+    kv_rank: int
+    nope: int
+    rope_dim: int
+    v_dim: int
+    rope: Rope = Rope()
+
+    def __post_init__(self):
+        if min(self.n_heads, self.kv_rank, self.nope, self.v_dim) < 1 \
+                or self.rope_dim < 2 or self.rope_dim % 2:
+            raise ValueError("a latent-attention block needs heads, a latent,"
+                             " key and value sizes and an even rotary size, "
+                             "not %r" % (self,))
+        if self.rope.share != 1.0:
+            raise ValueError("the rotary key of a latent-attention block "
+                             "turns whole (rope_dim says how wide it is), "
+                             "not a share of %r" % (self.rope.share,))
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -172,9 +208,9 @@ class TransformerConfig:
     # work).  No-op at tp=1, so single-chip programs are unchanged.
     collective_matmul: bool = False
     # One period of the layer pattern, ((mixer, feed-forward), ...) out of
-    # MIXERS (or a SoftmaxAttention) x FEED_FORWARDS, either of which may
-    # be None (not both); n_layers less the leading layers is a multiple of
-    # its length.
+    # MIXERS (or a SoftmaxAttention, or a LatentAttention) x FEED_FORWARDS,
+    # either of which may be None (not both); n_layers less the leading
+    # layers is a multiple of its length.
     layer_pattern: Tuple[Tuple[object, Optional[str]], ...] = (
         ("attention", "dense"),)
     # Entries of the same kinds that run once, each with parameters of its
@@ -203,12 +239,13 @@ class TransformerConfig:
                              "'dots_no_batch', got %r"
                              % (self.remat_policy,))
         for mixer, ffn in self.pairs:
-            if not (isinstance(mixer, SoftmaxAttention)
+            if not (isinstance(mixer, (SoftmaxAttention, LatentAttention))
                     or mixer in MIXERS + (None,)) \
                     or ffn not in FEED_FORWARDS + (None,) \
                     or (mixer is None and ffn is None):
                 raise ValueError("layer_pattern pairs a mixer of %s (or a "
-                                 "SoftmaxAttention) with a feed-forward of "
+                                 "SoftmaxAttention or a LatentAttention) "
+                                 "with a feed-forward of "
                                  "%s, or holds one of them alone, not %r"
                                  % (MIXERS, FEED_FORWARDS, (mixer, ffn)))
             if (mixer == "linear_attention" and not self.linear_attention) \
@@ -282,6 +319,16 @@ def _init_layers(key, cfg: TransformerConfig, mixer, ffn, n: int):
                                       pd))
     elif mixer == "state_space":
         layers.update(init_ssm_params(keys[1], d, cfg.state_space, n, pd))
+    elif isinstance(mixer, LatentAttention):
+        h, rank = mixer.n_heads, mixer.kv_rank
+        layers.update({
+            "wq": norm(keys[1], (n, d, h * mixer.qk_dim), d),
+            "wkv_a": norm(keys[2], (n, d, rank + mixer.rope_dim), d),
+            "kv_norm": jnp.ones((n, rank), pd),
+            "wkv_b": norm(keys[3], (n, rank, h * (mixer.nope + mixer.v_dim)),
+                          rank),
+            "wo": norm(keys[4], (n, h * mixer.v_dim, d), h * mixer.v_dim),
+        })
     elif kind is not None:
         qh, kvh = kind.n_heads, kind.n_kv_heads
         layers.update({
@@ -348,6 +395,16 @@ def _layer_specs(cfg: TransformerConfig, mixer, ffn):
         specs.update(kda_param_specs(tp))
     elif mixer == "state_space":
         specs.update(ssm_param_specs())
+    elif isinstance(mixer, LatentAttention):
+        # Heads over tp: wq and wkv_b by columns, wo by rows; the latent's
+        # projection and its norm are every shard's, whole.
+        specs.update({
+            "wq": P(None, None, tp),
+            "wkv_a": P(None, None, None),
+            "kv_norm": P(None, None),
+            "wkv_b": P(None, None, tp),
+            "wo": P(None, tp, None),
+        })
     elif kind is not None:
         specs.update({
             "wq": P(None, None, tp),
@@ -528,8 +585,46 @@ def _softmax_attention_block(x, lp, cfg: TransformerConfig,
         return _row_parallel_product(attn, lp["wo"].astype(x.dtype), cfg)
 
 
+@jax.named_scope(scopes.ATTENTION)
+def _latent_attention_block(x, lp, cfg: TransformerConfig,
+                            kind: LatentAttention, tables):
+    """Multi-head latent attention with its projections: ``q = x W_q``, a
+    head's ``[nope | rope]``; ``[c | k_rope] = x W_kv_a``; ``[k_nope | v] =
+    rms_norm(c) W_kv_b`` a head; the rotary table turns every head's
+    ``q_rope`` and the one ``k_rope``, which every head's key ends in; the
+    scores' scale is the query/key size's (``flash_attention`` and
+    ``local_attention`` take it from q).  The heads are what the weights
+    hold on this tp shard; the sequence is whole (``_mix`` refuses a split
+    one)."""
+    with jax.named_scope(scopes.LATENT_ATTENTION):
+        b, s, _ = x.shape
+        nope, rank = kind.nope, kind.kv_rank
+        metrics.counter(
+            "hvd_latent_attention_calls_total",
+            form="kernel" if pallas_kernels.use_flash_attention()
+            else "xla").inc()
+        q = (x @ lp["wq"].astype(x.dtype)).reshape(b, s, -1, kind.qk_dim)
+        latent = x @ lp["wkv_a"].astype(x.dtype)
+        kv = (rms_norm(latent[..., :rank], lp["kv_norm"], cfg.norm_eps)
+              @ lp["wkv_b"].astype(x.dtype)).reshape(
+                  b, s, -1, nope + kind.v_dim)
+        cos, sin = tables[kind]
+        q = jnp.concatenate([q[..., :nope],
+                             _rope(cos, sin, q[..., nope:])], axis=-1)
+        k_rope = _rope(cos, sin, latent[:, :, None, rank:])
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rope, kv.shape[:3] + (kind.rope_dim,))],
+            axis=-1)
+        attn = _causal_attention(q, k, kv[..., nope:], cfg, None,
+                                 1).reshape(b, s, -1)
+        # Row-sharded wo: partial sums live on each tp shard.
+        return _row_parallel_product(attn, lp["wo"].astype(x.dtype), cfg)
+
+
 def _causal_attention(q, k, v, cfg: TransformerConfig, window, sp_size):
-    """Causal softmax attention over ``[B, S, heads, head_dim]`` by
+    """Causal softmax attention over ``[B, S, heads, head_dim]`` (the
+    values' heads may be of another size than the queries' and keys') by
     whichever form the layout calls for."""
     if sp_size > 1 and window is not None:
         raise ValueError("a window of %d keys crosses the shards of a "
@@ -590,6 +685,14 @@ def _mix(h, lp, cfg: TransformerConfig, mixer, tables, sp_size):
     kind = cfg.softmax_kind(mixer)
     if kind is not None:
         return _softmax_attention_block(h, lp, cfg, kind, tables, sp_size)
+    if isinstance(mixer, LatentAttention):
+        if sp_size > 1:
+            raise ValueError(
+                "a latent-attention layer's query/key heads are of another "
+                "size than its values': neither parallel/ring_attention.py "
+                "nor parallel/ulysses.py carries that, so the sequence "
+                "cannot be split over %r" % cfg.sp_axis)
+        return _latent_attention_block(h, lp, cfg, mixer, tables)
     if sp_size > 1:
         raise ValueError("a %s layer keeps a state along the "
                          "sequence: the sequence cannot be split over %r"
@@ -629,6 +732,11 @@ def hidden(params, tokens, cfg: TransformerConfig):
     tables = {rope: rope_tables(pos, cfg.head_dim, rope, cfg.act_dtype)
               for rope in dict.fromkeys(kind.rope for kind in kinds
                                         if kind and kind.rope)}
+    # A latent-attention kind's table is as wide as its rotary key.
+    tables.update(
+        (mixer, rope_tables(pos, mixer.rope_dim, mixer.rope, cfg.act_dtype))
+        for mixer in dict.fromkeys(m for m, _ in cfg.pairs
+                                   if isinstance(m, LatentAttention)))
 
     x = _sharded_embed_lookup(params["embed"], tokens, cfg.tp_axis)
     x = x.astype(cfg.act_dtype)

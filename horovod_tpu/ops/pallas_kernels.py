@@ -327,63 +327,94 @@ def _k_spec(block_q: int, block_k: int, d: int, window, heads: int = 1):
     return pl.BlockSpec((heads, block_k, d), index)
 
 
+class _Sweep(NamedTuple):
+    """A swept grid: the schedule's tables (none under a window), the axes
+    after the heads' with their semantics, and the BlockSpecs of a query
+    block and a key block at the query/key size, of the row statistics, and
+    of a query block and a key block at the value's size."""
+    tables: tuple
+    axes: tuple
+    semantics: tuple
+    q: object
+    k: object
+    rows: object
+    o: object
+    v: object
+
+
 def _query_sweep(seq: int, block_q: int, block_k: int, d: int, causal: bool,
-                 window, heads: int, kernel: str):
-    """The grid of a kernel that sweeps a query block's keys (forward, dq):
-    ``(tables, the axes after the heads', their semantics, query spec, key
-    spec, row-statistics spec)``.  A full call's is its schedule, a banded
-    call's every query block by the band's key steps."""
+                 window, heads: int, kernel: str, d_v=None):
+    """The grid of a kernel that sweeps a query block's keys (forward, dq).
+    A full call's is its schedule, a banded call's every query block by the
+    band's key steps.  ``d`` is the query/key size, ``d_v`` the value's
+    where it is another."""
+    d_v = d if d_v is None else d_v
     if window is None:
         pairs = _block_schedule(seq, block_q, block_k, causal)[0]
-        return (_schedule_tables(pairs, kernel), (len(pairs.q),),
-                ("arbitrary",)) + _scheduled_specs(block_q, block_k, d, heads)
-    return ((), (seq // block_q, _band_steps(seq, block_q, block_k, window)[0]),
-            ("parallel", "arbitrary"),
-            pl.BlockSpec((heads, block_q, d), lambda i, j, t: (i, j, 0)),
-            _k_spec(block_q, block_k, d, window, heads),
-            # unit lane dim keeps the (sublane, lane) tiling legal and
-            # broadcasts against (block_q, block_k) scores directly
-            pl.BlockSpec((heads, block_q, 1), lambda i, j, t: (i, j, 0)))
+        q, k, rows = _scheduled_specs(block_q, block_k, d, heads)
+        o, v, _ = _scheduled_specs(block_q, block_k, d_v, heads)
+        return _Sweep(_schedule_tables(pairs, kernel), (len(pairs.q),),
+                      ("arbitrary",), q, k, rows, o, v)
+
+    def q_spec(width):
+        return pl.BlockSpec((heads, block_q, width), lambda i, j, t: (i, j, 0))
+
+    return _Sweep(
+        (), (seq // block_q, _band_steps(seq, block_q, block_k, window)[0]),
+        ("parallel", "arbitrary"), q_spec(d),
+        _k_spec(block_q, block_k, d, window, heads),
+        # unit lane dim keeps the (sublane, lane) tiling legal and
+        # broadcasts against (block_q, block_k) scores directly
+        q_spec(1), q_spec(d_v), _k_spec(block_q, block_k, d_v, window, heads))
+
+
+def _sized(name: str, d: int, d_v: int) -> str:
+    """A kernel's name with the call's two head sizes where they differ, so
+    that a trace tells such a call's kernels from the others."""
+    return name if d == d_v else "%s_%dx%d" % (name, d, d_v)
 
 
 def _flash_attention_fwd_flat(q, k, v, *, causal: bool, block_q: int,
                               block_k: int, interpret: bool, window=None,
                               heads: int = 1):
-    """(BH, S, D) → ((BH, S, D) output, (BH, S, 1) lse), D lane-padded.
-    ``heads`` flat heads a grid step (``_heads_a_step``)."""
+    """(BH, S, D) q, k and (BH, S, Dv) v → ((BH, S, Dv) output, (BH, S, 1)
+    lse), the sizes as ``_flash_fwd`` pads them.  ``heads`` flat heads a
+    grid step (``_heads_a_step``)."""
     from jax.experimental.pallas import tpu as pltpu
     bh, seq, d = q.shape
+    d_v = v.shape[-1]
     banded = window is not None
     kernel = functools.partial(
         _flash_attn_kernel, block_q=block_q, block_k=block_k,
         causal=causal, window=window)
-    tables, axes, semantics, qspec, kspec, rowspec = _query_sweep(
-        seq, block_q, block_k, d, causal, window, heads, "fwd")
+    sweep = _query_sweep(seq, block_q, block_k, d, causal, window, heads,
+                         "fwd", d_v)
     slab = () if heads == 1 else (heads,)
     with jax.named_scope(scopes.FLASH_WINDOW_FWD) if banded \
             else jax.named_scope(scopes.FLASH_FWD):
         return pl.pallas_call(
-            _heads_a_step(kernel, heads, len(tables)),
+            _heads_a_step(kernel, heads, len(sweep.tables)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=len(tables),
-                grid=(bh // heads,) + axes,
-                in_specs=[qspec, kspec, kspec],
-                out_specs=[qspec, rowspec],
+                num_scalar_prefetch=len(sweep.tables),
+                grid=(bh // heads,) + sweep.axes,
+                in_specs=[sweep.q, sweep.k, sweep.v],
+                out_specs=[sweep.o, sweep.rows],
                 scratch_shapes=[
                     pltpu.VMEM(slab + (block_q, 1), jnp.float32),
                     pltpu.VMEM(slab + (block_q, 1), jnp.float32),
-                    pltpu.VMEM(slab + (block_q, d), jnp.float32),
+                    pltpu.VMEM(slab + (block_q, d_v), jnp.float32),
                 ]),
             out_shape=[
-                _sds((bh, seq, d), q.dtype, q),
+                _sds((bh, seq, d_v), q.dtype, q),
                 _sds((bh, seq, 1), jnp.float32, q),
             ],
             compiler_params=None if interpret else pltpu.CompilerParams(
-                dimension_semantics=("parallel",) + semantics),
+                dimension_semantics=("parallel",) + sweep.semantics),
             interpret=interpret,
-            name=scopes.kernel_name(scopes.FLASH_WINDOW_FWD if banded
-                                    else scopes.FLASH_FWD),
-        )(*tables, q, k, v)
+            name=_sized(scopes.kernel_name(
+                scopes.FLASH_WINDOW_FWD if banded else scopes.FLASH_FWD),
+                d, d_v),
+        )(*sweep.tables, q, k, v)
 
 
 def _reference_attention(q, k, v, causal: bool, window=None):
@@ -442,6 +473,17 @@ _TUNED_BLOCKS: dict = {}
 
 _BLOCK_Q_DEFAULTS = (512, 256, 128, 64)
 _BLOCK_K_DEFAULTS = (1024, 512, 256, 128, 64)
+# (seq, d_qk, d_v, bytes an element) -> (block_q, block_k) of a full call
+# whose query/key size is another than the value's: the one shape the chip has
+# run, in bfloat16 (every other such call takes the default chains: in float32
+# these blocks pass the forward kernel's VMEM; ``autotune_flash_blocks``
+# sweeps ``d_qk == d_v`` calls).  At 192 over 128 a block pair costs more
+# whatever it computes (q, k and the one backward kernel's dq rows lie in 256
+# lanes) and 1024 x 1024 halves the pairs: in kanana's step a forward call of
+# 64 flat heads went 12.86 -> 11.93 ms and the one backward kernel 27.80 ->
+# 26.96 against 512 x 1024 (PERF.md, PR 37; 512 x 2048 passes the one
+# kernel's VMEM limit).
+_TWO_SIZE_BLOCKS = {(8192, 192, 128, 2): (1024, 1024)}
 
 
 def export_tuned_blocks() -> dict:
@@ -498,8 +540,10 @@ _ONEPASS_VMEM_BUDGET = 32 << 20
 _ONEPASS_VMEM_ROOM = 16 << 20
 
 
-def _onepass_vmem_bytes(heads: int, s: int, d_pad: int, itemsize: int):
-    return heads * s * d_pad * (4 + 2 * itemsize)
+def _onepass_vmem_bytes(heads: int, s: int, d: int, itemsize: int):
+    """dq of ``heads`` flat heads as the one kernel holds it, at the lanes
+    its query/key size takes in VMEM (192 lies in 256)."""
+    return heads * s * _d_pad(d) * (4 + 2 * itemsize)
 
 
 def _onepass_heads(flat_heads: int, s: int, d_pad: int, itemsize: int,
@@ -550,11 +594,17 @@ def _d_pad(d: int) -> int:
     return max(128, ((d + 127) // 128) * 128)
 
 
-def _plan(s: int, d: int, window=None):
+def _plan(s: int, d: int, window=None, d_v=None, itemsize: int = 2):
     """Block plan shared by fwd and bwd.  Large tiles amortize
     per-grid-step overhead; MXU tiles are 128-aligned so any divisor
     ≥64 works.  The head dim is lane-padded to 128 (zero columns add 0
-    to every dot product).  Precedence: HVD_TPU_FLASH_BLOCK_Q/K env
+    to every dot product); where the value's size ``d_v`` is another than
+    the query/key size ``d``, the query/key size goes in as it is (it is a
+    contraction, and a block as wide as its array is legal: 192 stays 192
+    in HBM), the value's is padded as ever by the caller, and the blocks
+    are the measured ones of ``_TWO_SIZE_BLOCKS`` or the default chains (the
+    pins are of ``d == d_v`` calls).
+    Precedence: HVD_TPU_FLASH_BLOCK_Q/K env
     overrides (must divide the sequence length) > blocks pinned by
     ``autotune_flash_blocks`` (the measured sweep) > the default
     chains.  Under a window the chains stop at the window: a block pair
@@ -582,9 +632,14 @@ def _plan(s: int, d: int, window=None):
         return next((b for b in dflt_chain if s % b == 0 and b <= cap),
                     None)
 
-    d_pad = _d_pad(d)
-    tuned = _TUNED_BLOCKS.get((s, d_pad) if window is None
-                              else (s, d_pad, window))
+    if d_v in (None, d):
+        d_pad = _d_pad(d)
+        tuned = _TUNED_BLOCKS.get((s, d_pad) if window is None
+                                  else (s, d_pad, window))
+    else:
+        d_pad = d
+        tuned = None if window else _TWO_SIZE_BLOCKS.get(
+            (s, d, d_v, itemsize))
     cap = s if window is None else max(64, window)
     block_q = _env_block("HVD_TPU_FLASH_BLOCK_Q",
                          tuned[0] if tuned else None, _BLOCK_Q_DEFAULTS)
@@ -624,16 +679,18 @@ def _flash_attention_impl(q, k, v, causal, window):
 
 def _flash_fwd(q, k, v, causal, window):
     b, s, h, d = q.shape
-    block_q, block_k, d_pad, pre_scale = _plan(s, d, window)
+    d_v = v.shape[-1]
+    block_q, block_k, d_pad, pre_scale = _plan(s, d, window, d_v,
+                                               q.dtype.itemsize)
     if block_q is None or block_k is None:
         out = _reference_attention(q, k, v, causal, window)
         return out, (q, k, v, None, None)
     out, lse = _flash_attention_fwd_flat(
         _to_flat(q * pre_scale, d_pad), _to_flat(k, d_pad),
-        _to_flat(v, d_pad), causal=causal, block_q=block_q,
+        _to_flat(v, _d_pad(d_v)), causal=causal, block_q=block_q,
         block_k=block_k, interpret=not on_tpu(), window=window,
         heads=_heads_of(b * h, window))
-    out = out[:, :, :d].reshape(b, h, s, d)
+    out = out[:, :, :d_v].reshape(b, h, s, d_v)
     out = jnp.swapaxes(out, 1, 2)
     return out, (q, k, v, out, lse)
 
@@ -782,35 +839,38 @@ def _flash_bwd_dkv_kernel(*refs, block_q: int, block_k: int, causal: bool,
 def _flash_attention_bwd_flat(q, k, v, g, lse, delta, *, causal: bool,
                               block_q: int, block_k: int,
                               interpret: bool, window=None, heads: int = 1):
-    """Flat (BH, S, D) backward via the two Pallas kernels above;
-    returns (dq, dk, dv) with dq still in the fwd's q scaling.  ``heads``
-    flat heads a grid step (``_heads_a_step``)."""
+    """Flat backward via the two Pallas kernels above ((BH, S, D) q, k and
+    (BH, S, Dv) v, g); returns (dq, dk, dv) with dq still in the fwd's q
+    scaling.  ``heads`` flat heads a grid step (``_heads_a_step``)."""
     from jax.experimental.pallas import tpu as pltpu
     bh, seq, d = q.shape
+    d_v = v.shape[-1]
     banded = window is not None
     slab = () if heads == 1 else (heads,)
-    tables, axes, semantics, qspec, kspec, rowspec = _query_sweep(
-        seq, block_q, block_k, d, causal, window, heads, "dq")
+    sweep = _query_sweep(seq, block_q, block_k, d, causal, window, heads,
+                         "dq", d_v)
     with jax.named_scope(scopes.FLASH_WINDOW_DQ) if banded \
             else jax.named_scope(scopes.FLASH_DQ):
         dq = pl.pallas_call(
             _heads_a_step(functools.partial(
                 _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                causal=causal, window=window), heads, len(tables)),
+                causal=causal, window=window), heads, len(sweep.tables)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=len(tables),
-                grid=(bh // heads,) + axes,
-                in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-                out_specs=qspec,
+                num_scalar_prefetch=len(sweep.tables),
+                grid=(bh // heads,) + sweep.axes,
+                in_specs=[sweep.q, sweep.k, sweep.v, sweep.o, sweep.rows,
+                          sweep.rows],
+                out_specs=sweep.q,
                 scratch_shapes=[
                     pltpu.VMEM(slab + (block_q, d), jnp.float32)]),
             out_shape=_sds((bh, seq, d), q.dtype, q),
             compiler_params=None if interpret else pltpu.CompilerParams(
-                dimension_semantics=("parallel",) + semantics),
+                dimension_semantics=("parallel",) + sweep.semantics),
             interpret=interpret,
-            name=scopes.kernel_name(scopes.FLASH_WINDOW_DQ if banded
-                                    else scopes.FLASH_DQ),
-        )(*tables, q, k, v, g, lse, delta)
+            name=_sized(scopes.kernel_name(
+                scopes.FLASH_WINDOW_DQ if banded else scopes.FLASH_DQ),
+                d, d_v),
+        )(*sweep.tables, q, k, v, g, lse, delta)
     with jax.named_scope(scopes.FLASH_WINDOW_DKV) if banded \
             else jax.named_scope(scopes.FLASH_DKV):
         dk, dv = _flash_bwd_by_key_block(
@@ -830,9 +890,11 @@ def _flash_bwd_by_key_block(q, k, v, g, lse, delta, *, causal: bool,
     step), the query blocks that meet the key block (the last one held for
     the steps a narrower key block has left over).  (dk, dv), or with
     ``with_dq`` (dq, dk, dv) from the one kernel, whose dq is a whole-head
-    block that stays put through the head's sweep."""
+    block that stays put through the head's sweep.  q, k, dq and dk are
+    ``d`` wide, v, g and dv ``d_v``."""
     from jax.experimental.pallas import tpu as pltpu
     bh, seq, d = q.shape
+    d_v = v.shape[-1]
     slab = () if heads == 1 else (heads,)
     nq = seq // block_q
     if window is None:
@@ -841,30 +903,37 @@ def _flash_bwd_by_key_block(q, k, v, g, lse, delta, *, causal: bool,
         axes, semantics = (len(pairs.q),), ("arbitrary",)
         qspec2, kspec2, rowspec2 = _scheduled_specs(block_q, block_k, d,
                                                     heads)
+        gspec2, vspec2, _ = _scheduled_specs(block_q, block_k, d_v, heads)
     else:
         def q_at(t, u):
             return jnp.minimum(
                 _band_first_q(t, block_q, block_k) + u,
                 _band_last_q(t, block_q, block_k, window, nq))
+
+        def q_spec(width):
+            return pl.BlockSpec((heads, block_q, width),
+                                lambda i, t, u: (i, q_at(t, u), 0))
+
+        def k_spec(width):
+            return pl.BlockSpec((heads, block_k, width),
+                                lambda i, t, j: (i, t, 0))
+
         tables = ()
         axes = (seq // block_k, _band_steps(seq, block_q, block_k, window)[1])
         # Every key block of a head but its last leaves the one kernel's dq
         # unfinished, so the key axis is then nobody's to split.
         semantics = ("arbitrary" if with_dq else "parallel", "arbitrary")
-        qspec2 = pl.BlockSpec((heads, block_q, d),
-                              lambda i, t, u: (i, q_at(t, u), 0))
-        kspec2 = pl.BlockSpec((heads, block_k, d), lambda i, t, j: (i, t, 0))
-        rowspec2 = pl.BlockSpec((heads, block_q, 1),
-                                lambda i, t, u: (i, q_at(t, u), 0))
+        qspec2, kspec2, rowspec2 = q_spec(d), k_spec(d), q_spec(1)
+        gspec2, vspec2 = q_spec(d_v), k_spec(d_v)
     kernel = functools.partial(
         _flash_bwd_onepass_kernel if with_dq else _flash_bwd_dkv_kernel,
         block_q=block_q, block_k=block_k, causal=causal, window=window,
         n_q_blocks=nq)
-    out_specs = [kspec2, kspec2]
+    out_specs = [kspec2, vspec2]
     out_shape = [_sds((bh, seq, d), k.dtype, k),
-                 _sds((bh, seq, d), v.dtype, v)]
+                 _sds((bh, seq, d_v), v.dtype, v)]
     scratch = [pltpu.VMEM(slab + (block_k, d), jnp.float32),
-               pltpu.VMEM(slab + (block_k, d), jnp.float32)]
+               pltpu.VMEM(slab + (block_k, d_v), jnp.float32)]
     params = {}
     if with_dq:
         out_specs.insert(0, pl.BlockSpec((heads, seq, d),
@@ -878,14 +947,14 @@ def _flash_bwd_by_key_block(q, k, v, g, lse, delta, *, causal: bool,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables),
             grid=(bh // heads,) + axes,
-            in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
+            in_specs=[qspec2, kspec2, vspec2, gspec2, rowspec2, rowspec2],
             out_specs=out_specs,
             scratch_shapes=scratch),
         out_shape=out_shape,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel",) + semantics, **params),
         interpret=interpret,
-        name=name,
+        name=_sized(name, d, d_v),
     )(*tables, q, k, v, g, lse, delta)
 
 
@@ -956,7 +1025,9 @@ def _flash_bwd(causal, window, res, g):
             q, k, v)
         return vjp(g)
     b, s, h, d = q.shape
-    block_q, block_k, d_pad, pre_scale = _plan(s, d, window)
+    d_v = v.shape[-1]
+    block_q, block_k, d_pad, pre_scale = _plan(s, d, window, d_v,
+                                               q.dtype.itemsize)
     form, heads = _backward_form(b * h, s, d_pad, q.dtype.itemsize, window)
     count(form)
     if form == "chunked":
@@ -972,7 +1043,7 @@ def _flash_bwd(causal, window, res, g):
                 else _flash_attention_bwd_flat)
     dq, dk, dv = bwd_flat(
         _to_flat(q * pre_scale, d_pad), _to_flat(k, d_pad),
-        _to_flat(v, d_pad), _to_flat(g, d_pad), lse, delta,
+        _to_flat(v, _d_pad(d_v)), _to_flat(g, _d_pad(d_v)), lse, delta,
         causal=causal, block_q=block_q, block_k=block_k,
         interpret=not on_tpu(), window=window, heads=heads)
     # The kernels differentiate w.r.t. the PRE-SCALED q, so
@@ -982,7 +1053,7 @@ def _flash_bwd(causal, window, res, g):
     # picks up one rounding, not two.
     return (_from_flat(dq.astype(jnp.float32) * pre_scale, b, h, d, q),
             _from_flat(dk, b, h, d, k),
-            _from_flat(dv, b, h, d, v))
+            _from_flat(dv, b, h, d_v, v))
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -992,13 +1063,22 @@ def flash_attention(q, k, v, causal: bool = True, window=None):
     """Fused blocked attention, layout ``(batch, seq, heads, dim)``
     (the framework's attention layout).  Differentiable; compiled
     Pallas on TPU, interpreted elsewhere.  Sequences not divisible by
-    64 fall back to plain XLA attention.  GQA (kv_heads < heads) is
-    handled by repeating KV head groups.  A call's kernels visit the block
+    64 fall back to plain XLA attention.  ``q`` and ``k`` are ``[B, S, H,
+    d_qk]``, ``v`` ``[B, S, H, d_v]`` and the result ``[B, S, H, d_v]``; the
+    scores are scaled by ``d_qk^-1/2``.  The two sizes may differ (latent
+    attention's 192 over 128): the same kernels then contract the scores
+    over ``d_qk`` as it is and keep ``d_v`` for the values, the output and
+    their gradients, under names that end in the sizes
+    (``hvd_flash_fwd_192x128``); nothing is padded to the larger size, and
+    where the sizes are equal the call is what it was.  Grouped queries
+    (fewer key/value heads than query heads): every key/value head is
+    repeated for its group before the kernels, and the repeat's transpose
+    sums the group's gradients.  A call's kernels visit the block
     pairs that hold work and no others (``_block_schedule``: under the
     causal mask the pairs that meet the triangle).  ``window``: a causal
     query sees its last ``window`` keys, itself among them; the kernels
     then visit the blocks of that band alone, under scopes and names of
-    their own (``hvd.flash_window_*``)."""
+    their own (``hvd.flash_window_*``), at one head size or two."""
     if window is not None:
         if not causal or window < 1:
             raise ValueError("a window of %r keys needs a causal mask and "
@@ -1026,7 +1106,7 @@ def use_flash_attention() -> bool:
 
 def flash_plan_info(s: int, d: int) -> dict:
     """Attribution record for the benchmark JSON: which blocks the plan
-    would pick for (seq, head_dim) and WHY (env override, autotuned
+    would pick for (seq, head_dim) of a ``d_qk == d_v`` call and WHY (env override, autotuned
     pin, or default chain), plus the active backward variant.  Pure
     metadata — never traces or compiles anything."""
     import os
@@ -1048,8 +1128,8 @@ def flash_plan_info(s: int, d: int) -> dict:
 
 def flash_block_candidates(seq: int, d: int,
                            vmem_budget_bytes: int = 12 << 20):
-    """(block_q, block_k) sweep grid for one (seq, head_dim) shape:
-    every sublane-aligned pair dividing the sequence whose resident
+    """(block_q, block_k) sweep grid for one (seq, head_dim) shape of a
+    ``d_qk == d_v`` call: every sublane-aligned pair dividing the sequence whose resident
     f32 working set (scores + dq/dk/dv accumulators + double-buffered
     in/out blocks) fits the VMEM budget (~16 MB/core minus headroom)."""
     d_pad = _d_pad(d)
@@ -1100,6 +1180,8 @@ def autotune_flash_blocks(seq: int, d: int, *, batch_heads: int = 8,
                           allreduce_scores=None, report_core=True,
                           pin: bool = True):
     """Measure fwd(+bwd) TFLOP/s for each (block_q, block_k) candidate
+    of a ``d_qk == d_v`` call (a call at two head sizes takes the default
+    chains, ``_plan``)
     on the local device and PIN the winner into the plan registry, so
     the blocks the kernels run with are tuned, not hardcoded (the
     kernel-parameter leg of the autotune plane; fusion/cycle stay with

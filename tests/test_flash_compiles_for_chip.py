@@ -103,6 +103,78 @@ def test_the_scheduled_two_backward_kernels_compile_for_v5e(one_chip,
         == [((flat_heads, seq, 128), jnp.dtype(jnp.bfloat16))] * 3
 
 
+# kanana's latent-attention calls: [2, 8192, 32 heads] as 64 flat heads,
+# query/key heads of 192 over values of 128, neither padded; the forward
+# kernel, the one backward kernel (dq of a head in 256 lanes: 16 MiB) and the
+# two kernels ``HVD_TPU_FLASH_BWD=pallas`` would take; and the forward kernel
+# in float32, which the cell's builder runs once before the first step (the
+# measured 1024 x 1024 blocks are bfloat16's: in float32 they pass the
+# kernel's VMEM, and the plan gives the chains' 512 x 1024).
+@pytest.mark.parametrize("kernel, calls, dtype", [
+    ("forward", 1, jnp.bfloat16), ("onepass", 1, jnp.bfloat16),
+    ("two_kernel", 2, jnp.bfloat16), ("forward", 1, jnp.float32)])
+def test_the_kernels_at_two_head_sizes_compile_for_v5e(one_chip, kernel,
+                                                       calls, dtype):
+    flat_heads, seq, d_qk, d_v = 64, 8192, 192, 128
+    block_q, block_k, d_pad, _ = pk._plan(seq, d_qk, None, d_v,
+                                          jnp.dtype(dtype).itemsize)
+    assert (block_q, block_k) == ((1024, 1024) if dtype == jnp.bfloat16
+                                  else (512, 1024))
+    assert (d_pad, pk._d_pad(d_v)) == (192, 128)
+    assert pk._backward_form(flat_heads, seq, d_pad, 2, None) \
+        == ("onepass", 1)
+
+    def shape(width, dtype=dtype):
+        return jax.ShapeDtypeStruct((flat_heads, seq, width), dtype,
+                                    sharding=one_chip)
+
+    qk, v, rows = shape(d_qk), shape(d_v), shape(1, jnp.float32)
+    plan = dict(causal=True, block_q=block_q, block_k=block_k,
+                interpret=False)
+    if kernel == "forward":
+        compiled = jax.jit(functools.partial(
+            pk._flash_attention_fwd_flat, **plan)).lower(qk, qk, v).compile()
+        want = [(v.shape, v.dtype), (rows.shape, rows.dtype)]
+    else:
+        flat = (pk._flash_attention_bwd_onepass_flat if kernel == "onepass"
+                else pk._flash_attention_bwd_flat)
+        compiled = jax.jit(functools.partial(flat, **plan)).lower(
+            qk, qk, v, v, rows, rows).compile()
+        # dq and dk at the query/key size, dv at the value's
+        want = [(qk.shape, qk.dtype)] * 2 + [(v.shape, v.dtype)]
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == calls
+    assert "192x128" in text
+    assert [(o.shape, o.dtype) for o in compiled.out_info] == want
+
+
+def test_the_banded_kernels_at_two_head_sizes_compile_for_v5e(one_chip):
+    """No cell runs a window over two head sizes; the banded grids share the
+    full calls' plumbing, and Mosaic takes them at 192 over 128 (two heads a
+    step in the one backward kernel: dq of four would pass its budget)."""
+    flat_heads, seq, window = 64, 8192, 512
+    block_q, block_k, d_pad, _ = pk._plan(seq, 192, window, 128)
+    assert pk._backward_form(flat_heads, seq, d_pad, 2, window) \
+        == ("onepass", 2)
+
+    def shape(width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((flat_heads, seq, width), dtype,
+                                    sharding=one_chip)
+
+    qk, v, rows = shape(192), shape(128), shape(1, jnp.float32)
+    plan = dict(causal=True, block_q=block_q, block_k=block_k,
+                interpret=False, window=window)
+    fwd = jax.jit(functools.partial(
+        pk._flash_attention_fwd_flat, heads=pk._heads_of(flat_heads, window),
+        **plan)).lower(qk, qk, v).compile()
+    bwd = jax.jit(functools.partial(
+        pk._flash_attention_bwd_onepass_flat, heads=2, **plan)).lower(
+            qk, qk, v, v, rows, rows).compile()
+    assert "hvd_flash_window_fwd_192x128" in fwd.as_text()
+    assert [(o.shape, o.dtype) for o in bwd.out_info] \
+        == [(qk.shape, qk.dtype)] * 2 + [(v.shape, v.dtype)]
+
+
 # nemotron's scan (2 x 8192 tokens, 64 heads of 64 in 8 groups, state 128,
 # chunk 128, bfloat16) first; then what else ``ssd_kernels.takes`` says yes
 # to: a chunk of 256, float32 activations, a head a lane tile, four heads a

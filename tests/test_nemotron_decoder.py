@@ -403,7 +403,11 @@ def test_the_scan_and_the_mixer_carry_their_scopes():
 # ``form="swiglu"`` and patterns of pairs the traced programs are the
 # parent's to the letter.  A PR that changes those programs on purpose
 # computes these again (``hashlib.sha256(lowered(cell, builder).encode())``).
+# ``nemotron``'s own is of PR 36 (commit d259f55), before the latent-attention
+# mixer and the flash kernels' second head size.
 PARENTS = {
+    ("nemotron-3-nano-30b-a3b.dp1-pt8k", "nemotron_h"):
+        "e2652c33ba96cd0fbf2e58291c393e635ba99544018c9dbaffc82b9148a0f361",
     ("solar-open2-250b.dp1-pt8k", "solar_open2"):
         "5fcd1a630d4b705efe90181c0306daf9c57773270250aaadb55cdeaef2911a1a",
     ("laguna-xs2.dp1-pt8k", "laguna"):

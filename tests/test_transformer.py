@@ -221,6 +221,10 @@ def test_every_pair_of_the_pattern_builds_and_steps(mixer, ffn):
         experts=ExpertShare(n_experts=4, first=1, count=2, top_k=2,
                             d_model=32, d_ff=16, d_shared=16,
                             block_rows=8))
+    _one_step_moves_every_leaf(cfg)
+
+
+def _one_step_moves_every_leaf(cfg):
     params = init_params(jax.random.PRNGKey(0), cfg)
     specs = param_specs(cfg)
     assert isinstance(params["layers"], tuple) and len(params["layers"]) == 1
@@ -242,6 +246,42 @@ def test_every_pair_of_the_pattern_builds_and_steps(mixer, ffn):
         assert np.isfinite(g).all(), path
         if path[-1].key != "router_bias":   # chooses experts, no gradient
             assert np.abs(g).max() > 0, path
+
+
+LATENT = transformer.LatentAttention(n_heads=4, kv_rank=16, nope=8,
+                                     rope_dim=4, v_dim=6)
+
+
+@pytest.mark.parametrize("leading, pattern", [
+    (((LATENT, "dense"),), (("attention", "dense"),)),
+    ((), ((LATENT, "expert_share"),)),
+])
+def test_a_latent_attention_entry_builds_and_steps(leading, pattern):
+    """A ``LatentAttention`` stands where a mixer stands, in a leading
+    layer and in the scanned pattern: its five leaves (``wq``, ``wkv_a``,
+    ``kv_norm``, ``wkv_b``, ``wo``) have specs and gradients."""
+    cfg = _cfg(
+        n_layers=2 + len(leading), leading_layers=leading,
+        layer_pattern=pattern,
+        experts=ExpertShare(n_experts=4, first=1, count=2, top_k=2,
+                            d_model=32, d_ff=16, d_shared=16,
+                            block_rows=8))
+    layers = init_params(jax.random.PRNGKey(0), cfg)[
+        "leading" if leading else "layers"][0]
+    assert {"wq", "wkv_a", "kv_norm", "wkv_b", "wo"} <= set(layers)
+    assert layers["wkv_a"].shape[1:] == (32, 16 + 4)
+    assert layers["wkv_b"].shape[1:] == (16, 4 * (8 + 6))
+    _one_step_moves_every_leaf(cfg)
+
+
+def test_a_latent_attention_entry_refuses_a_split_sequence():
+    cfg = _cfg(layer_pattern=((LATENT, "dense"),))
+    build, _ = make_train_step(cfg, _mesh((1, 2, 1), ("dp", "sp", "tp")),
+                               optax.sgd(1.0))
+    step, placed, opt_state = build(init_params(jax.random.PRNGKey(0), cfg))
+    with pytest.raises(ValueError, match="latent-attention.*cannot be split"):
+        step(placed, opt_state, {k: jnp.zeros((2, 16), jnp.int32)
+                                 for k in ("tokens", "targets")})
 
 
 def test_both_models_take_the_kernel_choice_from_ops(monkeypatch):
