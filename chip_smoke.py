@@ -17,8 +17,9 @@ made from a seed and a few steps each:
                    step must hold the Mosaic kernels and no interpreted
                    one, and the flash forward and backward must agree
                    with ``_reference_attention`` at the flagship shape,
-                   and again with query/key heads of 192 over its values
-                   (a latent-attention call).
+                   and again with query/key heads of 192 over its values,
+                   64 of them a key part all heads share (a
+                   latent-attention call).
 * ``collectives``  the eager ``horovod_tpu.ops.api`` surface in
                    ``inprocess`` mode at a small and a large
                    (64 MiB per rank) size, every result against numpy.
@@ -434,15 +435,26 @@ def check_flash_against_reference():
         ok &= close("backward (two_kernel) d" + name, got, want)
 
     # One latent-attention call: query/key heads of 192 over the same
-    # values (the kernels at two head sizes, their own names).
-    q, k = (jnp.asarray(
-        rng.standard_normal(shape[:3] + (192,), np.float32) * 0.5,
-        jnp.bfloat16) for _ in range(2))
-    (_, want_out), want_grads = weighted(pk._reference_attention)(q, k, v)
-    (_, out), grads = weighted(pk.flash_attention)(q, k, v)
-    ok &= close("forward 192x%d" % shape[3], out, want_out)
-    for name, got, want in zip("qkv", grads, want_grads):
-        ok &= close("backward 192x%d d%s" % (shape[3], name), got, want)
+    # values, the last 64 of every head's key one rotary key a position,
+    # which the kernels read as an operand of its own (names of their own).
+    q, shared = (jnp.asarray(
+        rng.standard_normal(dims, np.float32) * 0.5, jnp.bfloat16)
+        for dims in (shape[:3] + (shape[3] + 64,), shape[:2] + (64,)))
+
+    def in_two_parts(attn):
+        return weighted(lambda q, parts, v, causal: attn(
+            q, parts[0], v, causal, k_shared=parts[1]))
+
+    (_, want_out), want_grads = in_two_parts(
+        lambda q, k, v, causal, k_shared: pk._reference_attention(
+            q, pk.whole_key(k, k_shared), v, causal))(q, (k, shared), v)
+    (_, out), grads = in_two_parts(pk.flash_attention)(q, (k, shared), v)
+    sizes = "%ds64x%d" % (shape[3], shape[3])
+    ok &= close("forward " + sizes, out, want_out)
+    for name, got, want in zip(
+            ("q", "k", "k_shared", "v"), jax.tree.leaves(grads),
+            jax.tree.leaves(want_grads)):
+        ok &= close("backward %s d%s" % (sizes, name), got, want)
     assert ok, "flash attention disagrees with the reference on the chip"
     return form
 

@@ -296,6 +296,13 @@ NAMES: Dict[str, Tuple[str, str]] = {
                    "no mask; diagonal = cut by the causal mask); the "
                    "call's schedule, counted as its kernel is traced, like "
                    "hvd_flash_backward_calls_total"),
+    "hvd_flash_shared_key_calls_total": (
+        "counter", "flash kernels that read a shared key part (k_shared: "
+                   "columns every head's key ends in, [B, S, d_s]) as an "
+                   "operand of its own, labeled kernel "
+                   "(fwd|dq|dkv|onepass); counted as the kernel is traced, "
+                   "like hvd_flash_block_pairs_total, and absent where no "
+                   "call hands the kernels such a part"),
     "hvd_ssd_scan_calls_total": (
         "counter", "state-space scans (models/state_space.py: "
                    "ssd_chunked) by the form their shapes took, labeled "

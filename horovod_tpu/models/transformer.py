@@ -593,9 +593,11 @@ def _latent_attention_block(x, lp, cfg: TransformerConfig,
     rms_norm(c) W_kv_b`` a head; the rotary table turns every head's
     ``q_rope`` and the one ``k_rope``, which every head's key ends in; the
     scores' scale is the query/key size's (``flash_attention`` and
-    ``local_attention`` take it from q).  The heads are what the weights
-    hold on this tp shard; the sequence is whole (``_mix`` refuses a split
-    one)."""
+    ``local_attention`` take it from q).  The flash kernels take ``k_rope``
+    as it is, ``[B, S, rope_dim]`` (``k_shared``), and no key of the whole
+    size is built; ``local_attention`` gets it repeated behind every head's
+    ``k_nope``.  The heads are what the weights hold on this tp shard; the
+    sequence is whole (``_mix`` refuses a split one)."""
     with jax.named_scope(scopes.LATENT_ATTENTION):
         b, s, _ = x.shape
         nope, rank = kind.nope, kind.kv_rank
@@ -611,20 +613,22 @@ def _latent_attention_block(x, lp, cfg: TransformerConfig,
         cos, sin = tables[kind]
         q = jnp.concatenate([q[..., :nope],
                              _rope(cos, sin, q[..., nope:])], axis=-1)
-        k_rope = _rope(cos, sin, latent[:, :, None, rank:])
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_rope, kv.shape[:3] + (kind.rope_dim,))],
-            axis=-1)
-        attn = _causal_attention(q, k, kv[..., nope:], cfg, None,
-                                 1).reshape(b, s, -1)
+        k_rope = _rope(cos, sin, latent[:, :, None, rank:])[:, :, 0]
+        if pallas_kernels.use_flash_attention():
+            attn = pallas_kernels.flash_attention(
+                q, kv[..., :nope], kv[..., nope:], causal=True,
+                k_shared=k_rope)
+        else:
+            attn = local_attention(
+                q, pallas_kernels.whole_key(kv[..., :nope], k_rope),
+                kv[..., nope:], causal=True)
+        attn = attn.reshape(b, s, -1)
         # Row-sharded wo: partial sums live on each tp shard.
         return _row_parallel_product(attn, lp["wo"].astype(x.dtype), cfg)
 
 
 def _causal_attention(q, k, v, cfg: TransformerConfig, window, sp_size):
-    """Causal softmax attention over ``[B, S, heads, head_dim]`` (the
-    values' heads may be of another size than the queries' and keys') by
+    """Causal softmax attention over ``[B, S, heads, head_dim]`` by
     whichever form the layout calls for."""
     if sp_size > 1 and window is not None:
         raise ValueError("a window of %d keys crosses the shards of a "

@@ -127,6 +127,54 @@ def _scheduled_specs(block_q: int, block_k: int, d: int, heads: int):
     return spec(block_q, d, 0), spec(block_k, d, 1), spec(block_q, 1, 0)
 
 
+# A key in two parts (latent attention: every head's own columns and, behind
+# them, the one rotary key a position that all heads of a batch entry end
+# in): the shared part goes to a full call's kernels as an operand of its
+# own, ``[B, S, d_s]``, a block a key block by the call's schedule, and is
+# never repeated over the heads in HBM.
+
+def _shared_key_spec(block_k: int, d_s: int, group: int, kernel: str):
+    """BlockSpec of the shared key part on a scheduled grid: flat head ``i``
+    reads batch entry ``i // group``'s block, by the table its own key block
+    follows.  Counted as it is made: once for every time a call's kernel is
+    traced, like ``hvd_flash_block_pairs_total``."""
+    metrics.counter("hvd_flash_shared_key_calls_total", kernel=kernel).inc()
+    return pl.BlockSpec(
+        (1, block_k, d_s),
+        lambda i, step, *tables: (i // group, tables[1][step], 0))
+
+
+class _JoinedKey:
+    """A key block in two refs, every head's own columns and the shared ones
+    behind them, for a kernel written for one: read (``ref[0]``), the two are
+    joined in VMEM; written (the key's gradient, once a key block), each ref
+    takes its columns."""
+
+    def __init__(self, own, shared):
+        self.own, self.shared, self.dtype = own, shared, own.dtype
+
+    def __getitem__(self, index):
+        return jnp.concatenate([self.own[index], self.shared[index]], axis=-1)
+
+    def __setitem__(self, index, value):
+        d_k = self.own.shape[-1]
+        self.own[index] = value[:, :d_k]
+        self.shared[index] = value[:, d_k:]
+
+
+def _key_joined(kernel, *at):
+    """``kernel`` (written for a key in one ref) over refs that hold a
+    shared key part right behind the key's own: ``at`` says where, the key
+    among the inputs first and then its gradient among the outputs, each
+    place counted with the pairs before it already joined."""
+    def body(*refs):
+        refs = list(refs)
+        for i in at:
+            refs[i:i + 2] = [_JoinedKey(refs[i], refs[i + 1])]
+        kernel(*refs)
+    return body
+
+
 # A window of ``w`` keys (sliding-window attention): query ``i`` sees key
 # ``j`` when ``i - w < j <= i``, itself among them.  The kernels below run
 # their inner grid axis over the blocks that meet that band and no others:
@@ -331,7 +379,9 @@ class _Sweep(NamedTuple):
     """A swept grid: the schedule's tables (none under a window), the axes
     after the heads' with their semantics, and the BlockSpecs of a query
     block and a key block at the query/key size, of the row statistics, and
-    of a query block and a key block at the value's size."""
+    of a query block and a key block at the value's size; a key in two
+    parts has ``k`` at its own columns' size and the shared part's BlockSpec
+    in ``shared``."""
     tables: tuple
     axes: tuple
     semantics: tuple
@@ -340,21 +390,28 @@ class _Sweep(NamedTuple):
     rows: object
     o: object
     v: object
+    shared: tuple = ()
 
 
 def _query_sweep(seq: int, block_q: int, block_k: int, d: int, causal: bool,
-                 window, heads: int, kernel: str, d_v=None):
+                 window, heads: int, kernel: str, d_v=None, shared=None):
     """The grid of a kernel that sweeps a query block's keys (forward, dq).
     A full call's is its schedule, a banded call's every query block by the
     band's key steps.  ``d`` is the query/key size, ``d_v`` the value's
-    where it is another."""
+    where it is another; ``shared`` is ``(d_s, flat heads a batch entry)``
+    where the last ``d_s`` of the key's ``d`` are a part of their own."""
     d_v = d if d_v is None else d_v
     if window is None:
         pairs = _block_schedule(seq, block_q, block_k, causal)[0]
         q, k, rows = _scheduled_specs(block_q, block_k, d, heads)
         o, v, _ = _scheduled_specs(block_q, block_k, d_v, heads)
-        return _Sweep(_schedule_tables(pairs, kernel), (len(pairs.q),),
-                      ("arbitrary",), q, k, rows, o, v)
+        tables = _schedule_tables(pairs, kernel)
+        shared_spec = ()
+        if shared is not None:
+            k = _scheduled_specs(block_q, block_k, d - shared[0], heads)[1]
+            shared_spec = (_shared_key_spec(block_k, *shared, kernel),)
+        return _Sweep(tables, (len(pairs.q),), ("arbitrary",), q, k, rows, o,
+                      v, shared_spec)
 
     def q_spec(width):
         return pl.BlockSpec((heads, block_q, width), lambda i, j, t: (i, j, 0))
@@ -368,27 +425,43 @@ def _query_sweep(seq: int, block_q: int, block_k: int, d: int, causal: bool,
         q_spec(1), q_spec(d_v), _k_spec(block_q, block_k, d_v, window, heads))
 
 
-def _sized(name: str, d: int, d_v: int) -> str:
+def _sized(name: str, d: int, d_v: int, d_s: int = 0) -> str:
     """A kernel's name with the call's two head sizes where they differ, so
-    that a trace tells such a call's kernels from the others."""
+    that a trace tells such a call's kernels from the others; a key in two
+    parts names both (``hvd_flash_fwd_128s64x128``: 64 shared behind 128)."""
+    if d_s:
+        return "%s_%ds%dx%d" % (name, d - d_s, d_s, d_v)
     return name if d == d_v else "%s_%dx%d" % (name, d, d_v)
+
+
+def _shared_of(k_shared, flat_heads: int):
+    """``((d_s, flat heads a batch entry), (k_shared,), d_s)`` of a flat
+    call's shared key part ``[B, S, d_s]``, or of none."""
+    if k_shared is None:
+        return None, (), 0
+    d_s = k_shared.shape[-1]
+    return (d_s, flat_heads // k_shared.shape[0]), (k_shared,), d_s
 
 
 def _flash_attention_fwd_flat(q, k, v, *, causal: bool, block_q: int,
                               block_k: int, interpret: bool, window=None,
-                              heads: int = 1):
+                              heads: int = 1, k_shared=None):
     """(BH, S, D) q, k and (BH, S, Dv) v → ((BH, S, Dv) output, (BH, S, 1)
     lse), the sizes as ``_flash_fwd`` pads them.  ``heads`` flat heads a
-    grid step (``_heads_a_step``)."""
+    grid step (``_heads_a_step``).  With ``k_shared`` (B, S, Ds) of a full
+    call, k holds the key's first D - Ds columns."""
     from jax.experimental.pallas import tpu as pltpu
     bh, seq, d = q.shape
     d_v = v.shape[-1]
     banded = window is not None
+    shared, shared_key, d_s = _shared_of(k_shared, bh)
     kernel = functools.partial(
         _flash_attn_kernel, block_q=block_q, block_k=block_k,
         causal=causal, window=window)
     sweep = _query_sweep(seq, block_q, block_k, d, causal, window, heads,
-                         "fwd", d_v)
+                         "fwd", d_v, shared)
+    if shared:
+        kernel = _key_joined(kernel, len(sweep.tables) + 1)
     slab = () if heads == 1 else (heads,)
     with jax.named_scope(scopes.FLASH_WINDOW_FWD) if banded \
             else jax.named_scope(scopes.FLASH_FWD):
@@ -397,7 +470,7 @@ def _flash_attention_fwd_flat(q, k, v, *, causal: bool, block_q: int,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(sweep.tables),
                 grid=(bh // heads,) + sweep.axes,
-                in_specs=[sweep.q, sweep.k, sweep.v],
+                in_specs=[sweep.q, sweep.k, *sweep.shared, sweep.v],
                 out_specs=[sweep.o, sweep.rows],
                 scratch_shapes=[
                     pltpu.VMEM(slab + (block_q, 1), jnp.float32),
@@ -413,8 +486,8 @@ def _flash_attention_fwd_flat(q, k, v, *, causal: bool, block_q: int,
             interpret=interpret,
             name=_sized(scopes.kernel_name(
                 scopes.FLASH_WINDOW_FWD if banded else scopes.FLASH_FWD),
-                d, d_v),
-        )(*sweep.tables, q, k, v)
+                d, d_v, d_s),
+        )(*sweep.tables, q, k, *shared_key, v)
 
 
 def _reference_attention(q, k, v, causal: bool, window=None):
@@ -594,7 +667,8 @@ def _d_pad(d: int) -> int:
     return max(128, ((d + 127) // 128) * 128)
 
 
-def _plan(s: int, d: int, window=None, d_v=None, itemsize: int = 2):
+def _plan(s: int, d: int, window=None, d_v=None, itemsize: int = 2,
+          two_part_key: bool = False):
     """Block plan shared by fwd and bwd.  Large tiles amortize
     per-grid-step overhead; MXU tiles are 128-aligned so any divisor
     ≥64 works.  The head dim is lane-padded to 128 (zero columns add 0
@@ -603,7 +677,8 @@ def _plan(s: int, d: int, window=None, d_v=None, itemsize: int = 2):
     contraction, and a block as wide as its array is legal: 192 stays 192
     in HBM), the value's is padded as ever by the caller, and the blocks
     are the measured ones of ``_TWO_SIZE_BLOCKS`` or the default chains (the
-    pins are of ``d == d_v`` calls).
+    pins are of ``d == d_v`` calls); so it is with a key in two parts (the
+    kernels join them, and q has to be as wide as the two) whatever ``d_v``.
     Precedence: HVD_TPU_FLASH_BLOCK_Q/K env
     overrides (must divide the sequence length) > blocks pinned by
     ``autotune_flash_blocks`` (the measured sweep) > the default
@@ -632,7 +707,7 @@ def _plan(s: int, d: int, window=None, d_v=None, itemsize: int = 2):
         return next((b for b in dflt_chain if s % b == 0 and b <= cap),
                     None)
 
-    if d_v in (None, d):
+    if d_v in (None, d) and not two_part_key:
         d_pad = _d_pad(d)
         tuned = _TUNED_BLOCKS.get((s, d_pad) if window is None
                                   else (s, d_pad, window))
@@ -668,31 +743,45 @@ def _from_flat(x, b, h, d, like):
     return jnp.swapaxes(x, 1, 2).astype(like.dtype)
 
 
+def whole_key(k, k_shared):
+    """The key ``[B, S, H, d_k + d_s]`` of a key in two parts: the shared
+    part ``[B, S, d_s]`` behind every head's own ``[B, S, H, d_k]`` (what a
+    caller hands an attention that takes one key; ``k`` itself where there
+    is no shared part)."""
+    if k_shared is None:
+        return k
+    return jnp.concatenate(
+        [k, jnp.broadcast_to(k_shared[:, :, None, :],
+                             k.shape[:3] + k_shared.shape[-1:])], axis=-1)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_attention(q, k, v, causal, window):
-    return _flash_attention_impl(q, k, v, causal, window)
+def _flash_attention(q, k, v, causal, window, k_shared=None):
+    return _flash_attention_impl(q, k, v, causal, window, k_shared)
 
 
-def _flash_attention_impl(q, k, v, causal, window):
-    return _flash_fwd(q, k, v, causal, window)[0]
+def _flash_attention_impl(q, k, v, causal, window, k_shared=None):
+    return _flash_fwd(q, k, v, causal, window, k_shared)[0]
 
 
-def _flash_fwd(q, k, v, causal, window):
+def _flash_fwd(q, k, v, causal, window, k_shared=None):
     b, s, h, d = q.shape
     d_v = v.shape[-1]
+    d_s = 0 if k_shared is None else k_shared.shape[-1]
     block_q, block_k, d_pad, pre_scale = _plan(s, d, window, d_v,
-                                               q.dtype.itemsize)
+                                               q.dtype.itemsize, bool(d_s))
     if block_q is None or block_k is None:
-        out = _reference_attention(q, k, v, causal, window)
-        return out, (q, k, v, None, None)
+        out = _reference_attention(q, whole_key(k, k_shared), v, causal,
+                                   window)
+        return out, (q, k, v, k_shared, None, None)
     out, lse = _flash_attention_fwd_flat(
-        _to_flat(q * pre_scale, d_pad), _to_flat(k, d_pad),
+        _to_flat(q * pre_scale, d_pad), _to_flat(k, d_pad - d_s),
         _to_flat(v, _d_pad(d_v)), causal=causal, block_q=block_q,
         block_k=block_k, interpret=not on_tpu(), window=window,
-        heads=_heads_of(b * h, window))
+        heads=_heads_of(b * h, window), k_shared=k_shared)
     out = out[:, :, :d_v].reshape(b, h, s, d_v)
     out = jnp.swapaxes(out, 1, 2)
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, k_shared, out, lse)
 
 
 def _flash_bwd_dq_kernel(*refs, block_q: int, block_k: int, causal: bool,
@@ -838,28 +927,36 @@ def _flash_bwd_dkv_kernel(*refs, block_q: int, block_k: int, causal: bool,
 
 def _flash_attention_bwd_flat(q, k, v, g, lse, delta, *, causal: bool,
                               block_q: int, block_k: int,
-                              interpret: bool, window=None, heads: int = 1):
+                              interpret: bool, window=None, heads: int = 1,
+                              k_shared=None):
     """Flat backward via the two Pallas kernels above ((BH, S, D) q, k and
     (BH, S, Dv) v, g); returns (dq, dk, dv) with dq still in the fwd's q
-    scaling.  ``heads`` flat heads a grid step (``_heads_a_step``)."""
+    scaling.  ``heads`` flat heads a grid step (``_heads_a_step``).  With
+    ``k_shared`` (B, S, Ds) of a full call, k and dk hold the key's first
+    D - Ds columns and the shared part's gradient comes behind dk, a flat
+    head's share each: (dq, dk, (BH, S, Ds), dv)."""
     from jax.experimental.pallas import tpu as pltpu
     bh, seq, d = q.shape
     d_v = v.shape[-1]
     banded = window is not None
     slab = () if heads == 1 else (heads,)
+    shared, shared_key, d_s = _shared_of(k_shared, bh)
     sweep = _query_sweep(seq, block_q, block_k, d, causal, window, heads,
-                         "dq", d_v)
+                         "dq", d_v, shared)
+    kernel = functools.partial(
+        _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
+        causal=causal, window=window)
+    if shared:
+        kernel = _key_joined(kernel, len(sweep.tables) + 1)
     with jax.named_scope(scopes.FLASH_WINDOW_DQ) if banded \
             else jax.named_scope(scopes.FLASH_DQ):
         dq = pl.pallas_call(
-            _heads_a_step(functools.partial(
-                _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                causal=causal, window=window), heads, len(sweep.tables)),
+            _heads_a_step(kernel, heads, len(sweep.tables)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(sweep.tables),
                 grid=(bh // heads,) + sweep.axes,
-                in_specs=[sweep.q, sweep.k, sweep.v, sweep.o, sweep.rows,
-                          sweep.rows],
+                in_specs=[sweep.q, sweep.k, *sweep.shared, sweep.v, sweep.o,
+                          sweep.rows, sweep.rows],
                 out_specs=sweep.q,
                 scratch_shapes=[
                     pltpu.VMEM(slab + (block_q, d), jnp.float32)]),
@@ -869,41 +966,50 @@ def _flash_attention_bwd_flat(q, k, v, g, lse, delta, *, causal: bool,
             interpret=interpret,
             name=_sized(scopes.kernel_name(
                 scopes.FLASH_WINDOW_DQ if banded else scopes.FLASH_DQ),
-                d, d_v),
-        )(*sweep.tables, q, k, v, g, lse, delta)
+                d, d_v, d_s),
+        )(*sweep.tables, q, k, *shared_key, v, g, lse, delta)
     with jax.named_scope(scopes.FLASH_WINDOW_DKV) if banded \
             else jax.named_scope(scopes.FLASH_DKV):
-        dk, dv = _flash_bwd_by_key_block(
+        return (dq, *_flash_bwd_by_key_block(
             q, k, v, g, lse, delta, causal=causal, block_q=block_q,
             block_k=block_k, interpret=interpret, window=window, heads=heads,
             name=scopes.kernel_name(scopes.FLASH_WINDOW_DKV if banded
-                                    else scopes.FLASH_DKV))
-    return dq, dk, dv
+                                    else scopes.FLASH_DKV),
+            k_shared=k_shared))
 
 
 def _flash_bwd_by_key_block(q, k, v, g, lse, delta, *, causal: bool,
                             block_q: int, block_k: int, interpret: bool,
                             window, heads: int, name: str,
-                            with_dq: bool = False):
+                            with_dq: bool = False, k_shared=None):
     """The ``pallas_call`` that sweeps a key block's queries: a full call's
     grid is its schedule by key block, a banded call's (bh, k block, q
     step), the query blocks that meet the key block (the last one held for
     the steps a narrower key block has left over).  (dk, dv), or with
     ``with_dq`` (dq, dk, dv) from the one kernel, whose dq is a whole-head
     block that stays put through the head's sweep.  q, k, dq and dk are
-    ``d`` wide, v, g and dv ``d_v``."""
+    ``d`` wide, v, g and dv ``d_v``.  With ``k_shared`` (B, S, Ds) of a full
+    call, k and dk are the key's own ``d - Ds`` columns and the shared
+    part's gradient, a flat head's share (BH, S, Ds), comes behind dk."""
     from jax.experimental.pallas import tpu as pltpu
     bh, seq, d = q.shape
     d_v = v.shape[-1]
     slab = () if heads == 1 else (heads,)
     nq = seq // block_q
+    shared, shared_key, d_s = _shared_of(k_shared, bh)
+    shared_in = shared_out = ()
     if window is None:
+        kind = "onepass" if with_dq else "dkv"
         pairs = _block_schedule(seq, block_q, block_k, causal)[1]
-        tables = _schedule_tables(pairs, "onepass" if with_dq else "dkv")
+        tables = _schedule_tables(pairs, kind)
         axes, semantics = (len(pairs.q),), ("arbitrary",)
         qspec2, kspec2, rowspec2 = _scheduled_specs(block_q, block_k, d,
                                                     heads)
         gspec2, vspec2, _ = _scheduled_specs(block_q, block_k, d_v, heads)
+        if shared:
+            kspec2 = _scheduled_specs(block_q, block_k, d - d_s, heads)[1]
+            shared_in = (_shared_key_spec(block_k, *shared, kind),)
+            shared_out = (_scheduled_specs(block_q, block_k, d_s, heads)[1],)
     else:
         def q_at(t, u):
             return jnp.minimum(
@@ -929,8 +1035,13 @@ def _flash_bwd_by_key_block(q, k, v, g, lse, delta, *, causal: bool,
         _flash_bwd_onepass_kernel if with_dq else _flash_bwd_dkv_kernel,
         block_q=block_q, block_k=block_k, causal=causal, window=window,
         n_q_blocks=nq)
-    out_specs = [kspec2, vspec2]
-    out_shape = [_sds((bh, seq, d), k.dtype, k),
+    if shared:
+        # the key among the inputs, its gradient among the outputs
+        kernel = _key_joined(kernel, len(tables) + 1,
+                             len(tables) + 6 + with_dq)
+    out_specs = [kspec2, *shared_out, vspec2]
+    out_shape = [_sds((bh, seq, d - d_s), k.dtype, k),
+                 *(_sds((bh, seq, d_s), k.dtype, k) for _ in shared_out),
                  _sds((bh, seq, d_v), v.dtype, v)]
     scratch = [pltpu.VMEM(slab + (block_k, d), jnp.float32),
                pltpu.VMEM(slab + (block_k, d_v), jnp.float32)]
@@ -947,15 +1058,16 @@ def _flash_bwd_by_key_block(q, k, v, g, lse, delta, *, causal: bool,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables),
             grid=(bh // heads,) + axes,
-            in_specs=[qspec2, kspec2, vspec2, gspec2, rowspec2, rowspec2],
+            in_specs=[qspec2, kspec2, *shared_in, vspec2, gspec2, rowspec2,
+                      rowspec2],
             out_specs=out_specs,
             scratch_shapes=scratch),
         out_shape=out_shape,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel",) + semantics, **params),
         interpret=interpret,
-        name=_sized(name, d, d_v),
-    )(*tables, q, k, v, g, lse, delta)
+        name=_sized(name, d, d_v, d_s),
+    )(*tables, q, k, *shared_key, v, g, lse, delta)
 
 
 def _flash_bwd_onepass_kernel(*refs, **plan):
@@ -970,11 +1082,13 @@ def _flash_bwd_onepass_kernel(*refs, **plan):
 def _flash_attention_bwd_onepass_flat(q, k, v, g, lse, delta, *,
                                       causal: bool, block_q: int,
                                       block_k: int, interpret: bool,
-                                      window=None, heads: int = 1):
+                                      window=None, heads: int = 1,
+                                      k_shared=None):
     """Flat (BH, S, D) backward via the one kernel; returns (dq, dk, dv)
     in the inputs' dtypes with dq still in the fwd's q scaling.  A banded
     call's kernel sits under ``hvd.flash_window_dkv``: the scope that reads
-    the banded backward's time."""
+    the banded backward's time.  ``k_shared`` as in
+    ``_flash_attention_bwd_flat``: (dq, dk, the shared part's, dv)."""
     banded = window is not None
     with jax.named_scope(scopes.FLASH_WINDOW_DKV) if banded \
             else jax.named_scope(scopes.FLASH_BWD_ONEPASS):
@@ -983,7 +1097,7 @@ def _flash_attention_bwd_onepass_flat(q, k, v, g, lse, delta, *,
             block_k=block_k, interpret=interpret, window=window, heads=heads,
             name=scopes.kernel_name(scopes.FLASH_WINDOW_DKV if banded
                                     else scopes.FLASH_BWD_ONEPASS),
-            with_dq=True)
+            with_dq=True, k_shared=k_shared)
 
 
 def _flash_bwd_chunked(causal, window, res, g):
@@ -1009,7 +1123,7 @@ def _flash_bwd_chunked(causal, window, res, g):
 
 
 def _flash_bwd(causal, window, res, g):
-    q, k, v, o, lse = res
+    q, k, v, k_shared, o, lse = res
 
     def count(form):
         # As the call is traced: once for every time the layer scan and the
@@ -1020,19 +1134,23 @@ def _flash_bwd(causal, window, res, g):
     if lse is None:  # fwd fell back to plain XLA attention
         count("xla")
         _, vjp = jax.vjp(
-            lambda q_, k_, v_: _reference_attention(q_, k_, v_, causal,
-                                                    window),
-            q, k, v)
+            lambda q_, k_, v_, shared_: _reference_attention(
+                q_, whole_key(k_, shared_), v_, causal, window),
+            q, k, v, k_shared)
         return vjp(g)
     b, s, h, d = q.shape
     d_v = v.shape[-1]
+    d_s = 0 if k_shared is None else k_shared.shape[-1]
     block_q, block_k, d_pad, pre_scale = _plan(s, d, window, d_v,
-                                               q.dtype.itemsize)
+                                               q.dtype.itemsize, bool(d_s))
     form, heads = _backward_form(b * h, s, d_pad, q.dtype.itemsize, window)
     count(form)
     if form == "chunked":
         # A/B escape hatch (docs/benchmarks.md records the comparison).
-        return _flash_bwd_chunked(causal, window, (q, k, v), g)
+        whole, parts = jax.vjp(whole_key, k, k_shared)
+        dq, dk, dv = _flash_bwd_chunked(causal, window, (q, whole, v), g)
+        dk, dk_shared = parts(dk)
+        return dq, dk, dv, dk_shared
     # delta = rowsum(g ⊙ o): the softmax-jacobian correction term,
     # cheap in XLA (one elementwise pass).  Unit lane dim to match the
     # lse layout.
@@ -1041,25 +1159,31 @@ def _flash_bwd(causal, window, res, g):
                     axis=-1).reshape(b * h, s, 1)
     bwd_flat = (_flash_attention_bwd_onepass_flat if form == "onepass"
                 else _flash_attention_bwd_flat)
-    dq, dk, dv = bwd_flat(
-        _to_flat(q * pre_scale, d_pad), _to_flat(k, d_pad),
+    dq, dk, *dk_shared, dv = bwd_flat(
+        _to_flat(q * pre_scale, d_pad), _to_flat(k, d_pad - d_s),
         _to_flat(v, _d_pad(d_v)), _to_flat(g, _d_pad(d_v)), lse, delta,
         causal=causal, block_q=block_q, block_k=block_k,
-        interpret=not on_tpu(), window=window, heads=heads)
+        interpret=not on_tpu(), window=window, heads=heads,
+        k_shared=k_shared)
     # The kernels differentiate w.r.t. the PRE-SCALED q, so
     # d(loss)/d(q) = dq_flat * pre_scale; dk comes out exact with no
     # correction (ds^T @ q_prescaled == scale * ds_raw^T @ q).  The
     # scale multiply runs in f32 BEFORE the final dtype cast so dq
     # picks up one rounding, not two.
+    # The shared part's gradient is the sum of its heads' shares, added up
+    # in float32 ([b h, s, d_s] -> [b, s, d_s]).
     return (_from_flat(dq.astype(jnp.float32) * pre_scale, b, h, d, q),
-            _from_flat(dk, b, h, d, k),
-            _from_flat(dv, b, h, d_v, v))
+            _from_flat(dk, b, h, d - d_s, k),
+            _from_flat(dv, b, h, d_v, v),
+            dk_shared[0].reshape(b, h, s, d_s).sum(1, dtype=jnp.float32)
+            .astype(k_shared.dtype) if dk_shared else None)
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, causal: bool = True, window=None):
+def flash_attention(q, k, v, causal: bool = True, window=None,
+                    k_shared=None):
     """Fused blocked attention, layout ``(batch, seq, heads, dim)``
     (the framework's attention layout).  Differentiable; compiled
     Pallas on TPU, interpreted elsewhere.  Sequences not divisible by
@@ -1078,18 +1202,41 @@ def flash_attention(q, k, v, causal: bool = True, window=None):
     causal mask the pairs that meet the triangle).  ``window``: a causal
     query sees its last ``window`` keys, itself among them; the kernels
     then visit the blocks of that band alone, under scopes and names of
-    their own (``hvd.flash_window_*``), at one head size or two."""
+    their own (``hvd.flash_window_*``), at one head size or two.
+    ``k_shared``: ``[B, S, d_s]``, columns that every head's key ends in
+    (latent attention's one rotary key a position): ``k`` is then the
+    heads' own ``[B, S, H, d_k]``, ``q`` ``[B, S, H, d_k + d_s]``, the
+    scores ``q[..., :d_k] . k + q[..., d_k:] . k_shared`` scaled by ``(d_k +
+    d_s)^-1/2``, and the result and the gradients those of a call with
+    ``whole_key(k, k_shared)``; the kernels read the part as an operand of
+    its own, once a batch entry, and the key is never built at ``d_k + d_s``
+    a head in HBM.  Full calls only: under a window it is refused."""
     if window is not None:
         if not causal or window < 1:
             raise ValueError("a window of %r keys needs a causal mask and "
                              "at least the query itself" % (window,))
         if window >= q.shape[1]:
             window = None           # the band is the whole triangle
+    if k_shared is not None:
+        if window is not None:
+            raise ValueError(
+                "a window of %d keys over a key with a shared part: the "
+                "banded kernels take the key whole (hand them "
+                "whole_key(k, k_shared))" % window)
+        if k_shared.shape != k.shape[:2] + (q.shape[-1] - k.shape[-1],):
+            raise ValueError(
+                "k_shared %s is not [B, S, d_qk - d_k] of q %s and k %s"
+                % (k_shared.shape, q.shape, k.shape))
+        # One key for heads that a mesh axis may split (tp): varying like
+        # them from here on, so that its gradient is summed over that axis
+        # as the repeat over the heads would have had it summed.
+        from ..parallel.ring_attention import pvary_missing
+        k_shared = pvary_missing(k_shared, tuple(jax.typeof(k).vma))
     if k.shape[2] != q.shape[2]:
         rep = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    return _flash_attention(q, k, v, causal, window)
+    return _flash_attention(q, k, v, causal, window, k_shared)
 
 
 def use_flash_attention() -> bool:

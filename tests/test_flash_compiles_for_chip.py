@@ -148,6 +148,51 @@ def test_the_kernels_at_two_head_sizes_compile_for_v5e(one_chip, kernel,
     assert [(o.shape, o.dtype) for o in compiled.out_info] == want
 
 
+# The same calls with the one rotary key as an operand of its own: q at 192,
+# every head's own key at 128, the shared part [2, 8192, 64] read by flat
+# head i from batch entry i // 32; dk comes back at 128 and the part's
+# gradient a flat head's share at 64.  bfloat16 at the measured 1024 x 1024
+# and float32 (the builder's read) at the chains' 512 x 1024, the forward
+# kernel and both backward forms.
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("kernel, calls", [
+    ("forward", 1), ("onepass", 1), ("two_kernel", 2)])
+def test_the_kernels_with_a_shared_key_part_compile_for_v5e(one_chip, kernel,
+                                                            calls, dtype):
+    batch, heads, seq, d_k, d_s, d_v = 2, 32, 8192, 128, 64, 128
+    itemsize = jnp.dtype(dtype).itemsize
+    block_q, block_k, d_pad, _ = pk._plan(seq, d_k + d_s, None, d_v, itemsize,
+                                          True)
+    assert (block_q, block_k, d_pad) == (
+        (1024, 1024, 192) if dtype == jnp.bfloat16 else (512, 1024, 192))
+    assert pk._backward_form(batch * heads, seq, d_pad, itemsize, None) \
+        == ("onepass", 1)
+
+    def shape(width, dtype=dtype, rows=batch * heads):
+        return jax.ShapeDtypeStruct((rows, seq, width), dtype,
+                                    sharding=one_chip)
+
+    q, k, v, rows = shape(d_k + d_s), shape(d_k), shape(d_v), \
+        shape(1, jnp.float32)
+    shared = shape(d_s, rows=batch)
+    flat = {"forward": pk._flash_attention_fwd_flat,
+            "onepass": pk._flash_attention_bwd_onepass_flat,
+            "two_kernel": pk._flash_attention_bwd_flat}[kernel]
+    rest = (v,) if kernel == "forward" else (v, v, rows, rows)
+    compiled = jax.jit(
+        lambda shared, *operands: flat(
+            *operands, causal=True, block_q=block_q, block_k=block_k,
+            interpret=False, k_shared=shared)).lower(
+                shared, q, k, *rest).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == calls
+    assert "128s64x128" in text
+    assert [(o.shape, o.dtype) for o in compiled.out_info] == (
+        [(v.shape, v.dtype), (rows.shape, rows.dtype)] if kernel == "forward"
+        else [(x.shape, x.dtype) for x in (q, k, shape(d_s), v)])
+
+
 def test_the_banded_kernels_at_two_head_sizes_compile_for_v5e(one_chip):
     """No cell runs a window over two head sizes; the banded grids share the
     full calls' plumbing, and Mosaic takes them at 192 over 128 (two heads a

@@ -1,6 +1,7 @@
 """Multi-head latent attention: the flash kernels at a query/key head size
 that differs from the value's (interpreted) against the plain attention in
-values and every gradient; the block of ``models/transformer.py`` against
+values and every gradient; the same kernels with the key's shared part as an
+operand of its own against the key joined in HBM; the block of ``models/transformer.py`` against
 the benchmark's plain reference (``yardstick/builders/deepseek_v3.py``);
 the rotation's layout; the scale; the shares of heads and of experts; the
 six-layer tiny decoder against the reference; scopes, counter, refusals."""
@@ -168,6 +169,93 @@ def test_the_plan_of_two_sizes_pads_nothing_and_keeps_the_pins(monkeypatch):
     # dq of a head of 192 lies in 256 lanes: 16 MiB of the 32 it may take
     assert pk._onepass_vmem_bytes(1, 8192, 192, 2) == 16 << 20
     assert pk._backward_form(64, 8192, 192, 2, None) == ("onepass", 1)
+
+
+# -- the key's shared part as an operand of its own ---------------------------
+
+def shared_inputs(d_k, d_s, d_v, dtype, batch=2, heads=3, seq=SEQ):
+    """q at ``d_k + d_s``, every head's own key at ``d_k``, the part the
+    heads of a batch entry share ``[B, S, d_s]``, v and the output's weight."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    return tuple(
+        jax.random.normal(key, shape).astype(dtype) for key, shape in zip(ks, (
+            (batch, seq, heads, d_k + d_s), (batch, seq, heads, d_k),
+            (batch, seq, d_s), (batch, seq, heads, d_v),
+            (batch, seq, heads, d_v))))
+
+
+def shared_out_and_grads(attention, q, k, shared, v, weight):
+    f32 = jnp.float32
+    return jax.jit(jax.value_and_grad(
+        lambda *a: (attention(*a).astype(f32) * weight.astype(f32)).sum(),
+        argnums=(0, 1, 2, 3)))(q, k, shared, v)
+
+
+# Square and oblong blocks (a key block wider and narrower than the query
+# block), the one backward kernel and the two, both precisions; the tiny
+# decoder's sizes (all under a lane tile) and the cell's 128 + 64 over 128.
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("backward", ["pallas_onepass", "pallas"])
+@pytest.mark.parametrize("d_k, d_s, d_v, block_q, block_k", [
+    (16, 8, 12, 64, 64), (16, 8, 12, 64, 128), (128, 64, 128, 128, 64)])
+def test_a_shared_key_part_equals_the_key_joined_in_hbm(blocks, d_k, d_s, d_v,
+                                                        block_q, block_k,
+                                                        backward, dtype):
+    blocks(block_q, block_k, backward)
+    q, k, shared, v, weight = shared_inputs(d_k, d_s, d_v, dtype)
+    got = shared_out_and_grads(
+        lambda q, k, shared, v: pk.flash_attention(q, k, v, k_shared=shared),
+        q, k, shared, v, weight)
+    want = shared_out_and_grads(
+        lambda q, k, shared, v: pk.flash_attention(
+            q, pk.whole_key(k, shared), v), q, k, shared, v, weight)
+    assert [g.shape for g in got[1]] \
+        == [q.shape, k.shape, shared.shape, v.shape]
+    (dq, dk, dshared, dv), (dq_w, dk_w, dshared_w, dv_w) = got[1], want[1]
+    # the same products on the same numbers: to the bit
+    for a, b in ((got[0], want[0]), (dq, dq_w), (dk, dk_w), (dv, dv_w)):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+    # the three heads' shares are added up in float32 here and by the
+    # repeat's transpose, in the operands' precision, there
+    assert off(dshared, dshared_w) < (1e-6 if dtype == jnp.float32 else 8e-3)
+
+
+def test_a_shared_key_part_against_the_plain_attention(blocks):
+    """Not only equal to the joined call: right (grouped key/value heads,
+    each repeated for its group, and the chunked XLA backward among them)."""
+    q, k, shared, v, weight = shared_inputs(16, 8, 12, jnp.float32, heads=4)
+    k, v = k[:, :, :2], v[:, :, :2]
+    want = shared_out_and_grads(
+        lambda q, k, shared, v: pk._reference_attention(
+            q, pk.whole_key(jnp.repeat(k, 2, 2), shared),
+            jnp.repeat(v, 2, 2), True), q, k, shared, v, weight)
+    for backward in ("pallas_onepass", "chunked"):
+        blocks(64, 128, backward)
+        got = shared_out_and_grads(
+            lambda q, k, shared, v: pk.flash_attention(q, k, v,
+                                                       k_shared=shared),
+            q, k, shared, v, weight)
+        assert off(got, want) < 1e-5, backward
+
+
+def test_a_shared_key_part_under_a_window_is_refused_by_name(blocks):
+    blocks(64, 64)
+    q, k, shared, v, _ = shared_inputs(16, 8, 12, jnp.float32)
+    with pytest.raises(ValueError, match="window of 100 keys.*shared part"):
+        pk.flash_attention(q, k, v, window=100, k_shared=shared)
+    with pytest.raises(ValueError, match=r"not \[B, S, d_qk - d_k\]"):
+        pk.flash_attention(q, k, v, k_shared=shared[..., :4])
+    # a window as long as the sequence is no band: the call is a full one
+    assert pk.flash_attention(q, k, v, window=SEQ, k_shared=shared).shape \
+        == v.shape
+
+
+def shared_key_calls():
+    rows = metrics.snapshot().get("hvd_flash_shared_key_calls_total",
+                                  {"series": []})["series"]
+    return {r["labels"]["kernel"]: r["value"] for r in rows}
 
 
 # sha256 of a full call's lowered text (forward and the three gradients,
@@ -475,9 +563,74 @@ def test_the_block_carries_its_scope_in_both_passes_and_is_counted(tiny,
     for scope in (scopes.FLASH_FWD, scopes.FLASH_BWD_ONEPASS):
         found = [ln for ln in text.splitlines() if scope in ln]
         assert found and all(scopes.LATENT_ATTENTION in ln for ln in found)
-    assert "hvd_flash_fwd_24x128" in text
+    assert "hvd_flash_fwd_16s8x128" in text
     assert series().get("kernel", 0) \
         - before.get("kernel", 0) == 2
+
+
+def test_the_block_under_the_kernels_builds_no_key_of_the_whole_size(
+        monkeypatch):
+    """Traced where the models take the kernels, both passes, the block
+    hands them the one rotary key as it is: nothing repeats it over the
+    heads and one concatenation alone makes ``[B, S, H, nope + rope]``, q's
+    (the XLA form has the key's too); each kernel that reads the part is
+    counted once a traced call, and a call without the part adds nothing."""
+    cfg, lp = block_params()
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 64))
+    tables = {KIND: T.rope_tables(jnp.arange(64), KIND.rope_dim, KIND.rope,
+                                  cfg.act_dtype)}
+    heads = (2, 64, KIND.n_heads)
+
+    def eqns_of(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns_of(sub)
+
+    def traced():
+        return jax.make_jaxpr(jax.shard_map(
+            jax.grad(lambda lp, x: T._latent_attention_block(
+                x, lp, cfg, KIND, tables).sum()),
+            mesh=mesh_of((1, 1, 1)),
+            in_specs=(jax.tree.map(lambda _: P(), lp), P()),
+            out_specs=jax.tree.map(lambda _: P(), lp), check_vma=False))(
+                lp, x)
+
+    def made(closed, primitive, width):
+        return sum(eqn.primitive.name == primitive
+                   and eqn.outvars[0].aval.shape == heads + (width,)
+                   for eqn in eqns_of(closed.jaxpr))
+
+    whole = KIND.nope + KIND.rope_dim
+    on_cpu = traced()
+    assert made(on_cpu, "broadcast_in_dim", KIND.rope_dim) == 1
+    assert made(on_cpu, "concatenate", whole) == 2
+    before = shared_key_calls()
+    monkeypatch.setattr(pk, "use_flash_attention", lambda: True)
+    with_kernels = traced()
+    assert made(with_kernels, "broadcast_in_dim", KIND.rope_dim) == 0
+    assert made(with_kernels, "concatenate", whole) == 1
+    assert "hvd_flash_fwd_16s8x128" in str(with_kernels) \
+        and "hvd_flash_bwd_onepass_16s8x128" in str(with_kernels)
+    after = shared_key_calls()
+    assert {k: after[k] - before.get(k, 0) for k in ("fwd", "onepass")} \
+        == {"fwd": 1, "onepass": 1}
+    q, k, v, weight = inputs(24, 12, seq=64, heads=4)
+    jax.make_jaxpr(jax.grad(lambda *a: pk.flash_attention(*a).sum(),
+                            argnums=(0, 1, 2)))(q, k, v)
+    assert shared_key_calls() == after
+    # a call inside a recomputed layer traces its forward kernel twice (the
+    # call itself into the layer's program, then the rule that keeps the
+    # residuals when that is differentiated): a built step whose two traced
+    # layers are recomputed reads fwd 4, onepass 2, as the block pairs of
+    # ``hvd_flash_block_pairs_total{fwd}`` are four calls' there
+    jax.make_jaxpr(jax.grad(jax.checkpoint(
+        lambda q, k, v, shared: pk.flash_attention(
+            q, k, v, k_shared=shared).sum())))(
+                q, k[..., :16], v, q[:, :, 0, 16:])
+    recomputed = shared_key_calls()
+    assert {k: recomputed[k] - after[k] for k in ("fwd", "onepass")} \
+        == {"fwd": 2, "onepass": 1}
 
 
 def test_the_kernel_form_of_the_decoder_matches_the_reference(tiny,
