@@ -24,12 +24,14 @@ Phases, opened by the step builders (``jax/data_parallel.py``,
                  inner scope wins
 
 Blocks inside the model, both passes: ``ATTENTION`` (softmax attention
-with its projections), ``HEAD`` (logits and cross entropy),
-``LINEAR_ATTENTION`` (a delta-rule mixer whole; ``KDA_CORE`` inside it is
-the chunked recurrence alone, kernels or XLA form), ``STATE_SPACE`` (a
-Mamba-2 mixer whole: projections, convolution, scan, gated norm;
-``SSD_CORE`` inside it is the chunked state-space scan alone, kernels or XLA
-form), ``MOE`` (an
+with its projections), ``HEAD`` (logits and cross entropy), ``DENSE_FFN``
+(a dense SwiGLU feed-forward whole), ``LINEAR_ATTENTION`` (a delta-rule
+mixer whole; ``KDA_CORE`` inside it is the chunked recurrence alone,
+kernels or XLA form, of a layer with a decay for every key channel, and
+``GATED_DELTA_CORE`` the same of a layer with one decay a head),
+``STATE_SPACE`` (a Mamba-2 mixer whole: projections, convolution, scan,
+gated norm; ``SSD_CORE`` inside it is the chunked state-space scan alone,
+kernels or XLA form), ``MOE`` (an
 expert layer whole; inside it ``ROUTER`` is the scores, the choice, the sort
 of the pairs by expert, the blocks' indices and weights and, under
 ``ROUTER_ROWS``, the row movement alone: each block's gathers of its
@@ -45,11 +47,11 @@ backward kernel sits under ``FLASH_WINDOW_DKV``; a sliding layer's
 whole block is ``WINDOW_ATTENTION``, inside ``ATTENTION``, and a
 latent-attention layer's ``LATENT_ATTENTION``, inside ``ATTENTION`` too: both
 projections of the latent, its norm, the rotary turns, the flash kernels,
-``wo``); ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two,
-inside ``KDA_CORE``); ``SSD_FWD`` and ``SSD_BWD`` (the state-space scan's
-two, inside ``SSD_CORE``); ``kernel_name`` gives the same words as the ``name=``
-of the call (``hvd_flash_fwd``), which is what the trace viewer prints for
-a Mosaic kernel.
+``wo``); ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two, inside
+``KDA_CORE`` or ``GATED_DELTA_CORE``); ``SSD_FWD`` and ``SSD_BWD`` (the
+state-space scan's two, inside ``SSD_CORE``); ``kernel_name`` gives the
+same words as the ``name=`` of the call (``hvd_flash_fwd``), which is
+what the trace viewer prints for a Mosaic kernel.
 
 Host spans (``common/metrics.py: span``; ``host_spans`` is the whole
 list, and a name outside it raises there).  They time the program's
@@ -91,6 +93,8 @@ ATTENTION = "hvd.attention"
 HEAD = "hvd.head"
 LINEAR_ATTENTION = "hvd.linear_attention"
 KDA_CORE = "hvd.kda_core"
+GATED_DELTA_CORE = "hvd.gated_delta_core"
+DENSE_FFN = "hvd.dense_ffn"
 STATE_SPACE = "hvd.state_space"
 SSD_CORE = "hvd.ssd_core"
 MOE = "hvd.moe"

@@ -23,8 +23,11 @@ shapes, bf16 activations on the MXU, optional ``jax.checkpoint`` remat).
 
 What a layer is made of is said in one place, ``layer_pattern``: one
 period of (mixer, feed-forward) entries out of ``MIXERS`` and
-``FEED_FORWARDS``.  An entry with both is a pair, each sub-layer under a
-norm of its own (``ln1``, ``ln2``) with its own residual add; an entry
+``FEED_FORWARDS``.  An entry with both is a pair, each sub-layer with a
+norm of its own (``ln1``, ``ln2``) and its own residual add: the norm on
+the sub-layer's input, ``x + sub(rms_norm(x))``, or, where the
+configuration says ``post_norm`` (OLMo 2's reordered norm), on its output,
+``x + rms_norm(sub(x))``; an entry
 whose mixer or whose feed-forward is None is a block of the one sub-layer
 that is there, under its one norm (a model whose blocks alternate instead
 of pairing).  The scan runs over periods, ``params["layers"]`` is a
@@ -116,13 +119,17 @@ class SoftmaxAttention:
     """What a kind of layer fixes of softmax attention: query and
     key/value heads on this tp shard group, the ``window`` of keys a query
     sees (None: every key before it), its rotary table (None: no
-    positional encoding) and whether a sigmoid gate from a projection of
-    its own multiplies the heads' output before ``wo``."""
+    positional encoding), whether a sigmoid gate from a projection of
+    its own multiplies the heads' output before ``wo``, and whether an
+    RMSNorm runs over the whole q and the whole k projection before the
+    heads are split (``qk_norm``, OLMo 2's: one mean square over every
+    head, so the heads cannot be split over ``tp``)."""
     n_heads: int
     n_kv_heads: int
     window: Optional[int] = None
     rope: Optional[Rope] = Rope()
     gate: bool = False
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.n_kv_heads < 1 or self.n_heads % self.n_kv_heads:
@@ -229,6 +236,10 @@ class TransformerConfig:
     # computed (and recomputed in the backward pass) a block at a time and
     # [tokens, V] never exists.  0 = all at once.
     head_block: int = 0
+    # Where a block's norms act: on each sub-layer's input (False,
+    # ``x + sub(rms_norm(x))``) or on its output (True, OLMo 2's reordered
+    # norm, ``x + rms_norm(sub(x))``).
+    post_norm: bool = False
 
     def __post_init__(self):
         if self.sp_mode not in ("ring", "ulysses"):
@@ -340,6 +351,9 @@ def _init_layers(key, cfg: TransformerConfig, mixer, ffn, n: int):
         if kind.gate:
             layers["wg"] = norm(jax.random.fold_in(key, 12),
                                 (n, d, qh * hd), d)
+        if kind.qk_norm:
+            layers["q_norm"] = jnp.ones((n, qh * hd), pd)
+            layers["k_norm"] = jnp.ones((n, kvh * hd), pd)
     if ffn == "dense":
         layers.update({
             "w1": norm(keys[5], (n, d, f), d),
@@ -392,7 +406,7 @@ def _layer_specs(cfg: TransformerConfig, mixer, ffn):
              if part is not None}
     kind = cfg.softmax_kind(mixer)
     if mixer == "linear_attention":
-        specs.update(kda_param_specs(tp))
+        specs.update(kda_param_specs(cfg.linear_attention, tp))
     elif mixer == "state_space":
         specs.update(ssm_param_specs())
     elif isinstance(mixer, LatentAttention):
@@ -414,6 +428,8 @@ def _layer_specs(cfg: TransformerConfig, mixer, ffn):
         })
         if kind.gate:
             specs["wg"] = P(None, None, tp)
+        if kind.qk_norm:
+            specs["q_norm"] = specs["k_norm"] = P(None, tp)
     if ffn == "dense":
         specs.update({
             "w1": P(None, None, tp),
@@ -563,14 +579,27 @@ def _softmax_attention_block(x, lp, cfg: TransformerConfig,
     heads are what the weights hold; a rotary table turns q and k, or none
     does (the causal mask is then all the order it sees); a gate is a
     sigmoid, from a projection of its own, on every element of the heads'
-    output before ``wo``.  A layer with a window sits under
-    ``hvd.window_attention`` as well."""
+    output before ``wo``; a QK-norm is an RMSNorm over the whole q and the
+    whole k projection, every head together, before the heads are split.
+    A layer with a window sits under ``hvd.window_attention`` as well."""
     with jax.named_scope(scopes.WINDOW_ATTENTION) if kind.window \
             else contextlib.nullcontext():
         b, s, _ = x.shape
         hd = cfg.head_dim
-        q, k, v = ((x @ lp[name].astype(x.dtype)).reshape(b, s, -1, hd)
-                   for name in ("wq", "wk", "wv"))
+        if kind.qk_norm:
+            if lax.axis_size(cfg.tp_axis) > 1:
+                raise ValueError(
+                    "a QK-norm's mean square spans every head of the q and "
+                    "k projections: the heads cannot be split over %r"
+                    % cfg.tp_axis)
+            q, k, v = (x @ lp[name].astype(x.dtype)
+                       for name in ("wq", "wk", "wv"))
+            q, k, v = (y.reshape(b, s, -1, hd) for y in (
+                rms_norm(q, lp["q_norm"], cfg.norm_eps),
+                rms_norm(k, lp["k_norm"], cfg.norm_eps), v))
+        else:
+            q, k, v = ((x @ lp[name].astype(x.dtype)).reshape(b, s, -1, hd)
+                       for name in ("wq", "wk", "wv"))
         if kind.rope is not None:
             cos, sin = tables[kind.rope]
             q = _rope(cos, sin, q)
@@ -669,6 +698,7 @@ def _row_parallel_product(x, w, cfg: TransformerConfig):
     return lax.psum(x @ w, cfg.tp_axis)
 
 
+@jax.named_scope(scopes.DENSE_FFN)
 def _dense_ffn(h, lp, cfg: TransformerConfig):
     a = jax.nn.silu(h @ lp["w1"].astype(h.dtype))
     g = h @ lp["w3"].astype(h.dtype)
@@ -754,11 +784,19 @@ def hidden(params, tokens, cfg: TransformerConfig):
         x, aux = carry
         counts = ()
         if mixer is not None:
-            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            x = x + _mix(h, lp, cfg, mixer, tables, sp_size)
+            if cfg.post_norm:
+                x = x + rms_norm(_mix(x, lp, cfg, mixer, tables, sp_size),
+                                 lp["ln1"], cfg.norm_eps)
+            else:
+                h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+                x = x + _mix(h, lp, cfg, mixer, tables, sp_size)
         if ffn is not None:
-            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            y, a, counts = _feed_forward(h, lp, cfg, ffn, sp_size)
+            if cfg.post_norm:
+                y, a, counts = _feed_forward(x, lp, cfg, ffn, sp_size)
+                y = rms_norm(y, lp["ln2"], cfg.norm_eps)
+            else:
+                h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+                y, a, counts = _feed_forward(h, lp, cfg, ffn, sp_size)
             x, aux = x + y, aux if a is None else aux + a
         return (x, aux), counts
 

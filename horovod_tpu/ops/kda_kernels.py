@@ -2,14 +2,18 @@
 (``models/linear_attention.py`` has the recurrence and the XLA form).
 
 A chunk of ``C`` steps of two heads a grid step (of one where the heads
-are odd): a head's state (kept transposed, ``Z = S^T``, so a channel's
-decay scales a lane) and every chunk-local matrix stay in VMEM.  The
-kernels take ``kb = beta k`` and ``vb = beta v`` beside ``k``, so ``beta``
-itself never enters them:
+are odd): a head's state (``Dk x Dv``, kept transposed, ``Z = S^T``, so a
+key channel's decay scales a lane) and every chunk-local matrix stay in
+VMEM.  Keys (q, k and their decays) are ``Dk`` lanes a head, values ``Dv``;
+the heads of a grid step fill whole lane tiles together
+(``models/linear_attention.py`` pads with zeros, which is exact, where
+they would not: ``padded``).  The kernels take
+``kb = beta k`` and ``vb = beta v`` beside ``k``, so ``beta`` itself never
+enters them:
 
     N = tril(kb k^T . decay, -1)       P = tril(q k^T . decay)
     [u | w_k] = (I + N)^-1 [vb | kb e^G]
-    w = u - w_k S      o = D^-1/2 ((q e^G) S + P w)
+    w = u - w_k S      o = scale ((q e^G) S + P w)
     S' = Diag(e^{G_C}) S + (k e^{G_C - G})^T w
 
 **Decayed products with no positive exponent.**  ``decay[r, i] =
@@ -43,7 +47,6 @@ are two fifths of the forward kernel (``PERF.md``, PR 28).
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -71,10 +74,26 @@ def _eye(c):
     return jnp.where(rows == cols, 1.0, 0.0)
 
 
-def takes(head_size: int, chunk: int) -> bool:
-    """The shapes the kernels are written for: a head that fills the 128
-    lanes, a chunk that halves down to single rows and fills sublanes."""
-    return head_size % 128 == 0 and chunk >= 8 and chunk & (chunk - 1) == 0
+def lanes(size: int) -> int:
+    """``size`` rounded up to whole lane tiles."""
+    return -(-size // 128) * 128
+
+
+def padded(size: int, heads: int) -> int:
+    """What a head's keys or values take in the kernels: ``size`` where the
+    heads of a grid step fill whole lane tiles together (two heads of 192
+    are three tiles: the kernels slice the second at lane 64), else
+    ``size`` padded to whole tiles."""
+    return size if _heads_a_step(heads) * size % 128 == 0 else lanes(size)
+
+
+def takes(key_size: int, value_size: int, chunk: int) -> bool:
+    """The shapes the kernels are run for: keys and values that fill more
+    than half of the lane tiles they are padded to (128 and 256 whole, 96
+    and 192 padded to 128 and 256; 64 or 16 would be mostly padding), a
+    chunk that halves down to single rows and fills sublanes."""
+    return all(2 * size > lanes(size) for size in (key_size, value_size)) \
+        and chunk >= 8 and chunk & (chunk - 1) == 0
 
 
 @functools.lru_cache(maxsize=None)
@@ -114,8 +133,10 @@ def _sums(sums, x, dims=_NN):
     rest = x - hi.astype(jnp.float32)
     mid = rest.astype(jnp.bfloat16)
     low = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    # DEFAULT said outright: bfloat16 operands, whatever precision a caller
+    # sets for the float32 products round the kernel.
     parts = lax.dot_general(sums, jnp.concatenate([hi, mid, low], axis=1),
-                            (dims, ((), ())),
+                            (dims, ((), ())), precision=lax.Precision.DEFAULT,
                             preferred_element_type=jnp.float32)
     return parts[:, :d] + (parts[:, d:2 * d] + parts[:, 2 * d:])
 
@@ -136,7 +157,7 @@ def _decays(e, c):
 def _local(q, k, kb, vb, decays, masks):
     """What a chunk computes before it meets the state: ``P``,
     ``(I + N)^-1``, ``u``, ``w_k``."""
-    c, d = k.shape
+    c, dv = vb.shape
     levels = masks.shape[0]
     e_lvl, e_in, _ = decays
     both = jnp.concatenate([q, kb])
@@ -152,7 +173,7 @@ def _local(q, k, kb, vb, decays, masks):
     solved = _dot(x, jnp.concatenate([vb, kb * e_in], axis=1))
     # P alone has a diagonal: a step's own key, undecayed.
     p = pn[:c] + eye * jnp.sum(q * k, axis=1, keepdims=True)
-    return p, x, solved[:, :d], solved[:, d:]
+    return p, x, solved[:, :dv], solved[:, dv:]
 
 
 def _advance(z, k, u, w_k, decays):
@@ -165,7 +186,7 @@ def _advance(z, k, u, w_k, decays):
 def _fwd_kernel(sums_ref, masks_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
                 o_ref, starts_ref, z_scr, *, per: int, scale: float):
     t = pl.program_id(2)
-    d = z_scr.shape[-1]
+    dv, dk = z_scr.shape[1:]
 
     @pl.when(t == 0)
     def _():
@@ -183,16 +204,16 @@ def _fwd_kernel(sums_ref, masks_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
         # The heads of a step share nothing: their chains of small
         # dependent products fill each other's waits.
         for i in range(z_scr.shape[0]):
-            at = slice(i * d, (i + 1) * d)
+            at, at_v = slice(i * dk, (i + 1) * dk), slice(i * dv, (i + 1) * dv)
             q, k = q_ref[0, :, at], k_ref[0, :, at]
             decays = _decays(_exponentials(g_ref[0, :, at], sums_ref),
                              q.shape[0])
-            p, _, u, w_k = _local(q, k, kb_ref[0, :, at], vb_ref[0, :, at],
+            p, _, u, w_k = _local(q, k, kb_ref[0, :, at], vb_ref[0, :, at_v],
                                   decays, masks_ref)
             z = z_scr[i]
             w, z_scr[i] = _advance(z, k, u, w_k, decays)
-            o_ref[0, :, at] = scale * (_dot(q * decays[1], z, _NT)
-                                       + _dot(p, w))
+            o_ref[0, :, at_v] = scale * (_dot(q * decays[1], z, _NT)
+                                         + _dot(p, w))
 
 
 def _bwd_kernel(sums_ref, masks_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
@@ -202,7 +223,7 @@ def _bwd_kernel(sums_ref, masks_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
     j, t = pl.program_id(2), pl.program_id(3)
     masks = masks_ref
     levels = masks.shape[0]
-    heads, d = dz_scr.shape[:2]
+    heads, dv, dk = dz_scr.shape
     c = q_ref.shape[1]
 
     @pl.when(jnp.logical_and(j == 0, t == 0))
@@ -218,24 +239,25 @@ def _bwd_kernel(sums_ref, masks_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
         # Forward again over the segment: the state every chunk starts
         # from and what a chunk computes before it meets the state.
         for i in range(heads):
-            at = slice(i * d, (i + 1) * d)
+            at = slice(i * dk, (i + 1) * dk)
             k = k_ref[0, :, at]
             e = _exponentials(g_ref[0, :, at], sums_ref)
             decays = _decays(e, c)
             p, x, u, w_k = _local(q_ref[0, :, at], k, kb_ref[0, :, at],
-                                  vb_ref[0, :, at], decays, masks)
+                                  vb_ref[0, :, slice(i * dv, (i + 1) * dv)],
+                                  decays, masks)
             e_scr[i, t], p_scr[i, t], x_scr[i, t] = e, p, x
             u_scr[i, t], wk_scr[i, t] = u, w_k
             z_scr[i, t + 1] = _advance(z_scr[i, t], k, u, w_k, decays)[1]
 
-    def _back_chunk(i, t, at):
+    def _back_chunk(i, t, at, at_v):
         q, k, kb = q_ref[0, :, at], k_ref[0, :, at], kb_ref[0, :, at]
         e_lvl, e_in, e_out = _decays(e_scr[i, t], c)
         p, x, u, w_k = p_scr[i, t], x_scr[i, t], u_scr[i, t], wk_scr[i, t]
         z, dz_next = z_scr[i, t], dz_scr[i]
         whole = e_in[-1:]
         w = u - _dot(w_k, z, _NT)
-        do = scale * do_ref[0, :, at]
+        do = scale * do_ref[0, :, at_v]
         q_in, k_out = q * e_in, k * e_out
         dq_in = _dot(do, z)
         dw = _dot(p, do, _TN) + _dot(k_out, dz_next, _NT)
@@ -245,7 +267,7 @@ def _bwd_kernel(sums_ref, masks_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
             - _dot(dw, w_k, _TN)
         # [u | w_k] = X [vb | kb e^G]; dN = -X^T dX X^T, below the diagonal.
         back = _dot(x, jnp.concatenate([dw, -_dot(dw, z)], axis=1), _TN)
-        dvb, d_rhs = back[:, :d], back[:, d:]
+        dvb, d_rhs = back[:, :dv], back[:, dv:]
         dp = _dot(do, w, _NT)
         dpn = jnp.concatenate([
             dp, -_dot(back, jnp.concatenate([u, w_k], axis=1), _NT)])
@@ -267,14 +289,15 @@ def _bwd_kernel(sums_ref, masks_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
         dx.append((dq_in * q + d_rhs * kb) * e_in)
         dx.append(dk_out * k * e_out)
         dq_ref[0, :, at], dk_ref[0, :, at] = dq, dk
-        dkb_ref[0, :, at], dvb_ref[0, :, at] = dkb, dvb
+        dkb_ref[0, :, at], dvb_ref[0, :, at_v] = dkb, dvb
         dg_ref[0, :, at] = _sums(sums_ref[:], jnp.concatenate(dx), _TN) \
             + d_whole
 
     @pl.when(t >= per)
     def _back():
         for i in range(heads):
-            _back_chunk(i, 2 * per - 1 - t, slice(i * d, (i + 1) * d))
+            _back_chunk(i, 2 * per - 1 - t, slice(i * dk, (i + 1) * dk),
+                        slice(i * dv, (i + 1) * dv))
 
 
 def _constant_inputs(c, like):
@@ -300,27 +323,34 @@ def _params(interpret, grid_rank, vmem_bytes):
         vmem_limit_bytes=vmem_bytes)
 
 
+def _rows(chunk, width, index_map):
+    return pl.BlockSpec((1, chunk, width), index_map)
+
+
 @jax.named_scope(scopes.KDA_FWD)
-def forward(q, k, kb, vb, g, chunk, per, d):
-    """``[B, S, H D]`` each -> (o ``[B, S, H D]``, the transposed state
-    every segment of ``per`` chunks starts from ``[B, H, S / (per C), D,
-    D]``)."""
+def forward(q, k, kb, vb, g, chunk, per, h, scale):
+    """q, k, kb, g ``[B, S, H Dk]`` and vb ``[B, S, H Dv]`` -> (o ``[B, S,
+    H Dv]``, the transposed state every segment of ``per`` chunks starts
+    from ``[B, H, S / (per C), Dv, Dk]``); ``o = scale (S^T q)``."""
     from jax.experimental.pallas import tpu as pltpu
     bsz, s, width = q.shape
-    h, n = width // d, s // chunk
+    dk, dv, n = width // h, vb.shape[-1] // h, s // chunk
     interpret = not on_tpu()
     sums, masks = _constant_inputs(chunk, q)
     heads = _heads_a_step(h)
-    row = pl.BlockSpec((1, chunk, heads * d), lambda b, i, t: (b, t, i))
+    keys, values = (_rows(chunk, heads * size, lambda b, i, t: (b, t, i))
+                    for size in (dk, dv))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, per=per, scale=1.0 / math.sqrt(d)),
+        functools.partial(_fwd_kernel, per=per, scale=scale),
         grid=(bsz, h // heads, n),
-        in_specs=[_whole(sums.shape), _whole(masks.shape)] + [row] * 5,
-        out_specs=[row, pl.BlockSpec((1, heads, 1, d, d),
-                                     lambda b, i, t: (b, i, t // per, 0, 0))],
-        out_shape=[_sds((bsz, s, width), jnp.float32, q),
-                   _sds((bsz, h, n // per, d, d), jnp.float32, q)],
-        scratch_shapes=[pltpu.VMEM((heads, d, d), jnp.float32)],
+        in_specs=[_whole(sums.shape), _whole(masks.shape)]
+        + [keys, keys, keys, values, keys],
+        out_specs=[values,
+                   pl.BlockSpec((1, heads, 1, dv, dk),
+                                lambda b, i, t: (b, i, t // per, 0, 0))],
+        out_shape=[_sds((bsz, s, h * dv), jnp.float32, q),
+                   _sds((bsz, h, n // per, dv, dk), jnp.float32, q)],
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)],
         compiler_params=_params(interpret, 3, 32 << 20),
         interpret=interpret,
         name=scopes.kernel_name(scopes.KDA_FWD),
@@ -328,11 +358,11 @@ def forward(q, k, kb, vb, g, chunk, per, d):
 
 
 @jax.named_scope(scopes.KDA_BWD)
-def backward(q, k, kb, vb, g, do, starts, chunk, per, d):
-    """Gradients of q, k, kb, vb, g, ``[B, S, H D]`` each."""
+def backward(q, k, kb, vb, g, do, starts, chunk, per, h, scale):
+    """Gradients of q, k, kb, vb, g, each of its input's shape."""
     from jax.experimental.pallas import tpu as pltpu
     bsz, s, width = q.shape
-    h, n = width // d, s // chunk
+    dk, dv, n = width // h, vb.shape[-1] // h, s // chunk
     segments = n // per
     interpret = not on_tpu()
     sums, masks = _constant_inputs(chunk, q)
@@ -348,26 +378,29 @@ def backward(q, k, kb, vb, g, do, starts, chunk, per, d):
         return (segments - 1 - j) * per + per - 1 - jnp.maximum(t - per, 0)
 
     heads = _heads_a_step(h)
-    swept = pl.BlockSpec((1, chunk, heads * d),
-                         lambda b, i, j, t: (b, chunk_of(j, t), i))
-    back = pl.BlockSpec((1, chunk, heads * d),
-                        lambda b, i, j, t: (b, chunk_back(j, t), i))
+    swept_k, swept_v = (
+        _rows(chunk, heads * size, lambda b, i, j, t: (b, chunk_of(j, t), i))
+        for size in (dk, dv))
+    back_k, back_v = (
+        _rows(chunk, heads * size, lambda b, i, j, t: (b, chunk_back(j, t), i))
+        for size in (dk, dv))
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, per=per, scale=1.0 / math.sqrt(d)),
+        functools.partial(_bwd_kernel, per=per, scale=scale),
         grid=(bsz, h // heads, segments, 2 * per),
-        in_specs=[_whole(sums.shape), _whole(masks.shape)] + [swept] * 5
-        + [back, pl.BlockSpec((1, heads, 1, d, d), lambda b, i, j, t:
-                              (b, i, segments - 1 - j, 0, 0))],
-        out_specs=[back] * 5,
-        out_shape=[_sds((bsz, s, width), jnp.float32, q)] * 5,
-        scratch_shapes=[pltpu.VMEM((heads, per + 1, d, d), jnp.float32),
-                        pltpu.VMEM((heads, d, d), jnp.float32),
-                        pltpu.VMEM((heads, per, sums.shape[0], d),
+        in_specs=[_whole(sums.shape), _whole(masks.shape)]
+        + [swept_k, swept_k, swept_k, swept_v, swept_k]
+        + [back_v, pl.BlockSpec((1, heads, 1, dv, dk), lambda b, i, j, t:
+                                (b, i, segments - 1 - j, 0, 0))],
+        out_specs=[back_k, back_k, back_k, back_v, back_k],
+        out_shape=[_sds(x.shape, jnp.float32, q) for x in (q, k, kb, vb, g)],
+        scratch_shapes=[pltpu.VMEM((heads, per + 1, dv, dk), jnp.float32),
+                        pltpu.VMEM((heads, dv, dk), jnp.float32),
+                        pltpu.VMEM((heads, per, sums.shape[0], dk),
                                    jnp.float32),
                         pltpu.VMEM((heads, per, chunk, chunk), jnp.float32),
                         pltpu.VMEM((heads, per, chunk, chunk), jnp.float32),
-                        pltpu.VMEM((heads, per, chunk, d), jnp.float32),
-                        pltpu.VMEM((heads, per, chunk, d), jnp.float32)],
+                        pltpu.VMEM((heads, per, chunk, dv), jnp.float32),
+                        pltpu.VMEM((heads, per, chunk, dk), jnp.float32)],
         compiler_params=_params(interpret, 4, 48 << 20),
         interpret=interpret,
         name=scopes.kernel_name(scopes.KDA_BWD),

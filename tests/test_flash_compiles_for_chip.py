@@ -268,3 +268,36 @@ def test_the_state_space_kernels_compile_for_v5e(one_chip, monkeypatch,
     assert [(o.shape, o.dtype) for o in bwd.out_info] \
         == [(v.shape, v.dtype) for v in (x, scal, bc, bc)] \
         + [((bsz, groups, 8, per * head), jnp.float32)]
+
+
+# The delta rule's two kernels at the cells' shapes: olmo's 30 heads of
+# 96 over 192 (keys padded to 128 lanes, two heads' values three lane tiles,
+# the second head's sliced at lane 64, a decay a head broadcast over the
+# key channels), pt8k's 8 heads of 128 over 128 (a decay for every
+# channel), and one head a grid step at olmo's sizes (values padded to 256).
+@pytest.mark.parametrize("batch, heads, key, value, per_head", [
+    (1, 30, 96, 192, True), (2, 8, 128, 128, False), (1, 3, 96, 192, True)])
+def test_the_delta_rule_kernels_compile_for_v5e(one_chip, monkeypatch, batch,
+                                                heads, key, value, per_head):
+    from horovod_tpu.models import linear_attention as la
+    from horovod_tpu.ops import kda_kernels
+    monkeypatch.setattr(kda_kernels, "on_tpu", lambda: True)
+    seq, chunk = 8192, 64
+    assert kda_kernels.takes(key, value, chunk)
+
+    def shape(*dims, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    args = (shape(batch, seq, heads, key), shape(batch, seq, heads, key),
+            shape(batch, seq, heads, value, dt=jnp.bfloat16),
+            shape(batch, seq, heads) if per_head
+            else shape(batch, seq, heads, key), shape(batch, seq, heads))
+    compiled = jax.jit(jax.grad(
+        lambda *a: la.kda_chunked(*a, chunk).sum(), argnums=range(5))).lower(
+            *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    width = heads * kda_kernels.padded(value, heads)
+    assert "f32[%d,%d,%d]" % (batch, seq, width) in text
+    assert [(o.shape, o.dtype) for o in compiled.out_info] \
+        == [(a.shape, a.dtype) for a in args]

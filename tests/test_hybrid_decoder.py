@@ -272,7 +272,7 @@ def test_chunked_delta_rule_matches_the_recurrence(form, decay, segment):
     h, d, chunk = FORMS[form]
     args = delta_rule_inputs(jax.random.PRNGKey(int(decay)), decay, h=h, d=d)
     assert float(args[4].max()) > 1.5
-    assert kda_kernels.takes(d, chunk) == (form != "xla")
+    assert kda_kernels.takes(d, d, chunk) == (form != "xla")
 
     def plain(*a):
         return jax.vmap(reference.kda_recurrence)(*a)
@@ -323,7 +323,7 @@ def test_shapes_choose_the_delta_rules_form(head, chunk, kernels):
     """Heads that fill the 128 lanes and a chunk that halves down to
     single rows take the kernels; anything else the XLA form.  No option
     chooses."""
-    assert kda_kernels.takes(head, chunk) == kernels
+    assert kda_kernels.takes(head, head, chunk) == kernels
     args = delta_rule_inputs(jax.random.PRNGKey(0), 1.0, b=1, s=2 * chunk,
                              h=1, d=head)
     jaxpr = str(jax.make_jaxpr(lambda *a: kda_chunked(*a, chunk))(*args))

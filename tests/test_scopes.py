@@ -373,4 +373,4 @@ def test_one_vocabulary():
     assert {host[use[7:]] for use in host_uses} | stages == scopes.host_spans
     assert all(re.fullmatch(r"hvd\.[a-z_]+", v) for v in constants.values())
     assert len(set(constants.values())) == len(constants)
-    assert len(device) == 27 and len(host) == len(scopes.host_spans) == 14
+    assert len(device) == 29 and len(host) == len(scopes.host_spans) == 14
