@@ -311,9 +311,11 @@ NAMES: Dict[str, Tuple[str, str]] = {
     "hvd_delta_rule_calls_total": (
         "counter", "delta-rule cores (models/linear_attention.py: "
                    "kda_chunked) by the form their shapes took, labeled "
-                   "form (kernel|xla) + decay (channel = one for every key "
-                   "channel | head = one a head); counted as a core is "
-                   "traced, like hvd_flash_backward_calls_total"),
+                   "form (kernel = the kernels for a decay for every "
+                   "channel | head_kernel = those for a decay a head | "
+                   "xla) + decay (channel = one for every key channel | "
+                   "head = one a head); counted as a core is traced, like "
+                   "hvd_flash_backward_calls_total"),
     "hvd_latent_attention_calls_total": (
         "counter", "latent-attention blocks (models/transformer.py: "
                    "_latent_attention_block) by the form their attention "

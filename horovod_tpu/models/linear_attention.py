@@ -12,8 +12,8 @@ above 1 the transition has negative eigenvalues.  The decay has one of two
 forms (``KdaConfig.decay``): one for every key channel (``"channel"``,
 KDA's: a low-rank product of the input) or one scalar a head (``"head"``,
 Gated DeltaNet's: ``Diag(exp(g_t))`` is ``exp(g_t) I``).  Both run the same
-chunked form: a head's decay is the channel decay broadcast over the key
-channels, which is exact.
+chunked form; the XLA form below broadcasts a head's decay over the key
+channels (exact), the kernels take it as it is.
 ``kda_chunked`` computes this a chunk of ``C`` steps at a time.  With
 ``G`` the running sum of ``g`` inside a chunk and ``S`` the state at its
 start, the pseudo-values ``w_r = beta_r (v_r - (k_r e^{G_r})^T S -
@@ -38,7 +38,9 @@ zero-padded where a grid step's heads would not fill whole tiles, which is
 exact: zero key channels add nothing to ``k k^T``, ``q k^T`` or ``k^T w``
 and the padded rows and columns of ``S`` stay zero.  A forward kernel
 keeps the head's state and a chunk's matrices in VMEM, and a custom VJP's
-backward kernel walks the segments in reverse.  Other heads (the tests'
+backward kernel walks the segments in reverse; a decay a head enters its
+own pair of kernels as one value a step, which computes a chunk's decayed
+products as one ``C x C`` matrix for every channel.  Other heads (the tests'
 small configurations) take the XLA form below, whose backward pass is
 autodiff through ``_segment``; it is also the kernels' oracle.
 
@@ -358,18 +360,21 @@ def kda_chunked(q, k, v, g, beta, chunk: int, segment: int = 16):
     the backward pass does not walk the sequence again.  Shapes choose the
     form (``kda_kernels.takes``); the kernels see keys and values padded
     with zeros where a grid step's heads would not fill whole lane tiles
-    (``kda_kernels.padded``)."""
+    (``kda_kernels.padded``), and a decay a head as it is (their own pair,
+    ``form=head_kernel``)."""
     bsz, s, h, d = q.shape
     dv = v.shape[-1]
     if s % chunk:
         raise ValueError("the delta rule runs in chunks of %d steps; a "
                          "sequence of %d is not a multiple" % (chunk, s))
     kernel = kda_kernels.takes(d, dv, chunk)
+    per_head = g.ndim == 3
     # As the core is traced: once for every time a layer scan or a
     # recomputation traces it, not once a step.
     metrics.counter("hvd_delta_rule_calls_total",
-                    form="kernel" if kernel else "xla",
-                    decay="head" if g.ndim == 3 else "channel").inc()
+                    form=("head_kernel" if per_head else "kernel") if kernel
+                    else "xla",
+                    decay="head" if per_head else "channel").inc()
     if not kernel:
         return kda_chunked_xla(q, k, v, g, beta, chunk, segment)
 
@@ -383,7 +388,7 @@ def kda_chunked(q, k, v, g, beta, chunk: int, segment: int = 16):
 
     by = beta.astype(jnp.float32)[..., None]
     o = _kda_kernels(rows(q), rows(k), rows(by * k), rows(by * v),
-                     rows(_per_channel(g, d)), chunk,
+                     g.astype(jnp.float32) if per_head else rows(g), chunk,
                      math.gcd(s // chunk, segment), h, 1.0 / math.sqrt(d))
     return o.reshape(bsz, s, h, -1)[..., :dv]
 

@@ -4,6 +4,8 @@ Mosaic refuses (a slice off the tiling, more VMEM than the limit) it
 refuses here, which interpret mode cannot show.  A compile, not a speed."""
 
 import functools
+import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -270,18 +272,11 @@ def test_the_state_space_kernels_compile_for_v5e(one_chip, monkeypatch,
         + [((bsz, groups, 8, per * head), jnp.float32)]
 
 
-# The delta rule's two kernels at the cells' shapes: olmo's 30 heads of
-# 96 over 192 (keys padded to 128 lanes, two heads' values three lane tiles,
-# the second head's sliced at lane 64, a decay a head broadcast over the
-# key channels), pt8k's 8 heads of 128 over 128 (a decay for every
-# channel), and one head a grid step at olmo's sizes (values padded to 256).
-@pytest.mark.parametrize("batch, heads, key, value, per_head", [
-    (1, 30, 96, 192, True), (2, 8, 128, 128, False), (1, 3, 96, 192, True)])
-def test_the_delta_rule_kernels_compile_for_v5e(one_chip, monkeypatch, batch,
-                                                heads, key, value, per_head):
+def _delta_rule_grad(one_chip, batch, heads, key, value, per_head):
+    """The lowered gradient of ``kda_chunked`` at a cell's shapes, chunk 64,
+    and its arguments."""
     from horovod_tpu.models import linear_attention as la
     from horovod_tpu.ops import kda_kernels
-    monkeypatch.setattr(kda_kernels, "on_tpu", lambda: True)
     seq, chunk = 8192, 64
     assert kda_kernels.takes(key, value, chunk)
 
@@ -292,12 +287,89 @@ def test_the_delta_rule_kernels_compile_for_v5e(one_chip, monkeypatch, batch,
             shape(batch, seq, heads, value, dt=jnp.bfloat16),
             shape(batch, seq, heads) if per_head
             else shape(batch, seq, heads, key), shape(batch, seq, heads))
-    compiled = jax.jit(jax.grad(
+    return jax.jit(jax.grad(
         lambda *a: la.kda_chunked(*a, chunk).sum(), argnums=range(5))).lower(
-            *args).compile()
+            *args), args
+
+
+def _custom_call(text, name):
+    """(results, operands) of the one ``tpu_custom_call`` named ``name`` in
+    a compiled text: their shapes as the text writes them, ``f32[1,8]``."""
+    (line,) = [ln for ln in text.splitlines()
+               if ln.lstrip().startswith("%%%s." % name)
+               and 'custom_call_target="tpu_custom_call"' in ln]
+    results = line.split(" = ", 1)[1].split(" custom-call(", 1)[0]
+    operands = line.split("operand_layout_constraints={", 1)[1].split(
+        "}, frontend_attributes", 1)[0]
+    return tuple(re.findall(r"\w+\[[\d,]*\]", x) for x in (results, operands))
+
+
+# The delta rule's two kernels at the cells' shapes: olmo's 30 heads of
+# 96 over 192 (keys padded to 128 lanes, two heads' values three lane tiles,
+# the second head's sliced at lane 64, a decay a head as one value a step in
+# a pair of kernels of its own), pt8k's 8 heads of 128 over 128 (a decay for
+# every channel), and one head a grid step at olmo's sizes (values padded to
+# 256).
+@pytest.mark.parametrize("batch, heads, key, value, per_head", [
+    (1, 30, 96, 192, True), (2, 8, 128, 128, False), (1, 3, 96, 192, True)])
+def test_the_delta_rule_kernels_compile_for_v5e(one_chip, monkeypatch, batch,
+                                                heads, key, value, per_head):
+    from horovod_tpu.ops import kda_kernels
+    monkeypatch.setattr(kda_kernels, "on_tpu", lambda: True)
+    seq = 8192
+    lowered, args = _delta_rule_grad(one_chip, batch, heads, key, value,
+                                     per_head)
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
     width = heads * kda_kernels.padded(value, heads)
     assert "f32[%d,%d,%d]" % (batch, seq, width) in text
     assert [(o.shape, o.dtype) for o in compiled.out_info] \
         == [(a.shape, a.dtype) for a in args]
+    # q, k, kb, vb, g in and their gradients out: a decay for every channel
+    # at the keys' width (olmo's would be f32[1,8192,3840]), a decay a head
+    # as a row of 64 steps a head and chunk (2 MB at olmo's shape).
+    def rows(size):
+        return "f32[%d,%d,%d]" % (batch, seq,
+                                  heads * kda_kernels.padded(size, heads))
+
+    keys, values = rows(key), rows(value)
+    steps = kda_kernels._heads_a_step(heads)
+    decay = "f32[%d,%d,%d,%d,64]" % (batch, heads // steps, seq // 64,
+                                     steps) if per_head else keys
+    name = "_head" if per_head else ""
+    _, fwd = _custom_call(text, "hvd_kda_fwd" + name)
+    grads, bwd = _custom_call(text, "hvd_kda_bwd" + name)
+    inputs = [keys, keys, keys, values, decay]
+    assert fwd[2:] == inputs and bwd[2:8] == inputs + [values]
+    assert grads == inputs
+
+
+# sha256 of the Mosaic modules (no locations) of pt8k's two delta-rule
+# kernels at PR 39 (commit 04437dc), before a decay a head took a pair of
+# kernels of its own: a decay for every channel runs the parent's kernels
+# to the letter.  A PR that changes them on purpose computes these again.
+CHANNEL_PARENTS = {
+    "hvd_kda_fwd":
+        "573d0e1751f5d78d736286ebe36f5e68810712e97b64c5be1787e2ea28f17aae",
+    "hvd_kda_bwd":
+        "8f443ad29885b2bef4f230bcab8567c0c9ae701e9b6625aa770bf7f05dc57af8"}
+
+
+def test_the_channel_delta_rule_kernels_lower_to_the_parents_text(
+        one_chip, monkeypatch):
+    from jax._src import tpu_custom_call
+    from horovod_tpu.ops import kda_kernels
+    monkeypatch.setattr(kda_kernels, "on_tpu", lambda: True)
+    bodies = {}
+    serialize = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def keep(module, **kw):
+        text = module.operation.get_asm(enable_debug_info=False)
+        name = text.split("module @", 1)[1].split(" ", 1)[0]
+        bodies[name] = hashlib.sha256(text.encode()).hexdigest()
+        return serialize(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", keep)
+    _delta_rule_grad(one_chip, 2, 8, 128, 128, False)
+    assert bodies == CHANNEL_PARENTS

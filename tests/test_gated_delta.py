@@ -1,9 +1,10 @@
 """The gated delta rule with one decay a head and keys narrower than values
 (Gated DeltaNet, ``olmo-hybrid-7b``): ``kda_chunked`` in its XLA form and
-through the kernels (interpreted) against the token-by-token recurrence in
-values and every gradient; which shapes take the kernels and the counter
-that says so; the block with its norms after the sub-layers and the QK-norm
-against their written-out equations; the tiny decoder against the
+through the kernels that take a decay a head (interpreted) against the
+token-by-token recurrence and against each other in values and every
+gradient; which shapes take which kernels and the counter that says so;
+the block with its norms after the sub-layers and the QK-norm against
+their written-out equations; the tiny decoder against the
 benchmark's plain reference (``yardstick/builders/olmo_hybrid.py``); scopes
 and refusals."""
 
@@ -17,7 +18,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.common import metrics, scopes
 from horovod_tpu.models import transformer as T
-from horovod_tpu.models.linear_attention import KdaConfig, kda_chunked
+from horovod_tpu.models.linear_attention import (KdaConfig, kda_chunked,
+                                                 kda_chunked_xla)
 from horovod_tpu.ops import kda_kernels
 from yardstick import manifest as mf
 from yardstick.builders import olmo_hybrid as builder
@@ -50,47 +52,60 @@ def plain(*args):
 
 # (heads, keys, values): 24 / 48 take the XLA form; 72 / 136 the kernels,
 # padded to 128 / 256; the cell's 96 / 192, keys padded to 128 and two heads'
-# values three lane tiles, the second head's sliced at lane 64.
+# values three lane tiles, the second head's sliced at lane 64; one head a
+# grid step at the cell's sizes, values padded to 256.
 FORMS = {"xla": (2, 24, 48), "kernels": (2, 72, 136),
-         "kernels, the cell's sizes": (2, 96, 192)}
+         "kernels, the cell's sizes": (2, 96, 192),
+         "kernels, one head a step": (1, 96, 192)}
 
 
 @pytest.mark.parametrize("form,decay", [
-    ("xla", 0.05), ("xla", 4.0), ("kernels", 4.0),
-    ("kernels, the cell's sizes", 0.05), ("kernels, the cell's sizes", 4.0)])
+    ("xla", 0.05), ("xla", 4.0), ("kernels", 0.05), ("kernels", 4.0),
+    ("kernels, the cell's sizes", 0.05), ("kernels, the cell's sizes", 4.0),
+    ("kernels, one head a step", 0.05), ("kernels, one head a step", 4.0)])
 def test_a_decay_a_head_over_narrow_keys_is_the_recurrence(form, decay):
-    """Output and the gradient of every input, decay a head broadcast over
-    the key channels, keys not a lane multiple, beta above 1."""
+    """Output and the gradient of every input, keys not a lane multiple,
+    beta above 1, a weak and a strong decay: against the recurrence, and
+    the kernels (which take the decay a head as it is) against the XLA
+    form (which broadcasts it over the key channels)."""
     h, dk, dv = FORMS[form]
     args = inputs(jax.random.PRNGKey(int(decay)), h, dk, dv, decay)
     assert float(args[4].max()) > 1.5
     assert kda_kernels.takes(dk, dv, 16) == (form != "xla")
+    weight = jax.random.normal(jax.random.PRNGKey(9), (1, 64, h, dv))
 
-    def chunked(*a):
-        return kda_chunked(*a, 16, segment=2)
+    def both(f):
+        def loss(*a):
+            out = f(*a)
+            return (out * weight).sum(), out
+        grads, out = jax.grad(loss, argnums=range(5), has_aux=True)(*args)
+        return (out,) + grads
 
-    out, want = chunked(*args), plain(*args)
-    assert out.shape == want.shape == (1, 64, h, dv)
-    assert float(jnp.abs(out - want).max()) < 1e-5
-    weight = jax.random.normal(jax.random.PRNGKey(9), out.shape)
-    grads = jax.grad(lambda *a: (chunked(*a) * weight).sum(),
-                     argnums=range(5))(*args)
-    wants = jax.grad(lambda *a: (plain(*a) * weight).sum(),
-                     argnums=range(5))(*args)
-    for g, w in zip(grads, wants):
-        assert g.shape == w.shape and bool(jnp.isfinite(g).all())
-        assert float(jnp.abs(g - w).max()) < 1e-4 * float(jnp.abs(w).max())
+    got = both(lambda *a: kda_chunked(*a, 16, segment=2))
+    oracles = [plain] + ([] if form == "xla" else
+                         [lambda *a: kda_chunked_xla(*a, 16, segment=2)])
+    for oracle in oracles:
+        want = both(oracle)
+        assert got[0].shape == want[0].shape == (1, 64, h, dv)
+        assert float(jnp.abs(got[0] - want[0]).max()) < 1e-5
+        for g, w in zip(got[1:], want[1:]):
+            assert g.shape == w.shape and bool(jnp.isfinite(g).all())
+            assert float(jnp.abs(g - w).max()) \
+                < 1e-4 * float(jnp.abs(w).max())
 
 
-@pytest.mark.parametrize("dk,dv,chunk,kernels", [
-    (96, 192, 64, True), (128, 128, 64, True), (72, 136, 16, True),
-    (64, 128, 64, False), (96, 64, 64, False), (24, 48, 16, False),
-    (96, 192, 24, False)])
+@pytest.mark.parametrize("dk,dv,chunk,kernels,decay", [
+    (96, 192, 64, True, "head"), (128, 128, 64, True, "head"),
+    (72, 136, 16, True, "head"), (64, 128, 64, False, "head"),
+    (96, 64, 64, False, "head"), (24, 48, 16, False, "head"),
+    (96, 192, 24, False, "head"), (96, 192, 64, True, "channel"),
+    (64, 128, 64, False, "channel")])
 def test_shapes_choose_the_form_and_the_counter_says_which(dk, dv, chunk,
-                                                          kernels):
+                                                          kernels, decay):
     """Keys and values that fill more than half of their lane tiles, and a
-    chunk that halves down to single rows, take the kernels; the core
-    counts its form and its decay as it is traced."""
+    chunk that halves down to single rows, take the kernels: a decay a head
+    its own pair (``hvd_kda_fwd_head``), a decay for every channel the
+    other; the core counts its form and its decay as it is traced."""
     assert kda_kernels.takes(dk, dv, chunk) == kernels
     assert kda_kernels.padded(192, 30) == 192 == kda_kernels.padded(192, 2)
     assert kda_kernels.padded(192, 1) == 256 == kda_kernels.padded(136, 2)
@@ -103,12 +118,19 @@ def test_shapes_choose_the_form_and_the_counter_says_which(dk, dv, chunk,
                 for r in rows}
 
     before = series()
-    args = inputs(jax.random.PRNGKey(0), 1, dk, dv, 1.0, s=2 * chunk)
-    jaxpr = str(jax.make_jaxpr(lambda *a: kda_chunked(*a, chunk))(*args))
+    q, k, v, g, beta = inputs(jax.random.PRNGKey(0), 1, dk, dv, 1.0,
+                              s=2 * chunk)
+    if decay == "channel":
+        g = g[..., None] * jnp.linspace(0.5, 1.5, dk)
+    jaxpr = str(jax.make_jaxpr(lambda *a: kda_chunked(*a, chunk))(
+        q, k, v, g, beta))
     assert ("hvd_kda_fwd" in jaxpr) == kernels
-    form = "kernel" if kernels else "xla"
-    assert series().get((form, "head"), 0) \
-        - before.get((form, "head"), 0) == 1
+    assert ("hvd_kda_fwd_head" in jaxpr) == (kernels and decay == "head")
+    form = ("head_kernel" if decay == "head" else "kernel") if kernels \
+        else "xla"
+    changed = {key: n - before.get(key, 0) for key, n in series().items()
+               if n != before.get(key, 0)}
+    assert changed == {(form, decay): 1}
 
 
 # -- the block ----------------------------------------------------------------
