@@ -322,6 +322,13 @@ NAMES: Dict[str, Tuple[str, str]] = {
                    "took, labeled form (kernel = flash_attention at the "
                    "two head sizes | xla = local_attention); counted as a "
                    "block is traced, like hvd_flash_backward_calls_total"),
+    "hvd_dense_ffn_calls_total": (
+        "counter", "dense SwiGLU feed-forwards (models/transformer.py: "
+                   "_dense_ffn) by their form, labeled form (split = every "
+                   "operand of the products a buffer of its own: the "
+                   "weights' casts, the input, silu(a) * g, (d_a, d_g) and "
+                   "the incoming gradient made once); counted as a layer is traced, like "
+                   "hvd_flash_backward_calls_total"),
     # -- lifecycle: the program's own set-up, timed on the host --
     "hvd_span_seconds": (
         "histogram", "wall time of one host span, labeled span (the host "

@@ -700,9 +700,83 @@ def _row_parallel_product(x, w, cfg: TransformerConfig):
 
 @jax.named_scope(scopes.DENSE_FFN)
 def _dense_ffn(h, lp, cfg: TransformerConfig):
-    a = jax.nn.silu(h @ lp["w1"].astype(h.dtype))
-    g = h @ lp["w3"].astype(h.dtype)
-    return _row_parallel_product(a * g, lp["w2"].astype(h.dtype), cfg)
+    """``(silu(h w1) * (h w3)) w2``, every operand of its products a buffer
+    made once: the weights' casts, ``h``, ``silu(a) * g``, ``(d_a, d_g)``
+    and the incoming gradient.  Left to itself XLA fuses those passes (and
+    the neighbouring norm's) into the products' operands and computes them
+    again for every tile of the product."""
+    metrics.counter("hvd_dense_ffn_calls_total", form="split").inc()
+    w1, w3, w2 = _made_once(tuple(lp[name].astype(h.dtype)
+                                  for name in ("w1", "w3", "w2")))
+    # One varying type for the gate's operands: the sums over the axes
+    # one of them lacks are then its cotangent's, outside the gate.
+    vma = tuple(sorted(set().union(*(jax.typeof(x).vma
+                                     for x in (h, w1, w3)))))
+    u = _swiglu_gate(*(pvary_missing(x, vma) for x in (h, w1, w3)))
+    return _cotangent_once(_row_parallel_product(u, w2, cfg))
+
+
+@jax.custom_vjp
+def _made_once(x):
+    """``x`` as a buffer of its own; its cotangent passes as it is."""
+    return lax.optimization_barrier(x)
+
+
+_made_once.defvjp(lambda x: (_made_once(x), None), lambda _, dx: (dx,))
+
+
+@jax.custom_vjp
+def _cotangent_once(y):
+    """``y``, whose cotangent reaches the products behind it as a buffer of
+    its own: a post-norm's gradient is made once."""
+    return y
+
+
+_cotangent_once.defvjp(lambda y: (y, None),
+                       lambda _, dy: (lax.optimization_barrier(dy),))
+
+
+def _gate(a, g):
+    """``silu(a) * g`` in float32, rounded once to the products' dtype."""
+    return (jax.nn.silu(a.astype(jnp.float32))
+            * g.astype(jnp.float32)).astype(a.dtype)
+
+
+def _tokens_product(x, dy):
+    """``x^T dy`` over every token: ``[.., m]``, ``[.., n]`` -> ``[m, n]``,
+    a weight's gradient in the operands' dtype, as autodiff gives it."""
+    lead = tuple(range(x.ndim - 1))
+    return lax.dot_general(x, dy, ((lead, lead), ((), ())))
+
+
+@jax.custom_vjp
+def _swiglu_gate(h, w1, w3):
+    """``u = silu(h w1) * (h w3)``, kept ``h``, ``a = h w1`` and ``g = h
+    w3``.  The way back makes ``(d_a, d_g)`` in one float32 pass, the last
+    reader of ``du``, ``a`` and ``g``, and hands the products ``h``, ``d_a``
+    and ``d_g`` as buffers."""
+    return _swiglu_gate_fwd(h, w1, w3)[0]
+
+
+def _swiglu_gate_fwd(h, w1, w3):
+    h = lax.optimization_barrier(h)
+    a, g = h @ w1, h @ w3
+    return lax.optimization_barrier(_gate(a, g)), (h, a, g, w1, w3)
+
+
+def _swiglu_gate_bwd(res, du):
+    h, a, g, w1, w3 = res
+    f32 = jnp.float32
+    a32, g32, du32 = a.astype(f32), g.astype(f32), du.astype(f32)
+    s = jax.nn.sigmoid(a32)
+    d_a, d_g = lax.optimization_barrier(
+        ((du32 * g32 * s * (1 + a32 * (1 - s))).astype(a.dtype),
+         (du32 * a32 * s).astype(g.dtype)))
+    dh = d_a @ w1.T + d_g @ w3.T
+    return dh, _tokens_product(h, d_a), _tokens_product(h, d_g)
+
+
+_swiglu_gate.defvjp(_swiglu_gate_fwd, _swiglu_gate_bwd)
 
 
 def _moe_block(h, lp, cfg: TransformerConfig, sp_size):

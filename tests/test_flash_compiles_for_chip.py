@@ -373,3 +373,120 @@ def test_the_channel_delta_rule_kernels_lower_to_the_parents_text(
     monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", keep)
     _delta_rule_grad(one_chip, 2, 8, 128, 128, False)
     assert bodies == CHANNEL_PARENTS
+
+
+def _fusions(text):
+    """{computation: {instruction: (opcode, operands, called computations,
+    op_name)}} of a compiled HLO text."""
+    comps, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            body = comps[head.group(1)] = {}
+            continue
+        ins = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\((.*)$",
+                       line)
+        if body is not None and ins:
+            name, opcode, rest = ins.groups()
+            operands = rest.split(")", 1)[0]
+            op_name = re.search(r'op_name="([^"]*)"', rest)
+            body[name] = (opcode, re.findall(r"%([\w.\-]+)", operands),
+                          re.findall(r"calls=%([\w.\-]+)", rest),
+                          op_name.group(1) if op_name else "")
+    return comps
+
+
+def _logistic_in_product_operands(text, scope):
+    """The fusions under ``scope`` whose product (a ``convolution``) reads
+    an operand that a ``logistic`` (on the chip: an ``exponential``) is
+    computed into, inside the fusion: rebuilt for every tile."""
+    comps = _fusions(text)
+
+    def opcodes(comp):
+        for opcode, _, calls, _ in comps[comp].values():
+            yield opcode
+            for called in calls:
+                yield from opcodes(called)
+
+    def feeds(comp):
+        body = comps[comp]
+        for opcode, operands, calls, _ in body.values():
+            if opcode == "convolution":
+                todo, seen = list(operands), set()
+                while todo:
+                    name = todo.pop()
+                    if name in seen or name not in body:
+                        continue
+                    seen.add(name)
+                    op, more, called, _ = body[name]
+                    inner = {op}.union(*(set(opcodes(c)) for c in called))
+                    if inner & {"logistic", "exponential"}:
+                        return True
+                    todo += more
+            if any(feeds(c) for c in calls):
+                return True
+        return False
+
+    found, products = [], 0
+    for body in comps.values():
+        for name, (opcode, _, calls, op_name) in body.items():
+            if opcode == "fusion" and scope in op_name \
+                    and "convolution" in set(opcodes(calls[0])):
+                products += 1
+                if feeds(calls[0]):
+                    found.append(name)
+    return found, products
+
+
+# A post-norm SwiGLU layer's train step with AdamW after it, small: the
+# dense feed-forward's products, forward and backward, read silu(a) * g, its
+# derivative and the norm's gradient as buffers made once; the plain
+# formula, one expression left to XLA, has them rebuilt inside a weight
+# gradient's product for each of its tiles.
+@pytest.mark.parametrize("split", [True, False])
+def test_no_swiglu_product_rebuilds_its_operands_on_v5e(one_chip, split):
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, PartitionSpec as P
+    from horovod_tpu.common import scopes
+    from horovod_tpu.models import transformer as T
+    tokens, width, inner = 1024, 256, 1024
+    cfg = T.TransformerConfig(d_model=width, d_ff=inner, dtype="bfloat16",
+                              post_norm=True)
+
+    @jax.named_scope(scopes.DENSE_FFN)
+    def formula(h, lp, cfg):
+        w1, w3, w2 = (lp[k].astype(h.dtype) for k in ("w1", "w3", "w2"))
+        return jax.lax.psum((jax.nn.silu(h @ w1) * (h @ w3)) @ w2,
+                            cfg.tp_axis)
+
+    ffn = T._dense_ffn if split else formula
+
+    def loss(lp, x):
+        y = x + T.rms_norm(ffn(x, lp, cfg), lp["ln2"], cfg.norm_eps)
+        return jnp.mean(jnp.square(y.astype(jnp.float32)))
+
+    opt = optax.adamw(1e-3)
+    mesh = Mesh(np.asarray(list(one_chip.device_set)).reshape(1, 1, 1),
+                ("dp", "sp", "tp"))
+
+    def step(lp, state, x):
+        grads = jax.shard_map(
+            jax.grad(jax.checkpoint(loss)), mesh=mesh, in_specs=(P(), P()),
+            out_specs=P(), check_vma=False)(lp, x)
+        updates, state = opt.update(grads, state, lp)
+        return optax.apply_updates(lp, updates), state
+
+    def shape(*dims, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    lp = {"w1": shape(width, inner), "w3": shape(width, inner),
+          "w2": shape(inner, width), "ln2": shape(width)}
+    state = jax.eval_shape(opt.init, lp)
+    state = jax.tree.map(lambda s: shape(*s.shape, dt=s.dtype), state)
+    text = jax.jit(step).lower(
+        lp, state, shape(1, tokens, width, dt=jnp.bfloat16)).compile() \
+        .as_text()
+    found, products = _logistic_in_product_operands(text, scopes.DENSE_FFN)
+    assert products >= 6
+    assert bool(found) != split, found

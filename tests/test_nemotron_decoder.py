@@ -404,14 +404,16 @@ def test_the_scan_and_the_mixer_carry_their_scopes():
 # parent's to the letter.  A PR that changes those programs on purpose
 # computes these again (``hashlib.sha256(lowered(cell, builder).encode())``).
 # ``nemotron``'s own is of PR 36 (commit d259f55), before the latent-attention
-# mixer and the flash kernels' second head size.
+# mixer and the flash kernels' second head size.  ``laguna``'s is of the dense
+# SwiGLU's split form (``models/transformer.py: _dense_ffn``), which its
+# leading layer runs.
 PARENTS = {
     ("nemotron-3-nano-30b-a3b.dp1-pt8k", "nemotron_h"):
         "e2652c33ba96cd0fbf2e58291c393e635ba99544018c9dbaffc82b9148a0f361",
     ("solar-open2-250b.dp1-pt8k", "solar_open2"):
         "5fcd1a630d4b705efe90181c0306daf9c57773270250aaadb55cdeaef2911a1a",
     ("laguna-xs2.dp1-pt8k", "laguna"):
-        "cc339850126c92d5462d8523e5677fcc11cb26b9cc196048e56cdfece509fb20",
+        "2d7db3af3af4a262235e09b5010631c5227acc2ad26f732b72a80472ad768194",
 }
 
 
