@@ -105,17 +105,10 @@ def transformer_metrics(jax, jnp, on_accel, peak):
     return tok_s, mfu, config_tag
 
 
-def lever_attribution(jax, jnp, on_accel, peak):
-    """Per-lever attribution block for the BENCH JSON (r9): which flash
-    block plan and backward variant the flagship transformer ran with
-    (and why — env / autotuned / default), a fwd/bwd TFLOP/s split of
-    the attention kernels at the flagship shape, and the hier-op plane
-    config — so a trajectory delta is attributable to a specific lever
-    instead of a whole round."""
-    from horovod_tpu.ops import pallas_kernels as pk
-
-    seq, d = (2048, 128) if on_accel else (128, 32)
-    bh = 32 if on_accel else 2          # flagship b4 x h8
+def lever_attribution():
+    """Per-lever attribution block for the BENCH JSON (r9): the hier-op
+    plane config and the other planes' settings — so a trajectory delta
+    is attributable to a specific lever instead of a whole round."""
     lev = {}
     try:
         # Config.from_env is the one parser the gate itself uses —
@@ -139,31 +132,6 @@ def lever_attribution(jax, jnp, on_accel, peak):
             "error_feedback_ops": ["allreduce", "reducescatter"],
             "residual_buckets": int(cfg.compression_residual_buckets),
         }
-        # flash_plan_info validates the env hooks and raises on bad
-        # values — attribution must degrade, never kill the headline
-        # JSON (e.g. an on-chip block override run on the CPU smoke
-        # shape fails the divisibility check).
-        lev["flash"] = pk.flash_plan_info(seq, d)
-        # fwd/bwd TFLOP/s split at the planned blocks (no pin: the
-        # probe must never change the plan it is attributing).  Chip
-        # only: an interpret-mode TFLOP/s number would be noise, and
-        # the CPU smoke must stay cheap.
-        plan = lev["flash"]
-        if on_accel and plan["block_q"] and plan["block_k"]:
-            probe = pk.autotune_flash_blocks(
-                seq, d, batch_heads=bh, iters=4 if on_accel else 1,
-                candidates=[(plan["block_q"], plan["block_k"])],
-                report_core=False, pin=False)
-            sample = probe["samples"][probe["best"]]
-            lev["flash"]["fwd_tflops"] = round(
-                sample["fwd_tflops"], 2)
-            lev["flash"]["bwd_tflops"] = round(
-                sample["bwd_tflops"], 2)
-            if peak:
-                lev["flash"]["fwd_frac_of_peak"] = round(
-                    sample["fwd_tflops"] * 1e12 / peak, 4)
-                lev["flash"]["bwd_frac_of_peak"] = round(
-                    sample["bwd_tflops"] * 1e12 / peak, 4)
     except Exception as exc:  # noqa: BLE001 - attribution is optional
         print("lever attribution degraded: %s" % exc, file=sys.stderr)
     try:
@@ -381,19 +349,6 @@ def main():
     # fields, `value`/`mfu` meanings unchanged.
     tf_tok_s = tf_mfu = tf_cfg = None
     if workload == "resnet50":
-        if os.environ.get("HVD_TPU_FLASH_AUTOTUNE") == "1":
-            # Tune the flagship attention blocks before the transformer
-            # bench traces, so the measured number runs the tuner's
-            # winner (blocks are then tuned, not hardcoded).
-            try:
-                from horovod_tpu.ops import pallas_kernels as pk
-                seq_d = (2048, 128) if on_accel else (128, 32)
-                pk.autotune_flash_blocks(
-                    *seq_d, batch_heads=32 if on_accel else 2,
-                    iters=4 if on_accel else 1)
-            except Exception as exc:  # noqa: BLE001 - keep the headline
-                print("flash autotune failed: %s" % exc,
-                      file=sys.stderr)
         tf_tok_s, tf_mfu, tf_cfg = transformer_metrics(
             jax, jnp, on_accel, peak)
 
@@ -414,7 +369,7 @@ def main():
         rec["transformer_tok_s"] = round(tf_tok_s, 1)
         rec["transformer_mfu"] = tf_mfu and round(tf_mfu, 4)
         rec["transformer_config"] = tf_cfg
-    rec["levers"] = lever_attribution(jax, jnp, on_accel, peak)
+    rec["levers"] = lever_attribution()
     print(json.dumps(rec))
 
 

@@ -421,17 +421,29 @@ def check_flash_against_reference():
     (_, want_out), want_grads = weighted(pk._reference_attention)(q, k, v)
     (_, out), grads = weighted(pk.flash_attention)(q, k, v)
     ok = close("forward", out, want_out)
-    form = pk.flash_plan_info(shape[1], shape[3])["bwd"]
+    batch, seq, heads, d = shape
+    block_q, block_k, d_pad, scale = pk._plan(seq, d)
+    form = pk._backward_form(batch * heads, seq, d_pad, 2, None)[0]
     for name, got, want in zip("qkv", grads, want_grads):
         ok &= close("backward (%s) d%s" % (form, name), got, want)
 
-    # The A/B hatch the docs promise: the two kernels, whatever the shape.
-    os.environ["HVD_TPU_FLASH_BWD"] = "pallas"
-    try:
-        _, grads = weighted(pk.flash_attention)(q, k, v)
-    finally:
-        del os.environ["HVD_TPU_FLASH_BWD"]
-    for name, got, want in zip("qkv", grads, want_grads):
+    # The two kernels, which a call takes where the one kernel's dq does
+    # not fit, on the flat operands the call hands the kernels.
+    @jax.jit
+    def two_kernels(q, k, v, g):
+        f32 = jnp.float32
+        q, k, v, g = (pk._to_flat(x, d_pad) for x in (q * scale, k, v, g))
+        plan = dict(causal=True, block_q=block_q, block_k=block_k,
+                    interpret=False)
+        o, lse = pk._flash_attention_fwd_flat(q, k, v, **plan)
+        delta = jnp.sum(g.astype(f32) * o.astype(f32), -1, keepdims=True)
+        dq, dk, dv = pk._flash_attention_bwd_flat(q, k, v, g, lse, delta,
+                                                  **plan)
+        return (pk._from_flat(dq.astype(f32) * scale, batch, heads, d, q),
+                pk._from_flat(dk, batch, heads, d, k),
+                pk._from_flat(dv, batch, heads, d, v))
+
+    for name, got, want in zip("qkv", two_kernels(q, k, v, g), want_grads):
         ok &= close("backward (two_kernel) d" + name, got, want)
 
     # One latent-attention call: query/key heads of 192 over the same

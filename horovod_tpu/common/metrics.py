@@ -285,17 +285,17 @@ NAMES: Dict[str, Tuple[str, str]] = {
     # -- kernels (ops/pallas_kernels.py, ops/ssd_kernels.py) --
     "hvd_flash_backward_calls_total": (
         "counter", "backward passes of flash_attention by the form they "
-                   "took, labeled form (onepass|two_kernel|chunked|xla) + "
+                   "took, labeled form (onepass|two_kernel|xla) + "
                    "window (0|1); counted as a call is traced, so once "
                    "for every time a layer scan or a recomputation "
                    "traces it and never again for a compiled step"),
     "hvd_flash_block_pairs_total": (
-        "counter", "block pairs a flat head's grid visits in a full "
-                   "(un-windowed) flash call, labeled kernel "
+        "counter", "block pairs a flat head's grid visits in a flash "
+                   "call, full or banded, labeled kernel "
                    "(fwd|dq|dkv|onepass) + kind (interior = seen whole, "
-                   "no mask; diagonal = cut by the causal mask); the "
-                   "call's schedule, counted as its kernel is traced, like "
-                   "hvd_flash_backward_calls_total"),
+                   "no mask; masked = cut by the diagonal or a window's "
+                   "far edge); the call's schedule, counted as its kernel "
+                   "is traced, like hvd_flash_backward_calls_total"),
     "hvd_flash_shared_key_calls_total": (
         "counter", "flash kernels that read a shared key part (k_shared: "
                    "columns every head's key ends in, [B, S, d_s]) as an "

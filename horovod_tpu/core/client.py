@@ -524,9 +524,9 @@ class TcpCore:
                 "warmup_left": int(warmup.value)}
 
     def kernel_tune_record(self, choice: int, score: float):
-        """Report one kernel-parameter sample (flash block-shape sweep)
-        to the core's KernelTuner — the native twin of
-        utils.autotune.KernelBlockTuner."""
+        """Report one kernel-parameter sample to the core's KernelTuner
+        (argmax by mean score; no Python caller since the flash blocks
+        came from the call's shape alone)."""
         self._lib.hvd_tcp_kernel_tune_record(int(choice), float(score))
 
     def kernel_tune_best(self) -> int:

@@ -39,13 +39,11 @@ class BayesianOptimization {
   GaussianProcess gp_;
 };
 
-// Categorical argmax-by-mean tuner for kernel launch parameters
-// (flash-attention block shapes): the Python sweep measures TFLOP/s per
-// (block_q, block_k) choice and reports (choice, score) samples here;
-// Best() is the choice with the highest mean score.  The discrete
-// choice set is tiny, so no GP is warranted — this is the native twin
-// of utils/autotune.py KernelBlockTuner, kept on the core so the TCP
-// world has a rank-0 aggregation point across runs.
+// Categorical argmax-by-mean tuner for kernel launch parameters: a
+// caller reports (choice, score) samples here; Best() is the choice with
+// the highest mean score.  The discrete choice set is tiny, so no GP is
+// warranted.  No Python code reports samples since the flash kernels'
+// blocks came from the call's shape alone.
 class KernelTuner {
  public:
   void Record(int choice, double score) EXCLUDES(mu_);

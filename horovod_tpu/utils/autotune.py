@@ -159,48 +159,6 @@ class BayesianOptimizer:
         return int(np.argmin(((self.grid - np.array(best_p)) ** 2).sum(1)))
 
 
-class KernelBlockTuner:
-    """Categorical argmax-by-mean tuner for kernel launch parameters
-    (flash-attention block shapes).  The search space is a handful of
-    discrete choices, so unlike the fusion/cycle surface no GP is
-    warranted: repeated samples per choice are averaged and the best
-    mean wins.  A native twin (``KernelTuner`` in
-    ``core/src/parameter_manager.cc``) aggregates the same scores on
-    the TCP core for cross-run observability; this class is the
-    in-process source of truth for the sweep
-    (``ops.pallas_kernels.autotune_flash_blocks``)."""
-
-    def __init__(self, choices):
-        self.choices = list(choices)
-        if not self.choices:
-            raise ValueError("KernelBlockTuner needs at least 1 choice")
-        self._sums = np.zeros(len(self.choices), np.float64)
-        self._counts = np.zeros(len(self.choices), np.int64)
-
-    def record(self, index: int, score: float):
-        if not 0 <= index < len(self.choices):
-            raise IndexError("choice index %d out of range [0, %d)"
-                             % (index, len(self.choices)))
-        self._sums[index] += float(score)
-        self._counts[index] += 1
-
-    def samples(self) -> int:
-        return int(self._counts.sum())
-
-    def scores_vector(self) -> np.ndarray:
-        """Per-choice mean scores; unsampled choices are -inf so they
-        can never win an argmax (and so the vector has a fixed length
-        for a deterministic cross-rank reduction)."""
-        with np.errstate(invalid="ignore"):
-            means = self._sums / np.maximum(self._counts, 1)
-        return np.where(self._counts > 0, means, -np.inf)
-
-    def best(self):
-        if self.samples() == 0:
-            raise RuntimeError("no samples recorded")
-        return self.choices[int(np.argmax(self.scores_vector()))]
-
-
 class PlanTuner:
     """GP/EI proposer over one (op, size_class)'s candidate plan grid —
     the widened search space of ROADMAP item 1: hier-vs-flat leg

@@ -3,17 +3,16 @@
 Rounds 9-13 built every ingredient of ROADMAP item 1 — the GP/EI
 autotuner (``utils/autotune.py`` + ``core/src/parameter_manager.cc``),
 per-(op, size_class) path telemetry (``mh_collective_seconds``,
-``mh_collective_path_total``) and the r9 flash-block plan registry —
-but every job still cold-started from static defaults and one global
-``HOROVOD_HIERARCHICAL_ALLREDUCE_THRESHOLD``.  This module closes the
-loop:
+``mh_collective_path_total``) — but every job still cold-started from
+static defaults and one global ``HOROVOD_HIERARCHICAL_ALLREDUCE_THRESHOLD``.
+This module closes the loop:
 
 * **Plan model** — one *plan set* per topology fingerprint
   (``n_procs x local_chips x device_kind``): a per-``(op, size_class)``
-  decision table (hier-vs-flat leg + cross-host codec engagement), the
-  tuned ``(fusion_threshold, cycle_time)`` operating point, and the r9
-  flash-block registry, folded into ONE plane so kernel and collective
-  plans live together.
+  decision table (hier-vs-flat leg + cross-host codec engagement) and the
+  tuned ``(fusion_threshold, cycle_time)`` operating point.  (Plans
+  written when the flash kernels' blocks were tuned hold
+  ``flash_blocks`` too: such a file loads, and the field is not read.)
 * **Persistence** — a versioned on-disk blob under
   ``HOROVOD_PLAN_CACHE_DIR`` written with the spill-plane atomicity
   conventions (MAGIC + schema version + length + CRC32, same-directory
@@ -28,19 +27,17 @@ loop:
   would diverge the negotiated XLA programs).  Without a KV, the cache
   directory must be shared storage (like ``HOROVOD_STATE_SPILL_DIR``)
   or hold identical content on every host.
-* **Tuning** — :func:`tune_collective_plans` is the SPMD sweep
-  (``autotune_flash_blocks``'s convention: every member calls it with
-  identical arguments): per class, the GP/EI :class:`~.autotune.PlanTuner`
-  proposes candidate plans, candidates are scored from the live
-  ``mh_collective_seconds{op,size_class}`` telemetry the r11 metrics
-  plane records, and scores are cross-rank averaged before every
-  proposal/argmax so all members pin the same winner.
+* **Tuning** — :func:`tune_collective_plans` is the SPMD sweep (every
+  member calls it with identical arguments): per class, the GP/EI
+  :class:`~.autotune.PlanTuner` proposes candidate plans, candidates are
+  scored from the live ``mh_collective_seconds{op,size_class}`` telemetry
+  the r11 metrics plane records, and scores are cross-rank averaged before
+  every proposal/argmax so all members pin the same winner.
 
-Env precedence matches the r9 flash-block convention: explicit gate
-envs (``HOROVOD_HIERARCHICAL_ALLREDUCE`` on/off or an explicit
-``HOROVOD_HIERARCHICAL_ALLREDUCE_THRESHOLD``) win over any plan AND
-suppress pinning; explicit ``HOROVOD_FUSION_THRESHOLD`` /
-``HOROVOD_CYCLE_TIME`` suppress the tuned-point warm start the same
+Env precedence: explicit gate envs (``HOROVOD_HIERARCHICAL_ALLREDUCE``
+on/off or an explicit ``HOROVOD_HIERARCHICAL_ALLREDUCE_THRESHOLD``) win
+over any plan AND suppress pinning; explicit ``HOROVOD_FUSION_THRESHOLD``
+/ ``HOROVOD_CYCLE_TIME`` suppress the tuned-point warm start the same
 way.
 """
 
@@ -97,22 +94,17 @@ def empty_plan(fingerprint: str) -> dict:
         # op -> {size_class(str) -> {"path": "hier"|"flat",
         #                            "codec": "none"|codec name}}
         "collectives": {},
-        # "SEQxDPAD" -> [block_q, block_k] (the r9 flash registry,
-        # folded into the same plane).
-        "flash_blocks": {},
     }
 
 
 def _is_plan(obj) -> bool:
     return (isinstance(obj, dict) and obj.get("fingerprint")
-            and isinstance(obj.get("collectives", {}), dict)
-            and isinstance(obj.get("flash_blocks", {}), dict))
+            and isinstance(obj.get("collectives", {}), dict))
 
 
 def plan_has_content(plan: Optional[dict]) -> bool:
     return bool(plan) and bool(plan.get("tuned")
-                               or plan.get("collectives")
-                               or plan.get("flash_blocks"))
+                               or plan.get("collectives"))
 
 
 # -- blob codec (spill-plane conventions) -----------------------------------
@@ -393,7 +385,7 @@ class PlanController:
     def pin(self, op: str, cls: str, entry: dict) -> bool:
         """Pin a tuned winner for one class; refused (False) when env
         pins suppress planning — an explicit operator A/B must stay
-        exactly what was asked for, matching the flash-block rule."""
+        exactly what was asked for."""
         if self.env_pinned:
             return False
         with self._lock:
@@ -480,18 +472,6 @@ def _env_pins_gate() -> bool:
     explicit_mode = v.strip().lower() not in ("", "auto")
     return explicit_mode or env_explicit(
         "HIERARCHICAL_ALLREDUCE_THRESHOLD")
-
-
-def _apply_flash(plan: dict):
-    """Seed the r9 flash-block registry from the plan (env block
-    overrides win and suppress seeding, the flash precedence rule)."""
-    if not plan.get("flash_blocks"):
-        return
-    if (os.environ.get("HVD_TPU_FLASH_BLOCK_Q")
-            or os.environ.get("HVD_TPU_FLASH_BLOCK_K")):
-        return
-    from ..ops import pallas_kernels
-    pallas_kernels.seed_tuned_blocks(plan["flash_blocks"])
 
 
 def _agree_plan(plane, config, mode, n_procs, kv_world,
@@ -638,8 +618,6 @@ def bootstrap(config, topology, mode: str) -> Optional[dict]:
         metrics.counter("plan_apply_total",
                         source=plane.source or "cache").inc()
 
-    _apply_flash(plan)
-
     if mode == "multihost":
         plane.controller = PlanController(
             plane.fingerprint, plan, plane.source,
@@ -720,13 +698,6 @@ def _merged_plan() -> Optional[dict]:
             dst.update(classes)
             merged[op] = dst
         plan["collectives"] = merged
-    try:
-        from ..ops import pallas_kernels
-        blocks = dict(plan.get("flash_blocks", {}))
-        blocks.update(pallas_kernels.export_tuned_blocks())
-        plan["flash_blocks"] = blocks
-    except Exception:  # noqa: BLE001 - flash plane is optional here
-        pass
     return plan
 
 
@@ -994,8 +965,8 @@ def tune_collective_plans(sizes_bytes=(1 << 20,), ops=("allreduce",),
     """SPMD per-(op, size_class) plan sweep over the widened search
     space: hier-vs-flat leg x cross-host codec engagement.
 
-    EVERY member process must call this with identical arguments (the
-    ``autotune_flash_blocks`` contract): the sweep forces one candidate
+    EVERY member process must call this with identical arguments: the
+    sweep forces one candidate
     plan at a time, drives ``iters`` real collectives through the
     public eager API, scores the candidate from the live
     ``mh_collective_seconds{op,size_class}`` dispatch-to-completion

@@ -34,6 +34,26 @@ import pytest  # noqa: E402
 
 
 @pytest.fixture
+def flash_blocks(monkeypatch):
+    """``pin(block_q, block_k, backward)``: every flash call of a test takes
+    these blocks whatever its shape (the plan's chains and its cap at the
+    window set aside, so that a block may be wider than a band), and with
+    ``backward="pallas"`` the two backward kernels (no dq fits the one
+    kernel's budget); ``"pallas_onepass"`` or None leave the backward form
+    to the shape, which at a test's sizes is the one kernel."""
+    from horovod_tpu.ops import pallas_kernels as pk
+    plan, budget = pk._plan, pk._ONEPASS_VMEM_BUDGET
+
+    def pin(block_q=None, block_k=None, backward=None):
+        if block_q is not None:
+            monkeypatch.setattr(pk, "_plan", lambda *a, **kw: (
+                block_q, block_k) + plan(*a, **kw)[2:])
+        monkeypatch.setattr(pk, "_ONEPASS_VMEM_BUDGET",
+                            0 if backward == "pallas" else budget)
+    return pin
+
+
+@pytest.fixture
 def hvd_world():
     """Initialized in-process world over the 8 CPU devices; torn down after."""
     import horovod_tpu as hvd

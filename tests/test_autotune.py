@@ -7,7 +7,6 @@ import pytest
 
 from horovod_tpu.utils.autotune import (BayesianOptimizer,
                                         GaussianProcess,
-                                        KernelBlockTuner,
                                         ParameterManager,
                                         expected_improvement)
 
@@ -106,31 +105,6 @@ def test_parameter_manager_samples_and_freezes(tmp_path):
     settled = pm.fusion_threshold
     pm.observe(nbytes=123, secs=1e-3)
     assert pm.fusion_threshold == settled
-
-
-def test_kernel_block_tuner_argmax_by_mean():
-    t = KernelBlockTuner([(64, 64), (128, 128), (256, 256)])
-    t.record(0, 50.0)
-    t.record(1, 80.0)
-    t.record(1, 100.0)   # mean 90 — repeated samples average
-    t.record(0, 60.0)    # mean 55
-    assert t.best() == (128, 128)
-    assert t.samples() == 4
-    v = t.scores_vector()
-    assert v[1] == 90.0 and v[0] == 55.0
-    # unsampled choices are -inf: fixed-length vector for the cross-
-    # rank mean, and an unsampled choice can never win the argmax
-    assert v[2] == -np.inf
-
-
-def test_kernel_block_tuner_guards():
-    with pytest.raises(ValueError):
-        KernelBlockTuner([])
-    t = KernelBlockTuner([(64, 64)])
-    with pytest.raises(RuntimeError):
-        t.best()
-    with pytest.raises(IndexError):
-        t.record(3, 1.0)
 
 
 def test_engine_skips_observations_on_compile_cycles(hvd_world):

@@ -85,7 +85,7 @@ def test_the_scheduled_forward_kernel_compiles_for_v5e(one_chip, flat_heads,
 
 
 # The two backward kernels, which a call past ``_ONEPASS_VMEM_BUDGET`` takes
-# (a sequence of 16384) and ``HVD_TPU_FLASH_BWD=pallas`` at any shape.
+# (a sequence of 16384), at the cells' shapes and that one.
 @pytest.mark.parametrize("flat_heads, seq, causal", [
     (96, 8192, True), (8, 16384, True), (64, 512, False)])
 def test_the_scheduled_two_backward_kernels_compile_for_v5e(one_chip,
@@ -108,7 +108,7 @@ def test_the_scheduled_two_backward_kernels_compile_for_v5e(one_chip,
 # kanana's latent-attention calls: [2, 8192, 32 heads] as 64 flat heads,
 # query/key heads of 192 over values of 128, neither padded; the forward
 # kernel, the one backward kernel (dq of a head in 256 lanes: 16 MiB) and the
-# two kernels ``HVD_TPU_FLASH_BWD=pallas`` would take; and the forward kernel
+# two kernels a longer sequence would take; and the forward kernel
 # in float32, which the cell's builder runs once before the first step (the
 # measured 1024 x 1024 blocks are bfloat16's: in float32 they pass the
 # kernel's VMEM, and the plan gives the chains' 512 x 1024).

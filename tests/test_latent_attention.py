@@ -48,30 +48,19 @@ def off(got, want):
                for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
 
 
-@pytest.fixture
-def blocks(monkeypatch):
-    def pin(block_q, block_k, backward=None):
-        monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", str(block_q))
-        monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", str(block_k))
-        if backward:
-            monkeypatch.setenv("HVD_TPU_FLASH_BWD", backward)
-    return pin
-
-
 # -- the kernels at two head sizes ------------------------------------------
 
-# The one backward kernel (what a call takes unasked), the two kernels and
-# the chunked XLA form; square and oblong blocks; the cell's sizes and a
-# pair under a lane tile.
+# The one backward kernel (what a call takes unasked) and the two kernels;
+# square and oblong blocks; the cell's sizes and a pair under a lane tile.
 @pytest.mark.parametrize("d_qk, d_v, block_q, block_k, backward", [
     (48, 32, 64, 64, None), (48, 32, 64, 128, None),
     (192, 128, 64, 64, None), (192, 128, 128, 64, None),
     (48, 32, 64, 128, "pallas"), (192, 128, 64, 64, "pallas"),
-    (48, 32, 64, 64, "chunked"), (64, 128, 64, 64, None)])
-def test_the_kernels_take_a_value_size_of_their_own(blocks, d_qk, d_v,
+    (64, 128, 64, 64, None)])
+def test_the_kernels_take_a_value_size_of_their_own(flash_blocks, d_qk, d_v,
                                                     block_q, block_k,
                                                     backward):
-    blocks(block_q, block_k, backward)
+    flash_blocks(block_q, block_k, backward)
     q, k, v, weight = inputs(d_qk, d_v)
     got = out_and_grads(lambda *a: pk.flash_attention(*a, causal=True),
                         q, k, v, weight)
@@ -82,8 +71,8 @@ def test_the_kernels_take_a_value_size_of_their_own(blocks, d_qk, d_v,
     assert off(got, want) < 1e-5
 
 
-def test_the_kernels_in_bfloat16_and_without_a_mask(blocks):
-    blocks(64, 128)
+def test_the_kernels_in_bfloat16_and_without_a_mask(flash_blocks):
+    flash_blocks(64, 128)
     q, k, v, weight = inputs(192, 128, dtype=jnp.bfloat16, heads=3)
     for causal in (True, False):
         got = out_and_grads(lambda *a: pk.flash_attention(*a, causal=causal),
@@ -97,8 +86,8 @@ def test_the_kernels_in_bfloat16_and_without_a_mask(blocks):
         assert off(got, want) < 3e-2, causal
 
 
-def test_the_scale_is_of_the_query_key_size(blocks):
-    blocks(64, 64)
+def test_the_scale_is_of_the_query_key_size(flash_blocks):
+    flash_blocks(64, 64)
     q, k, v, _ = inputs(48, 32)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
     seen = jnp.tril(jnp.ones((SEQ, SEQ), bool))
@@ -113,8 +102,8 @@ def test_the_scale_is_of_the_query_key_size(blocks):
     assert off(got, plain(32)) > 1e-2
 
 
-def test_a_call_at_two_sizes_names_them_and_keeps_its_schedule(blocks):
-    blocks(64, 64)
+def test_a_call_at_two_sizes_names_them_and_keeps_its_schedule(flash_blocks):
+    flash_blocks(64, 64)
     q, k, v, weight = inputs(192, 128)
     before = metrics.snapshot().get("hvd_flash_block_pairs_total",
                                     {"series": []})["series"]
@@ -139,10 +128,10 @@ def test_a_call_at_two_sizes_names_them_and_keeps_its_schedule(blocks):
 
 
 @pytest.mark.parametrize("backward", ["pallas_onepass", "pallas"])
-def test_a_window_takes_two_sizes(blocks, backward):
+def test_a_window_takes_two_sizes(flash_blocks, backward):
     """The banded grids share the full calls' plumbing: the one backward
     kernel and the two, under their own names with the sizes."""
-    blocks(64, 64, backward)
+    flash_blocks(64, 64, backward)
     q, k, v, weight = inputs(48, 32, heads=4)
     got = out_and_grads(lambda *a: pk.flash_attention(*a, window=100),
                         q, k, v, weight)
@@ -154,10 +143,9 @@ def test_a_window_takes_two_sizes(blocks, backward):
     assert "hvd_flash_window_fwd_48x128" in traced
 
 
-def test_the_plan_of_two_sizes_pads_nothing_and_keeps_the_pins(monkeypatch):
-    monkeypatch.setitem(pk._TUNED_BLOCKS, (8192, 128), (256, 512))
-    assert pk._plan(8192, 128)[:3] == (256, 512, 128)
-    assert pk._plan(8192, 128, None, 128)[:3] == (256, 512, 128)
+def test_the_plan_of_two_sizes_pads_nothing_and_keeps_the_pins():
+    assert pk._plan(8192, 128)[:3] == (512, 1024, 128)
+    assert pk._plan(8192, 128, None, 128)[:3] == (512, 1024, 128)
     block_q, block_k, d_pad, scale = pk._plan(8192, 192, None, 128)
     assert (block_q, block_k, d_pad) == (1024, 1024, 192)
     assert math.isclose(scale, 192 ** -0.5)
@@ -199,10 +187,10 @@ def shared_out_and_grads(attention, q, k, shared, v, weight):
 @pytest.mark.parametrize("backward", ["pallas_onepass", "pallas"])
 @pytest.mark.parametrize("d_k, d_s, d_v, block_q, block_k", [
     (16, 8, 12, 64, 64), (16, 8, 12, 64, 128), (128, 64, 128, 128, 64)])
-def test_a_shared_key_part_equals_the_key_joined_in_hbm(blocks, d_k, d_s, d_v,
+def test_a_shared_key_part_equals_the_key_joined_in_hbm(flash_blocks, d_k, d_s, d_v,
                                                         block_q, block_k,
                                                         backward, dtype):
-    blocks(block_q, block_k, backward)
+    flash_blocks(block_q, block_k, backward)
     q, k, shared, v, weight = shared_inputs(d_k, d_s, d_v, dtype)
     got = shared_out_and_grads(
         lambda q, k, shared, v: pk.flash_attention(q, k, v, k_shared=shared),
@@ -222,17 +210,17 @@ def test_a_shared_key_part_equals_the_key_joined_in_hbm(blocks, d_k, d_s, d_v,
     assert off(dshared, dshared_w) < (1e-6 if dtype == jnp.float32 else 8e-3)
 
 
-def test_a_shared_key_part_against_the_plain_attention(blocks):
+def test_a_shared_key_part_against_the_plain_attention(flash_blocks):
     """Not only equal to the joined call: right (grouped key/value heads,
-    each repeated for its group, and the chunked XLA backward among them)."""
+    each repeated for its group, by the one backward kernel and the two)."""
     q, k, shared, v, weight = shared_inputs(16, 8, 12, jnp.float32, heads=4)
     k, v = k[:, :, :2], v[:, :, :2]
     want = shared_out_and_grads(
         lambda q, k, shared, v: pk._reference_attention(
             q, pk.whole_key(jnp.repeat(k, 2, 2), shared),
             jnp.repeat(v, 2, 2), True), q, k, shared, v, weight)
-    for backward in ("pallas_onepass", "chunked"):
-        blocks(64, 128, backward)
+    for backward in ("pallas_onepass", "pallas"):
+        flash_blocks(64, 128, backward)
         got = shared_out_and_grads(
             lambda q, k, shared, v: pk.flash_attention(q, k, v,
                                                        k_shared=shared),
@@ -240,8 +228,8 @@ def test_a_shared_key_part_against_the_plain_attention(blocks):
         assert off(got, want) < 1e-5, backward
 
 
-def test_a_shared_key_part_under_a_window_is_refused_by_name(blocks):
-    blocks(64, 64)
+def test_a_shared_key_part_under_a_window_is_refused_by_name(flash_blocks):
+    flash_blocks(64, 64)
     q, k, shared, v, _ = shared_inputs(16, 8, 12, jnp.float32)
     with pytest.raises(ValueError, match="window of 100 keys.*shared part"):
         pk.flash_attention(q, k, v, window=100, k_shared=shared)
@@ -261,9 +249,9 @@ def shared_key_calls():
 # sha256 of a full call's lowered text (forward and the three gradients,
 # interpreted, ``as_text()``) at PR 36 (commit d259f55), before the kernels
 # took a value size of their own: where ``d_qk == d_v`` the call is the
-# parent's to the letter.  ``(head size, block_q, block_k,
-# HVD_TPU_FLASH_BWD)``; a PR that changes the full calls on purpose computes
-# these again.
+# parent's to the letter.  ``(head size, block_q, block_k, backward form:
+# "pallas" the two kernels, "pallas_onepass" the one)``; a PR that changes
+# the full calls on purpose computes these again.
 FULL_PARENTS = {
     (32, 64, 64, "pallas"):
         "f85757f723c81aca92e78e427479eaf395c54bee57cba40bc07e6768c6893f54",
@@ -276,9 +264,9 @@ FULL_PARENTS = {
 
 @pytest.mark.parametrize("d, block_q, block_k, backward",
                          sorted(FULL_PARENTS))
-def test_a_call_at_one_size_lowers_to_the_parents_text(blocks, d, block_q,
+def test_a_call_at_one_size_lowers_to_the_parents_text(flash_blocks, d, block_q,
                                                        block_k, backward):
-    blocks(block_q, block_k, backward)
+    flash_blocks(block_q, block_k, backward)
     q, k, v, weight = inputs(d, d)
     text = jax.jit(jax.grad(
         lambda *a: (pk.flash_attention(*a) * weight).sum(),

@@ -91,9 +91,8 @@ def test_flash_attention_grad_multiblock_grid(causal):
 # The r9 kernel grid: both Pallas backward structures (two-pass dq/dkv
 # and the one kernel that keeps dq of a head in VMEM) across block shapes, causal
 # on/off, and a lane-padded vs exact head dim — the interpret-mode
-# numerics net under any kernel restructure.  Block shapes are driven
-# through the HVD_TPU_FLASH_BLOCK_Q/K hooks, exactly how an A/B or the
-# autotune sweep drives them.
+# numerics net under any kernel restructure.  Block shapes and the form are
+# pinned by the ``flash_blocks`` fixture (tests/conftest.py).
 @pytest.mark.parametrize(
     "variant,block_q,block_k,causal",
     # full causal coverage over the block pairs; non-causal once per
@@ -102,10 +101,8 @@ def test_flash_attention_grad_multiblock_grid(causal):
     [(v, bq, bk, True) for v in ("pallas", "pallas_onepass")
      for bq, bk in ((64, 128), (128, 64), (128, 128))]
     + [(v, 128, 128, False) for v in ("pallas", "pallas_onepass")])
-def test_flash_bwd_grid(monkeypatch, variant, block_q, block_k, causal):
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", variant)
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", str(block_q))
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", str(block_k))
+def test_flash_bwd_grid(flash_blocks, variant, block_q, block_k, causal):
+    flash_blocks(block_q, block_k, variant)
     q, k, v = _qkv(b=1, s=128, h=1, d=32, seed=11)
 
     def loss_flash(q_, k_, v_):
@@ -123,10 +120,10 @@ def test_flash_bwd_grid(monkeypatch, variant, block_q, block_k, causal):
 
 
 @pytest.mark.parametrize("variant", ["pallas", "pallas_onepass"])
-def test_flash_bwd_grid_exact_lane_dim(monkeypatch, variant):
+def test_flash_bwd_grid_exact_lane_dim(flash_blocks, variant):
     # d=128: no lane padding (d_pad == d) — the zero-column path of the
     # d=32 grid above must not be the only covered layout.
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", variant)
+    flash_blocks(backward=variant)
     q, k, v = _qkv(b=1, s=128, h=1, d=128, seed=12)
 
     def loss_flash(q_, k_, v_):
@@ -143,11 +140,10 @@ def test_flash_bwd_grid_exact_lane_dim(monkeypatch, variant):
                                    err_msg="d%s mismatch" % lbl)
 
 
-def test_flash_bwd_onepass_multiblock_grid(monkeypatch):
+def test_flash_bwd_onepass_multiblock_grid():
     # s=192 -> 3x3 grid of 64-blocks: the one kernel's dk/dv scratch and
     # its whole-head dq accumulator across a schedule that leaves out the
     # pairs above the diagonal (nothing is added to their query blocks' rows).
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas_onepass")
     q, k, v = _qkv(b=1, s=192, h=2, d=32, seed=13)
 
     def loss_flash(q_, k_, v_):
@@ -178,7 +174,7 @@ def test_the_schedule_visits_every_live_pair_once_in_both_orders(
         seq, block_q, block_k, causal):
     """By query block and by key block: the pairs that hold a seen score,
     each once, a row's pairs together and ascending, the row's first and
-    last flagged, and ``crosses`` on the pairs that also hold a hidden one."""
+    last flagged, and ``masked`` on the pairs that also hold a hidden one."""
     rows, cols = np.arange(seq)[:, None], np.arange(seq)[None, :]
     seen = (cols <= rows) if causal else np.ones((seq, seq), bool)
     blocks = seen.reshape(seq // block_q, block_q, seq // block_k, block_k)
@@ -190,7 +186,7 @@ def test_the_schedule_visits_every_live_pair_once_in_both_orders(
                                   (by_key, by_key.k, by_key.q)):
         visited = list(zip(pairs.q.tolist(), pairs.k.tolist()))
         assert len(visited) == len(set(visited)) and set(visited) == set(live)
-        assert [live[pair] for pair in visited] == pairs.crosses.tolist()
+        assert [live[pair] for pair in visited] == pairs.masked.tolist()
         # rows in order and unbroken, each row's partners ascending
         assert row_of.tolist() == sorted(row_of.tolist())
         starts = np.flatnonzero(np.diff(row_of, prepend=-1))
@@ -203,17 +199,32 @@ def test_the_schedule_visits_every_live_pair_once_in_both_orders(
             assert (np.diff(in_row[a:b + 1]) == 1).all()
     if not causal:
         assert len(by_query.q) == (seq // block_q) * (seq // block_k)
-        assert not by_query.crosses.any() and not by_key.crosses.any()
+        assert not by_query.masked.any() and not by_key.masked.any()
 
 
 def test_the_schedule_at_the_cells_shape():
     """8192 positions in blocks of 512 x 1024: 72 of the rectangle's 128
     pairs, 16 of them on the diagonal; BERT's 512 without a mask: one."""
     for pairs in pk._block_schedule(8192, *pk._plan(8192, 128)[:2], True):
-        assert (len(pairs.q), int(pairs.crosses.sum())) == (72, 16)
+        assert (len(pairs.q), int(pairs.masked.sum())) == (72, 16)
     for pairs in pk._block_schedule(512, *pk._plan(512, 64)[:2], False):
-        assert (len(pairs.q), int(pairs.crosses.sum())) == (1, 0)
+        assert (len(pairs.q), int(pairs.masked.sum())) == (1, 0)
         assert pairs.first.all() and pairs.last.all()
+
+
+# Every cell's call shape and the blocks its plan takes, from the shape
+# alone: BERT's (mlm512), the full softmax layers at 8192 (pt8k, laguna,
+# nemotron, olmo), laguna's sliding layers, kanana's latent attention (192
+# over 128, the rotary key shared) and a full call read in float32.
+@pytest.mark.parametrize("shape, blocks", [
+    (dict(s=512, d=64), (512, 512)),
+    (dict(s=8192, d=128), (512, 1024)),
+    (dict(s=8192, d=128, window=512), (512, 512)),
+    (dict(s=8192, d=192, d_v=128, two_part_key=True), (1024, 1024)),
+    (dict(s=8192, d=128, itemsize=4), (512, 1024))],
+    ids=["mlm512", "full-8192", "window-512", "kanana", "float32"])
+def test_the_plan_at_each_cells_call_shape(shape, blocks):
+    assert pk._plan(**shape)[:2] == blocks
 
 
 def _lse(q, k, causal):
@@ -234,11 +245,9 @@ def _lse(q, k, causal):
 @pytest.mark.parametrize("block_q, block_k, causal", [
     (64, 128, True), (128, 64, True), (64, 64, True), (256, 256, True),
     (64, 128, False)])
-def test_a_scheduled_call_matches_the_reference(monkeypatch, variant, block_q,
-                                                block_k, causal):
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", variant)
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", str(block_q))
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", str(block_k))
+def test_a_scheduled_call_matches_the_reference(flash_blocks, variant,
+                                                block_q, block_k, causal):
+    flash_blocks(block_q, block_k, variant)
     q, k, v = _qkv(b=1, s=256, h=2, d=32, seed=21)
     weight = _qkv(b=1, s=256, h=2, d=32, seed=22)[0]
     got, (*_, lse) = pk._flash_fwd(q, k, v, causal, None)
@@ -291,13 +300,10 @@ def _block_pairs():
             for row in series}
 
 
-def test_the_visited_pairs_are_counted_by_kernel_and_kind(monkeypatch):
+def test_the_visited_pairs_are_counted_by_kernel_and_kind(flash_blocks):
     """``hvd_flash_block_pairs_total``: what a flat head's grid visits, as a
-    call's kernels are traced; a banded call has no schedule and adds
-    nothing."""
-    for name in ("HVD_TPU_FLASH_BWD", "HVD_TPU_FLASH_BLOCK_Q",
-                 "HVD_TPU_FLASH_BLOCK_K"):
-        monkeypatch.delenv(name, raising=False)
+    call's kernels are traced, the pairs no mask cuts and those it does; a
+    banded call counts its band's."""
     x = jax.ShapeDtypeStruct((1, 8192, 1, 128), jnp.bfloat16)
 
     def trace(**kw):
@@ -308,17 +314,21 @@ def test_the_visited_pairs_are_counted_by_kernel_and_kind(monkeypatch):
         return _block_pairs()
 
     # the cells' full calls: the rectangle's grid would read 128
-    assert trace() == {("fwd", "interior"): 56, ("fwd", "diagonal"): 16,
+    assert trace() == {("fwd", "interior"): 56, ("fwd", "masked"): 16,
                        ("onepass", "interior"): 56,
-                       ("onepass", "diagonal"): 16}
+                       ("onepass", "masked"): 16}
     assert trace(causal=False) == {("fwd", "interior"): 128,
-                                   ("fwd", "diagonal"): 0,
+                                   ("fwd", "masked"): 0,
                                    ("onepass", "interior"): 128,
-                                   ("onepass", "diagonal"): 0}
-    assert trace(window=512) == {}
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas")
+                                   ("onepass", "masked"): 0}
+    # laguna's sliding layers, 512 x 512 under 512 keys: every pair of the
+    # band is cut, by the diagonal or by the band's far edge
+    assert trace(window=512) == {("fwd", "interior"): 0, ("fwd", "masked"): 31,
+                                 ("onepass", "interior"): 0,
+                                 ("onepass", "masked"): 31}
+    flash_blocks(backward="pallas")
     assert trace() == {(kernel, kind): n for kernel in ("fwd", "dq", "dkv")
-                       for kind, n in (("interior", 56), ("diagonal", 16))}
+                       for kind, n in (("interior", 56), ("masked", 16))}
 
 
 def _backward_kernels(q, k, v, **kw):
@@ -339,12 +349,9 @@ def _backward_calls():
 
 @pytest.mark.parametrize("window", [None, 64])
 def test_flash_bwd_form_follows_the_shape_when_unset(monkeypatch, window):
-    """With ``HVD_TPU_FLASH_BWD`` unset the one kernel runs where dq of a
-    grid step's heads fits the VMEM budget and the two kernels where it
-    does not; ``pallas`` is the two kernels whatever the shape,
-    ``pallas_onepass`` refuses a shape that does not fit, and every traced
+    """The one kernel runs where dq of a grid step's heads fits the VMEM
+    budget and the two kernels where one head's does not, and every traced
     call is counted by its form."""
-    monkeypatch.delenv("HVD_TPU_FLASH_BWD", raising=False)
     metrics.reset()
     q, k, v = _qkv(b=1, s=128, h=2, d=32, seed=16)
     banded = str(int(window is not None))
@@ -352,20 +359,13 @@ def test_flash_bwd_form_follows_the_shape_when_unset(monkeypatch, window):
     two = ({"hvd_flash_window_dq", "hvd_flash_window_dkv"} if window
            else {"hvd_flash_dq", "hvd_flash_dkv"})
     assert _backward_kernels(q, k, v, window=window) == one
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas")
-    assert _backward_kernels(q, k, v, window=window) == two
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "chunked")
-    assert _backward_kernels(q, k, v, window=window) == set()
     # dq of one head: 128 x 128 float32 and its float32 output block twice
     monkeypatch.setattr(pk, "_ONEPASS_VMEM_BUDGET", 128 * 128 * 12 - 1)
-    monkeypatch.delenv("HVD_TPU_FLASH_BWD")
     assert _backward_kernels(q, k, v, window=window) == two
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas_onepass")
-    with pytest.raises(ValueError, match="does not fit"):
-        _backward_kernels(q, k, v, window=window)
-    assert _backward_calls() == {("onepass", banded): 1,
-                                 ("two_kernel", banded): 2,
-                                 ("chunked", banded): 1}
+    monkeypatch.setattr(pk, "_ONEPASS_VMEM_BUDGET", 128 * 128 * 12)
+    assert _backward_kernels(q, k, v, window=window) == one
+    assert _backward_calls() == {("onepass", banded): 2,
+                                 ("two_kernel", banded): 1}
 
 
 def test_flash_bwd_heads_a_step_shrink_to_the_budget(monkeypatch):
@@ -387,68 +387,9 @@ def test_flash_bwd_heads_a_step_shrink_to_the_budget(monkeypatch):
     assert _backward_calls() == {("xla", "0"): 1}
 
 
-def test_flash_plan_info_tells_the_backward_form(monkeypatch):
-    for name in ("HVD_TPU_FLASH_BWD", "HVD_TPU_FLASH_BLOCK_Q",
-                 "HVD_TPU_FLASH_BLOCK_K"):
-        monkeypatch.delenv(name, raising=False)
-    assert pk.flash_plan_info(8192, 128)["bwd"] == "onepass"
-    assert pk.flash_plan_info(512, 64)["bwd"] == "onepass"
-    assert pk.flash_plan_info(100, 64)["bwd"] == "xla"
-    monkeypatch.setattr(pk, "_ONEPASS_VMEM_BUDGET", 1 << 20)
-    assert pk.flash_plan_info(8192, 128)["bwd"] == "two_kernel"
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "chunked")
-    assert pk.flash_plan_info(8192, 128)["bwd"] == "chunked"
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "pallas")
-    assert pk.flash_plan_info(8192, 128)["bwd"] == "two_kernel"
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "onepass")
-    with pytest.raises(ValueError, match="HVD_TPU_FLASH_BWD"):
-        pk.flash_plan_info(8192, 128)
-
-
-def test_flash_bwd_unknown_variant_fails_loudly(monkeypatch):
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "onepass")  # typo'd value
-    q, k, v = _qkv(b=1, s=128, h=1, d=32, seed=14)
-    with pytest.raises(ValueError, match="HVD_TPU_FLASH_BWD"):
-        jax.grad(lambda q_: jnp.sum(
-            flash_attention(q_, k, v, causal=True) ** 2))(q)
-
-
-def test_autotune_flash_blocks_pins_plan(monkeypatch):
-    # The sweep measures each candidate and PINS the winner into the
-    # plan registry: _plan must consult it, and env overrides must win
-    # over (and suppress) the pin.
-    monkeypatch.delenv("HVD_TPU_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("HVD_TPU_FLASH_BLOCK_K", raising=False)
-    try:
-        info = pk.autotune_flash_blocks(
-            128, 32, batch_heads=1, iters=1, include_bwd=False,
-            candidates=[(64, 64), (128, 128)], report_core=False)
-        assert info["pinned"], info
-        assert info["best"] in info["candidates"]
-        assert pk._TUNED_BLOCKS[(128, 128)] == info["best"]
-        plan = pk.flash_plan_info(128, 32)
-        assert plan["source"] == "autotuned"
-        assert (plan["block_q"], plan["block_k"]) == info["best"]
-        # tuned blocks still produce oracle-exact attention
-        q, k, v = _qkv(b=1, s=128, h=1, d=32, seed=15)
-        np.testing.assert_allclose(
-            np.asarray(flash_attention(q, k, v, causal=True)),
-            np.asarray(_reference_attention(q, k, v, True)),
-            atol=2e-5, rtol=2e-5)
-        # an explicit env A/B wins over the tuner and suppresses pinning
-        monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", "64")
-        assert pk.flash_plan_info(128, 32)["source"] == "env"
-        info2 = pk.autotune_flash_blocks(
-            128, 32, batch_heads=1, iters=1, include_bwd=False,
-            candidates=[(64, 64)], report_core=False)
-        assert not info2["pinned"]
-    finally:
-        pk._TUNED_BLOCKS.clear()
-
-
 def test_kernel_tuner_native_mirror():
-    # The C++ KernelTuner (core/src/parameter_manager.cc) must agree
-    # with the Python KernelBlockTuner on argmax-by-mean.
+    # The C++ KernelTuner (core/src/parameter_manager.cc) picks the choice
+    # of the best mean score.
     pytest.importorskip("ctypes")
     from horovod_tpu.core.client import (core_library_available,
                                          load_library)
@@ -464,24 +405,6 @@ def test_kernel_tuner_native_mirror():
     lib.hvd_tcp_kernel_tune_record(7, 10.0e18)  # mean 5.5e18 beats 4e18
     assert lib.hvd_tcp_kernel_tune_best() == 7
     assert lib.hvd_tcp_kernel_tune_samples() == base + 4
-
-
-def test_flash_attention_grad_chunked_escape_hatch(monkeypatch):
-    # HVD_TPU_FLASH_BWD=chunked selects the XLA chunked backward; both
-    # paths must match the oracle.
-    monkeypatch.setenv("HVD_TPU_FLASH_BWD", "chunked")
-    q, k, v = _qkv(b=1, s=128, h=1, d=32, seed=5)
-
-    def loss_flash(q_):
-        return jnp.sum(flash_attention(q_, k, v, causal=True) ** 2)
-
-    def loss_ref(q_):
-        return jnp.sum(_reference_attention(q_, k, v, True) ** 2)
-
-    g1 = jax.grad(loss_flash)(q)
-    g2 = jax.grad(loss_ref)(q)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
-                               atol=2e-4, rtol=2e-4)
 
 
 def test_fused_scale_sum():

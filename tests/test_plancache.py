@@ -1,7 +1,7 @@
 """Collective-plan cache tests (utils/plancache.py): blob codec +
 atomic persistence, loud fallbacks for corrupt/mismatched blobs, KV
 publish/adopt, per-class routing precedence (env wins and suppresses
-pinning, the r9 flash-block convention), the PlanTuner GP sweep unit,
+pinning), the PlanTuner GP sweep unit,
 the crash-safe AutotuneLog writer, and the slow-marked cold-vs-warm
 2-proc e2e the CI perf-smoke step runs by node id."""
 
@@ -24,15 +24,11 @@ FP = plancache.topology_fingerprint(2, 4, "TPU v5e")
 
 @pytest.fixture(autouse=True)
 def _fresh_planes():
-    from horovod_tpu.ops import pallas_kernels as pk
-    saved_blocks = dict(pk._TUNED_BLOCKS)
     metrics.reset()
     plancache.reset()
     yield
     metrics.reset()
     plancache.reset()
-    pk._TUNED_BLOCKS.clear()
-    pk._TUNED_BLOCKS.update(saved_blocks)
 
 
 def _plan(fingerprint=FP):
@@ -42,7 +38,6 @@ def _plan(fingerprint=FP):
     plan["collectives"] = {
         "allreduce": {"20": {"path": "hier", "codec": "int8"},
                       "12": {"path": "flat", "codec": "none"}}}
-    plan["flash_blocks"] = {"512x128": [256, 512]}
     return plan
 
 
@@ -252,7 +247,7 @@ def test_route_pin_wins_over_cached_plan():
 
 
 def test_env_pins_suppress_plan_and_pinning():
-    # The r9 flash-block convention: an explicit operator gate env
+    # An explicit operator gate env
     # wins over any persisted plan AND refuses tuner pinning.
     ctl = _controller(env_pinned=True)
     assert ctl.route("allreduce", "12", True) == (True, True)
@@ -729,21 +724,19 @@ def test_warm_cache_run_skips_retuning_2proc(tmp_path):
             assert ("PLAN_%s_OK" % phase.upper()) in out
 
 
-# -- flash-block seeding (the folded r9 registry) --------------------------
+# -- plans written when the flash blocks were tuned -------------------------
 
-def test_seed_tuned_blocks_roundtrip_and_malformed_skipped(caplog):
+def test_an_old_plan_with_flash_blocks_loads_and_moves_no_block(tmp_path):
+    """A plan file from before the flash blocks came from the call's shape
+    alone holds ``flash_blocks``: it loads, its tuned point applies, and no
+    flash call's blocks move."""
     from horovod_tpu.ops import pallas_kernels as pk
-    saved = dict(pk._TUNED_BLOCKS)
-    try:
-        pk._TUNED_BLOCKS.clear()
-        with caplog.at_level(logging.WARNING, "horovod_tpu"):
-            pk.seed_tuned_blocks({"512x128": [256, 512],
-                                  "notashape": [1, 2],
-                                  "128x128": [0, 64],
-                                  "256x128": "bogus"})
-        assert pk._TUNED_BLOCKS == {(512, 128): (256, 512)}
-        assert caplog.text.count("malformed tuned-block entry") == 3
-        assert pk.export_tuned_blocks() == {"512x128": [256, 512]}
-    finally:
-        pk._TUNED_BLOCKS.clear()
-        pk._TUNED_BLOCKS.update(saved)
+    before = pk._plan(512, 128)
+    plan = _plan(plancache.topology_fingerprint(1, 1, "host"))
+    plan["flash_blocks"] = {"512x128": [256, 512]}
+    plancache.store(plan, str(tmp_path))
+    cfg = Config(plan_cache_dir=str(tmp_path))
+    assert plancache.bootstrap(cfg, _topo(), mode="tcp") == plan
+    assert plancache.tuned_warm_start() == (1 << 25, 3.5, True)
+    assert pk._plan(512, 128) == before
+    assert before[:2] == (512, 512)

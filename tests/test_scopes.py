@@ -292,15 +292,12 @@ def _pallas_calls(jaxpr, found):
     ("pallas", 64, [scopes.FLASH_WINDOW_FWD, scopes.FLASH_WINDOW_DQ,
                     scopes.FLASH_WINDOW_DKV]),
     (None, 64, [scopes.FLASH_WINDOW_FWD, scopes.FLASH_WINDOW_DKV])])
-def test_each_flash_kernel_sits_under_its_scope(monkeypatch, variant, window,
-                                                want):
-    """Unset, the backward is the one kernel: under its own scope for a
-    full call, under the banded dk/dv's for a call with a window (the scope
-    the benchmark reads the banded backward from)."""
-    if variant is None:
-        monkeypatch.delenv("HVD_TPU_FLASH_BWD", raising=False)
-    else:
-        monkeypatch.setenv("HVD_TPU_FLASH_BWD", variant)
+def test_each_flash_kernel_sits_under_its_scope(flash_blocks, variant,
+                                                window, want):
+    """Where dq fits, the backward is the one kernel: under its own scope
+    for a full call, under the banded dk/dv's for a call with a window (the
+    scope the benchmark reads the banded backward from)."""
+    flash_blocks(backward=variant)
     q = jnp.ones((1, 256, 2, 64), jnp.float32)
 
     def loss(q, k, v):

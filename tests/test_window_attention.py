@@ -55,15 +55,6 @@ def off(got, want):
                for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
 
 
-@pytest.fixture
-def blocks(monkeypatch):
-    def pin(block_q, block_k, backward="pallas"):
-        monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", str(block_q))
-        monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", str(block_k))
-        monkeypatch.setenv("HVD_TPU_FLASH_BWD", backward)
-    return pin
-
-
 @pytest.mark.parametrize("window", [1, 17, 64, 100, SEQ, 400])
 def test_the_xla_form_matches_a_plain_masked_softmax(window):
     q, k, v, weight = inputs()
@@ -80,9 +71,9 @@ def test_the_xla_form_matches_a_plain_masked_softmax(window):
     (1, 64, 64), (32, 64, 64), (64, 64, 64), (65, 64, 128), (64, 128, 64),
     (100, 64, 64), (96, 128, 128), (200, 64, 64), (SEQ - 1, 64, 64),
     (SEQ, 64, 64), (400, 64, 64)])
-def test_the_kernels_match_forward_and_every_gradient(blocks, window,
+def test_the_kernels_match_forward_and_every_gradient(flash_blocks, window,
                                                       block_q, block_k):
-    blocks(block_q, block_k)
+    flash_blocks(block_q, block_k, "pallas")
     q, k, v, weight = inputs()
     got = out_and_grads(lambda *a: pk.flash_attention(*a, causal=True,
                                                       window=window),
@@ -92,10 +83,10 @@ def test_the_kernels_match_forward_and_every_gradient(blocks, window,
 
 
 @pytest.mark.parametrize("heads, kv_heads", [(6, 2), (8, 2)])
-def test_grouped_queries_under_a_window(blocks, heads, kv_heads):
+def test_grouped_queries_under_a_window(flash_blocks, heads, kv_heads):
     """Three and four query heads a key/value head, as a model's full and
     sliding layers have them."""
-    blocks(64, 64)
+    flash_blocks(64, 64, "pallas")
     q, k, v, weight = inputs(seq=128, heads=heads, kv_heads=kv_heads, d=16)
     got = out_and_grads(lambda *a: pk.flash_attention(*a, window=48),
                         q, k, v, weight)
@@ -103,12 +94,12 @@ def test_grouped_queries_under_a_window(blocks, heads, kv_heads):
                                   q, k, v, weight)) < 1e-5
 
 
-@pytest.mark.parametrize("backward", ["pallas_onepass", "chunked"])
-def test_the_other_backward_forms_under_a_window(blocks, backward):
-    """The chunked form carries the mask; the one kernel carries the band
-    and sits where the banded dk/dv kernel sat, so nothing runs under the
-    unbanded names or the banded dq's."""
-    blocks(64, 64, backward)
+@pytest.mark.parametrize("backward", ["pallas_onepass"])
+def test_the_other_backward_forms_under_a_window(flash_blocks, backward):
+    """The one kernel carries the band and sits where the banded dk/dv
+    kernel sat, so nothing runs under the unbanded names or the banded
+    dq's."""
+    flash_blocks(64, 64, backward)
     q, k, v, weight = inputs()
     fn = lambda *a: pk.flash_attention(*a, window=100)     # noqa: E731
     got = out_and_grads(fn, q, k, v, weight)
@@ -118,7 +109,41 @@ def test_the_other_backward_forms_under_a_window(blocks, backward):
         lambda *a: (fn(*a) * weight).sum(), argnums=(0, 1, 2)))(q, k, v))
     assert "hvd_flash_bwd_onepass" not in traced
     assert "hvd_flash_window_dq" not in traced
-    assert ("hvd_flash_window_dkv" in traced) == (backward != "chunked")
+    assert "hvd_flash_window_dkv" in traced
+
+
+@pytest.mark.parametrize("block_q, block_k", [(64, 128), (128, 64)])
+def test_the_two_backward_kernels_under_a_window_at_oblong_blocks(
+        flash_blocks, block_q, block_k):
+    """The two kernels where the one kernel's dq does not fit, on a banded
+    schedule whose key blocks are wider and narrower than its query blocks,
+    four flat heads a grid step."""
+    flash_blocks(block_q, block_k, "pallas")
+    q, k, v, weight = inputs(heads=4, kv_heads=4, d=16)
+    fn = lambda *a: pk.flash_attention(*a, window=100)     # noqa: E731
+    traced = str(jax.make_jaxpr(jax.grad(
+        lambda *a: (fn(*a) * weight).sum(), argnums=(0, 1, 2)))(q, k, v))
+    assert "hvd_flash_window_dq" in traced and "hvd_flash_window_dkv" in traced
+    assert "grid=(1, " in traced            # four heads a grid step
+    assert off(out_and_grads(fn, q, k, v, weight),
+               out_and_grads(lambda *a: plain(*a, 100), q, k, v,
+                             weight)) < 1e-5
+
+
+@pytest.mark.parametrize("backward", ["pallas", "pallas_onepass"])
+def test_a_window_wider_than_a_block_leaves_pairs_no_mask_cuts(flash_blocks,
+                                                               backward):
+    """A band of 300 keys over blocks of 64: the pairs between its far edge
+    and the diagonal are seen whole, and the forward kernel takes its body
+    without the mask there; the numbers are still the plain attention's."""
+    flash_blocks(64, 64, backward)
+    by_query = pk._block_schedule(512, 64, 64, True, 300)[0]
+    assert 0 < int(by_query.masked.sum()) < len(by_query.q)
+    q, k, v, weight = inputs(seq=512, heads=2, kv_heads=2, d=16, seed=3)
+    fn = lambda *a: pk.flash_attention(*a, window=300)     # noqa: E731
+    assert off(out_and_grads(fn, q, k, v, weight),
+               out_and_grads(lambda *a: plain(*a, 300), q, k, v,
+                             weight)) < 1e-5
 
 
 # The one backward kernel (dq of a whole head in VMEM): not causal, causal,
@@ -134,8 +159,8 @@ def test_the_other_backward_forms_under_a_window(blocks, backward):
         (256, 8, 4, 100, True, 128, 128), (1024, 2, 2, 512, True, 256, 512),
         (1024, 8, 4, 512, True, 512, 256)])
 def test_the_one_backward_kernel_matches_every_gradient(
-        blocks, seq, heads, step, window, causal, block_q, block_k):
-    blocks(block_q, block_k, "pallas_onepass")
+        flash_blocks, seq, heads, step, window, causal, block_q, block_k):
+    flash_blocks(block_q, block_k, "pallas_onepass")
     q, k, v, weight = inputs(seq=seq, heads=heads, kv_heads=heads, d=16)
     assert pk._backward_form(heads, seq, 128, 4, window) == ("onepass", step)
     traced = str(jax.make_jaxpr(jax.grad(lambda *a: (pk.flash_attention(
@@ -153,11 +178,11 @@ def test_the_one_backward_kernel_matches_every_gradient(
 
 
 @pytest.mark.parametrize("heads, kv_heads", [(6, 1), (8, 1)])
-def test_the_one_backward_kernel_under_grouped_queries(blocks, heads,
+def test_the_one_backward_kernel_under_grouped_queries(flash_blocks, heads,
                                                        kv_heads):
     """Six and eight query heads a key/value head, as laguna's full and
     sliding layers have them, with and without the band."""
-    blocks(64, 64, "pallas_onepass")
+    flash_blocks(64, 64, "pallas_onepass")
     q, k, v, weight = inputs(seq=128, heads=heads, kv_heads=kv_heads, d=16)
     for window in (None, 48):
         got = out_and_grads(lambda *a: pk.flash_attention(*a, window=window),
@@ -174,11 +199,11 @@ def test_a_ragged_sequence_takes_the_xla_form_with_its_window():
                                   q, k, v, weight)) < 2e-6
 
 
-def test_no_window_is_the_program_it_was(blocks):
+def test_no_window_is_the_program_it_was(flash_blocks):
     """``window=None`` (and a window that holds the whole triangle) traces
     to the call of before: the same kernels, names and grids, and the same
     numbers to the bit."""
-    blocks(64, 128)
+    flash_blocks(64, 128, "pallas")
     q, k, v, weight = inputs()
 
     def traced(**window):
@@ -197,34 +222,42 @@ def test_no_window_is_the_program_it_was(blocks):
     assert off(a, b) == 0.0
 
 
-@pytest.mark.parametrize("seq, block_q, block_k, window, steps", [
-    (8192, 512, 512, 512, (2, 2)), (8192, 512, 1024, 512, (2, 3)),
-    (8192, 256, 256, 512, (3, 3)), (8192, 128, 128, 512, (5, 5)),
-    (256, 64, 64, 1, (1, 1)), (256, 64, 128, 65, (2, 3)),
-    (256, 64, 64, 255, (4, 4))])
+# (seq, block_q, block_k, window, pairs a head): laguna's plan, oblong and
+# narrower blocks at its window, a window of one key, one just wider than a
+# query block, one a key short of the sequence.
+@pytest.mark.parametrize("seq, block_q, block_k, window, pairs", [
+    (8192, 512, 512, 512, 31), (8192, 512, 1024, 512, 23),
+    (8192, 256, 256, 512, 93), (8192, 128, 128, 512, 310),
+    (256, 64, 64, 1, 4), (256, 64, 128, 65, 5), (256, 64, 64, 255, 10)])
 def test_the_banded_grids_visit_the_band_alone(seq, block_q, block_k, window,
-                                               steps):
-    """The inner axes are as long as the widest block's band, every block
-    the band touches is visited once, and no other: a dead block costs
-    neither a product nor a copy (a step past the band holds the index of
-    the step before, so the pipeline fetches nothing)."""
-    assert pk._band_steps(seq, block_q, block_k, window) == steps
-    nq, nk = seq // block_q, seq // block_k
-    touched = {(j, t) for j in range(nq) for t in range(nk)
-               if t * block_k <= j * block_q + block_q - 1
-               and t * block_k + block_k - 1 > j * block_q - window}
-    index = pk._k_spec(block_q, block_k, 128, window).index_map
-    by_query = [[int(index(0, j, u)[1]) for u in range(steps[0])]
-                for j in range(nq)]
-    assert {(j, t) for j, row in enumerate(by_query) for t in row} == touched
-    for row in by_query:        # ascending, the last one held
-        assert row == sorted(row) and len(set(row)) >= 1
-    by_key = {(j, t) for t in range(nk) for j in range(nq)
-              if int(pk._band_first_q(t, block_q, block_k)) <= j
-              <= int(pk._band_last_q(t, block_q, block_k, window, nq))}
-    assert by_key == touched
-    assert max(sum(1 for j, t in touched if t == key)
-               for key in range(nk)) == steps[1]
+                                               pairs):
+    """A banded call's schedule visits every block pair the band touches
+    once and no other, in both orders, a row's partners together and
+    ascending; a pair is masked where the diagonal or the band's far edge
+    cuts it: a dead block costs neither a step, a product nor a copy."""
+    rows, cols = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    seen = (cols <= rows) & (cols > rows - window)
+    blocks = seen.reshape(seq // block_q, block_q, seq // block_k, block_k)
+    touched = {(j, t): not blocks[j, :, t].all()
+               for j in range(seq // block_q) for t in range(seq // block_k)
+               if blocks[j, :, t].any()}
+    assert len(touched) == pairs
+    by_query, by_key = pk._block_schedule(seq, block_q, block_k, True, window)
+    for sweep, row_of, in_row in ((by_query, by_query.q, by_query.k),
+                                  (by_key, by_key.k, by_key.q)):
+        visited = list(zip(sweep.q.tolist(), sweep.k.tolist()))
+        assert len(visited) == len(set(visited))
+        assert set(visited) == set(touched)
+        assert [touched[pair] for pair in visited] == sweep.masked.tolist()
+        assert row_of.tolist() == sorted(row_of.tolist())
+        starts = np.flatnonzero(np.diff(row_of, prepend=-1))
+        ends = np.flatnonzero(np.diff(row_of, append=-1))
+        assert sweep.first.tolist() == np.isin(np.arange(len(visited)),
+                                               starts).tolist()
+        assert sweep.last.tolist() == np.isin(np.arange(len(visited)),
+                                              ends).tolist()
+        for a, b in zip(starts, ends):
+            assert (np.diff(in_row[a:b + 1]) == 1).all()
 
 
 @pytest.mark.parametrize("heads", [2, 4])
@@ -255,32 +288,33 @@ def test_several_heads_a_grid_step_are_the_heads_one_by_one(heads):
     traced = str(jax.make_jaxpr(lambda *a: pk._flash_attention_fwd_flat(
         *a, causal=True, block_q=64, block_k=64, interpret=True, window=100,
         heads=heads))(q, k, v))
-    assert "grid=(%d, 4, 3)" % (4 // heads) in traced
+    assert "grid=(%d, 9)" % (4 // heads) in traced     # nine live pairs
     assert [pk._heads_of(n, 512) for n in (128, 96, 6, 7)] == [4, 4, 2, 1]
     assert pk._heads_of(128, None) == 1
 
 
 # sha256 of a banded call's lowered text (forward and the three gradients,
-# interpreted, ``as_text()``) at PR 35 (commit 836838e), before a full call's
-# grid became a schedule of block pairs: the banded kernels, their grids and
-# their index maps are the parent's to the letter.  ``(block_q, block_k,
-# HVD_TPU_FLASH_BWD, query heads)``; a PR that changes the banded path on
-# purpose computes these again.
+# interpreted, ``as_text()``) since the banded calls run on the full calls'
+# schedule of block pairs (computed again on purpose then: the kernels,
+# grids and index maps changed, the numbers did not).  ``(block_q,
+# block_k, backward form: "pallas" the two kernels, "pallas_onepass" the
+# one, query heads)``; a PR that changes the banded path on purpose
+# computes these again.
 BANDED_PARENTS = {
     (64, 64, "pallas", 2):
-        "201696d4cb0f5dff5f6a626610b6622715c3f82aa2c3ac588256a7d75bed3765",
+        "042b02d132470ed3709b8a5161276c6a9f20a76aa06603609cb72e8c8bf7fb13",
     (64, 128, "pallas_onepass", 4):
-        "32404b745495adfd9cb91c083ab0df63f89ea195e8e8cfecd349f15f0b648f68",
+        "3a1322cec06265304c364b3b5496b9a2616d5517d8b030e5715aba45b88082b5",
     (128, 64, "pallas_onepass", 4):
-        "1c33044caf9d50a84d135904aea74814c0b81168874c8982627c035783077f5a",
+        "8319e0f0de1a78a956c2cdf95db081f5ca019f6fa26653a7ab2ea6b0a8c725f5",
 }
 
 
 @pytest.mark.parametrize("block_q, block_k, backward, heads",
                          sorted(BANDED_PARENTS))
-def test_a_banded_call_lowers_to_the_parents_text(blocks, block_q, block_k,
-                                                  backward, heads):
-    blocks(block_q, block_k, backward)
+def test_a_banded_call_lowers_to_the_parents_text(flash_blocks, block_q,
+                                                  block_k, backward, heads):
+    flash_blocks(block_q, block_k, backward)
     q, k, v, weight = inputs(heads=heads)
     text = jax.jit(jax.grad(
         lambda *a: (pk.flash_attention(*a, window=100) * weight).sum(),
@@ -289,8 +323,8 @@ def test_a_banded_call_lowers_to_the_parents_text(blocks, block_q, block_k,
         == BANDED_PARENTS[block_q, block_k, backward, heads]
 
 
-def test_the_banded_calls_carry_their_own_scopes_and_names(blocks):
-    blocks(64, 64)
+def test_the_banded_calls_carry_their_own_scopes_and_names(flash_blocks):
+    flash_blocks(64, 64, "pallas")
     q, k, v, weight = inputs()
 
     def names(**window):
@@ -314,26 +348,15 @@ def test_the_banded_calls_carry_their_own_scopes_and_names(blocks):
         assert scope in lowered
 
 
-def test_the_plan_takes_the_window_as_a_third_key(monkeypatch):
-    for name in ("HVD_TPU_FLASH_BLOCK_Q", "HVD_TPU_FLASH_BLOCK_K"):
-        monkeypatch.delenv(name, raising=False)
-    monkeypatch.setattr(pk, "_TUNED_BLOCKS", {})
+def test_the_plan_takes_the_window_as_a_third_key():
     assert pk._plan(8192, 128)[:2] == pk._plan(8192, 128, None)[:2] \
         == (512, 1024)
     # no block wider than the band: a 1024-wide key block over 512 keys
     # would do twice the work
     assert pk._plan(8192, 128, 512)[:2] == (512, 512)
     assert pk._plan(8192, 128, 100)[:2] == (64, 64)
-    pk._TUNED_BLOCKS[(8192, 128)] = (256, 2048)
-    pk._TUNED_BLOCKS[(8192, 128, 512)] = (256, 256)
-    assert pk._plan(8192, 128)[:2] == (256, 2048)
-    assert pk._plan(8192, 128, 512)[:2] == (256, 256)
     assert pk._plan(8192, 128, 1024)[:2] == (512, 1024)
-    saved = pk.export_tuned_blocks()
-    assert saved == {"8192x128": [256, 2048], "8192x128x512": [256, 256]}
-    pk._TUNED_BLOCKS.clear()
-    pk.seed_tuned_blocks(dict(saved, **{"8192x128x512x1": [64, 64]}))
-    assert pk.export_tuned_blocks() == saved
+    assert pk._plan(8192, 128, 16)[:2] == (64, 64)
 
 
 @pytest.mark.parametrize("causal, window", [(False, 64), (True, 0),
@@ -344,8 +367,8 @@ def test_a_window_refuses_what_it_cannot_mean(causal, window):
         pk.flash_attention(q, k, v, causal=causal, window=window)
 
 
-def test_bfloat16_under_a_window_stays_near_float32(blocks):
-    blocks(64, 64)
+def test_bfloat16_under_a_window_stays_near_float32(flash_blocks):
+    flash_blocks(64, 64, "pallas")
     q, k, v, weight = inputs()
     want = plain(q, k, v, 100)
     got = pk.flash_attention(*(x.astype(jnp.bfloat16) for x in (q, k, v)),
