@@ -329,6 +329,17 @@ NAMES: Dict[str, Tuple[str, str]] = {
                    "weights' casts, the input, silu(a) * g, (d_a, d_g) and "
                    "the incoming gradient made once); counted as a layer is traced, like "
                    "hvd_flash_backward_calls_total"),
+    "hvd_indexer_scored_pairs_total": (
+        "counter", "(query, key) pairs the lightning indexers of a step's "
+                   "sparse-attention layers score (every causal pair of a "
+                   "sequence; models/transformer.py: _count_sparse_pairs), "
+                   "counted as a step is traced: once a traced step, "
+                   "every layer of the scan with its own"),
+    "hvd_sparse_attention_pairs_total": (
+        "counter", "(query, key) pairs a step's sparse attention attends "
+                   "over (a query's top_k kept keys, or every causal key it "
+                   "has where it has fewer), counted as a step is traced, "
+                   "like hvd_indexer_scored_pairs_total"),
     # -- lifecycle: the program's own set-up, timed on the host --
     "hvd_span_seconds": (
         "histogram", "wall time of one host span, labeled span (the host "

@@ -49,7 +49,14 @@ latent-attention layer's ``LATENT_ATTENTION``, inside ``ATTENTION`` too: both
 projections of the latent, its norm, the rotary turns, the flash kernels,
 ``wo``); ``KDA_FWD`` and ``KDA_BWD`` (the delta rule's two, inside
 ``KDA_CORE`` or ``GATED_DELTA_CORE``); ``SSD_FWD`` and ``SSD_BWD`` (the
-state-space scan's two, inside ``SSD_CORE``); ``kernel_name`` gives the
+state-space scan's two, inside ``SSD_CORE``).  A learned-sparse-attention
+layer's whole block is ``SPARSE_ATTENTION`` (inside ``ATTENTION``): inside
+it ``INDEXER`` is the lightning indexer's projections, scores, choice and
+loss, ``INDEXER_TOPK`` (inside ``INDEXER``) the kernel that chooses every
+query's keys, and ``SPARSE_FLASH_FWD`` and ``SPARSE_FLASH_BWD`` the attention
+over the chosen keys (``ops/sparse_kernels.py``; the indexer loss's
+gradient is computed inside the two backward kernels, so it is
+``SPARSE_FLASH_BWD``'s time).  ``kernel_name`` gives the
 same words as the ``name=`` of the call (``hvd_flash_fwd``), which is
 what the trace viewer prints for a Mosaic kernel.
 
@@ -115,6 +122,11 @@ KDA_FWD = "hvd.kda_fwd"
 KDA_BWD = "hvd.kda_bwd"
 SSD_FWD = "hvd.ssd_fwd"
 SSD_BWD = "hvd.ssd_bwd"
+SPARSE_ATTENTION = "hvd.sparse_attention"
+INDEXER = "hvd.indexer"
+INDEXER_TOPK = "hvd.indexer_topk"
+SPARSE_FLASH_FWD = "hvd.sparse_flash_fwd"
+SPARSE_FLASH_BWD = "hvd.sparse_flash_bwd"
 
 IMPORT = "hvd.import"
 INIT = "hvd.init"

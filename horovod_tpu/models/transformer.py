@@ -44,7 +44,10 @@ entry may hold in the mixer's place; ``attention`` and
 ``gated_nope_attention`` name two of its settings.  Multi-head latent
 attention (keys and values out of one low-rank latent, query/key heads of
 another size than the values', one rotary key for all heads) is a block of
-its own: a ``LatentAttention`` in the mixer's place.
+its own: a ``LatentAttention`` in the mixer's place.  So is learned sparse
+attention (a lightning indexer chooses every query's keys, GQA attends over
+them alone, and the indexer's own loss joins the model's): a
+``SparseAttention``.
 """
 
 from __future__ import annotations
@@ -60,7 +63,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..common import metrics, scopes
-from ..ops import pallas_kernels
+from ..ops import pallas_kernels, sparse_kernels
 from ..parallel.moe import SAVED as moe_saved_names
 from ..parallel.moe import (ExpertShare, MoeConfig, expert_share_ffn,
                             init_expert_share_params, moe_ffn)
@@ -174,6 +177,43 @@ class LatentAttention:
 
 
 @dataclasses.dataclass(frozen=True)
+class SparseAttention:
+    """Learned sparse attention (DeepSeek-V3.2's lightning indexer over
+    GQA): ``n_heads`` query heads over ``n_kv_heads`` key/value heads of the
+    model's head size, each head's q and k under an RMSNorm of its own
+    channels with one scale for all heads (Qwen3's ``q_norm`` / ``k_norm``),
+    the rotary table over the whole head.  An indexer of ``index_heads``
+    heads of ``index_dim`` over ONE key a position (LayerNorm with a bias),
+    the first half of each turned at the same theta (DeepSeek-V3.2's
+    share), reads the layer's input with no gradient, scores every causal
+    key of a query and keeps the ``top_k`` of the largest score; the
+    attention runs over those alone, and the indexer learns from the KL
+    divergence of its softmax over them from the attention's probabilities
+    there (``ops/sparse_kernels.py``), which the block adds to the loss."""
+    n_heads: int
+    n_kv_heads: int
+    index_heads: int
+    index_dim: int
+    top_k: int
+    rope: Rope = Rope()
+
+    def __post_init__(self):
+        if self.n_kv_heads < 1 or self.n_heads % self.n_kv_heads:
+            raise ValueError("%d key/value heads do not divide %d query "
+                             "heads" % (self.n_kv_heads, self.n_heads))
+        if min(self.index_heads, self.index_dim, self.top_k) < 1 \
+                or self.index_dim % 4:
+            raise ValueError("an indexer needs heads, keys to keep and a "
+                             "head size of a multiple of 4 (its first half "
+                             "turns by pairs), not %r" % (self,))
+
+    @property
+    def index_rope(self) -> Rope:
+        """The indexer's rotary table: the first half of its head."""
+        return Rope(theta=self.rope.theta, share=0.5)
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -215,7 +255,8 @@ class TransformerConfig:
     # work).  No-op at tp=1, so single-chip programs are unchanged.
     collective_matmul: bool = False
     # One period of the layer pattern, ((mixer, feed-forward), ...) out of
-    # MIXERS (or a SoftmaxAttention, or a LatentAttention) x FEED_FORWARDS,
+    # MIXERS (or a SoftmaxAttention, a LatentAttention or a
+    # SparseAttention) x FEED_FORWARDS,
     # either of which may be None (not both); n_layers less the leading
     # layers is a multiple of its length.
     layer_pattern: Tuple[Tuple[object, Optional[str]], ...] = (
@@ -250,15 +291,19 @@ class TransformerConfig:
                              "'dots_no_batch', got %r"
                              % (self.remat_policy,))
         for mixer, ffn in self.pairs:
-            if not (isinstance(mixer, (SoftmaxAttention, LatentAttention))
+            if not (isinstance(mixer, (SoftmaxAttention, LatentAttention,
+                                       SparseAttention))
                     or mixer in MIXERS + (None,)) \
                     or ffn not in FEED_FORWARDS + (None,) \
                     or (mixer is None and ffn is None):
                 raise ValueError("layer_pattern pairs a mixer of %s (or a "
-                                 "SoftmaxAttention or a LatentAttention) "
-                                 "with a feed-forward of "
+                                 "SoftmaxAttention, a LatentAttention or a "
+                                 "SparseAttention) with a feed-forward of "
                                  "%s, or holds one of them alone, not %r"
                                  % (MIXERS, FEED_FORWARDS, (mixer, ffn)))
+            if isinstance(mixer, SparseAttention) and self.post_norm:
+                raise ValueError("a sparse-attention block norms its input: "
+                                 "it has no post_norm form")
             if (mixer == "linear_attention" and not self.linear_attention) \
                     or (mixer == "state_space" and not self.state_space) \
                     or (ffn == "expert_share" and not self.experts):
@@ -293,6 +338,13 @@ class TransformerConfig:
             return SoftmaxAttention(self.n_heads, self.n_kv_heads,
                                     rope=None, gate=True)
         return None
+
+    @property
+    def sparse(self) -> bool:
+        """Whether a layer is learned sparse attention, whose indexer's
+        loss joins the model's."""
+        return any(isinstance(mixer, SparseAttention)
+                   for mixer, _ in self.pairs)
 
     @property
     def act_dtype(self):
@@ -339,6 +391,23 @@ def _init_layers(key, cfg: TransformerConfig, mixer, ffn, n: int):
             "wkv_b": norm(keys[3], (n, rank, h * (mixer.nope + mixer.v_dim)),
                           rank),
             "wo": norm(keys[4], (n, h * mixer.v_dim, d), h * mixer.v_dim),
+        })
+    elif isinstance(mixer, SparseAttention):
+        qh, kvh = mixer.n_heads, mixer.n_kv_heads
+        hi, di = mixer.index_heads, mixer.index_dim
+        more = jax.random.split(jax.random.fold_in(key, 14), 3)
+        layers.update({
+            "wq": norm(keys[1], (n, d, qh * hd), d),
+            "wk": norm(keys[2], (n, d, kvh * hd), d),
+            "wv": norm(keys[3], (n, d, kvh * hd), d),
+            "wo": norm(keys[4], (n, qh * hd, d), qh * hd),
+            "q_norm": jnp.ones((n, hd), pd),
+            "k_norm": jnp.ones((n, hd), pd),
+            "w_iq": norm(more[0], (n, d, hi * di), d),
+            "w_ik": norm(more[1], (n, d, di), d),
+            "ik_norm": jnp.ones((n, di), pd),
+            "ik_bias": jnp.zeros((n, di), pd),
+            "w_iw": norm(more[2], (n, d, hi), d),
         })
     elif kind is not None:
         qh, kvh = kind.n_heads, kind.n_kv_heads
@@ -419,6 +488,23 @@ def _layer_specs(cfg: TransformerConfig, mixer, ffn):
             "wkv_b": P(None, None, tp),
             "wo": P(None, tp, None),
         })
+    elif isinstance(mixer, SparseAttention):
+        # Heads over tp, each head's norm whole on every shard; the indexer
+        # is every shard's.  (The block refuses tp > 1: the indexer's
+        # target sums every head's probabilities.)
+        specs.update({
+            "wq": P(None, None, tp),
+            "wk": P(None, None, tp),
+            "wv": P(None, None, tp),
+            "wo": P(None, tp, None),
+            "q_norm": P(None, None),
+            "k_norm": P(None, None),
+            "w_iq": P(None, None, None),
+            "w_ik": P(None, None, None),
+            "ik_norm": P(None, None),
+            "ik_bias": P(None, None),
+            "w_iw": P(None, None, None),
+        })
     elif kind is not None:
         specs.update({
             "wq": P(None, None, tp),
@@ -448,7 +534,8 @@ def _layer_specs(cfg: TransformerConfig, mixer, ffn):
         specs.update({name: P(None, None, None, None)
                       for name in cfg.experts.names("we")})
         specs["router"] = P(None, None, None)
-        specs["router_bias"] = P(None, None)
+        if cfg.experts.scoring == "sigmoid":
+            specs["router_bias"] = P(None, None)
         if cfg.experts.d_shared:
             specs.update({name: P(None, None, None)
                           for name in cfg.experts.names("ws")})
@@ -656,6 +743,85 @@ def _latent_attention_block(x, lp, cfg: TransformerConfig,
         return _row_parallel_product(attn, lp["wo"].astype(x.dtype), cfg)
 
 
+def head_qk_norm(x, lp, cfg: TransformerConfig, kind, tables):
+    """``q, k, v`` ``[B, S, heads, head_dim]`` of a block whose q and k are
+    normed a head at a time (an RMSNorm over each head's channels, one scale
+    of ``head_dim`` for every head: Qwen3's ``q_norm`` / ``k_norm``) and
+    then turned by the kind's rotary table.  A head's norm needs no other
+    head, so the heads may lie on any tp shard."""
+    b, s, _ = x.shape
+    hd = cfg.head_dim
+    q, k, v = ((x @ lp[name].astype(x.dtype)).reshape(b, s, -1, hd)
+               for name in ("wq", "wk", "wv"))
+    q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+    k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    if kind.rope is not None:
+        cos, sin = tables[kind.rope]
+        q, k = _rope(cos, sin, q), _rope(cos, sin, k)
+    return q, k, v
+
+
+def layer_norm(x, scale, bias, eps):
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    return ((xf - mean) * lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def indexer_inputs(x, lp, cfg: TransformerConfig, kind: SparseAttention,
+                   tables):
+    """The lightning indexer's ``qI`` ``[B, S, HI, DI]``, ``kI`` ``[B, S,
+    DI]`` and weights ``[B, S, HI]`` float32 from the layer's normed input,
+    which it reads with no gradient: ``qI = x W_iq``, ``kI = LayerNorm(x
+    W_ik)`` (eps ``cfg.norm_eps``, a bias), the first half of each turned,
+    ``w = x W_iw / sqrt(HI DI)``."""
+    b, s, _ = x.shape
+    x = lax.stop_gradient(x)
+    qi = (x @ lp["w_iq"].astype(x.dtype)).reshape(
+        b, s, kind.index_heads, kind.index_dim)
+    ki = layer_norm(x @ lp["w_ik"].astype(x.dtype), lp["ik_norm"],
+                    lp["ik_bias"], cfg.norm_eps)
+    cos, sin = tables[("indexer", kind)]
+    qi = _rope(cos, sin, qi)
+    ki = _rope(cos, sin, ki[:, :, None])[:, :, 0]
+    w = jnp.matmul(x, lp["w_iw"].astype(x.dtype),
+                   preferred_element_type=jnp.float32) \
+        * (kind.index_heads * kind.index_dim) ** -0.5
+    return qi, ki, w
+
+
+@jax.named_scope(scopes.ATTENTION)
+def _sparse_attention_block(x, lp, cfg: TransformerConfig,
+                            kind: SparseAttention, tables, sp_size):
+    """Learned sparse attention with its projections: (the block's output,
+    the indexer's loss, the mean over this shard's queries of ``KL(p ||
+    softmax(I))`` over each query's kept keys).  The kernels of
+    ``ops/sparse_kernels.py`` where the models take kernels, the same
+    equations in XLA elsewhere."""
+    with jax.named_scope(scopes.SPARSE_ATTENTION):
+        if sp_size > 1 or lax.axis_size(cfg.tp_axis) > 1:
+            raise ValueError(
+                "a sparse-attention layer chooses every query's keys over "
+                "the whole sequence and its indexer's target sums every "
+                "head's probabilities: neither the sequence (%r) nor the "
+                "heads (%r) can be split" % (cfg.sp_axis, cfg.tp_axis))
+        b, s, _ = x.shape
+        q, k, v = head_qk_norm(x, lp, cfg, kind, tables)
+        with jax.named_scope(scopes.INDEXER):
+            qi, ki, w = indexer_inputs(x, lp, cfg, kind, tables)
+        if pallas_kernels.use_flash_attention():
+            attn, kl = sparse_kernels.sparse_attention(q, k, v, qi, ki, w,
+                                                       kind.top_k)
+        else:
+            attn, kl = sparse_kernels.sparse_attention_xla(q, k, v, qi, ki,
+                                                           w, kind.top_k)
+        out = _row_parallel_product(attn.reshape(b, s, -1),
+                                    lp["wo"].astype(x.dtype), cfg)
+        # The one tp shard's loss, as every shard's (tp is 1 here).
+        return out, lax.pmean(jnp.mean(kl), cfg.tp_axis)
+
+
 def _causal_attention(q, k, v, cfg: TransformerConfig, window, sp_size):
     """Causal softmax attention over ``[B, S, heads, head_dim]`` by
     whichever form the layout calls for."""
@@ -828,8 +994,10 @@ def _feed_forward(h, lp, cfg: TransformerConfig, ffn, sp_size):
 
 def hidden(params, tokens, cfg: TransformerConfig):
     """Per-shard decoder up to the final norm: tokens [B_loc, S_loc] ->
-    (x [B, S, d], aux, expert counts).  ``aux`` is the ``moe`` layers'
-    load-balancing losses summed; the counts are one ``[1, n_experts]``
+    (x [B, S, d], aux, expert counts, indexer loss).  ``aux`` is the ``moe``
+    layers' load-balancing losses summed; the indexer loss is the
+    sparse-attention layers' summed (None without such a layer); the
+    counts are one ``[1, n_experts]``
     array for each leading ``expert_share`` layer, then one ``[periods,
     n_experts]`` array for each ``expert_share`` layer of the period."""
     sp_size = lax.axis_size(cfg.sp_axis)
@@ -845,6 +1013,15 @@ def hidden(params, tokens, cfg: TransformerConfig):
         (mixer, rope_tables(pos, mixer.rope_dim, mixer.rope, cfg.act_dtype))
         for mixer in dict.fromkeys(m for m, _ in cfg.pairs
                                    if isinstance(m, LatentAttention)))
+    sparse = list(dict.fromkeys(m for m, _ in cfg.pairs
+                                if isinstance(m, SparseAttention)))
+    for mixer in sparse:
+        tables[mixer.rope] = rope_tables(pos, cfg.head_dim, mixer.rope,
+                                         cfg.act_dtype)
+        tables[("indexer", mixer)] = rope_tables(
+            pos, mixer.index_dim, mixer.index_rope, cfg.act_dtype)
+    if sparse:
+        _count_sparse_pairs(tokens.shape[0], s_loc, cfg)
 
     x = _sharded_embed_lookup(params["embed"], tokens, cfg.tp_axis)
     x = x.astype(cfg.act_dtype)
@@ -855,9 +1032,14 @@ def hidden(params, tokens, cfg: TransformerConfig):
         x = pvary_missing(x, (cfg.tp_axis,))
 
     def layer(mixer, ffn, carry, lp):
-        x, aux = carry
+        x, aux, *indexer = carry
         counts = ()
-        if mixer is not None:
+        if isinstance(mixer, SparseAttention):
+            y, kl = _sparse_attention_block(
+                rms_norm(x, lp["ln1"], cfg.norm_eps), lp, cfg, mixer, tables,
+                sp_size)
+            x, indexer = x + y, [indexer[0] + kl]
+        elif mixer is not None:
             if cfg.post_norm:
                 x = x + rms_norm(_mix(x, lp, cfg, mixer, tables, sp_size),
                                  lp["ln1"], cfg.norm_eps)
@@ -872,7 +1054,7 @@ def hidden(params, tokens, cfg: TransformerConfig):
                 h = rms_norm(x, lp["ln2"], cfg.norm_eps)
                 y, a, counts = _feed_forward(h, lp, cfg, ffn, sp_size)
             x, aux = x + y, aux if a is None else aux + a
-        return (x, aux), counts
+        return (x, aux, *indexer), counts
 
     layer_fns = [partial(layer, mixer, ffn) for mixer, ffn in cfg.pairs]
     if cfg.remat:
@@ -881,7 +1063,8 @@ def hidden(params, tokens, cfg: TransformerConfig):
         # state-space scan's chunk states, an expert layer's choice and
         # sort).
         pol = {"full": jax.checkpoint_policies.save_only_these_names(
-                   *kda_saved_names, *ssm_saved_names, *moe_saved_names),
+                   *kda_saved_names, *ssm_saved_names, *moe_saved_names,
+                   *sparse_kernels.SAVED),
                "dots": jax.checkpoint_policies.dots_saveable,
                "dots_no_batch":
                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
@@ -902,13 +1085,36 @@ def hidden(params, tokens, cfg: TransformerConfig):
     if any(ffn == "moe" for _, ffn in cfg.pairs):
         aux0 = pvary_missing(aux0, (cfg.dp_axis, cfg.sp_axis))
     n_lead = len(cfg.leading_layers)
+    # A sparse-attention layer's indexer loss, summed over the layers, rides
+    # the carry beside the auxiliary loss.
+    indexer0 = (pvary_missing(jnp.zeros((), jnp.float32),
+                              (cfg.dp_axis, cfg.sp_axis)),) \
+        if cfg.sparse else ()
     carry, lead_counts = run(
-        layer_fns[:n_lead], (x, aux0),
+        layer_fns[:n_lead], (x, aux0) + indexer0,
         jax.tree.map(lambda w: w[0], params.get("leading", ())))
-    (x, aux), counts = lax.scan(partial(run, layer_fns[n_lead:]), carry,
-                                params["layers"])
+    (x, aux, *indexer), counts = lax.scan(
+        partial(run, layer_fns[n_lead:]), carry, params["layers"])
     return (rms_norm(x, params["ln_f"], cfg.norm_eps), aux,
-            tuple(c[None] for c in lead_counts) + counts)
+            tuple(c[None] for c in lead_counts) + counts,
+            indexer[0] if indexer else None)
+
+
+def _count_sparse_pairs(batch: int, seq: int, cfg: TransformerConfig):
+    """The (query, key) pairs a step's sparse-attention layers score with
+    their indexers (every causal pair) and attend over (a query's ``top_k``
+    or every causal key it has, if fewer), counted as the step is traced."""
+    periods = (cfg.n_layers - len(cfg.leading_layers)) \
+        // len(cfg.layer_pattern)
+    for at, (mixer, _) in enumerate(cfg.pairs):
+        if isinstance(mixer, SparseAttention):
+            layers = 1 if at < len(cfg.leading_layers) else periods
+            k = min(mixer.top_k, seq)
+            kept = k * (k + 1) // 2 + (seq - k) * k
+            metrics.counter("hvd_indexer_scored_pairs_total").inc(
+                layers * batch * seq * (seq + 1) // 2)
+            metrics.counter("hvd_sparse_attention_pairs_total").inc(
+                layers * batch * kept)
 
 
 def _logits(x, params, cfg: TransformerConfig):
@@ -926,7 +1132,7 @@ def forward(params, tokens, cfg: TransformerConfig):
     Must run inside a shard_map over a mesh containing
     (dp_axis, sp_axis, tp_axis).  logits are [B, S, V/tp] in f32.
     """
-    x, aux, _ = hidden(params, tokens, cfg)
+    x, aux = hidden(params, tokens, cfg)[:2]
     with jax.named_scope(scopes.HEAD):
         logits = _logits(x, params, cfg)
     return logits, aux / cfg.n_layers
@@ -959,10 +1165,13 @@ def _nll_sum(x, targets, params, cfg: TransformerConfig):
 def loss_fn(params, batch, cfg: TransformerConfig):
     """Per-shard mean nll (+ MoE aux); psum-averaged over dp and sp."""
     tokens, targets = batch["tokens"], batch["targets"]
-    x, aux, _ = hidden(params, tokens, cfg)
+    x, aux, _, indexer = hidden(params, tokens, cfg)
     aux = aux / cfg.n_layers
     nll_mean = _nll_sum(x, targets, params, cfg) / targets.size
     loss = nll_mean + cfg.aux_loss_weight * aux
+    if indexer is not None:
+        # Each sparse-attention layer's indexer loss at weight 1.
+        loss = loss + indexer
     return lax.pmean(loss, (cfg.dp_axis, cfg.sp_axis))
 
 

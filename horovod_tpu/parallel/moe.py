@@ -200,11 +200,19 @@ class ExpertShare:
     # w2``; ``relu2`` two, ``relu(x w1)^2 w2``, and the tree then has no
     # ``we3`` / ``ws3``.
     form: str = "swiglu"
+    # How the router scores the experts: ``sigmoid`` (a score each, chosen
+    # under the ``router_bias`` balancing buffer) or ``softmax`` (one
+    # softmax over all of them and no buffer: the tree has no
+    # ``router_bias``).
+    scoring: str = "sigmoid"
 
     def __post_init__(self):
         if self.form not in _FORMS:
             raise ValueError("an expert is one of %s, not %r"
                              % (sorted(_FORMS), self.form))
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError("a router scores by sigmoid or softmax, not %r"
+                             % (self.scoring,))
         if not 0 <= self.first <= self.first + self.count <= self.n_experts:
             raise ValueError("experts %d..%d are not among %d"
                              % (self.first, self.first + self.count - 1,
@@ -233,7 +241,8 @@ def init_expert_share_params(key, share: ExpertShare, n: int,
     ``router_bias`` is the family's
     load-balancing buffer: added to the scores where the experts are
     chosen and nowhere else, so no gradient reaches it; it starts at zero
-    and is set from outside (a checkpoint, or a calibration)."""
+    and is set from outside (a checkpoint, or a calibration).  A softmax
+    router has none."""
     ks = jax.random.split(key, 7)
     d, f, fs = share.d_model, share.d_ff, share.d_shared
 
@@ -250,8 +259,9 @@ def init_expert_share_params(key, share: ExpertShare, n: int,
                 for name in share.names(prefix)}
 
     params = {"router": norm(ks[0], (n, d, share.n_experts), d),
-              "router_bias": jnp.zeros((n, share.n_experts), dtype),
               **expert("we", ks[1:4], (n, share.count), f)}
+    if share.scoring == "sigmoid":
+        params["router_bias"] = jnp.zeros((n, share.n_experts), dtype)
     if fs:
         params.update(expert("ws", ks[4:7], (n,), fs))
     return params
@@ -292,7 +302,8 @@ def route(x, router, bias, share: ExpertShare):
     ``top_k`` experts with the largest score + bias; their scores (without
     the bias) renormalised to sum to ``routed_scaling``.  Returns (weights
     ``[T, k]`` float32, ids ``[T, k]``; the ids carry a ``checkpoint_name``,
-    ``SAVED``).
+    ``SAVED``).  A ``softmax`` router scores by one softmax over the logits
+    of every expert and chooses by the scores alone (``bias`` is None).
 
     The product is float32 at ``Precision.HIGHEST`` whatever ``x`` is.  Of
     its six bfloat16 passes the TPU's compiler drops the three that
@@ -300,12 +311,17 @@ def route(x, router, bias, share: ExpertShare):
     which are zero (0.69 ms for ``[16384, 4096] x [4096, 320]``, the MXU's
     peak for three passes): a three-pass form written out by hand took as
     long and 60 MiB more (``PERF.md``, PR 30)."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
-    ids = checkpoint_name(_top_k_ids(
-        lax.stop_gradient(scores) + bias.astype(jnp.float32), share.top_k),
-        SAVED[0])
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    if share.scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        ids = checkpoint_name(_top_k_ids(lax.stop_gradient(scores),
+                                         share.top_k), SAVED[0])
+    else:
+        scores = jax.nn.sigmoid(logits)
+        ids = checkpoint_name(_top_k_ids(
+            lax.stop_gradient(scores) + bias.astype(jnp.float32),
+            share.top_k), SAVED[0])
     top = _take(scores, ids)
     return top / top.sum(-1, keepdims=True) * share.routed_scaling, ids
 
@@ -469,7 +485,7 @@ def expert_share_ffn(params, x, share: ExpertShare):
     layer got (``[n_experts]``, a device value; the held experts' are
     ``[first:first + count]``)."""
     with jax.named_scope(scopes.ROUTER):
-        weights, ids = route(x, params["router"], params["router_bias"],
+        weights, ids = route(x, params["router"], params.get("router_bias"),
                              share)
         order, loads = _sorted_pairs(ids, share)
         sizes = lax.dynamic_slice_in_dim(loads, share.first, share.count)

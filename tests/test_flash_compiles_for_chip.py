@@ -490,3 +490,37 @@ def test_no_swiglu_product_rebuilds_its_operands_on_v5e(one_chip, split):
     found, products = _logistic_in_product_operands(text, scopes.DENSE_FFN)
     assert products >= 6
     assert bool(found) != split, found
+
+
+# keye-vl2's learned sparse attention at the cell's shape: one sequence of
+# 32768, 32 query heads over 4 of 128, an indexer of 16 heads of 64 keeping
+# 2048 keys a query; the choice, the forward kernel and the indexer loss's,
+# with both backward kernels in bfloat16, and the forward alone in float32
+# (the benchmark's read before the first step), whose blocks take twice
+# the VMEM.
+@pytest.mark.parametrize("dtype, backward", [(jnp.bfloat16, True),
+                                             (jnp.float32, False)])
+def test_the_sparse_attention_kernels_compile_for_v5e(one_chip, dtype,
+                                                      backward):
+    from horovod_tpu.ops import sparse_kernels as sk
+    seq = 32768
+
+    def sds(shape, kind=dtype):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=one_chip)
+
+    args = (sds((1, seq, 32, 128)), sds((1, seq, 4, 128)),
+            sds((1, seq, 4, 128)), sds((1, seq, 16, 64)), sds((1, seq, 64)),
+            sds((1, seq, 16), jnp.float32))
+
+    def loss(*a):
+        o, kl = sk.sparse_attention(*a, 2048, interpret=False)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(kl)
+
+    step = jax.value_and_grad(loss, argnums=range(6)) if backward else loss
+    text = jax.jit(step).lower(*args).compile().as_text()
+    names = ["hvd_indexer_topk", "hvd_sparse_flash_fwd", "hvd_indexer_kl"]
+    if backward:
+        names += ["hvd_sparse_flash_bwd_dq", "hvd_sparse_flash_bwd_dkv"]
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert name in text, name
